@@ -25,13 +25,20 @@ DIAG_TOPIC = "diagnostics"
 
 
 def runtime_params() -> dict:
+    """What this process runs on: the device as JAX reports it, and
+    which storage codec (native .so or the NumPy fallback) is loaded."""
     import jax
 
+    from banyandb_tpu.utils import native
+
+    dev = jax.devices()[0]
     return {
         "python": sys.version.split()[0],
         "jax": jax.__version__,
-        "backend": jax.default_backend(),
+        "backend": dev.platform,
+        "device_kind": dev.device_kind,
         "device_count": jax.device_count(),
+        "codec": native.codec_name(),
         "pid": __import__("os").getpid(),
     }
 

@@ -496,10 +496,10 @@ def main(argv=None) -> int:
             print(f"no data dir under node root {root}", file=sys.stderr)
             return 2
         # the offline agent opens storage and may run query kernels:
-        # share the node's persistent XLA compile cache
+        # share the machine's persistent XLA compile cache
         from banyandb_tpu.utils import compile_cache
 
-        compile_cache.enable(root / "compile-cache")
+        compile_cache.enable()
         # refuse a root whose owning node process is still alive: a
         # second Shard owner over the same dirs loses in-flight writes
         pid_file = root / "data" / ".bydb-node.pid"

@@ -151,12 +151,6 @@ class WorkerClient:
         # module docstring); it must also never spawn a pool of its own
         env["BYDB_STREAMAGG_AUTOLOAD"] = "0"
         env["BYDB_WORKERS"] = "0"
-        if not env.get("BYDB_COMPILE_CACHE_DIR"):
-            # one shared persistent XLA cache for the whole fleet: the
-            # second worker's first plan compile is a disk hit
-            env["BYDB_COMPILE_CACHE_DIR"] = str(
-                self.root.parent / "compile-cache"
-            )
         pkg_root = str(Path(__file__).resolve().parents[2])
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (pkg_root, env.get("PYTHONPATH", "")) if p
@@ -235,9 +229,14 @@ class WorkerClient:
 
     def wait_ready(self, timeout: float = _SPAWN_TIMEOUT_S) -> None:
         if not self._ready.wait(timeout) or not self.alive:
+            log_path = self.root / "worker.log"
+            try:
+                tail = log_path.read_bytes()[-2000:].decode(errors="replace")
+            except OSError:
+                tail = ""
             raise TransportError(
                 f"worker {self.name} failed to start "
-                f"(exit={self.proc.poll()}, log={self.root / 'worker.log'})"
+                f"(exit={self.proc.poll()}, log={log_path}): {tail.strip()}"
             )
 
     # -- RPC ---------------------------------------------------------------
@@ -945,6 +944,26 @@ class WorkerPool:
             )
         return "\n".join(p for p in parts if p)
 
+    def runtimes(self) -> dict:
+        """worker name -> the runtime block of its diagnostics snapshot
+        (backend / device_kind / device_count): what each query-executing
+        process serves from.  A dead or unreachable worker maps to None."""
+        from banyandb_tpu.admin.diagnostics import DIAG_TOPIC
+
+        out: dict = {}
+        for i in range(self.n):
+            client = self._clients[i]
+            out[self._names[i]] = None
+            if client is None or not client.alive:
+                continue
+            try:
+                out[self._names[i]] = client.call(
+                    DIAG_TOPIC, {}, timeout=10.0
+                )["runtime"]
+            except TransportError:
+                continue
+        return out
+
     def stats(self) -> dict:
         return {
             "workers": self.n,
@@ -1417,9 +1436,14 @@ def worker_main(argv=None) -> int:
         fileno=args.fd
     )
     root = Path(args.root)
-    # workers share the pool's persistent XLA compile cache (the parent
-    # stamps BYDB_COMPILE_CACHE_DIR into the child env): plan kernels
-    # compile once per machine, not once per worker process
+    # a worker executes queries: it must hold the backend that was asked
+    # for (never a silent CPU fallback under a parent holding the chip)
+    from banyandb_tpu.utils import devices
+
+    devices.claim_backend(f"worker {args.name}")
+    # the fleet shares the machine's persistent XLA compile cache
+    # (utils/compile_cache: one fixed path for parent and children), so
+    # plan kernels compile once per machine, not once per worker process
     compile_cache.enable()
     registry = SchemaRegistry(root)
     node = DataNode(args.name, registry, root / "data")

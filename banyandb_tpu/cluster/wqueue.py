@@ -91,6 +91,13 @@ class WriteQueue:
         self._jitter = random.Random(0xBDB)
         # orphaned sealed parts from a previous process retry first
         self._pending: list[tuple[str, int, Path]] = self._recover_spool()
+        # parts a ship_pending call has taken off _pending and not yet
+        # put back or shipped: still pending to every observer, and
+        # flush() waits them out (the tick thread and a caller's flush
+        # run ship_pending concurrently)
+        self._inflight = 0
+        self._ships_idle = threading.Event()
+        self._ships_idle.set()
         # per-part byte sizes, measured ONCE (at seal/recovery) and
         # reused when the ship frees them
         self._part_bytes: dict[str, int] = {
@@ -388,49 +395,61 @@ class WriteQueue:
         now = time.monotonic()
         with self._lock:
             pending, self._pending = self._pending, []
+            if pending:
+                self._inflight += len(pending)
+                self._ships_idle.clear()
         shipped = failed = 0
         still: list[tuple[str, int, Path]] = []
-        for group, shard, part_dir in pending:
-            key = str(part_dir)
-            attempts, next_try = self._retry.get(key, (0, 0.0))
-            if not force and now < next_try:
-                still.append((group, shard, part_dir))  # not due yet
-                continue
-            try:
-                self.shipper(group, shard, part_dir)
-                shutil.rmtree(part_dir.parent, ignore_errors=True)
-                shipped += 1
-                with self._lock:
-                    self._retry.pop(key, None)
-                    freed = self._part_bytes.pop(key, 0)
-                    self._spool_bytes = max(0, self._spool_bytes - freed)
-                global_meter().counter_add("wqueue_shipped", 1.0)
-            except Exception:  # noqa: BLE001 - retried after backoff
-                attempts += 1
-                delay = min(
-                    self.retry_cap_s,
-                    self.retry_base_s * (2 ** (attempts - 1)),
-                )
-                delay *= 1.0 + 0.25 * self._jitter.random()
-                with self._lock:
-                    self._retry[key] = (attempts, time.monotonic() + delay)
-                still.append((group, shard, part_dir))
-                failed += 1
-                global_meter().counter_add("wqueue_ship_retry", 1.0)
-        with self._lock:
-            self._pending.extend(still)
-            global_meter().gauge_set("wqueue_spool_bytes", self._spool_bytes)
+        try:
+            for group, shard, part_dir in pending:
+                key = str(part_dir)
+                attempts, next_try = self._retry.get(key, (0, 0.0))
+                if not force and now < next_try:
+                    still.append((group, shard, part_dir))  # not due yet
+                    continue
+                try:
+                    self.shipper(group, shard, part_dir)
+                    shutil.rmtree(part_dir.parent, ignore_errors=True)
+                    shipped += 1
+                    with self._lock:
+                        self._retry.pop(key, None)
+                        freed = self._part_bytes.pop(key, 0)
+                        self._spool_bytes = max(0, self._spool_bytes - freed)
+                    global_meter().counter_add("wqueue_shipped", 1.0)
+                except Exception:  # noqa: BLE001 - retried after backoff
+                    attempts += 1
+                    delay = min(
+                        self.retry_cap_s,
+                        self.retry_base_s * (2 ** (attempts - 1)),
+                    )
+                    delay *= 1.0 + 0.25 * self._jitter.random()
+                    with self._lock:
+                        self._retry[key] = (attempts, time.monotonic() + delay)
+                    still.append((group, shard, part_dir))
+                    failed += 1
+                    global_meter().counter_add("wqueue_ship_retry", 1.0)
+        finally:
+            with self._lock:
+                self._pending.extend(still)
+                self._inflight -= len(pending)
+                if not self._inflight:
+                    self._ships_idle.set()
+                global_meter().gauge_set("wqueue_spool_bytes", self._spool_bytes)
         return shipped, failed
 
     def flush(self, *, force: bool = False) -> tuple[int, int]:
         """Seal everything and attempt shipping (one tick, also the test
-        hook)."""
+        hook).  Returns once no ship is in flight: parts a concurrent
+        tick took are delivered (or back on the pending list) too, so
+        "flushed" means what it says to the caller."""
         self.seal_all()
-        return self.ship_pending(force=force)
+        out = self.ship_pending(force=force)
+        self._ships_idle.wait(timeout=60.0)
+        return out
 
     def pending_parts(self) -> int:
         with self._lock:
-            return len(self._pending)
+            return len(self._pending) + self._inflight
 
     def buffered_rows(self) -> int:
         with self._lock:

@@ -38,6 +38,11 @@ class DataServer:
     """Data role: a DataNode behind a gRPC bus + lifecycle loops."""
 
     def __init__(self, root: str | Path, *, name: str = "", port: int = 0):
+        # data nodes run the scan kernels: persistent XLA compile cache,
+        # wired before any kernel compiles
+        from banyandb_tpu.utils import compile_cache
+
+        compile_cache.enable()
         self.root = Path(root)
         self.registry = SchemaRegistry(self.root)
         self.name = name or self.root.name or "data"

@@ -29,6 +29,13 @@ Columns (None = not measured for that row's kind):
   plan kernels carry none, the parallel/dist-step mesh variant carries
   exactly its psum(count/sums) + pmin/pmax set.
 
+``bytes_class``/``fusion_class`` are XLA **CPU**-compile classes: the
+audit lowers and compiles for the host platform (no chip in CI), so
+they track the installed jaxlib's CPU pipeline and say nothing about
+the TPU executable.  Last re-measured with jax 0.9.0 / jaxlib 0.9.0; a
+jaxlib upgrade that shifts a class re-measures the table, it is not a
+kernel regression.
+
 Legitimately changing a row: land the kernel change, run
 ``python -m banyandb_tpu.lint --check`` (or scripts/kernel_smoke.py),
 and copy the measured value the failure reports into the row — tighter
@@ -75,18 +82,18 @@ BUDGETS: dict[str, KernelBudget] = {
     # scan chunk; puts = padded chunk columns + traced predicate arrays.
     # columns: (dispatches, gets, puts, widest, bytes_class,
     #           fusion_class, collectives)
-    "measure/flat-count":      _b(1, 1, 5, 4, 19, 3, 0),
-    "measure/group-eq-lut":    _b(1, 1, 8, 4, 22, 4, 0),
-    "measure/percentile-hist": _b(1, 1, 6, 4, 24, 4, 0),
+    "measure/flat-count":      _b(1, 1, 5, 4, 20, 4, 0),
+    "measure/group-eq-lut":    _b(1, 1, 8, 4, 23, 5, 0),
+    "measure/percentile-hist": _b(1, 1, 6, 4, 25, 5, 0),
     "measure/or-expr":         _b(1, 1, 7, 4, 20, 3, 0),
-    "measure/topn-dashboard":  _b(1, 1, 7, 4, 22, 4, 0),
+    "measure/topn-dashboard":  _b(1, 1, 7, 4, 23, 5, 0),
     # fused whole-plan twins (query/fused_exec): ONE dispatch + ONE
     # batched get per part-batch regardless of chunk count — the
     # executor's raison d'être, ratcheted so staging can never creep
     # back; puts stay the staged column count (stacked ships).
-    "fused/flat-count":        _b(1, 1, 5, 4, 19, 4, 0),
-    "fused/group-eq-lut":      _b(1, 1, 8, 4, 22, 5, 0),
-    "fused/percentile-hist":   _b(1, 1, 6, 4, 24, 4, 0),
+    "fused/flat-count":        _b(1, 1, 5, 4, 20, 4, 0),
+    "fused/group-eq-lut":      _b(1, 1, 8, 4, 23, 5, 0),
+    "fused/percentile-hist":   _b(1, 1, 6, 4, 25, 5, 0),
     "fused/or-expr":           _b(1, 1, 7, 4, 20, 3, 0),
     "fused/topn-dashboard":    _b(1, 1, 7, 4, 23, 5, 0),
     # the staging tripwire: a 2-chunk part-batch, still 1 dispatch/get
@@ -100,17 +107,17 @@ BUDGETS: dict[str, KernelBudget] = {
     # puts grow by the LUT/ordinal ships, bytes_class is pinned so the
     # in-program decode can never double the traffic class, and
     # widest=4 proves the i8->i32 widen never leaks 64-bit
-    "fused+decode/flat-count":      _b(1, 1, 5, 4, 19, 4, 0),
-    "fused+decode/group-eq-lut":    _b(1, 1, 11, 4, 22, 5, 0),
-    "fused+decode/percentile-hist": _b(1, 1, 8, 4, 24, 4, 0),
+    "fused+decode/flat-count":      _b(1, 1, 5, 4, 20, 4, 0),
+    "fused+decode/group-eq-lut":    _b(1, 1, 11, 4, 23, 5, 0),
+    "fused+decode/percentile-hist": _b(1, 1, 8, 4, 25, 5, 0),
     "fused+decode/or-expr":         _b(1, 1, 9, 4, 20, 3, 0),
-    "fused+decode/topn-dashboard":  _b(1, 1, 10, 4, 23, 5, 0),
+    "fused+decode/topn-dashboard":  _b(1, 1, 10, 4, 24, 5, 0),
     # compressed multi-chunk tripwire: staging AND decode-stage
     # de-fusion both show up here first
     "fused+decode/multi-chunk":     _b(1, 1, 5),
     # fused chunked-scan mesh step: the whole distributed scan as one
     # collective program, SAME psum(count/sums)+pmin+pmax set
-    "fused/dist-step":         _b(widest=4, bytes_class=16, fusion_class=4, collectives=4),
+    "fused/dist-step":         _b(widest=4, bytes_class=16, fusion_class=5, collectives=4),
     # stream retrieval mask: whole bool mask in one get
     "stream/mask-eq-in":       _b(1, 1, 3, 4, 19, 1, 0),
     # the narrow-ship twin (i8 source codes widened on device): same
@@ -120,8 +127,8 @@ BUDGETS: dict[str, KernelBudget] = {
     "stream+decode/mask-eq-in": _b(1, 1, 3),
     # shared ops reductions every plan lowers onto (no executor path of
     # their own: jaxpr + lowering columns only)
-    "ops/group_reduce":        _b(widest=4, bytes_class=24, fusion_class=3, collectives=0),
-    "ops/group_histogram":     _b(widest=4, bytes_class=20, fusion_class=2, collectives=0),
+    "ops/group_reduce":        _b(widest=4, bytes_class=24, fusion_class=4, collectives=0),
+    "ops/group_histogram":     _b(widest=4, bytes_class=21, fusion_class=3, collectives=0),
     # shard_map mesh step: psum(count)+psum(sums)+pmin+pmax = 4
     # collectives (the hist/topn outputs reduce over already-combined
     # vectors)

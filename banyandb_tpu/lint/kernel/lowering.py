@@ -72,14 +72,10 @@ def mesh_entry():
         topn=4,
     )
     mesh = pmesh.make_mesh(1)
-    if hasattr(jax, "shard_map"):
-        _shard_map = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
     from jax.sharding import PartitionSpec as P
 
     data_spec = P(("shard", "seg"))
-    step = _shard_map(
+    step = jax.shard_map(
         partial(dist_exec._step, plan),
         mesh=mesh,
         in_specs=(
@@ -145,14 +141,10 @@ def fused_mesh_entry():
     )
     num_chunks = 2
     mesh = pmesh.make_mesh(1)
-    if hasattr(jax, "shard_map"):
-        _shard_map = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
     from jax.sharding import PartitionSpec as P
 
     data_spec = P(("shard", "seg"))
-    step = _shard_map(
+    step = jax.shard_map(
         partial(fused_exec._fused_dist_step, plan, num_chunks),
         mesh=mesh,
         in_specs=(

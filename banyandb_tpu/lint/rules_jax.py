@@ -1,7 +1,7 @@
 """Hot-path rules: host syncs, recompile churn, precision drift.
 
-These encode the project's JAX performance contract (ROADMAP north star,
-BENCH_r05.json): device work in query/, ops/, parallel/ and index/ must
+These encode the project's JAX performance contract (ROADMAP north
+star): device work in query/, ops/, parallel/ and index/ must
 not round-trip to the host per column, must not rebuild jit wrappers per
 call, and must not silently promote kernel inputs to float64.
 

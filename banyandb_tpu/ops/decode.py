@@ -28,14 +28,9 @@ def delta_decode(first, deltas):
     column heads (absolute timestamps) must be REBASED by the caller
     (the chunk pipeline's epoch-relative convention) or decoded with
     i64 deltas under host x64; a concrete out-of-range ``first`` raises
-    instead of silently wrapping.  On TPU the 1-D i32 shape class
-    routes through the tiled Pallas prefix-sum kernel
-    (ops/pallas_kernels.prefix_sum_narrow), bit-identical to the jnp
-    cumsum fallback below.
+    instead of silently wrapping.
     """
     import numpy as _np
-
-    import jax
 
     if deltas.dtype in (jnp.int8, jnp.int16):
         deltas = deltas.astype(jnp.int32)
@@ -48,18 +43,6 @@ def delta_decode(first, deltas):
             f"first={first} does not fit the i32 decode width; "
             "rebase it to an epoch offset (ts - epoch) or pass i64 deltas"
         )
-    if (
-        jax.default_backend() == "tpu"
-        and deltas.ndim == 1
-        and deltas.dtype == jnp.int32
-    ):
-        from banyandb_tpu.ops import pallas_kernels
-
-        if (deltas.shape[0] + 1) % pallas_kernels.TILE == 0:
-            x = jnp.concatenate(
-                [jnp.asarray(first, jnp.int32)[None], deltas]
-            )
-            return pallas_kernels.prefix_sum_narrow(x)
     first = jnp.asarray(first, dtype=deltas.dtype)
     rest = first[..., None] + jnp.cumsum(deltas, axis=-1, dtype=deltas.dtype)
     head = jnp.broadcast_to(first[..., None], rest.shape[:-1] + (1,))
@@ -166,10 +149,10 @@ def decode_chunk(chunk: dict) -> dict:
 
 
 def _maybe_pallas_widen(vals):
-    """Route the hot i8/i16 widen through the Pallas decode kernel on
-    TPU (ops/pallas_kernels.widen_narrow; bench r03 proved ~89 Gpoints/s
-    viability for this shape class); plain jnp elsewhere — the CPU
-    fallback the tests pin parity against."""
+    """Route the 1-D (staged-chunk) i8/i16 widen through the Pallas
+    decode kernel on TPU (ops/pallas_kernels.widen_narrow; speed not
+    measured on this installation); plain jnp elsewhere, which is what
+    the tests pin parity against."""
     import jax
 
     if jax.default_backend() != "tpu" or vals.ndim != 1:

@@ -10,8 +10,7 @@ of dictionary sizes.  Aggregation is then a dense segment reduction:
 - ``matmul``: one-hot(keys) @ values on the MXU in one shot — for modest
   group counts (<= ~4096) and row counts that fit a single operand.
 - ``matmul_tiled``: lax.scan over row tiles of MXU one-hot contractions.
-  Kept as an oracle/fallback; measured slower than pallas on real TPU
-  (docs/tpu_measurements.md) so ``auto`` never picks it.
+  Kept as an oracle/fallback; ``auto`` never picks it.
 - ``pallas``: the hand-tiled Pallas kernel (ops.pallas_kernels) for
   count/sums; min/max still ride XLA scatter.
 - ``sort``: segment-sort grouping — stable sort by key, then the same
@@ -19,10 +18,10 @@ of dictionary sizes.  Aggregation is then a dense segment reduction:
   high-radix regime of the hash-vs-sort crossover (arXiv 2411.13245).
 
 All produce identical results; ``method="auto"`` resolves through
-``select_group_method`` per shape and backend from the measured
-crossovers (sort above SORT_GROUPS_THRESHOLD groups on any backend;
-below it TPU: pallas for bounded group counts, else scatter; off-TPU:
-matmul for small operands, else scatter).
+``select_group_method`` per shape and backend (sort above
+SORT_GROUPS_THRESHOLD groups on any backend; below it TPU: pallas for
+bounded group counts, else scatter; off-TPU: matmul for small operands,
+else scatter).
 
 Precision contract (tested by tests/test_precision.py): per-group sums
 accumulate in f32 *within* a bounded row tile (<= 65536 rows for scatter,
@@ -157,22 +156,19 @@ def select_group_method(nrows: int, num_groups: int) -> str:
     and the ``sort`` path is stable-sorted, keeping per-group
     accumulation in row order (bit-identical to ``scatter``).
 
-    Measured on a real v5e-1 (2026-07-29, docs/tpu_measurements.md): the
-    Pallas kernel is best-or-equal at every (N, G) tried — 11.3 Grows/s
-    at N=2^23 standalone vs 5.8 for one-shot matmul (which also OOMs
-    once N*(G+1) f32 exceeds HBM) and ~15 Mrows/s for eager scatter /
-    matmul_tiled, which drown in per-op dispatch.  Inside a fused jit
-    XLA's scatter reaches HBM bandwidth too, but pallas never loses, so
-    TPU takes it for bounded group counts (4 group tiles at GTILE=2048:
-    each extra tile re-streams the whole input from HBM).  Off-TPU,
-    pallas only interprets; one-hot matmul wins small operands.  Above
-    SORT_GROUPS_THRESHOLD groups (either backend) the accumulator table
-    no longer fits close storage and segment-sort grouping takes over
-    per the 2411.13245 crossover.
+    The crossovers below are not measured on this installation: no
+    chip run of this code records which method wins at which (N, G).
+    What the routing encodes is what each method needs — TPU takes the
+    Pallas kernel for bounded group counts (8 group tiles at
+    GTILE=1024: each extra tile re-streams the whole input from HBM)
+    and XLA scatter above that; off-TPU pallas only interprets, so
+    one-hot matmul serves small operands and scatter the rest.  Above
+    SORT_GROUPS_THRESHOLD groups (either backend) segment-sort grouping
+    takes over per the 2411.13245 crossover.
     """
     if num_groups > SORT_GROUPS_THRESHOLD:
         return "sort"
-    if jax.default_backend() == "tpu" and num_groups <= 4 * 2048:
+    if jax.default_backend() == "tpu" and num_groups <= 8192:
         return "pallas"
     if num_groups <= 4096 and nrows * (num_groups + 1) <= 2**25:
         return "matmul"

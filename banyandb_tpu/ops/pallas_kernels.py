@@ -13,7 +13,9 @@ every row step — TPU grids execute sequentially, so read-modify-write
 accumulation across steps is sound).
 
 Precision contract (shared with ops.group_reduce): each row tile's
-partial is an f32 MXU contraction over TILE=2048 rows; tile partials are
+partial is a full-precision f32 MXU contraction over TILE=2048 rows
+(Precision.HIGHEST — the MXU's default single bf16 pass breaks the
+contract, measured on the v5e); tile partials are
 combined with Kahan-compensated f32 accumulation across grid steps, so
 the cross-tile error stays O(eps) independent of row count (instead of
 O(n_tiles * eps) for naive f32 accumulation).
@@ -37,13 +39,22 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def _out_struct(shape, dtype, *inputs) -> jax.ShapeDtypeStruct:
+    """Output type of a pallas_call over ``inputs``: inside
+    ``jax.shard_map`` the result varies over every manual mesh axis any
+    input varies over, and shard_map's type check needs that declared
+    (outside shard_map the set is empty)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in inputs))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
 # Group-dimension tile: bounds the [GTILE, TILE] one-hot operand
-# (2048x2048 f32 = 16 MiB) plus the [F, GTILE] accumulator blocks in
-# VMEM, so group counts in the tens of thousands compile instead of
-# exhausting VMEM.  16 MiB leaves little headroom beyond a few fields on
-# a 128 MiB-VMEM v5e — verified to compile at G=40k; shrink GTILE before
-# growing anything else here.
-GTILE = 2048
+# (1024x2048 f32 = 8 MiB) plus the [F, GTILE] accumulator blocks in
+# VMEM.  The v5e's scoped-VMEM default is 16 MiB: a 2048-wide tile's
+# 16 MiB one-hot alone exceeds it (RESOURCE_EXHAUSTED at compile, seen
+# on the chip at G >= 2048), 1024 compiles with up to 3 fields; shrink
+# GTILE before growing anything else here.
+GTILE = 1024
 
 
 def _fused_kernel(
@@ -88,11 +99,20 @@ def _fused_kernel(
     )
     onehot_t = (gids == codes).astype(jnp.float32)  # [GTILE, TILE]
     dn = (((1,), (1,)), ((), ()))
+    # Mosaic's default f32 contraction is ONE bf16 pass.  Count operands
+    # are 0/1 (exact in bf16), so the default is exact there; field
+    # values are not, and a single pass rounds each to 8 mantissa bits
+    # (~1e-3 relative on the chip) — the sums contract at full f32
+    # precision to hold the 1e-5 contract.
     cnt_p = jax.lax.dot_general(
         mask, onehot_t, dn, preferred_element_type=jnp.float32
     )  # [1, GTILE]
     sum_p = jax.lax.dot_general(
-        vals * mask, onehot_t, dn, preferred_element_type=jnp.float32
+        vals * mask,
+        onehot_t,
+        dn,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # [F, GTILE] — one contraction, all fields
 
     # Kahan-compensated add of this tile's partials into the accumulators.
@@ -160,19 +180,18 @@ def fused_group_multi(
     cacc_spec = pl.BlockSpec((1, gt), lambda j, i: (0, j))
     sacc_spec = pl.BlockSpec((nf, gt), lambda j, i: (0, j))
 
+    operands = (codes2, pred2, values, valid2)
     count, total, ccomp, scomp = pl.pallas_call(
         _fused_kernel,
         grid=grid,
         in_specs=[row_spec, row_spec, val_spec, row_spec],
         out_specs=(cacc_spec, sacc_spec, cacc_spec, sacc_spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((1, gpad), jnp.float32),
-            jax.ShapeDtypeStruct((nf, gpad), jnp.float32),
-            jax.ShapeDtypeStruct((1, gpad), jnp.float32),
-            jax.ShapeDtypeStruct((nf, gpad), jnp.float32),
+        out_shape=tuple(
+            _out_struct((rows, gpad), jnp.float32, *operands)
+            for rows in (1, nf, 1, nf)
         ),
         interpret=interpret,
-    )(codes2, pred2, values, valid2)
+    )(*operands)
     # Fold the residual compensation back in (classic Kahan final step;
     # the compensation holds the negated running error).
     return (
@@ -181,12 +200,12 @@ def fused_group_multi(
     )
 
 
-# -- device-side decode kernels (ROADMAP item 3) -----------------------------
+# -- device-side decode kernel ------------------------------------------------
 # The compressed-ship path (storage/encoded.py + ops/decode.py) lands
-# narrow i8/i16 columns in HBM; these kernels widen them at VMEM tile
-# granularity.  bench r03 measured the Pallas decode shape class at
-# ~89 Gpoints/s — the jnp fallback (ops.decode.widen_codes / a plain
-# jnp.cumsum) is what runs on CPU and is what the parity tests pin.
+# narrow i8/i16 columns in HBM; this kernel widens them at VMEM tile
+# granularity on the staged (1-D chunk) path.  Its speed is not measured
+# on this installation; ops.decode.widen_codes (a plain astype) is what
+# runs off-TPU and what the parity tests pin.
 
 
 def _widen_kernel(x_ref, out_ref):
@@ -208,49 +227,7 @@ def widen_narrow(x: jax.Array, *, interpret: bool = False) -> jax.Array:
         grid=(n // TILE,),
         in_specs=[pl.BlockSpec((1, TILE), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, TILE), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
-        interpret=interpret,
-    )(x2)
-    return out[0]
-
-
-def _prefix_sum_kernel(x_ref, out_ref, carry_ref):
-    # Sequential TPU grid: tile i adds the running total of tiles < i
-    # (carried in a [1, 1] output block every step revisits) to its own
-    # in-tile cumsum — an exact integer prefix sum across the column.
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        carry_ref[:] = jnp.zeros_like(carry_ref)
-
-    c = jnp.cumsum(x_ref[:].astype(jnp.int32), axis=-1) + carry_ref[0, 0]
-    out_ref[:] = c
-    carry_ref[0, 0] = c[0, -1]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def prefix_sum_narrow(x: jax.Array, *, interpret: bool = False) -> jax.Array:
-    """Inclusive i32 prefix sum of a narrow delta column [N] (N a TILE
-    multiple) — the delta-decode hot loop: with x[0] = first and
-    x[1:] = deltas, out IS the decoded series (ops.decode.delta_decode's
-    fixed-width contract).  Exact integer math, so the jnp.cumsum
-    fallback is bit-identical."""
-    n = x.shape[0]
-    assert n % TILE == 0, f"N={n} must be a multiple of {TILE}"
-    x2 = x.reshape(1, n)
-    out, _carry = pl.pallas_call(
-        _prefix_sum_kernel,
-        grid=(n // TILE,),
-        in_specs=[pl.BlockSpec((1, TILE), lambda i: (0, i))],
-        out_specs=(
-            pl.BlockSpec((1, TILE), lambda i: (0, i)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((1, n), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        ),
+        out_shape=_out_struct((1, n), jnp.int32, x2),
         interpret=interpret,
     )(x2)
     return out[0]

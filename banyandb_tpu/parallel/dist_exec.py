@@ -125,13 +125,7 @@ def build_distributed_step(mesh: Mesh, plan: DistPlan):
         return cached
     data_spec = P(("shard", "seg"))
 
-    # jax.shard_map is top-level only from 0.5; 0.4.x ships it under
-    # jax.experimental — resolve whichever this runtime has
-    if hasattr(jax, "shard_map"):
-        _shard_map = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    step = _shard_map(
+    step = jax.shard_map(
         partial(_step, plan),
         mesh=mesh,
         in_specs=(

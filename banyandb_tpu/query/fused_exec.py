@@ -457,21 +457,28 @@ def _fused_dist_step(
             hist = _kahan_add(hist[0], hist[1], h)
         return (count, sums, mins, maxs, hist), None
 
-    init = (
-        (zero, zero),
-        {f: (zero, zero) for f in plan.fields},
-        {f: jnp.full(G, jnp.inf, jnp.float32) for f in plan.fields},
-        {f: jnp.full(G, -jnp.inf, jnp.float32) for f in plan.fields},
+    axes = ("shard", "seg")
+    # the carry becomes device-varying after one step (each device folds
+    # its own slice), so the init must be typed varying over the manual
+    # axes too or the scan's carry types do not match
+    init = jax.lax.pcast(
         (
-            (jnp.zeros((G, nhb), jnp.float32),) * 2
-            if plan.want_hist
-            else (zero, zero)
+            (zero, zero),
+            {f: (zero, zero) for f in plan.fields},
+            {f: jnp.full(G, jnp.inf, jnp.float32) for f in plan.fields},
+            {f: jnp.full(G, -jnp.inf, jnp.float32) for f in plan.fields},
+            (
+                (jnp.zeros((G, nhb), jnp.float32),) * 2
+                if plan.want_hist
+                else (zero, zero)
+            ),
         ),
+        axes,
+        to="varying",
     )
     (count, sums, mins, maxs, hist), _ = jax.lax.scan(step, init, chunks)
 
     # ---- the collective reduce: ICI replaces the proto partial hop ----
-    axes = ("shard", "seg")
     out = {
         "count": jax.lax.psum(count[0] - count[1], axes),
         "sums": {
@@ -512,12 +519,8 @@ def build_fused_dist_step(mesh, plan, num_chunks: int):
 
     from jax.sharding import PartitionSpec as P
 
-    if hasattr(jax, "shard_map"):
-        _shard_map = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
     data_spec = P(("shard", "seg"))
-    step = _shard_map(
+    step = jax.shard_map(
         partial(_fused_dist_step, plan, num_chunks),
         mesh=mesh,
         in_specs=(

@@ -1157,8 +1157,8 @@ def _reduce_partials(
     # host half = narrow pack + pad + H2D ship (pad_ship_s, overlapped
     # with device execution under BYDB_PIPELINE); the device half
     # (widen/remap/f32 convert) is fused into the plan dispatch and is
-    # deliberately part of device_execute.  Byte counters make the
-    # compression win attributable even on a cpu-fallback bench run.
+    # deliberately part of device_execute.  Byte counters attribute the
+    # compression ratio independently of the platform.
     decode_ms = sum(pad_ship_s) * 1000
     shipped_bytes = sum(s for s, _ in ship_stats)
     dense_bytes = sum(d for _, d in ship_stats)

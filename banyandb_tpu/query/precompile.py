@@ -33,10 +33,13 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import logging
 import os
 import threading
 from pathlib import Path
 from typing import Optional
+
+log = logging.getLogger("banyandb.precompile")
 
 MAX_STORED = 64
 
@@ -689,8 +692,14 @@ class PrecompileRegistry:
             try:
                 self._compile_one(kind, spec)
                 done += 1
-            except Exception:  # noqa: BLE001 — warm must never take a server down
+            except Exception:  # noqa: BLE001 — warm must never take a
+                # server down, but a kernel the compiler refuses must not
+                # leave one that merely LOOKS warm: count it (the
+                # precompile_errors gauge) and log the signature
                 self.errors += 1
+                log.exception(
+                    "precompile failed for %s signature %r", kind, spec
+                )
         self.compiled += done
         return done
 

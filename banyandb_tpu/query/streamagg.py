@@ -388,6 +388,23 @@ class StreamAggRegistry:
         from banyandb_tpu.qos.tenancy import tenant_of_group as _tenant_of
 
         _tenant = _tenant_of(group)
+        if sig.origin == "auto":
+            # the autoreg budget is enforced BEFORE the backfill: a
+            # signature that cannot fit it must not gather and intern
+            # every row of the measure under this registry's lock first
+            # and be evicted after (at 10M rows x 100k series that
+            # backfill ran for minutes and stalled queries and /metrics)
+            from banyandb_tpu.query import planner as _planner
+
+            est = self._estimate_states(spec)
+            if est * _planner._STATE_BYTES > (
+                _planner.autoreg_max_state_mb() << 20
+            ):
+                raise ValueError(
+                    f"streamagg: auto signature {spec.label()} would "
+                    f"materialize ~{est} window states, over the "
+                    f"{_planner.autoreg_max_state_mb()} MB autoreg budget"
+                )
         with self._lock:
             if spec in self._sigs:  # raced a concurrent register
                 return self._stats_one_locked(self._sigs[spec])
@@ -540,6 +557,47 @@ class StreamAggRegistry:
         return len(recs)
 
     # -- backfill ------------------------------------------------------------
+    def _estimate_states(self, spec: SigSpec) -> int:
+        """Window states ``spec`` would materialize, bounded from part
+        and memtable METADATA only (no column reads, no registry lock):
+        min(rows, key cardinality x shards x windows in the data's time
+        extent).  Key cardinality is the product of the largest
+        per-source dictionary of each key tag — exact when sources share
+        their value population (the dashboard shape)."""
+        db = self.engine._tsdb(spec.group)
+        rows = 0
+        lo, hi = _POS_INF_TS, _NEG_INF_TS
+        card = {t: 1 for t in spec.key_tags}
+        for seg in db.select_segments(0, _POS_INF_TS):
+            for shard in seg.shards:
+                for mem in shard.hot_columns(spec.measure):
+                    n = int(mem.ts.size)
+                    if not n:
+                        continue
+                    rows += n
+                    lo = min(lo, int(mem.ts.min()))
+                    hi = max(hi, int(mem.ts.max()))
+                    for t in card:
+                        card[t] = max(card[t], len(mem.dicts.get(t) or ()))
+                for part in shard.parts:
+                    if part.meta.get("measure") != spec.measure:
+                        continue
+                    rows += part.total_count
+                    lo = min(lo, part.min_ts)
+                    hi = max(hi, part.max_ts)
+                    for t in card:
+                        card[t] = max(card[t], len(part.dict_for(t)))
+        if not rows:
+            return 0
+        keys = 1
+        for n in card.values():
+            keys *= n
+        shards = self.engine.registry.get_group(
+            spec.group
+        ).resource_opts.shard_num
+        windows = (hi - lo) // spec.window_millis + 1
+        return min(rows, keys * shards * windows)
+
     def _backfill_snapshot(self, spec: SigSpec) -> tuple[list, set]:
         """(batches, consumed part ids): one batch (ts, series, version,
         shards, keycols, fieldcols) per source the engine currently

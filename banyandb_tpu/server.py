@@ -12,6 +12,7 @@ health, snapshot.
 from __future__ import annotations
 
 import base64
+import os
 import time
 from pathlib import Path
 
@@ -142,6 +143,19 @@ class StandaloneServer:
         from banyandb_tpu.obs.metrics import global_meter
         from banyandb_tpu.utils.envflag import env_float, env_int
 
+        # worker count first, before anything opens: -1 = auto, resolved
+        # against the platform asked for because a chip belongs to one
+        # process (utils/devices.resolve_workers raises on an explicit
+        # fleet that could not get chips)
+        from banyandb_tpu.utils import compile_cache, devices
+
+        n_workers = devices.resolve_workers(
+            workers if workers is not None else env_int("BYDB_WORKERS", 0),
+            devices.platform_asked(),
+            os.cpu_count() or 1,
+        )
+        # persistent XLA compile cache, wired before any kernel compiles
+        compile_cache.enable()
         self.root = Path(root)
         self.registry = SchemaRegistry(self.root)
         self.measure = MeasureEngine(self.registry, self.root / "data")
@@ -156,9 +170,6 @@ class StandaloneServer:
         # parent engines above then hold no data-plane rows; they keep
         # serving the property plane and schema state.
         self.pool = None
-        n_workers = (
-            workers if workers is not None else env_int("BYDB_WORKERS", 0)
-        )
         if n_workers > 0:
             from banyandb_tpu.cluster.workers import (
                 PoolMeasureAdapter,
@@ -603,7 +614,7 @@ class StandaloneServer:
                 "qos_inflight_bytes", float(used), {"tenant": tenant}
             )
         pr = default_registry().stats()
-        for k in ("recorded", "compiled", "errors"):
+        for k in ("recorded", "compiled", "errors", "warming"):
             self.meter.gauge_set(f"precompile_{k}", float(pr[k]))
         ar = self.autoreg.stats()
         for k in ("known_signatures", "registered_total", "evicted_total"):
@@ -745,9 +756,13 @@ class StandaloneServer:
         from banyandb_tpu.admin.diagnostics import DiagnosticsCollector
 
         collector = DiagnosticsCollector(self.root, self.meter)
-        return collector.collect(
+        snap = collector.collect(
             include_threads=bool(env.get("include_threads"))
         )
+        if self.pool is not None:
+            # the workers execute the queries: report what each runs on
+            snap["workers"] = self.pool.runtimes()
+        return snap
 
     def _stream_write(self, env):
         self.disk.check_write()
@@ -1083,11 +1098,6 @@ def build_config():
     cfg.register("http-port", 17913, "HTTP/JSON gateway; -1 disables", int)
     cfg.register("pprof-port", -1, "profiling endpoints; -1 disables", int)
     cfg.register(
-        "compile-cache-dir", "",
-        "persistent XLA compile cache; empty = <root>/compile-cache, "
-        "'off' disables", str,
-    )
-    cfg.register(
         "slow-query-ms", 500.0,
         "slow-query threshold: queries at/over it get the access-log "
         "slow mark AND a flight-recorder entry (cli.py slowlog)", float,
@@ -1101,8 +1111,9 @@ def build_config():
         "workers", -1,
         "shard-owning worker processes for the data plane "
         "(BYDB_WORKERS env): N>0 partitions shards over N subprocesses, "
-        "0 = single-process layout, -1 = auto (on by default on hosts "
-        "with >= 4 cores)", int,
+        "0 = single-process layout, -1 = auto (a fleet only when "
+        "JAX_PLATFORMS=cpu was asked for and the host has >= 4 cores; a "
+        "chip belongs to one process)", int,
     )
     # role topology (pkg/cmdsetup/root.go:89-91 standalone/data/liaison)
     cfg.register("role", "standalone", "standalone | data | liaison", str)
@@ -1118,18 +1129,6 @@ def main(argv=None) -> None:
     from banyandb_tpu.run import FuncUnit, Group
 
     s = build_config().load(argv)
-    # persistent XLA compile cache, wired before any kernel compiles:
-    # plan kernels compile once per machine, not once per process.  The
-    # flag has already folded CLI > BYDB_COMPILE_CACHE_DIR env > config
-    # file precedence via config.py.
-    from pathlib import Path as _Path
-
-    from banyandb_tpu.utils import compile_cache
-
-    if s.compile_cache_dir:
-        compile_cache.enable_at(s.compile_cache_dir)
-    else:
-        compile_cache.enable_at(_Path(s.root) / "compile-cache")
     # an armed fault plane must be impossible to miss in a server log
     # (docs/robustness.md): chaos harnesses set it on purpose, a stray
     # env var in production must not inject faults silently
@@ -1182,6 +1181,21 @@ def main(argv=None) -> None:
                 file=_sys.stderr,
                 flush=True,
             )
+    from banyandb_tpu.utils import compile_cache, devices, native
+
+    if s.role in ("standalone", "data"):
+        # this process executes queries: claim the backend that was
+        # asked for now, and say at boot what the node runs on
+        compile_cache.enable()
+        runtime = devices.claim_backend(f"{s.role} server")
+        print(
+            f"banyandb-tpu {s.role}: backend={runtime['backend']} "
+            f"device_kind={runtime['device_kind']!r} "
+            f"devices={runtime['device_count']} "
+            f"codec={native.codec_name()} "
+            f"compile-cache={compile_cache.stats()['dir']}",
+            flush=True,
+        )
     if s.role == "data":
         from banyandb_tpu.cluster_server import DataServer
 
@@ -1225,14 +1239,12 @@ def main(argv=None) -> None:
     elif s.role != "standalone":
         raise SystemExit(f"unknown role {s.role!r}")
     else:
-        # on-by-default A/B flag (docs/performance.md "Multi-process
-        # data plane"): auto resolves to a worker fleet on hosts with
-        # enough cores to win from one; tiny hosts keep the
-        # single-process layout (a 2-core box convoys either way)
-        workers = s.workers
-        if workers < 0:
-            cpu = _os.cpu_count() or 1
-            workers = min(4, cpu // 2) if cpu >= 4 else 0
+        try:
+            workers = devices.resolve_workers(
+                s.workers, devices.platform_asked(), os.cpu_count() or 1
+            )
+        except ValueError as e:  # --workers N that no chip could serve
+            raise SystemExit(f"standalone server: {e}") from e
         srv = StandaloneServer(
             s.root,
             s.port,
@@ -1269,8 +1281,6 @@ def main(argv=None) -> None:
     group.run()
     # grpc's worker threads are non-daemon; an in-flight slow handler
     # (e.g. a TPU compile) must not wedge process exit after SIGTERM.
-    import os
-
     os._exit(0)
 
 

@@ -2,33 +2,33 @@
 
 The cold path pays one XLA compile per plan signature per PROCESS; on a
 restart every dashboard query recompiles kernels whose HLO has not
-changed.  Pointing ``jax_compilation_cache_dir`` at a directory that
-outlives the process (default: ``<data-root>/compile-cache``) makes plan
-kernels compile once per machine — the Tailwind-style "plans stay
-resident across restarts" property, at the XLA executable layer.
+changed.  A cache directory that outlives the process makes plan kernels
+compile once per machine.
 
-Resolution order for the directory, most specific wins:
+The directory resolves in two steps and nowhere else:
 
-    explicit CLI flag (``--compile-cache-dir``, via enable_at)
-      >  BYDB_COMPILE_CACHE_DIR env var (``off``/``0`` disables)
-      >  the caller's computed default (``enable(default_dir)``)
+1. ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; this module
+   leaves the directory alone (the operator, or the harness that runs
+   several processes against one chip, owns the placement).
+2. unset: ``FIXED_DIR``, one git-ignored path inside the checkout.  The
+   path is part of what XLA keys entries on, so it must not move between
+   runs or between a parent and its children — a per-run or per-root
+   directory never hits.
 
-Wiring is process-global and first-wins (the cache key hashes the whole
-HLO, so sharing one directory between roots is safe); ``stats()`` feeds
-the /metrics surface and the bench artifact.  Hit/miss counts come from
-jax's own monitoring events (``/jax/compilation_cache/cache_hits`` and
-``.../cache_misses``) so they reflect what XLA actually did, not what we
-hoped.
+Wiring is process-global and idempotent; every entry point that can
+dispatch a kernel calls ``enable()``.  ``stats()`` feeds the /metrics
+surface.  Hit/miss counts come from jax's own monitoring events
+(``/jax/compilation_cache/cache_hits`` and ``.../cache_misses``) so they
+reflect what XLA actually did, not what we hoped.
 """
 
 from __future__ import annotations
 
 import os
 import threading
+from pathlib import Path
 
-from banyandb_tpu.utils.envflag import env_str
-
-_DISABLE_VALUES = ("0", "off", "no", "none", "false", "disabled")
+FIXED_DIR = Path(__file__).resolve().parents[2] / ".compile-cache"
 
 _lock = threading.Lock()
 _state = {
@@ -39,6 +39,14 @@ _state = {
     "listener": False,
     "error": None,
 }
+
+
+def resolve_dir() -> tuple[str, bool]:
+    """-> (cache directory, whether JAX_COMPILATION_CACHE_DIR named it)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if env:
+        return env, True
+    return str(FIXED_DIR), False
 
 
 def _install_listener() -> None:
@@ -64,57 +72,34 @@ def _install_listener() -> None:
         _state["error"] = f"listener: {type(e).__name__}: {e}"
 
 
-def _wire(target: str) -> str | None:
+def enable() -> str | None:
+    """Wire the persistent cache; -> the active directory, or None when
+    the directory cannot be created (the cache is an optimization)."""
     with _lock:
         if _state["enabled"]:
-            return _state["dir"]  # first wiring wins (process-global)
+            return _state["dir"]
         import jax
 
+        target, from_env = resolve_dir()
         try:
             os.makedirs(target, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", target)
-            # default thresholds skip sub-second compiles — exactly the
-            # population a dashboard's plan kernels live in
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception as e:  # noqa: BLE001 — cache is an optimization
+        except OSError as e:
             _state["error"] = f"{type(e).__name__}: {e}"
             return None
+        if not from_env:
+            jax.config.update("jax_compilation_cache_dir", target)
+        # default thresholds skip sub-second compiles — exactly the
+        # population a dashboard's plan kernels live in
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         _install_listener()
         _state["enabled"] = True
         _state["dir"] = target
         return target
 
 
-def enable(default_dir=None) -> str | None:
-    """Enable the persistent cache; env overrides the computed default.
-
-    Returns the active directory, or None when disabled (env set to an
-    off-value, or no directory resolvable).  Idempotent; later calls
-    with a different directory keep the first wiring."""
-    env = env_str("BYDB_COMPILE_CACHE_DIR")
-    if env and env.strip().lower() in _DISABLE_VALUES:
-        return None
-    target = env or (str(default_dir) if default_dir else None)
-    if not target:
-        return None
-    return _wire(target)
-
-
-def enable_at(path) -> str | None:
-    """Explicit-path form for CLI flags (flag already folded env/file
-    precedence via config.py); off-values disable."""
-    if str(path).strip().lower() in _DISABLE_VALUES:
-        return None
-    return _wire(str(path))
-
-
-def active_dir() -> str | None:
-    return _state["dir"]
-
-
 def stats() -> dict:
-    """Telemetry for /metrics and the bench artifact."""
+    """Telemetry for /metrics and the diagnostics topic."""
     entries = 0
     d = _state["dir"]
     if _state["enabled"] and d and os.path.isdir(d):

@@ -64,7 +64,6 @@ FLAGS: dict[str, str] = {
     "BYDB_AUTOREG_MAX_SIGNATURES": "int: autoreg signature cap",
     "BYDB_AUTOREG_MAX_STATE_MB": "int: autoreg total state budget",
     "BYDB_AUTOREG_MIN_HITS": "int: query-shape hits before autoreg",
-    "BYDB_COMPILE_CACHE_DIR": "str: persistent XLA compile-cache dir",
     "BYDB_CONFIG": "str: server config file path (CLI --config wins)",
     "BYDB_DEVICE_CACHE_BYTES": "int: device-resident block cache budget",
     "BYDB_DEVICE_DECODE": "bool: decode encoded blocks on-device",

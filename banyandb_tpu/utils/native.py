@@ -1,24 +1,45 @@
 """ctypes bindings to cpp/libbydb_native.so (the native hot-loop module).
 
 Loaded lazily and optional: every caller has a NumPy fallback, so the
-framework runs pure-Python when the .so hasn't been built (`make -C cpp`).
+framework runs pure-Python when the .so hasn't been built.  The library
+is a build product outside git, loaded from ONE place — the checkout's
+``cpp/`` — and built from source there by ``build()``; ``codec_name()``
+says which codec a process actually runs (servers log it at boot).
 """
 
 from __future__ import annotations
 
 import ctypes
+import subprocess
 from pathlib import Path
 from typing import Optional
 
 import numpy as np
 
-_SO_PATHS = [
-    Path(__file__).resolve().parents[2] / "cpp" / "libbydb_native.so",
-    Path("libbydb_native.so"),
-]
+_CPP_DIR = Path(__file__).resolve().parents[2] / "cpp"
+_SO_PATH = _CPP_DIR / "libbydb_native.so"
 
 _lib = None
 _tried = False
+
+
+def build(force: bool = False) -> Path:
+    """``make -C cpp`` (``force`` rebuilds even when up to date); -> the
+    library path.  Raises when the toolchain is missing or the build
+    fails.  The one build step tests/conftest.py and chip_smoke.py
+    share; must run before the first ``lib()`` call of the process."""
+    subprocess.run(
+        ["make", "-C", str(_CPP_DIR)] + (["-B"] if force else []),
+        check=True,
+        capture_output=True,
+        timeout=180,
+    )
+    return _SO_PATH
+
+
+def codec_name() -> str:
+    """``native`` when the .so loaded, else ``numpy`` (the fallback)."""
+    return "native" if lib() is not None else "numpy"
 
 
 def lib() -> Optional[ctypes.CDLL]:
@@ -26,33 +47,31 @@ def lib() -> Optional[ctypes.CDLL]:
     if _tried:
         return _lib
     _tried = True
-    for p in _SO_PATHS:
-        try:
-            L = ctypes.CDLL(str(p))
-        except OSError:
-            continue
-        L.bydb_delta_encode.restype = ctypes.c_int
-        L.bydb_delta_encode.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int),
-        ]
-        L.bydb_delta_decode.restype = ctypes.c_int
-        L.bydb_delta_decode.argtypes = [
-            ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
-            ctypes.c_int, ctypes.c_void_p,
-        ]
-        L.bydb_zigzag_varint_encode.restype = ctypes.c_int64
-        L.bydb_zigzag_varint_encode.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-        ]
-        L.bydb_zigzag_varint_decode.restype = ctypes.c_int64
-        L.bydb_zigzag_varint_decode.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
-        ]
-        L.bydb_crc32.restype = ctypes.c_uint32
-        L.bydb_crc32.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_uint32]
-        _lib = L
-        break
+    try:
+        L = ctypes.CDLL(str(_SO_PATH))
+    except OSError:
+        return None
+    L.bydb_delta_encode.restype = ctypes.c_int
+    L.bydb_delta_encode.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int),
+    ]
+    L.bydb_delta_decode.restype = ctypes.c_int
+    L.bydb_delta_decode.argtypes = [
+        ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+        ctypes.c_int, ctypes.c_void_p,
+    ]
+    L.bydb_zigzag_varint_encode.restype = ctypes.c_int64
+    L.bydb_zigzag_varint_encode.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+    ]
+    L.bydb_zigzag_varint_decode.restype = ctypes.c_int64
+    L.bydb_zigzag_varint_decode.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+    ]
+    L.bydb_crc32.restype = ctypes.c_uint32
+    L.bydb_crc32.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_uint32]
+    _lib = L
     return _lib
 
 

@@ -55,7 +55,7 @@ def main() -> int:
 
     root = Path(tempfile.mkdtemp(prefix="bydb-cold-smoke-"))
     try:
-        assert compile_cache.enable(root / "compile-cache"), "cache wiring"
+        assert compile_cache.enable(), "cache wiring"
         reg = SchemaRegistry(root)
         reg.create_group(Group("g", Catalog.MEASURE, ResourceOpts(shard_num=2)))
         reg.create_measure(

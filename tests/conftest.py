@@ -24,6 +24,11 @@ os.environ.setdefault("BYDB_AUTOREG", "0")
 # which passes workers=N to the server; everything else runs the
 # single-process layout it was written against)
 os.environ.setdefault("BYDB_WORKERS", "0")
+# no persistent XLA compile cache in the general suite: an in-process
+# server wires it (utils/compile_cache), and tests must neither write
+# into the checkout nor run executables a previous run compiled
+# (tests/test_cold_path.py re-enables it in its own subprocesses)
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 # race/leak sanitizers on for the whole suite (BYDB_SANITIZE=0 opts out)
 os.environ.setdefault("BYDB_SANITIZE", "1")
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -58,16 +63,10 @@ def pytest_configure(config):
         "slow: long-running E2E; tier-1 runs -m 'not slow' (ROADMAP.md), "
         "fast smoke variants keep the coverage",
     )
-    import subprocess
+    from banyandb_tpu.utils import native
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     try:
-        subprocess.run(
-            ["make", "-C", os.path.join(root, "cpp")],
-            check=True,
-            capture_output=True,
-            timeout=180,
-        )
+        native.build()
     except Exception as exc:  # noqa: BLE001 — toolchain-less envs skip
         print(f"# native build unavailable ({exc}); native tests will skip")
 

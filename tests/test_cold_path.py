@@ -540,40 +540,74 @@ _CHILD = """
 import json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["BYDB_PRECOMPILE"] = "1"
+import jax
+updates = []
+_real_update = jax.config.update
+def _spy(name, value):
+    updates.append(name)
+    return _real_update(name, value)
+jax.config.update = _spy
 from banyandb_tpu.utils import compile_cache
-assert compile_cache.enable(os.environ["CC_DIR"])
+active = compile_cache.enable()
 from banyandb_tpu.query.precompile import builtin_plans, PrecompileRegistry
 name, spec = builtin_plans()[0]  # measure/flat-count: the smallest plan
 r = PrecompileRegistry()
 assert r.warm(sigs=[("measure", spec)]) == 1 and r.errors == 0
-print(json.dumps(compile_cache.stats()))
+print(json.dumps({
+    **compile_cache.stats(), "active": active, "updates": updates,
+    "jax_dir": jax.config.jax_compilation_cache_dir,
+}))
 """
 
 
-def test_persistent_cache_hits_across_processes(tmp_path):
-    """Second process's first-plan compile must be a persistent-cache
-    hit — the ROADMAP item 2 'compile once per machine' property."""
+def _run_cache_child(env):
+    out = subprocess.run(
+        [sys.executable, "-c", _CHILD],
+        env=env,
+        capture_output=True,
+        text=True,
+        timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_persistent_cache_env_dir_wins_and_hits_across_processes(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: entries land there, this package
+    never config.update()s the directory, and the second process's
+    first-plan compile is a persistent-cache hit — 'compile once per
+    machine'."""
     env = dict(os.environ)
-    env["CC_DIR"] = str(tmp_path / "cc")
-    env.pop("BYDB_COMPILE_CACHE_DIR", None)
+    cc = tmp_path / "cc"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cc)
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"  # conftest pins it off
 
-    def run():
-        out = subprocess.run(
-            [sys.executable, "-c", _CHILD],
-            env=env,
-            capture_output=True,
-            text=True,
-            timeout=300,
-        )
-        assert out.returncode == 0, out.stderr[-2000:]
-        return json.loads(out.stdout.strip().splitlines()[-1])
-
-    first = run()
-    assert first["enabled"] and first["entries"] > 0
+    first = _run_cache_child(env)
+    assert first["enabled"] and first["active"] == str(cc)
+    assert first["dir"] == first["jax_dir"] == str(cc)
+    assert "jax_compilation_cache_dir" not in first["updates"]
+    assert first["entries"] > 0 and any(cc.iterdir())
     assert first["hits"] == 0  # fresh dir: everything compiles
-    second = run()
+    second = _run_cache_child(env)
     assert second["hits"] > 0, second  # the same plan loads, not compiles
     assert second["misses"] < first["misses"] + first["hits"] + 1
+
+
+def test_persistent_cache_unset_resolves_to_the_fixed_path(monkeypatch):
+    """Unset: ONE fixed path under the checkout — the same for every
+    process and every data root (a per-run directory never hits)."""
+    from banyandb_tpu.utils import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.resolve_dir() == (
+        os.path.join(repo, ".compile-cache"), False,
+    )
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert compile_cache.resolve_dir() == ("/some/dir", True)
+    # the fixed path is git-ignored (a cache is never committed)
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".compile-cache/" in f.read().split()
 
 
 # -- counters end-to-end over the bus ---------------------------------------

@@ -5,8 +5,8 @@ Covers:
   delta_decode (empty/single-row/boundary deltas), dod_decode,
   dict_gather's OOB clip guard, dict_remap, widen_codes, ints_to_f32,
   decode_chunk pass-through vs compressed decode;
-- the Pallas decode kernels (interpret mode) bit-identical to the jnp
-  fallbacks (widen_narrow, prefix_sum_narrow);
+- the Pallas decode kernel (widen_narrow, interpret mode) bit-identical
+  to the jnp fallback;
 - narrow width decisions: encode/decode_dict_codes_narrow at the
   i8/i16/i32 downcast boundaries, storage/encoded.narrow_int_dtype
   edges (non-integral, NaN, +-2^7/2^15 boundaries);
@@ -191,7 +191,7 @@ def test_decode_chunk_passthrough_and_compressed():
     assert np.asarray(out["fields"]["v"]).tolist() == [1.0, -2.0, 3.0, 4.0]
 
 
-# -- Pallas decode kernels (interpret mode) ----------------------------------
+# -- Pallas decode kernel (interpret mode) ----------------------------------
 
 
 def test_pallas_widen_narrow_matches_jnp():
@@ -204,18 +204,6 @@ def test_pallas_widen_narrow_matches_jnp():
     out = np.asarray(pk.widen_narrow(jnp.asarray(x), interpret=True))
     assert out.dtype == np.int32
     assert np.array_equal(out, x.astype(np.int32))
-
-
-def test_pallas_prefix_sum_matches_cumsum():
-    import jax.numpy as jnp
-
-    from banyandb_tpu.ops import pallas_kernels as pk
-
-    rng = np.random.default_rng(7)
-    x = rng.integers(-1000, 1000, 2 * pk.TILE).astype(np.int16)
-    out = np.asarray(pk.prefix_sum_narrow(jnp.asarray(x), interpret=True))
-    want = np.cumsum(x.astype(np.int32), dtype=np.int32)
-    assert np.array_equal(out, want)
 
 
 # -- narrow widths -----------------------------------------------------------

@@ -76,9 +76,7 @@ def test_jaxpr_f64_promotion_flagged():
     import jax
     import jax.numpy as jnp
 
-    if not hasattr(jax.experimental, "enable_x64"):
-        pytest.skip("no x64 context manager in this jax")
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fs, widest = jaxpr_audit.audit_entry(
             _entry(lambda x: x.astype(jnp.float64) * 2.0)
         )

@@ -263,6 +263,44 @@ def test_register_validation(eng):
     assert a["signature"] == b["signature"]
 
 
+def test_auto_registration_over_budget_refused_before_backfill(
+    tmp_path, monkeypatch
+):
+    """The autoreg state budget gates an AUTO signature before its
+    backfill (from part/memtable metadata), not by eviction after it: an
+    over-budget signature never gathers a row and is never installed.
+    Manual registrations are the operator's own memory decision."""
+    e = _engine(tmp_path)
+    try:
+        _write(e, 0, 3000)  # 3 s of data, 5 svc x 3 regions
+        e.flush()
+        _write(e, 3000, 500, seed=1)  # + memtable rows
+        sa = e.streamagg
+        spec_kw = dict(key_tags=("region", "svc"), fields=("v",),
+                       window_millis=1000)
+        from banyandb_tpu.query.streamagg import SigSpec
+
+        est = sa._estimate_states(
+            SigSpec("g", "m", ("region", "svc"), ("v",), 1000)
+        )
+        # (3 regions x the 3 services of the fuller shard) keys x 2
+        # shards x 4 windows — metadata only, and under the 3500 rows
+        assert est == 9 * 2 * 4
+        monkeypatch.setattr(
+            sa, "_backfill_snapshot",
+            lambda spec: pytest.fail("backfill ran for a refused signature"),
+        )
+        monkeypatch.setenv("BYDB_AUTOREG_MAX_STATE_MB", "0")
+        with pytest.raises(ValueError, match="over the 0 MB autoreg budget"):
+            sa.register("g", "m", origin="auto", **spec_kw)
+        assert sa.stats()["signatures"] == []
+        monkeypatch.undo()
+        info = sa.register("g", "m", origin="auto", **spec_kw)
+        assert info["origin"] == "auto" and 0 < info["states"] <= est
+    finally:
+        e.close()
+
+
 def test_late_rows_within_horizon_stay_consistent(eng, monkeypatch):
     """A late row landing in a kept (non-evicted) window re-accumulates
     and the fold still matches the rescan."""

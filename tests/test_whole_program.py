@@ -737,11 +737,9 @@ def test_plan_audit_dtype_promotion_flagged():
 def test_plan_audit_64bit_output_flagged():
     import jax
 
-    if not hasattr(jax.experimental, "enable_x64"):
-        pytest.skip("no x64 context manager in this jax")
     import jax.numpy as jnp
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fs = audit_kernel(
             _entry(
                 lambda x: x.astype(jnp.float64),
