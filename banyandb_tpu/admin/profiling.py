@@ -11,6 +11,12 @@ serves the equivalent diagnostics over a tiny HTTP listener:
                                   for N seconds (cpu profile); top
                                   frames by sample count
     GET /debug/vars               runtime counters (gc, threads, rss)
+    GET /debug/device?seconds=N   a device trace of this process for N
+                                  seconds (capped at 30), reduced to
+                                  JSON: busy / idle, device seconds by
+                                  program and by `bydb.` scope, idle
+                                  gaps by host span (obs/devtrace; the
+                                  bus topic `devtrace` is the same call)
 
 Plain text responses — curl-able under incident pressure, no tooling
 required.
@@ -20,6 +26,7 @@ from __future__ import annotations
 
 import collections
 import gc
+import json
 import sys
 import threading
 import time
@@ -114,6 +121,15 @@ class ProfilingServer:
                         body = _profile_text(float(q.get("seconds", ["5"])[0]))
                     elif u.path == "/debug/vars":
                         body = _vars_text()
+                    elif u.path == "/debug/device":
+                        from banyandb_tpu.obs import devtrace
+
+                        body = json.dumps(
+                            devtrace.capture(
+                                float(q.get("seconds", ["5"])[0])
+                            ),
+                            indent=1,
+                        ) + "\n"
                     else:
                         self.send_error(404)
                         return

@@ -443,18 +443,28 @@ def fill_trace(out, res) -> None:
     (common/v1 Trace; the reference threads pkg/query/tracer spans back
     the same way — dquery/measure.go:104).  The hierarchical span_tree
     (obs/tracer) maps natively onto common/v1 Span.children — a merged
-    cluster tree keeps per-node subtrees nested on the wire; remaining
-    keys of the internal trace dict become flat spans (the plan
-    rendering rides the span message so `trace=true` clients see the
-    plan tree)."""
+    cluster tree keeps per-node subtrees nested on the wire, each span
+    with its wall-clock ``start_time`` / ``end_time`` (the root's
+    ``start_unix_ms`` plus the span's ``start_ms`` / ``duration_ms``)
+    and ``Trace.trace_id`` the root's; the remaining scalar keys of the
+    internal trace dict (the plan rendering) ride a span's message so
+    `trace=true` clients see the plan tree."""
     tr = getattr(res, "trace", None)
     if not tr or not hasattr(out, "trace"):
         return
 
-    def fill_tree(sp, node: dict) -> None:
+    def fill_tree(sp, node: dict, unix_ms: float) -> None:
         sp.message = str(node.get("name", ""))
+        # a grafted remote subtree restarts the clock at its own root
+        unix_ms = float(node.get("start_unix_ms", unix_ms))
+        dur_ms = float(node.get("duration_ms", 0.0))
         # duration is nanoseconds on the wire (common/v1 Span.duration)
-        sp.duration = int(float(node.get("duration_ms", 0.0)) * 1e6)
+        sp.duration = int(dur_ms * 1e6)
+        if unix_ms and "start_ms" in node:
+            # whole nanoseconds: a float of ns since 1970 is good to 256
+            begin = int(unix_ms * 1e6) + int(float(node["start_ms"]) * 1e6)
+            sp.start_time.FromNanoseconds(begin)
+            sp.end_time.FromNanoseconds(begin + sp.duration)
         if node.get("error"):
             sp.error = True
             sp.tags.add(key="error", value=str(node["error"]))
@@ -462,26 +472,15 @@ def fill_trace(out, res) -> None:
             sp.tags.add(key=str(k), value=str(v))
         for child in node.get("children", ()):
             if isinstance(child, dict):
-                fill_tree(sp.children.add(), child)
-
-    def add_span(message: str, fields: dict) -> None:
-        span = out.trace.spans.add()
-        span.message = message
-        for k, v in fields.items():
-            span.tags.add(key=str(k), value=str(v))
+                fill_tree(sp.children.add(), child, unix_ms)
 
     for key, val in tr.items():
         if key == "span_tree" and isinstance(val, dict):
-            fill_tree(out.trace.spans.add(), val)
-        elif isinstance(val, list) and all(isinstance(x, dict) for x in val):
-            # per-phase span lists (measure _trace_spans): one proto span
-            # each, named by the entry's own name where present
-            for i, entry in enumerate(val):
-                add_span(str(entry.get("name", f"{key}[{i}]")), entry)
-        elif isinstance(val, dict):
-            add_span(key, val)
+            if val.get("trace_id"):
+                out.trace.trace_id = str(val["trace_id"])
+            fill_tree(out.trace.spans.add(), val, 0.0)
         else:
-            add_span(f"{key}: {val}", {})
+            out.trace.spans.add().message = f"{key}: {val}"
 
 
 def _has_tag(spec, name: str) -> bool:

@@ -550,8 +550,7 @@ class LiaisonServer:
         with adm, tenant_scope(adm.tenant):
             with tracer.span("qos") as sp:
                 sp.tag("tenant", adm.tenant)
-                if adm.queued_ms >= 1.0:
-                    sp.tag("queued_ms", round(adm.queued_ms, 2))
+                sp.tag("queued_ms", round(adm.queued_ms, 3))
             t0 = _time.perf_counter()
             if catalog == "measure":
                 res = self.liaison.query_measure(req, tracer=tracer)

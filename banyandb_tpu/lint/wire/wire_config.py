@@ -48,6 +48,7 @@ ROLES: dict[str, tuple[str, ...]] = {
 EXPECTED_MATRIX: dict[str, tuple[str, ...]] = {
     "standalone": (
         "bydbql",
+        "devtrace",
         "diagnostics",
         "fodc-pprof",
         "health",
@@ -397,6 +398,8 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "compile_cache_enabled": frozenset(),
     "decode_ship_bytes": frozenset({"form"}),
     "failover_attempts": frozenset(),
+    "jit_compile_seconds": frozenset(),
+    "jit_traces": frozenset(),
     "fault_injected": frozenset({"kind", "site"}),
     "kernel_dispatch_budget": frozenset({"signature"}),
     "lifecycle_stage_ms": frozenset({"stage"}),

@@ -804,8 +804,7 @@ class MeasureEngine:
             # per-engine latency must not go dark when queries fail)
             _H_QUERY.observe((time.perf_counter() - t_start) * 1000)
         if req.trace:
-            res.trace = _trace_spans(t_start, t_gather, sources, m.index_mode)
-            res.trace["plan"] = plan.explain()
+            res.trace = {"plan": plan.explain()}
             if own_tracer:
                 res.trace["span_tree"] = tracer.finish()
         return res
@@ -841,25 +840,7 @@ class MeasureEngine:
                     (time.perf_counter() - t_start) * 1000
                 )
         if req.trace:
-            from banyandb_tpu.storage.cache import device_cache, global_cache
-
-            res.trace = {
-                "spans": [
-                    {
-                        "name": "streamagg",
-                        "duration_ms": round(
-                            (time.perf_counter() - t_start) * 1000, 3
-                        ),
-                        "coverage": cover.kind,
-                    }
-                ],
-                "serving_cache": global_cache().stats(),
-                "device_cache": device_cache().stats(),
-                "total_ms": round(
-                    (time.perf_counter() - t_start) * 1000, 3
-                ),
-                "plan": plan.explain(),
-            }
+            res.trace = {"plan": plan.explain()}
             if own_tracer:
                 res.trace["span_tree"] = tracer.finish()
         return res
@@ -1486,32 +1467,6 @@ def _raw_rows(
     for ts, _ver, tags, fields, _sid in ordered[off : off + (req.limit or 100)]:
         res.data_points.append({"timestamp": ts, "tags": tags, "fields": fields})
     return res
-
-
-def _trace_spans(t_start, t_gather, sources, index_mode: bool) -> dict:
-    """In-band query trace (pkg/query/tracer.go Span analog)."""
-    from banyandb_tpu.storage.cache import device_cache, global_cache
-
-    t_end = time.perf_counter()
-    rows = sum(int(s.ts.size) for s in sources)
-    return {
-        "spans": [
-            {
-                "name": "gather_sources",
-                "duration_ms": round((t_gather - t_start) * 1000, 3),
-                "sources": len(sources),
-                "rows": rows,
-                "index_mode": index_mode,
-            },
-            {
-                "name": "execute",
-                "duration_ms": round((t_end - t_gather) * 1000, 3),
-            },
-        ],
-        "serving_cache": global_cache().stats(),
-        "device_cache": device_cache().stats(),
-        "total_ms": round((t_end - t_start) * 1000, 3),
-    }
 
 
 # -- series pruning helpers -------------------------------------------------

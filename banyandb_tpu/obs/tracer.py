@@ -11,8 +11,22 @@ under that node's scatter span, and the response carries ONE tree.
 Serialized form (JSON-safe, the ``res.trace["span_tree"]`` payload and
 the wire common/v1 Span mapping):
 
-    {"name": str, "duration_ms": float, "tags": {str: scalar},
-     "children": [<span>...], "error": str?}
+    {"name": str, "start_ms": float, "duration_ms": float,
+     "tags": {str: scalar}, "children": [<span>...], "error": str?}
+
+``start_ms`` is the span's offset from its tree's root start (the same
+``perf_counter`` as ``duration_ms``); the root also carries
+``start_unix_ms`` and ``trace_id`` (one id per request; ``attach`` puts
+it on a remote subtree's scatter span, whose own ``start_ms`` restarts
+at its node's root).
+
+The profiler's clock: while a span is open it holds one annotation
+``bydb:<name>`` made by ``_annotate`` — a module-level hook that
+``utils/devices.claim_backend`` points at ``jax.profiler.TraceAnnotation``
+at boot, so ``obs/`` imports no jax.  Whenever a device trace is running
+(``obs/devtrace.capture`` or anyone else's) every open span is an event
+in its host plane; with no hook a span costs what it did before.
+``annotate(name)`` is the bare form for work off the owner thread.
 
 Tracing off must cost nothing: callers thread ``None`` (executors skip
 span work on a ``None`` span) or ``NOOP_TRACER`` (handlers keep one
@@ -21,8 +35,30 @@ code path); both avoid allocation on the hot path.
 
 from __future__ import annotations
 
+import contextlib
+import os
 import time
-from typing import Optional
+from typing import Callable, Optional
+
+# (name, **kwargs) -> context manager; None until a process that owns a
+# JAX backend installs jax.profiler.TraceAnnotation (set_annotation_hook)
+_annotate: Optional[Callable] = None
+_NULL_CTX = contextlib.nullcontext()
+
+
+def set_annotation_hook(factory: Optional[Callable]) -> None:
+    """Install (or with None remove) the annotation factory every span
+    enters while it is open."""
+    global _annotate
+    _annotate = factory
+
+
+def annotate(name: str):
+    """A bare ``bydb:<name>`` annotation for work that is no span of its
+    own: phases inside ``gather``, pad thunks on a worker thread."""
+    if _annotate is None:
+        return _NULL_CTX
+    return _annotate("bydb:" + name)
 
 
 class Span:
@@ -30,15 +66,23 @@ class Span:
     owned by the thread that created it (worker-side timings are
     accumulated into plain tags by the owner, see measure_exec)."""
 
-    __slots__ = ("name", "t0", "t1", "tags", "children", "error_msg")
+    __slots__ = (
+        "name", "t0", "t1", "tags", "children", "error_msg", "trace_id",
+        "_ann",
+    )
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, trace_id: str = ""):
         self.name = name
-        self.t0 = time.perf_counter()
-        self.t1: Optional[float] = None
+        self.trace_id = trace_id
         self.tags: dict = {}
         self.children: list = []  # Span | dict (attached subtree)
         self.error_msg: Optional[str] = None
+        self.t1: Optional[float] = None
+        self._ann = None
+        if _annotate is not None:
+            self._ann = _annotate("bydb:" + name, trace_id=trace_id)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
 
     # -- building -----------------------------------------------------------
     def tag(self, key: str, value) -> "Span":
@@ -50,14 +94,18 @@ class Span:
         return self
 
     def child(self, name: str) -> "Span":
-        s = Span(name)
+        s = Span(name, self.trace_id)
         self.children.append(s)
         return s
 
     def attach(self, subtree: dict) -> None:
-        """Graft a serialized span tree (a remote node's subtree)."""
+        """Graft a serialized span tree (a remote node's subtree); this
+        (scatter) span is tagged with the request's trace_id so the
+        graft is findable from either side."""
         if subtree:
             self.children.append(subtree)
+            if self.trace_id:
+                self.tags.setdefault("trace_id", self.trace_id)
 
     def finish(self) -> "Span":
         if self.t1 is None:
@@ -66,6 +114,8 @@ class Span:
             # across requests); many roots run queries, but no two roots
             # ever hold the same Span instance
             self.t1 = time.perf_counter()
+            if self._ann is not None:  # left once: t1 guards re-entry
+                self._ann.__exit__(None, None, None)
         return self
 
     # spans double as context managers so executors can scope a leg
@@ -84,14 +134,17 @@ class Span:
         end = self.t1 if self.t1 is not None else time.perf_counter()
         return (end - self.t0) * 1000.0
 
-    def to_dict(self) -> dict:
+    def to_dict(self, root_t0: Optional[float] = None) -> dict:
         self.finish()
+        if root_t0 is None:
+            root_t0 = self.t0
         out = {
             "name": self.name,
+            "start_ms": round((self.t0 - root_t0) * 1000.0, 3),
             "duration_ms": round(self.duration_ms, 3),
             "tags": dict(self.tags),
             "children": [
-                c.to_dict() if isinstance(c, Span) else c
+                c.to_dict(root_t0) if isinstance(c, Span) else c
                 for c in self.children
             ],
         }
@@ -123,10 +176,11 @@ class Tracer:
     """Span-tree builder for one query.  Single-owner (the query's
     request thread); remote subtrees arrive serialized via attach."""
 
-    __slots__ = ("root", "_stack")
+    __slots__ = ("root", "_stack", "start_unix_ms")
 
     def __init__(self, name: str):
-        self.root = Span(name)
+        self.start_unix_ms = time.time() * 1000.0
+        self.root = Span(name, os.urandom(8).hex())
         self._stack: list[Span] = [self.root]
 
     def current(self) -> Span:
@@ -139,7 +193,10 @@ class Tracer:
 
     def finish(self) -> dict:
         """Close the root and return the serialized tree."""
-        return self.root.to_dict()
+        out = self.root.to_dict()
+        out["start_unix_ms"] = round(self.start_unix_ms, 3)
+        out["trace_id"] = self.root.trace_id
+        return out
 
 
 class _NoopSpan:

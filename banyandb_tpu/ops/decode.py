@@ -135,16 +135,21 @@ def decode_chunk(chunk: dict) -> dict:
         for k, v in chunk.items()
         if k not in ("tags_enc", "tags_lut", "src_ord", "fields_enc")
     }
-    tags_code = dict(out.get("tags_code", {}))
-    for t, codes in chunk.get("tags_enc", {}).items():
-        tags_code[t] = dict_remap(
-            _maybe_pallas_widen(codes), chunk["tags_lut"][t], chunk["src_ord"]
-        )
-    out["tags_code"] = tags_code
-    fields = dict(out.get("fields", {}))
-    for f, vals in chunk.get("fields_enc", {}).items():
-        fields[f] = ints_to_f32(_maybe_pallas_widen(vals))
-    out["fields"] = fields
+    import jax
+
+    with jax.named_scope("bydb.decode"):
+        tags_code = dict(out.get("tags_code", {}))
+        for t, codes in chunk.get("tags_enc", {}).items():
+            tags_code[t] = dict_remap(
+                _maybe_pallas_widen(codes),
+                chunk["tags_lut"][t],
+                chunk["src_ord"],
+            )
+        out["tags_code"] = tags_code
+        fields = dict(out.get("fields", {}))
+        for f, vals in chunk.get("fields_enc", {}).items():
+            fields[f] = ints_to_f32(_maybe_pallas_widen(vals))
+        out["fields"] = fields
     return out
 
 

@@ -228,7 +228,21 @@ def group_reduce(
     """
     if method == "auto":
         method = select_group_method(key.shape[-1], num_groups)
+    # the device trace names the method that ran, not the one asked for
+    with jax.named_scope(f"bydb.group_reduce.{method}"):
+        return _group_reduce(
+            key, valid, fields, num_groups, want_minmax, method
+        )
 
+
+def _group_reduce(
+    key: jax.Array,
+    valid: jax.Array,
+    fields: Mapping[str, jax.Array],
+    num_groups: int,
+    want_minmax: bool,
+    method: str,
+) -> GroupReduceResult:
     validf = valid.astype(jnp.float32)
     safe_key = jnp.where(valid, key, jnp.int32(num_groups))
 

@@ -191,6 +191,7 @@ def fused_group_multi(
             for rows in (1, nf, 1, nf)
         ),
         interpret=interpret,
+        name="bydb_group_multi",
     )(*operands)
     # Fold the residual compensation back in (classic Kahan final step;
     # the compensation holds the negated running error).
@@ -229,6 +230,7 @@ def widen_narrow(x: jax.Array, *, interpret: bool = False) -> jax.Array:
         out_specs=pl.BlockSpec((1, TILE), lambda i: (0, i)),
         out_shape=_out_struct((1, n), jnp.int32, x2),
         interpret=interpret,
+        name="bydb_widen",
     )(x2)
     return out[0]
 

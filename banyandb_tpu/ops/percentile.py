@@ -38,17 +38,18 @@ def group_histogram(
             f"num_groups={num_groups} x num_buckets={num_buckets} "
             "overflows int32 segment ids"
         )
-    width = span / num_buckets
-    bucket = jnp.clip(
-        ((values - lo) / width).astype(jnp.int32), 0, num_buckets - 1
-    )
-    safe_key = jnp.where(valid, key, jnp.int32(num_groups))
-    combined = safe_key * jnp.int32(num_buckets) + bucket
-    return jax.ops.segment_sum(
-        valid.astype(jnp.float32),
-        combined,
-        num_segments=(num_groups + 1) * num_buckets,
-    ).reshape(num_groups + 1, num_buckets)[:num_groups]
+    with jax.named_scope("bydb.histogram"):
+        width = span / num_buckets
+        bucket = jnp.clip(
+            ((values - lo) / width).astype(jnp.int32), 0, num_buckets - 1
+        )
+        safe_key = jnp.where(valid, key, jnp.int32(num_groups))
+        combined = safe_key * jnp.int32(num_buckets) + bucket
+        return jax.ops.segment_sum(
+            valid.astype(jnp.float32),
+            combined,
+            num_segments=(num_groups + 1) * num_buckets,
+        ).reshape(num_groups + 1, num_buckets)[:num_groups]
 
 
 def group_percentile_histogram(

@@ -25,11 +25,12 @@ def topk_groups(
     Empty groups sort last in either direction; callers drop entries whose
     returned value is +/-inf-sentinel by checking nonempty[indices].
     """
-    if descending:
-        m = jnp.where(nonempty, metric, -_SENTINEL)
-        vals, idx = jax.lax.top_k(m, n)
-    else:
-        m = jnp.where(nonempty, -metric, -_SENTINEL)
-        vals, idx = jax.lax.top_k(m, n)
-        vals = -vals
-    return vals, idx
+    with jax.named_scope("bydb.topk"):
+        if descending:
+            m = jnp.where(nonempty, metric, -_SENTINEL)
+            vals, idx = jax.lax.top_k(m, n)
+        else:
+            m = jnp.where(nonempty, -metric, -_SENTINEL)
+            vals, idx = jax.lax.top_k(m, n)
+            vals = -vals
+        return vals, idx

@@ -49,7 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from banyandb_tpu.query.measure_exec import PlanSpec, _kernel_body
+from banyandb_tpu.obs import tracer
+from banyandb_tpu.query.measure_exec import DeviceLeg, PlanSpec, _kernel_body
 from banyandb_tpu.utils.envflag import env_flag, env_int
 
 
@@ -107,16 +108,18 @@ def _build_kernel(fspec: FusedSpec):
 
     body = _kernel_body(fspec.plan)
 
-    def fused(chunks: dict, pred_vals: dict, hist_lo, hist_span):
+    # the name is the device trace's module line: jit_bydb_fused_plan
+    def bydb_fused_plan(chunks: dict, pred_vals: dict, hist_lo, hist_span):
         chunks = ops_decode.decode_chunk(chunks)
 
         def step(carry, chunk):
             return carry, body(chunk, pred_vals, hist_lo, hist_span)
 
-        _, stacked = jax.lax.scan(step, None, chunks)
+        with jax.named_scope("bydb.fused_scan"):
+            _, stacked = jax.lax.scan(step, None, chunks)
         return stacked
 
-    return jax.jit(fused)
+    return jax.jit(bydb_fused_plan)
 
 
 def _num_hist_buckets() -> int:
@@ -182,7 +185,8 @@ def _stacked_chunks(
     spec: PlanSpec,
     num_chunks: int,
     epoch: int,
-    pad_ship_s: list | None = None,
+    pack_s: list | None = None,
+    h2d_s: list | None = None,
     ship_stats: list | None = None,
 ) -> dict:
     """Pad the gathered columns into ``[C, nrows]`` device arrays.
@@ -196,8 +200,10 @@ def _stacked_chunks(
     [S, L] remap LUTs the in-program decode stage consumes.  Per-column
     pad work rides the chunk_stream prefetch worker (BYDB_PIPELINE
     honored) so padding column j+1 overlaps shipping column j.
-    ``ship_stats`` collects one (shipped, dense) byte pair for the
-    whole part-batch (decode-span attribution).
+    ``pack_s`` collects the pad thunks' seconds (worker thread),
+    ``h2d_s`` the ``jnp.asarray`` ships' (this thread); ``ship_stats``
+    one (shipped, dense) byte pair for the whole part-batch (decode-span
+    attribution).
     """
     from banyandb_tpu.storage.chunk_stream import prefetched
 
@@ -285,10 +291,11 @@ def _stacked_chunks(
         def pad_thunk():  # host-side work on the prefetch worker
             t0 = time.perf_counter()
             try:
-                return fn()
+                with tracer.annotate("decode.pack"):
+                    return fn()
             finally:
-                if pad_ship_s is not None:
-                    pad_ship_s.append(time.perf_counter() - t0)
+                if pack_s is not None:
+                    pack_s.append(time.perf_counter() - t0)
 
         return pad_thunk
 
@@ -306,8 +313,8 @@ def _stacked_chunks(
     ):
         t0 = time.perf_counter()
         dev = jnp.asarray(arr)
-        if pad_ship_s is not None:
-            pad_ship_s.append(time.perf_counter() - t0)
+        if h2d_s is not None:
+            h2d_s.append(time.perf_counter() - t0)
         if path in counted:
             shipped += dev.nbytes
         if len(path) == 1:
@@ -337,17 +344,21 @@ def run_fused(
     *,
     gather_key=None,
     dev_cache=None,
-    pad_ship_s: list | None = None,
+    pack_s: list | None = None,
+    h2d_s: list | None = None,
     ship_stats: list | None = None,
     min_bucket: int | None = None,
-) -> tuple[list[dict], float, str]:
+    decode_span=None,
+) -> tuple[list[dict], DeviceLeg, str]:
     """Execute one part-batch through the fused program.
 
     -> (per-chunk host partials in scan order for the staged f64 absorb
-    loop, seconds spent at the two accelerator boundaries, input-cache
-    outcome tag).  Exactly one kernel dispatch and one batched
-    device_get regardless of chunk count.  ``min_bucket`` (planner
-    hint) rounds the chunk-count bucket up — see ``_resolve_bucket``.
+    loop, the device leg's timings, input-cache outcome tag).  Exactly
+    one kernel dispatch and one batched device_get regardless of chunk
+    count.  ``min_bucket`` (planner hint) rounds the chunk-count bucket
+    up — see ``_resolve_bucket``.  ``decode_span`` (open, or None) is
+    finished when the stacked inputs are on the device: it covers the
+    pad + ship loop and nothing of the dispatch.
     """
     num_chunks = _resolve_bucket(len(chunk_spans), min_bucket)
     fspec = FusedSpec(plan=spec, num_chunks=num_chunks)
@@ -364,7 +375,7 @@ def run_fused(
     def _build():
         built.append(1)
         return _stacked_chunks(
-            chunks_np, chunk_spans, spec, num_chunks, epoch, pad_ship_s,
+            chunks_np, chunk_spans, spec, num_chunks, epoch, pack_s, h2d_s,
             ship_stats=ship_stats,
         )
 
@@ -384,21 +395,25 @@ def run_fused(
     else:
         dev_chunks = _build()
 
-    device_s = 0.0
-    t0 = time.perf_counter()
-    out = kernel(dev_chunks, pred_vals, hist_lo, hist_span)
-    device_s += time.perf_counter() - t0
+    if decode_span is not None:
+        decode_span.finish()
+
+    leg = DeviceLeg()
+    with leg.paid:  # what this dispatch traces or compiles
+        t0 = time.perf_counter()
+        out = kernel(dev_chunks, pred_vals, hist_lo, hist_span)
+        leg.dispatch_s = time.perf_counter() - t0
     t0 = time.perf_counter()
     # bdlint: disable=host-sync -- THE result boundary of the fused
     # plan: the whole part-batch's stacked partials move in one batched
     # transfer (1 get per part-batch, ratcheted by kernel_budgets)
     moved = jax.device_get(out)
-    device_s += time.perf_counter() - t0
+    leg.get_s = time.perf_counter() - t0
     chunks_out = [
         jax.tree_util.tree_map(lambda a, k=k: a[k], moved)
         for k in range(len(chunk_spans))
     ]
-    return chunks_out, device_s, ("built" if built else "hit")
+    return chunks_out, leg, ("built" if built else "hit")
 
 
 # ---------------------------------------------------------------------------
@@ -476,20 +491,22 @@ def _fused_dist_step(
         axes,
         to="varying",
     )
-    (count, sums, mins, maxs, hist), _ = jax.lax.scan(step, init, chunks)
+    with jax.named_scope("bydb.fused_scan"):
+        (count, sums, mins, maxs, hist), _ = jax.lax.scan(step, init, chunks)
 
     # ---- the collective reduce: ICI replaces the proto partial hop ----
-    out = {
-        "count": jax.lax.psum(count[0] - count[1], axes),
-        "sums": {
-            f: jax.lax.psum(sums[f][0] - sums[f][1], axes)
-            for f in plan.fields
-        },
-        "mins": {f: jax.lax.pmin(mins[f], axes) for f in plan.fields},
-        "maxs": {f: jax.lax.pmax(maxs[f], axes) for f in plan.fields},
-    }
-    if plan.want_hist:
-        out["hist"] = jax.lax.psum(hist[0] - hist[1], axes)
+    with jax.named_scope("bydb.collective"):
+        out = {
+            "count": jax.lax.psum(count[0] - count[1], axes),
+            "sums": {
+                f: jax.lax.psum(sums[f][0] - sums[f][1], axes)
+                for f in plan.fields
+            },
+            "mins": {f: jax.lax.pmin(mins[f], axes) for f in plan.fields},
+            "maxs": {f: jax.lax.pmax(maxs[f], axes) for f in plan.fields},
+        }
+        if plan.want_hist:
+            out["hist"] = jax.lax.psum(hist[0] - hist[1], axes)
     if plan.topn:
         mean = out["sums"][plan.fields[0]] / jnp.maximum(out["count"], 1.0)
         vals, idx = ops.topk_groups(mean, out["count"] > 0, plan.topn)
@@ -535,7 +552,12 @@ def build_fused_dist_step(mesh, plan, num_chunks: int):
         ),
         out_specs=dist_exec._out_specs(plan),
     )
-    jitted = jax.jit(step)
+
+    # the name is the device trace's module line
+    def bydb_fused_dist_step(chunks, pred_codes, hist_lo, hist_span):
+        return step(chunks, pred_codes, hist_lo, hist_span)
+
+    jitted = jax.jit(bydb_fused_dist_step)
     _DIST_STEP_CACHE[cache_key] = jitted
     return jitted
 

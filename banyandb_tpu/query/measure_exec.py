@@ -41,8 +41,9 @@ from banyandb_tpu.api.model import (
 )
 from banyandb_tpu.api.schema import Measure, TagType
 from banyandb_tpu.obs import metrics as obs_metrics
+from banyandb_tpu.obs import tracer
 from banyandb_tpu.storage.part import ColumnData
-from banyandb_tpu.utils import hostops
+from banyandb_tpu.utils import compile_cache, hostops
 from banyandb_tpu.utils.envflag import env_int
 
 # stage latency instruments (always on, spans or not): the attribution
@@ -121,6 +122,36 @@ class PlanSpec:
 _KERNEL_CACHE: dict[PlanSpec, object] = {}
 
 
+class DeviceLeg:
+    """Host-clock times at one reduction's accelerator boundaries: the
+    jitted calls returning (``dispatch_s``) and the ``device_get`` waits
+    (``get_s``) — a dispatch that blocks (a trace, a compile, a
+    synchronous transfer) and a device that works read differently —
+    plus what the dispatches traced or compiled on this thread."""
+
+    __slots__ = ("dispatch_s", "get_s", "paid")
+
+    def __init__(self):
+        self.dispatch_s = 0.0
+        self.get_s = 0.0
+        # entered around the dispatches (`with leg.paid:`)
+        self.paid = compile_cache.watch()
+
+    @property
+    def device_s(self) -> float:
+        return self.dispatch_s + self.get_s
+
+    def tag(self, span) -> None:
+        """The ``reduce`` span's device tags; device_ms stays the sum."""
+        span.tag("device_ms", round(self.device_s * 1000, 3)).tag(
+            "dispatch_ms", round(self.dispatch_s * 1000, 3)
+        ).tag("get_ms", round(self.get_s * 1000, 3))
+        if self.paid.compiled:
+            span.tag("compiled", self.paid.compiled).tag(
+                "compile_ms", round(self.paid.seconds * 1000, 3)
+            ).tag("program", self.paid.program)
+
+
 def _kernel_body(spec: PlanSpec):
     """The un-jitted per-chunk partial computation for `spec`.
 
@@ -151,18 +182,22 @@ def _kernel_body(spec: PlanSpec):
             right = eval_expr(node[2])
             return (left & right) if node[0] == "and" else (left | right)
 
-        if spec.expr:
-            mask = valid & eval_expr(spec.expr)
-        else:  # flat AND of all preds (original plan shape)
-            mask = ops.mask_and(
-                valid, *[pred_mask(i) for i in range(len(spec.preds))]
-            )
+        # named scopes are trace-time metadata only: the device trace's
+        # op names carry them (obs/devtrace reads the innermost `bydb.`)
+        with jax.named_scope("bydb.filter"):
+            if spec.expr:
+                mask = valid & eval_expr(spec.expr)
+            else:  # flat AND of all preds (original plan shape)
+                mask = ops.mask_and(
+                    valid, *[pred_mask(i) for i in range(len(spec.preds))]
+                )
 
-        key_cols = [chunk["tags_code"][t] for t in spec.group_tags]
-        if key_cols:
-            key, _ = ops.mixed_radix_key(key_cols, spec.radices)
-        else:
-            key = jnp.zeros_like(chunk["series"])
+        with jax.named_scope("bydb.group_key"):
+            key_cols = [chunk["tags_code"][t] for t in spec.group_tags]
+            if key_cols:
+                key, _ = ops.mixed_radix_key(key_cols, spec.radices)
+            else:
+                key = jnp.zeros_like(chunk["series"])
 
         res = ops.group_reduce(
             key,
@@ -196,32 +231,33 @@ def _kernel_body(spec: PlanSpec):
             # emission order and the representative row (reference
             # measure_plan_groupby.go first-appearance + aggregation
             # first-fed row semantics)
-            ts32 = chunk["ts"]
-            row32 = chunk["row"]
-            G1 = spec.num_groups + 1
-            skey = jnp.where(mask, key, jnp.int32(spec.num_groups))
-            if spec.rep_desc:
-                gts = jax.ops.segment_max(
-                    jnp.where(mask, ts32, jnp.int32(-(2**31) + 1)),
-                    skey, num_segments=G1,
-                )
-                at = mask & (ts32 == jnp.take(gts, skey, mode="clip"))
-                grow = jax.ops.segment_max(
-                    jnp.where(at, row32, jnp.int32(-1)),
-                    skey, num_segments=G1,
-                )
-            else:
-                gts = jax.ops.segment_min(
-                    jnp.where(mask, ts32, jnp.int32(2**31 - 1)),
-                    skey, num_segments=G1,
-                )
-                at = mask & (ts32 == jnp.take(gts, skey, mode="clip"))
-                grow = jax.ops.segment_min(
-                    jnp.where(at, row32, jnp.int32(2**31 - 1)),
-                    skey, num_segments=G1,
-                )
-            out["rep_ts"] = gts[: spec.num_groups]
-            out["rep_row"] = grow[: spec.num_groups]
+            with jax.named_scope("bydb.rep"):
+                ts32 = chunk["ts"]
+                row32 = chunk["row"]
+                G1 = spec.num_groups + 1
+                skey = jnp.where(mask, key, jnp.int32(spec.num_groups))
+                if spec.rep_desc:
+                    gts = jax.ops.segment_max(
+                        jnp.where(mask, ts32, jnp.int32(-(2**31) + 1)),
+                        skey, num_segments=G1,
+                    )
+                    at = mask & (ts32 == jnp.take(gts, skey, mode="clip"))
+                    grow = jax.ops.segment_max(
+                        jnp.where(at, row32, jnp.int32(-1)),
+                        skey, num_segments=G1,
+                    )
+                else:
+                    gts = jax.ops.segment_min(
+                        jnp.where(mask, ts32, jnp.int32(2**31 - 1)),
+                        skey, num_segments=G1,
+                    )
+                    at = mask & (ts32 == jnp.take(gts, skey, mode="clip"))
+                    grow = jax.ops.segment_min(
+                        jnp.where(at, row32, jnp.int32(2**31 - 1)),
+                        skey, num_segments=G1,
+                    )
+                out["rep_ts"] = gts[: spec.num_groups]
+                out["rep_row"] = grow[: spec.num_groups]
         return out
 
     return kernel
@@ -239,10 +275,11 @@ def _build_kernel(spec: PlanSpec):
     chunk pytree structure)."""
     body = _kernel_body(spec)
 
-    def kernel(chunk: dict, pred_vals: dict, hist_lo, hist_span):
+    # the name is the device trace's module line: jit_bydb_chunk_plan
+    def bydb_chunk_plan(chunk: dict, pred_vals: dict, hist_lo, hist_span):
         return body(ops.decode_chunk(chunk), pred_vals, hist_lo, hist_span)
 
-    return jax.jit(kernel)
+    return jax.jit(bydb_chunk_plan)
 
 
 class GlobalDicts:
@@ -694,6 +731,9 @@ def compute_partials(
             device_decode,
         )
 
+    # phase seconds of the gather that runs (none on a serving-cache hit)
+    phases: dict = {}
+
     def _do_gather():
         return _gather_rows(
             sources,
@@ -704,8 +744,12 @@ def compute_partials(
             request.time_range.end_millis,
             dict_state=dict_state,
             device_decode=device_decode,
+            phases=phases,
         )
 
+    # opened BEFORE the work it covers; no child spans under it (its self
+    # time is the benchmark's gather_ms): phases are tags + annotations
+    g = span.child("gather") if span is not None else None
     t_gather0 = _time.perf_counter()
     gather_loaded: list = []  # loader ran -> serving-cache miss
     if gather_key is not None:
@@ -722,17 +766,16 @@ def compute_partials(
     gather_ms = (_time.perf_counter() - t_gather0) * 1000
     _H_GATHER.observe(gather_ms)
     n = chunks_np["ts"].shape[0]
-    if span is not None:
-        g = span.child("gather").tag("rows", int(n)).tag(
-            "sources", len(sources)
-        ).tag(
+    if g is not None:
+        g.finish()
+        g.tag("rows", int(n)).tag("sources", len(sources)).tag(
             "serving_cache",
             ("off" if gather_key is None else "miss")
             if gather_loaded
             else "hit",
         )
-        g.t0 = t_gather0  # span covers the gather that already ran
-        g.finish()
+        for phase, seconds in phases.items():
+            g.tag(f"{phase}_ms", round(seconds * 1000, 3))
     # epoch = global min ts keeps chunk-relative int32 offsets
     # nonnegative for the scan-order key; spans >= 2^31 ms (~24.8 days)
     # would wrap the int32 cast, so rep tracking degrades to canonical
@@ -936,10 +979,12 @@ def _reduce_partials(
     """The reduction tail of compute_partials (cacheable unit).
 
     `span` gets the device/host attribution tags: device_ms is the time
-    spent at the two accelerator boundaries (kernel dispatch + the
-    batched device_get), host_ms the rest of the reduction; pad_ship_ms
-    is the prefetch thread's chunk pad+transfer work (overlapped, so it
-    is NOT a subset of the wall duration)."""
+    spent at the two accelerator boundaries (dispatch_ms: the jitted
+    calls returning; get_ms: the batched device_get), host_ms the rest
+    of the reduction.  Its `decode` child is open while chunks are
+    padded and shipped (pack_ms + h2d_ms = its host_ms; on the staged
+    path that work overlaps the dispatches, so it is NOT a subset of
+    the wall duration)."""
     import contextlib
     import time as _time
 
@@ -1037,10 +1082,12 @@ def _reduce_partials(
     #     host-sync audit that motivated bdlint).
     from banyandb_tpu.storage.chunk_stream import prefetched
 
-    # pad/ship accumulation crosses into the prefetch worker thread:
-    # plain list appends (GIL-atomic), summed by the owner below — Span
-    # objects themselves are single-owner and never touched off-thread
-    pad_ship_s: list = []
+    # pack (pad) / h2d (ship) accumulation crosses into the prefetch
+    # worker thread: plain list appends (GIL-atomic), summed by the owner
+    # below — Span objects themselves are single-owner and never touched
+    # off-thread
+    pack_s: list = []
+    h2d_s: list = []
     chunks_built: list = []
     # (shipped, dense) bytes per built chunk: the decode span's
     # compression evidence (dense = what the decoded i32/f32 ship form
@@ -1052,13 +1099,17 @@ def _reduce_partials(
     def _build_chunk(start: int, end: int):
         t0 = _time.perf_counter()
         chunks_built.append(1)
+        shipped: list = []  # this chunk's jnp.asarray seconds
         try:
-            return _device_chunk(
-                chunks_np, start, end, spec, epoch, ship_stats=ship_stats,
-                lut_cache=lut_cache,
-            )
+            with tracer.annotate("decode.chunk"):
+                return _device_chunk(
+                    chunks_np, start, end, spec, epoch,
+                    ship_stats=ship_stats, lut_cache=lut_cache, h2d_s=shipped,
+                )
         finally:
-            pad_ship_s.append(_time.perf_counter() - t0)
+            h2d = sum(shipped)
+            h2d_s.append(h2d)
+            pack_s.append(_time.perf_counter() - t0 - h2d)
 
     def _make_chunk(start: int, end: int):
         if dev_cache is not None:
@@ -1093,8 +1144,10 @@ def _reduce_partials(
     # come back stacked for the identical f64 absorb loop.
     from banyandb_tpu.query import fused_exec
 
-    device_s = 0.0  # time at the accelerator boundaries (dispatch + get)
+    leg = DeviceLeg()  # time at the accelerator boundaries (dispatch + get)
     dispatches = 0
+    # opened BEFORE the pad + ship work it covers, tagged after
+    dspan = span.child("decode") if span is not None else None
     fused_cache_tag = None
     # planner hints (query/planner): prefer_staged routes an estimated-
     # over-budget batch straight to the staged loop; min_bucket rounds
@@ -1110,7 +1163,7 @@ def _reduce_partials(
         spec, len(chunk_spans), min_bucket=min_bucket
     ):
         path = "fused"
-        moved_chunks, device_s, fused_cache_tag = fused_exec.run_fused(
+        moved_chunks, leg, fused_cache_tag = fused_exec.run_fused(
             chunks_np,
             chunk_spans,
             spec,
@@ -1120,9 +1173,11 @@ def _reduce_partials(
             epoch,
             gather_key=gather_key,
             dev_cache=dev_cache,
-            pad_ship_s=pad_ship_s,
+            pack_s=pack_s,
+            h2d_s=h2d_s,
             ship_stats=ship_stats,
             min_bucket=min_bucket,
+            decode_span=dspan,
         )
         dispatches = 1
         for moved in moved_chunks:
@@ -1130,36 +1185,44 @@ def _reduce_partials(
     else:
         path = "staged"
         pending = None
-        for chunk in prefetched(
-            [lambda s=s, e=e: _make_chunk(s, e) for s, e in chunk_spans],
-            name="bydb-chunk-prefetch",
-        ):
-            t_d = _time.perf_counter()
-            out = kernel(chunk, pred_vals, hist_lo_dev, hist_span_dev)
-            device_s += _time.perf_counter() - t_d
-            dispatches += 1
-            if pending is not None:
+        # what the chunk dispatches trace or compile on this thread
+        with leg.paid:
+            for chunk in prefetched(
+                [lambda s=s, e=e: _make_chunk(s, e) for s, e in chunk_spans],
+                name="bydb-chunk-prefetch",
+            ):
                 t_d = _time.perf_counter()
-                # bdlint: disable=host-sync -- the result boundary: one
-                # batched transfer per chunk, overlapped with dispatch above
-                moved = jax.device_get(pending)
-                device_s += _time.perf_counter() - t_d
-                _absorb(moved)
-            pending = out
+                out = kernel(chunk, pred_vals, hist_lo_dev, hist_span_dev)
+                leg.dispatch_s += _time.perf_counter() - t_d
+                dispatches += 1
+                if pending is not None:
+                    t_d = _time.perf_counter()
+                    # bdlint: disable=host-sync -- the result boundary: one
+                    # batched transfer per chunk, overlapped with dispatch
+                    # above
+                    moved = jax.device_get(pending)
+                    leg.get_s += _time.perf_counter() - t_d
+                    _absorb(moved)
+                pending = out
+        if dspan is not None:
+            dspan.finish()  # the last chunk is padded and shipped
         if pending is not None:
             t_d = _time.perf_counter()
             # bdlint: disable=host-sync -- final chunk's result boundary
             moved = jax.device_get(pending)
-            device_s += _time.perf_counter() - t_d
+            leg.get_s += _time.perf_counter() - t_d
             _absorb(moved)
+    device_s = leg.device_s
     _H_DEVICE.observe(device_s * 1000)
     # -- decode stage attribution (ROADMAP item 3) ------------------------
-    # host half = narrow pack + pad + H2D ship (pad_ship_s, overlapped
-    # with device execution under BYDB_PIPELINE); the device half
+    # host half = narrow pack + pad (pack_s) + H2D ship (h2d_s), overlapped
+    # with device execution under BYDB_PIPELINE; the device half
     # (widen/remap/f32 convert) is fused into the plan dispatch and is
     # deliberately part of device_execute.  Byte counters attribute the
     # compression ratio independently of the platform.
-    decode_ms = sum(pad_ship_s) * 1000
+    pack_ms = sum(pack_s) * 1000
+    h2d_ms = sum(h2d_s) * 1000
+    decode_ms = pack_ms + h2d_ms
     shipped_bytes = sum(s for s, _ in ship_stats)
     dense_bytes = sum(d for _, d in ship_stats)
     decode_mode = "device" if "src_ord" in chunks_np else "host"
@@ -1172,24 +1235,25 @@ def _reduce_partials(
         meter.counter_add(
             "decode_ship_bytes", float(dense_bytes), labels={"form": "dense"}
         )
-    if span is not None:
-        dspan = span.child("decode")
+    if dspan is not None:
         dspan.tag("mode", decode_mode).tag(
             "host_ms", round(decode_ms, 3)
+        ).tag("pack_ms", round(pack_ms, 3)).tag(
+            "h2d_ms", round(h2d_ms, 3)
         ).tag("shipped_bytes", shipped_bytes).tag(
             "dense_bytes", dense_bytes
         ).tag(
             "ratio",
             round(dense_bytes / shipped_bytes, 2) if shipped_bytes else 1.0,
         )
-        dspan.finish()
     if span is not None:
         total_ms = (_time.perf_counter() - t_reduce0) * 1000
-        span.tag("device_ms", round(device_s * 1000, 3)).tag(
+        leg.tag(span)
+        span.tag(
             "host_ms", round(max(total_ms - device_s * 1000, 0.0), 3)
-        ).tag("chunks", len(chunk_spans)).tag(
-            "pad_ship_ms", round(sum(pad_ship_s) * 1000, 3)
-        ).tag("path", path).tag("dispatches", dispatches)
+        ).tag("chunks", len(chunk_spans)).tag("path", path).tag(
+            "dispatches", dispatches
+        )
         if dev_cache is not None:
             if fused_cache_tag is not None:
                 span.tag("device_cache", fused_cache_tag)
@@ -1435,9 +1499,15 @@ def _gather_rows(
     end_millis: int,
     dict_state: Optional[DictState] = None,
     device_decode: bool = False,
+    phases: Optional[dict] = None,
 ) -> dict:
     """Concatenate sources with row-exact time filtering, global-code remap
     and version dedup (block pruning upstream is only block-granular).
+
+    ``phases`` (dict or None) receives the seconds of the four phases,
+    each also a bare ``bydb:gather.<phase>`` annotation: ``select``
+    (per-source time filter, column reads, remap), ``concat``, ``dedup``
+    (hostops.dedup_max_version) and ``take`` (the ``[keep]`` takes).
 
     ``device_decode`` (ROADMAP item 3, ``BYDB_DEVICE_DECODE``): the
     gathered snapshot keeps tag columns in the COMPRESSED ship form —
@@ -1448,6 +1518,8 @@ def _gather_rows(
     (ops.decode.decode_chunk).  Fields stay host-f64 (the exact host
     paths need them) but carry a ``fields_narrow`` dtype decision so the
     pad/ship stage can ship exact-int columns at i8/i16."""
+    import time as _time
+
     from banyandb_tpu.storage import encoded as enc_mod
 
     ts_l, series_l, ver_l = [], [], []
@@ -1456,58 +1528,65 @@ def _gather_rows(
     ord_l: list = []
     f_l: dict[str, list] = {f: [] for f in fields}
     n_src = 0
-    for src in sources:
-        if src.ts.size == 0:
-            continue
-        rng = (src.ts >= begin_millis) & (src.ts < end_millis)
-        if not rng.any():
-            continue
-        nsel = int(rng.sum())
-        ts_l.append(src.ts[rng])
-        series_l.append(src.series[rng])
-        ver_l.append(src.version[rng])
-        if device_decode:
-            ord_l.append(np.full(nsel, n_src, dtype=enc_mod.SRC_ORD_DTYPE))
-        n_src += 1
-        for t in tags_code:
-            col = src.tags.get(t)
-            if col is None:
-                # Source predates this tag (schema evolution): its rows all
-                # carry the empty value, same convention as merge/raw paths.
-                if dict_state is not None:
-                    with dict_state.lock:
-                        absent = gd.absent_code(t)
-                else:
-                    absent = gd.absent_code(t)
-                if device_decode:
-                    # compressed form: a one-entry LUT row and local
-                    # code 0 everywhere — the device remap lands the
-                    # same global absent code the dense path bakes in
-                    tc_l[t].append(np.zeros(nsel, dtype=np.int8))
-                    lut_l[t].append(np.asarray([absent], dtype=np.int32))
-                else:
-                    tc_l[t].append(np.full(nsel, absent, dtype=np.int32))
-            else:
-                lut = _source_lut(src, t, gd, dict_state)
-                codes = col[rng]
-                if device_decode:
-                    if lut.size:
-                        w = enc_mod.code_dtype(lut.size)
-                        tc_l[t].append(codes.astype(w, copy=False))
-                        lut_l[t].append(lut)
+    t_select0 = _time.perf_counter()
+    with tracer.annotate("gather.select"):
+        for src in sources:
+            if src.ts.size == 0:
+                continue
+            rng = (src.ts >= begin_millis) & (src.ts < end_millis)
+            if not rng.any():
+                continue
+            nsel = int(rng.sum())
+            ts_l.append(src.ts[rng])
+            series_l.append(src.series[rng])
+            ver_l.append(src.version[rng])
+            if device_decode:
+                ord_l.append(np.full(nsel, n_src, dtype=enc_mod.SRC_ORD_DTYPE))
+            n_src += 1
+            for t in tags_code:
+                col = src.tags.get(t)
+                if col is None:
+                    # Source predates this tag (schema evolution): its rows all
+                    # carry the empty value, same convention as merge/raw
+                    # paths.
+                    if dict_state is not None:
+                        with dict_state.lock:
+                            absent = gd.absent_code(t)
                     else:
+                        absent = gd.absent_code(t)
+                    if device_decode:
+                        # compressed form: a one-entry LUT row and local
+                        # code 0 everywhere — the device remap lands the
+                        # same global absent code the dense path bakes in
                         tc_l[t].append(np.zeros(nsel, dtype=np.int8))
-                        lut_l[t].append(np.zeros(1, dtype=np.int32))
+                        lut_l[t].append(np.asarray([absent], dtype=np.int32))
+                    else:
+                        tc_l[t].append(np.full(nsel, absent, dtype=np.int32))
                 else:
-                    tc_l[t].append(
-                        lut[codes] if lut.size else np.zeros(nsel, np.int32)
-                    )
-        for f in fields:
-            col = src.fields.get(f)
-            if col is None:
-                f_l[f].append(np.zeros(nsel, dtype=np.float64))
-            else:
-                f_l[f].append(col[rng])
+                    lut = _source_lut(src, t, gd, dict_state)
+                    codes = col[rng]
+                    if device_decode:
+                        if lut.size:
+                            w = enc_mod.code_dtype(lut.size)
+                            tc_l[t].append(codes.astype(w, copy=False))
+                            lut_l[t].append(lut)
+                        else:
+                            tc_l[t].append(np.zeros(nsel, dtype=np.int8))
+                            lut_l[t].append(np.zeros(1, dtype=np.int32))
+                    else:
+                        tc_l[t].append(
+                            lut[codes]
+                            if lut.size
+                            else np.zeros(nsel, np.int32)
+                        )
+            for f in fields:
+                col = src.fields.get(f)
+                if col is None:
+                    f_l[f].append(np.zeros(nsel, dtype=np.float64))
+                else:
+                    f_l[f].append(col[rng])
+    if phases is not None:
+        phases["select"] = _time.perf_counter() - t_select0
 
     if not ts_l:
         empty = dict(
@@ -1526,32 +1605,60 @@ def _gather_rows(
             }
         return empty
 
-    ts = np.concatenate(ts_l)
-    series = np.concatenate(series_l)
-    version = np.concatenate(ver_l)
+    # Same order of allocations as ever: each column is concatenated and
+    # taken in one expression, so its temporary is freed before the next
+    # is made.  Holding every concatenated column across the dedup cost
+    # 88 ms a query on the chip host (PERF.md, PR 25): the dedup's own
+    # temporaries then came from fresh pages instead of reused ones.
+    concat_s = take_s = 0.0
+
+    def cat(parts: list) -> np.ndarray:
+        nonlocal concat_s
+        t0 = _time.perf_counter()
+        with tracer.annotate("gather.concat"):
+            out = np.concatenate(parts)
+        concat_s += _time.perf_counter() - t0
+        return out
+
+    def take(col: np.ndarray) -> np.ndarray:
+        nonlocal take_s
+        t0 = _time.perf_counter()
+        with tracer.annotate("gather.take"):
+            out = col[keep]
+        take_s += _time.perf_counter() - t0
+        return out
+
+    ts = cat(ts_l)
+    series = cat(series_l)
+    version = cat(ver_l)
     # Global version dedup: keep the max-version row per (series, ts).
-    keep = hostops.dedup_max_version(series, ts, version)
+    t0 = _time.perf_counter()
+    with tracer.annotate("gather.dedup"):
+        keep = hostops.dedup_max_version(series, ts, version)
+    dedup_s = _time.perf_counter() - t0
 
     out = dict(
-        ts=ts[keep],
-        series=series[keep],
-        fields={f: np.concatenate(f_l[f])[keep] for f in fields},
+        ts=take(ts),
+        series=take(series),
+        fields={f: take(cat(f_l[f])) for f in fields},
     )
     if device_decode:
         # narrow gather: mixed per-source widths promote to the widest
         # (np.concatenate's int promotion), values untouched
-        out["tags_enc"] = {
-            t: np.concatenate(tc_l[t])[keep] for t in tags_code
-        }
+        out["tags_enc"] = {t: take(cat(tc_l[t])) for t in tags_code}
         out["tags_lut"] = {t: tuple(lut_l[t]) for t in tags_code}
-        out["src_ord"] = np.concatenate(ord_l)[keep]
+        out["src_ord"] = take(cat(ord_l))
+        t0 = _time.perf_counter()
         out["fields_narrow"] = {
             f: enc_mod.narrow_int_dtype(out["fields"][f]) for f in fields
         }
+        take_s += _time.perf_counter() - t0
     else:
-        out["tags_code"] = {
-            t: np.concatenate(tc_l[t])[keep] for t in tags_code
-        }
+        out["tags_code"] = {t: take(cat(tc_l[t])) for t in tags_code}
+    if phases is not None:
+        phases["concat"] = concat_s
+        phases["dedup"] = dedup_s
+        phases["take"] = take_s
     return out
 
 
@@ -1597,6 +1704,7 @@ def _device_chunk(
     epoch: int,
     ship_stats: Optional[list] = None,
     lut_cache: Optional[dict] = None,
+    h2d_s: Optional[list] = None,
 ) -> dict:
     """Pad one row range into the fixed chunk shape, ship to device.
 
@@ -1609,15 +1717,26 @@ def _device_chunk(
     collects (shipped_bytes, dense_bytes) per chunk for the decode span
     and the ``decode_ship_bytes_total`` counters — dense is what the
     decoded i32/f32 form would have shipped for the same columns.
+    ``h2d_s`` collects the seconds of each host-to-device ship (the
+    decode span's ``h2d_ms``; the rest of this function is ``pack_ms``).
     """
+    import time as _time
+
     n = end - start
     nb = spec.nrows
     compressed = "src_ord" in cols
 
+    def ship(a: np.ndarray):
+        t0 = _time.perf_counter()
+        dev = jnp.asarray(a)
+        if h2d_s is not None:
+            h2d_s.append(_time.perf_counter() - t0)
+        return dev
+
     def pad(a: np.ndarray, dtype):
         out = np.zeros((nb,), dtype=dtype)
         out[:n] = a[start:end]
-        return jnp.asarray(out)
+        return ship(out)
 
     valid = np.zeros((nb,), dtype=bool)
     valid[:n] = True
@@ -1628,9 +1747,9 @@ def _device_chunk(
     ts = np.zeros((nb,), dtype=np.int64)
     ts[:n] = ts_off
     chunk = {
-        "ts": jnp.asarray(ts.astype(np.int32)),
+        "ts": ship(ts.astype(np.int32)),
         "series": pad(cols["series"] % (2**31), np.int32),
-        "valid": jnp.asarray(valid),
+        "valid": ship(valid),
     }
     shipped = dense = 0
     if compressed:
@@ -1649,7 +1768,7 @@ def _device_chunk(
             for t in spec.tags_code:
                 dev = None if lut_cache is None else lut_cache.get(t)
                 if dev is None:
-                    dev = jnp.asarray(enc_mod.pack_luts(cols["tags_lut"][t]))
+                    dev = ship(enc_mod.pack_luts(cols["tags_lut"][t]))
                     if lut_cache is not None:
                         lut_cache[t] = dev
                     shipped += dev.nbytes
@@ -1688,7 +1807,7 @@ def _device_chunk(
     # rep-less plan must still serve a rep-tracking one
     row = np.zeros((nb,), dtype=np.int32)
     row[:n] = np.arange(start, end, dtype=np.int32)
-    chunk["row"] = jnp.asarray(row)
+    chunk["row"] = ship(row)
     if ship_stats is not None:
         ship_stats.append((shipped, dense))
     return chunk

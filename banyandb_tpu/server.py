@@ -45,6 +45,7 @@ from banyandb_tpu.admin.diagnostics import DIAG_TOPIC as TOPIC_DIAGNOSTICS  # no
 TOPIC_TOPN = "topn"
 TOPIC_STREAMAGG = "streamagg"
 TOPIC_QOS = "qos"
+TOPIC_DEVTRACE = "devtrace"
 
 # conservative per-point admission estimate for the memory protector
 _POINT_BYTES = 256
@@ -385,6 +386,7 @@ class StandaloneServer:
         b.subscribe(TOPIC_TOPN, self._topn)
         b.subscribe(TOPIC_STREAMAGG, self._streamagg)
         b.subscribe(TOPIC_QOS, self._qos)
+        b.subscribe(TOPIC_DEVTRACE, self._devtrace)
 
     # -- handlers -----------------------------------------------------------
     def _measure_write(self, env):
@@ -472,11 +474,11 @@ class StandaloneServer:
     @staticmethod
     def _tag_qos(tracer, adm) -> None:
         """The ``qos`` span on the obs plane: which tenant ran, and how
-        long admission queued it (only tagged when it actually queued)."""
+        long admission took (always a number: microseconds when it did
+        not queue)."""
         with tracer.span("qos") as sp:
             sp.tag("tenant", adm.tenant)
-            if adm.queued_ms >= 1.0:
-                sp.tag("queued_ms", round(adm.queued_ms, 2))
+            sp.tag("queued_ms", round(adm.queued_ms, 3))
 
     def _measure_query(self, env):
         from banyandb_tpu.obs import Tracer
@@ -598,6 +600,10 @@ class StandaloneServer:
         self.meter.gauge_set("compile_cache_enabled", float(cc["enabled"]))
         for k in ("hits", "misses", "entries"):
             self.meter.gauge_set(f"compile_cache_{k}", float(cc[k]))
+        # compile events inside the program: programs traced + lowered
+        # (cache hit or not) and what that cost (utils/compile_cache)
+        self.meter.gauge_set("jit_traces", float(cc["traces"]))
+        self.meter.gauge_set("jit_compile_seconds", cc["compile_seconds"])
         # multi-tenant QoS plane: admission gauges + per-tenant cache
         # partitions (tenant-labeled rows; the default tenant keeps its
         # original unlabeled series — no renames)
@@ -633,6 +639,16 @@ class StandaloneServer:
             if worker_text:
                 text = text + "\n" + worker_text
         return {"prometheus": text}
+
+    @staticmethod
+    def _devtrace(env):
+        """A device trace of this process, reduced (obs/devtrace):
+        ``{"seconds": n}``, capped at 30; one capture at a time — a
+        second caller gets an error, as does a process someone else is
+        already profiling.  ``GET /debug/device`` is the same call."""
+        from banyandb_tpu.obs import devtrace
+
+        return {"devtrace": devtrace.capture(float(env.get("seconds", 5.0)))}
 
     def _streamagg(self, env):
         """Streaming-aggregation control surface (query/streamagg.py):
@@ -865,9 +881,14 @@ class StandaloneServer:
     def _ql(self, env):
         from banyandb_tpu.obs import Tracer
 
-        catalog, req = bydbql.parse_with_catalog(
-            env["ql"], env.get("params", ())
-        )
+        # the tracer is made at handler entry so the root covers the
+        # BydbQL parse (its own span); the catalog names the root after
+        tracer = Tracer("standalone:ql")
+        with tracer.span("parse"):
+            catalog, req = bydbql.parse_with_catalog(
+                env["ql"], env.get("params", ())
+            )
+        tracer.root.name = f"standalone:{catalog}"
         if env.get("trace"):
             # cli.py explain (and any caller wanting the in-band tree):
             # force request-level tracing so the reply carries plan text
@@ -875,7 +896,6 @@ class StandaloneServer:
             import dataclasses as _dc
 
             req = _dc.replace(req, trace=True)
-        tracer = Tracer(f"standalone:{catalog}")
         adm = self._admit_query(req, env)
         with adm, tenant_scope(adm.tenant):
             self._tag_qos(tracer, adm)

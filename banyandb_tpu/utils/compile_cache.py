@@ -20,15 +20,31 @@ dispatch a kernel calls ``enable()``.  ``stats()`` feeds the /metrics
 surface.  Hit/miss counts come from jax's own monitoring events
 (``/jax/compilation_cache/cache_hits`` and ``.../cache_misses``) so they
 reflect what XLA actually did, not what we hoped.
+
+Compile events: a program that is traced again costs trace + lower +
+backend compile (or the load from this cache) whether or not the cache
+hits, so jax's time-span events for those three steps are counted too:
+``traces`` (programs lowered: one per top-level jit that had to be
+traced), ``compile_seconds`` (their summed time), one INFO line per
+program (``compile program=<name> ms=<n> cache=hit|miss``), and
+``watch()`` hands the calling thread's share to the query that paid.
 """
 
 from __future__ import annotations
 
+import logging
 import os
+import re
 import threading
 from pathlib import Path
 
 FIXED_DIR = Path(__file__).resolve().parents[2] / ".compile-cache"
+
+log = logging.getLogger("banyandb.compile")
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _lock = threading.Lock()
 _state = {
@@ -36,9 +52,96 @@ _state = {
     "dir": None,
     "hits": 0,
     "misses": 0,
+    "traces": 0,
+    "compile_seconds": 0.0,
     "listener": False,
     "error": None,
 }
+# per thread: the program being compiled on it (jax compiles on the
+# thread that dispatched) and the watch() open on it, if any
+_tl = threading.local()
+
+
+class _Pending:
+    """One program's compile as its events arrive on a thread."""
+
+    __slots__ = ("traces", "seconds", "program", "hit")
+
+    def __init__(self):
+        self.traces: list = []  # (start, end) of trace events booked
+        self.seconds = 0.0
+        self.program = ""
+        self.hit = False
+
+
+class watch:
+    """Context manager: what the calling thread compiled while it was
+    open (``compiled`` programs, ``seconds``, the last ``program``).
+    Set around a dispatch so the query that paid for a trace says so."""
+
+    __slots__ = ("compiled", "seconds", "program")
+
+    def __init__(self):
+        self.compiled = 0
+        self.seconds = 0.0
+        self.program = ""
+
+    def __enter__(self) -> "watch":
+        _tl.watch = self
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        _tl.watch = None
+
+
+def _pending() -> _Pending:
+    pend = getattr(_tl, "pending", None)
+    if pend is None:
+        pend = _tl.pending = _Pending()
+    return pend
+
+
+def _book(pend: _Pending, seconds: float) -> None:
+    pend.seconds += seconds
+    # float += under the GIL; counters are best-effort telemetry
+    _state["compile_seconds"] += seconds
+
+
+def _on_time_span(event: str, start: float, end: float, **kw) -> None:
+    """jax's trace / lower / backend-compile events of one program, in
+    that order, on the thread that dispatched it."""
+    if event == _TRACE_EVENT:
+        pend = _pending()
+        # the outer jit's trace contains its nested jits' traces, which
+        # ended (and were booked) before it: count their time once
+        inner = [(s, e) for s, e in pend.traces if s >= start and e <= end]
+        for span in inner:
+            pend.traces.remove(span)
+        pend.traces.append((start, end))
+        _book(pend, (end - start) - sum(e - s for s, e in inner))
+    elif event == _LOWER_EVENT:
+        pend = _pending()
+        pend.traces.clear()
+        pend.program = str(kw.get("fun_name", ""))
+        _state["traces"] += 1
+        _book(pend, end - start)
+    elif event == _BACKEND_EVENT:
+        pend = _pending()
+        _book(pend, end - start)
+        # `jit(fn)` -> `jit_fn`, the module name the device trace prints
+        program = re.sub(
+            r"\W+", "_", pend.program or str(kw.get("fun_name", "")) or "?"
+        ).strip("_")
+        log.info(
+            "compile program=%s ms=%.1f cache=%s",
+            program, pend.seconds * 1000.0, "hit" if pend.hit else "miss",
+        )
+        w = getattr(_tl, "watch", None)
+        if w is not None:
+            w.compiled += 1
+            w.seconds += pend.seconds
+            w.program = program
+        _tl.pending = None
 
 
 def resolve_dir() -> tuple[str, bool]:
@@ -50,7 +153,8 @@ def resolve_dir() -> tuple[str, bool]:
 
 
 def _install_listener() -> None:
-    """Count persistent-cache hits/misses via jax monitoring events.
+    """Count persistent-cache hits/misses and compile events via jax
+    monitoring events.
 
     Private-API dependent (jax._src.monitoring); counters degrade to 0
     rather than break wiring if the surface moves."""
@@ -63,10 +167,12 @@ def _install_listener() -> None:
             # int += under the GIL; counters are best-effort telemetry
             if event.endswith("/cache_hits"):
                 _state["hits"] += 1
+                _pending().hit = True
             elif event.endswith("/cache_misses"):
                 _state["misses"] += 1
 
         monitoring.register_event_listener(_on_event)
+        monitoring.register_event_time_span_listener(_on_time_span)
         _state["listener"] = True
     except Exception as e:  # noqa: BLE001 — counters are optional
         _state["error"] = f"listener: {type(e).__name__}: {e}"
@@ -112,6 +218,8 @@ def stats() -> dict:
         "dir": d,
         "hits": _state["hits"],
         "misses": _state["misses"],
+        "traces": _state["traces"],
+        "compile_seconds": _state["compile_seconds"],
         "entries": entries,
         "error": _state["error"],
     }

@@ -79,6 +79,11 @@ def claim_backend(role: str) -> dict:
             "chip (one process per chip).  Refusing to serve from the CPU "
             "silently; set JAX_PLATFORMS=cpu to do so on purpose."
         )
+    # from here on every open span is an event on the profiler's clock
+    # (obs/tracer keeps no jax import of its own)
+    from banyandb_tpu.obs import tracer
+
+    tracer.set_annotation_hook(jax.profiler.TraceAnnotation)
     return {
         "backend": dev.platform,
         "device_kind": dev.device_kind,

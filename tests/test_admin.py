@@ -113,10 +113,15 @@ def test_query_trace_in_band(tmp_path):
     r = eng.query(QueryRequest(("g",), "m", TimeRange(T0, T0 + 1000),
                                agg=Aggregation("count", "v"), trace=True))
     assert r.trace is not None
-    names = [s["name"] for s in r.trace["spans"]]
-    assert names == ["gather_sources", "execute"]
-    assert r.trace["spans"][0]["rows"] == 100
-    assert r.trace["total_ms"] > 0
+    # one trace format: the span tree (the flat `spans` list is gone)
+    assert "spans" not in r.trace
+    tree = r.trace["span_tree"]
+    names = [s["name"] for s in tree["children"]]
+    assert names == ["analyze", "planner", "part_gather", "execute"]
+    part_gather = tree["children"][2]
+    assert part_gather["tags"]["rows"] == 100
+    assert part_gather["tags"]["sources"] >= 1
+    assert tree["duration_ms"] > 0
     # trace off by default
     r2 = eng.query(QueryRequest(("g",), "m", TimeRange(T0, T0 + 1000),
                                 agg=Aggregation("count", "v")))

@@ -681,15 +681,17 @@ def test_cache_counters_via_running_server(tmp_path, monkeypatch):
     assert "banyandb_device_cache_hits" in metrics
     assert "banyandb_compile_cache_enabled" in metrics
     assert metrics["banyandb_precompile_recorded"] >= 1
-    # the query trace span carries the same counters in-band
+    # the query's span tree says in-band which cache answered it
     import dataclasses
 
     from banyandb_tpu import bydbql
+    from banyandb_tpu.obs.tracer import find_span
 
     req = dataclasses.replace(bydbql.parse(ql), trace=True)
     res = srv.measure.query(req)
-    assert "hits" in res.trace["serving_cache"]
-    assert "evictions" in res.trace["serving_cache"]
+    gather = find_span(res.trace["span_tree"], "gather")
+    assert gather["tags"]["serving_cache"] in ("hit", "miss")
+    assert "serving_cache" not in res.trace  # the flat stats copy is gone
 
 
 def test_serving_cache_eviction_counter():
