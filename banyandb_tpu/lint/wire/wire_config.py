@@ -401,6 +401,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "jit_compile_seconds": frozenset(),
     "jit_traces": frozenset(),
     "fault_injected": frozenset({"kind", "site"}),
+    "gather_rows": frozenset({"dedup"}),
     "kernel_dispatch_budget": frozenset({"signature"}),
     "lifecycle_stage_ms": frozenset({"stage"}),
     "measure_query_ms": frozenset(),

@@ -8,6 +8,7 @@ part columns and run the device executor (query/measure_exec.py).
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from pathlib import Path
 from typing import Optional
@@ -30,7 +31,7 @@ from banyandb_tpu.obs.tracer import NOOP_TRACER, Tracer
 from banyandb_tpu.query import filter as qfilter
 from banyandb_tpu.query import measure_exec
 from banyandb_tpu.storage.memtable import MemTable
-from banyandb_tpu.storage.part import ColumnData
+from banyandb_tpu.storage.part import ColumnData, KeySpan
 from banyandb_tpu.storage.tsdb import TSDB
 from banyandb_tpu.utils import hashing
 
@@ -851,11 +852,9 @@ class MeasureEngine:
         """Bounded rescan of one uncovered sub-range through the normal
         gather+compute path (block selection prunes to the range; the
         merged-part retry lives in gather_query_sources)."""
-        import dataclasses as _dc
-
         from banyandb_tpu.api.model import TimeRange as _TR
 
-        sub = _dc.replace(req, time_range=_TR(begin, end))
+        sub = dataclasses.replace(req, time_range=_TR(begin, end))
         sources = self.gather_query_sources(
             sub, shard_ids=shard_ids, serial=True
         )
@@ -1165,6 +1164,7 @@ class MeasureEngine:
                     cache_key=(
                         (*ckey, "sfilter", skey) if ckey else None
                     ),
+                    key_span=src.key_span,  # holds for any row subset
                 )
 
             def _read_part(part, blocks, filt):
@@ -1181,8 +1181,18 @@ class MeasureEngine:
                     continue
                 # live memtable + any in-flight flush snapshot (rows
                 # between flush's two commit points stay visible;
-                # version dedup collapses a racing double-expose)
-                hot_cols = shard.hot_columns(m.name)
+                # version dedup collapses a racing double-expose).
+                # Nothing proves their keys unique, and their min/max
+                # rect is all that is known of where the keys lie.
+                hot_cols = [
+                    dataclasses.replace(
+                        mc,
+                        key_span=KeySpan.unproven(
+                            str(shard.root), mc.series, mc.ts
+                        ),
+                    )
+                    for mc in shard.hot_columns(m.name)
+                ]
                 for mem_cols in hot_cols:
                     read_ops.append(
                         lambda mc=mem_cols, filt=_series_rows: filt(
@@ -1204,17 +1214,9 @@ class MeasureEngine:
                 plans: list = []  # (part, candidate blocks, marked set)
                 kept_intervals: list = []
                 if zone_conds and shard_parts:
-                    from banyandb_tpu.storage.part import KeyInterval
-
-                    for mem_cols in hot_cols:
-                        kept_intervals.append(
-                            KeyInterval.conservative(
-                                int(mem_cols.series.min()),
-                                int(mem_cols.series.max()),
-                                int(mem_cols.ts.min()),
-                                int(mem_cols.ts.max()),
-                            )
-                        )
+                    kept_intervals.extend(
+                        mc.key_span.interval for mc in hot_cols
+                    )
                 for part in shard_parts:
                     cands = part.select_blocks(
                         req.time_range.begin_millis,
@@ -1322,8 +1324,6 @@ def _join_hidden_tags(
     reference's series-metadata docs.  Scoped to the gathered (time-
     pruned) sources: a rewrite outside the queried range is invisible
     here, which matches block pruning's visibility everywhere else."""
-    import dataclasses as _dc
-
     latest: dict[str, dict[int, tuple]] = {t: {} for t in hidden}
     for src in sources:
         for t in hidden:
@@ -1365,7 +1365,7 @@ def _join_hidden_tags(
             out.append(src)
             continue
         out.append(
-            _dc.replace(src, tags=tags, dicts=dicts, cache_key=None)
+            dataclasses.replace(src, tags=tags, dicts=dicts, cache_key=None)
         )
     return out
 

@@ -731,8 +731,8 @@ def compute_partials(
             device_decode,
         )
 
-    # phase seconds of the gather that runs (none on a serving-cache hit)
-    phases: dict = {}
+    # span tags of the gather that runs (none on a serving-cache hit)
+    gather_tags: dict = {}
 
     def _do_gather():
         return _gather_rows(
@@ -744,7 +744,7 @@ def compute_partials(
             request.time_range.end_millis,
             dict_state=dict_state,
             device_decode=device_decode,
-            phases=phases,
+            tags_out=gather_tags,
         )
 
     # opened BEFORE the work it covers; no child spans under it (its self
@@ -774,8 +774,8 @@ def compute_partials(
             if gather_loaded
             else "hit",
         )
-        for phase, seconds in phases.items():
-            g.tag(f"{phase}_ms", round(seconds * 1000, 3))
+        for key, value in gather_tags.items():
+            g.tag(key, value)
     # epoch = global min ts keeps chunk-relative int32 offsets
     # nonnegative for the scan-order key; spans >= 2^31 ms (~24.8 days)
     # would wrap the int32 cast, so rep tracking degrades to canonical
@@ -1490,6 +1490,41 @@ def _source_lut(
         return lut
 
 
+def _dedup_components(spans: list) -> list[list[int]]:
+    """The groups of sources (ordinals into ``spans``, ascending) whose
+    rows must go through the version dedup together.
+
+    Two sources can hold the same (series, ts) key only if they have the
+    same scope and their intervals intersect (storage/part.py KeySpan);
+    a source with no span may collide with any other.  So the connected
+    components of that relation partition the keys, and a component of
+    ONE source that is ``unique`` has nothing to dedup: it is left out.
+    Pairs are tested inside one scope only, a shard's handful of parts."""
+    if any(sp is None for sp in spans):
+        return [list(range(len(spans)))]
+    root = list(range(len(spans)))
+
+    def find(i: int) -> int:
+        while root[i] != i:
+            root[i] = i = root[root[i]]
+        return i
+
+    by_scope: dict[str, list[int]] = {}
+    for i, sp in enumerate(spans):
+        by_scope.setdefault(sp.scope, []).append(i)
+    for members in by_scope.values():
+        for k, a in enumerate(members):
+            for b in members[k + 1 :]:
+                if spans[a].interval.intersects(spans[b].interval):
+                    root[find(a)] = find(b)
+    groups: dict[int, list[int]] = {}
+    for i in range(len(spans)):
+        groups.setdefault(find(i), []).append(i)
+    return [
+        g for g in groups.values() if len(g) > 1 or not spans[g[0]].unique
+    ]
+
+
 def _gather_rows(
     sources: list[ColumnData],
     tags_code: list[str],
@@ -1499,15 +1534,24 @@ def _gather_rows(
     end_millis: int,
     dict_state: Optional[DictState] = None,
     device_decode: bool = False,
-    phases: Optional[dict] = None,
+    tags_out: Optional[dict] = None,
 ) -> dict:
     """Concatenate sources with row-exact time filtering, global-code remap
     and version dedup (block pruning upstream is only block-granular).
 
-    ``phases`` (dict or None) receives the seconds of the four phases,
-    each also a bare ``bydb:gather.<phase>`` annotation: ``select``
-    (per-source time filter, column reads, remap), ``concat``, ``dedup``
-    (hostops.dedup_max_version) and ``take`` (the ``[keep]`` takes).
+    The dedup (max version per (series, ts)) runs only over sources whose
+    keys may collide (_dedup_components); the rows and their order are
+    those a dedup over everything gives.  ``gather_rows{dedup=skipped|
+    sorted}`` counts the rows either way.
+
+    ``tags_out`` (dict or None) receives the ``gather`` span's tags: the
+    milliseconds of the four phases, each also a bare
+    ``bydb:gather.<phase>`` annotation — ``select_ms`` (per-source time
+    filter, the interval tests, column reads, remap), ``concat_ms``,
+    ``dedup_ms`` (the component dedups: 0 when none ran) and ``take_ms``
+    (the ``[keep]`` takes, skipped when no row was dropped, and the
+    narrow-dtype scan) — and ``proven_unique_share``, the percent of the
+    selected rows that skipped the dedup.
 
     ``device_decode`` (ROADMAP item 3, ``BYDB_DEVICE_DECODE``): the
     gathered snapshot keeps tag columns in the COMPRESSED ship form —
@@ -1522,27 +1566,31 @@ def _gather_rows(
 
     from banyandb_tpu.storage import encoded as enc_mod
 
-    ts_l, series_l, ver_l = [], [], []
+    ts_l, series_l = [], []
+    ver_l: dict[int, np.ndarray] = {}  # of the sources that dedup
     tc_l: dict[str, list] = {t: [] for t in tags_code}
     lut_l: dict[str, list] = {t: [] for t in tags_code}
     ord_l: list = []
     f_l: dict[str, list] = {f: [] for f in fields}
-    n_src = 0
     t_select0 = _time.perf_counter()
     with tracer.annotate("gather.select"):
+        selected = []  # (source, its rows in range, how many)
         for src in sources:
             if src.ts.size == 0:
                 continue
             rng = (src.ts >= begin_millis) & (src.ts < end_millis)
-            if not rng.any():
-                continue
-            nsel = int(rng.sum())
+            nsel = int(np.count_nonzero(rng))
+            if nsel:
+                selected.append((src, rng, nsel))
+        comps = _dedup_components([sel[0].key_span for sel in selected])
+        dedups = {i for comp in comps for i in comp}
+        for n_src, (src, rng, nsel) in enumerate(selected):
             ts_l.append(src.ts[rng])
             series_l.append(src.series[rng])
-            ver_l.append(src.version[rng])
+            if n_src in dedups:
+                ver_l[n_src] = src.version[rng]
             if device_decode:
                 ord_l.append(np.full(nsel, n_src, dtype=enc_mod.SRC_ORD_DTYPE))
-            n_src += 1
             for t in tags_code:
                 col = src.tags.get(t)
                 if col is None:
@@ -1585,10 +1633,12 @@ def _gather_rows(
                     f_l[f].append(np.zeros(nsel, dtype=np.float64))
                 else:
                     f_l[f].append(col[rng])
-    if phases is not None:
-        phases["select"] = _time.perf_counter() - t_select0
+        del selected  # the masks
+    select_s = _time.perf_counter() - t_select0
 
     if not ts_l:
+        if tags_out is not None:
+            tags_out["select_ms"] = round(select_s * 1000, 3)
         empty = dict(
             ts=np.zeros(0, np.int64),
             series=np.zeros(0, np.int64),
@@ -1621,21 +1671,52 @@ def _gather_rows(
         return out
 
     def take(col: np.ndarray) -> np.ndarray:
+        # no row dropped: `col` is cat's fresh array, not a copy of it
         nonlocal take_s
         t0 = _time.perf_counter()
         with tracer.annotate("gather.take"):
-            out = col[keep]
+            out = col if keep is None else col[keep]
         take_s += _time.perf_counter() - t0
         return out
 
     ts = cat(ts_l)
     series = cat(series_l)
-    version = cat(ver_l)
-    # Global version dedup: keep the max-version row per (series, ts).
+    n = ts.shape[0]
+    # every source in one component: the dedup over everything, as ever
+    whole = len(comps) == 1 and len(comps[0]) == len(ts_l)
+    version = cat([ver_l[i] for i in comps[0]]) if whole else None
     t0 = _time.perf_counter()
     with tracer.annotate("gather.dedup"):
-        keep = hostops.dedup_max_version(series, ts, version)
+        if whole:
+            keep = hostops.dedup_max_version(series, ts, version)
+            if keep.shape[0] == n:
+                keep = None
+        else:
+            offs = np.cumsum([0] + [p.shape[0] for p in ts_l])
+            kept = None  # row mask, made when a component drops a row
+            for comp in comps:
+                k = hostops.dedup_max_version(
+                    np.concatenate([series_l[i] for i in comp]),
+                    np.concatenate([ts_l[i] for i in comp]),
+                    np.concatenate([ver_l[i] for i in comp]),
+                )
+                if k.shape[0] == sum(ts_l[i].shape[0] for i in comp):
+                    continue
+                if kept is None:
+                    kept = np.ones(n, dtype=bool)
+                # the component's rows, as positions in the concat
+                pos = np.concatenate(
+                    [
+                        np.arange(offs[i], offs[i + 1], dtype=np.int64)
+                        for i in comp
+                    ]
+                )
+                kept[pos] = False
+                kept[pos[k]] = True
+            keep = None if kept is None else np.flatnonzero(kept)
     dedup_s = _time.perf_counter() - t0
+    del version
+    n_dedup = sum(ts_l[i].shape[0] for i in dedups)
 
     out = dict(
         ts=take(ts),
@@ -1655,10 +1736,20 @@ def _gather_rows(
         take_s += _time.perf_counter() - t0
     else:
         out["tags_code"] = {t: take(cat(tc_l[t])) for t in tags_code}
-    if phases is not None:
-        phases["concat"] = concat_s
-        phases["dedup"] = dedup_s
-        phases["take"] = take_s
+    meter = obs_metrics.global_meter()
+    meter.counter_add("gather_rows", n - n_dedup, labels={"dedup": "skipped"})
+    meter.counter_add("gather_rows", n_dedup, labels={"dedup": "sorted"})
+    if tags_out is not None:
+        for phase, seconds in (
+            ("select", select_s),
+            ("concat", concat_s),
+            ("dedup", dedup_s),
+            ("take", take_s),
+        ):
+            tags_out[f"{phase}_ms"] = round(seconds * 1000, 3)
+        tags_out["proven_unique_share"] = round(
+            100.0 * (n - n_dedup) / n, 3
+        )
     return out
 
 

@@ -57,6 +57,9 @@ class ColumnData:
     # immutable identity for serving-cache layers (set for part-backed
     # sources; None for memtable/index sources, which mutate)
     cache_key: "Optional[tuple]" = None
+    # what is proven about these rows' (series, ts) keys, for the query
+    # gather's version dedup (None = nothing: dedup against everything)
+    key_span: "Optional[KeySpan]" = None
 
 
 @dataclass(frozen=True)
@@ -95,6 +98,31 @@ class KeyInterval:
         lex = self.lo <= other.hi and other.lo <= self.hi
         rect = self.ts_lo <= other.ts_hi and other.ts_lo <= self.ts_hi
         return lex and rect
+
+
+@dataclass(frozen=True)
+class KeySpan:
+    """Where a source's rows can collide on (series, ts): only with a
+    source of the same ``scope`` (the shard directory: series hash to
+    one shard, segments partition time) whose ``interval`` intersects,
+    and with each other unless ``unique`` (no key repeats among them).
+    A row subset of the source keeps all three."""
+
+    scope: str
+    interval: KeyInterval
+    unique: bool
+
+    @staticmethod
+    def unproven(scope: str, series: np.ndarray, ts: np.ndarray) -> "KeySpan":
+        """For rows nothing is recorded about (memtable, flushing
+        snapshot; non-empty): their min/max rect, not unique."""
+        return KeySpan(
+            scope,
+            KeyInterval.conservative(
+                series.min(), series.max(), ts.min(), ts.max()
+            ),
+            unique=False,
+        )
 
 
 def _col_file(name: str) -> str:
@@ -216,6 +244,13 @@ class PartWriter:
             "tags": sorted(tag_codes.keys()),
             "fields": sorted(fields.keys()),
             "has_payload": payloads is not None,
+            # no two rows share (series, ts): the rows are sorted, so a
+            # repeat is an adjacent pair.  A merged measure part is
+            # always unique, a flushed one unless a key was written
+            # twice; absent (older parts) = not proven
+            "unique_keys": not bool(
+                ((series[1:] == series[:-1]) & (ts[1:] == ts[:-1])).any()
+            ),
         }
         if extra_meta:
             meta.update(extra_meta)
@@ -295,6 +330,24 @@ class Part:
             (b["max_series"], b["max_ts"]),
             b["min_ts"],
             b["max_ts"],
+        )
+
+    def key_span(self, block_ids: Sequence[int]) -> "Optional[KeySpan]":
+        """What is proven about the keys of the rows of `block_ids`:
+        this part's scope, the hull of the blocks' intervals, and the
+        part-level `unique_keys` fact (it holds for any subset)."""
+        if not len(block_ids):
+            return None
+        ivs = [self.block_interval(i) for i in block_ids]
+        return KeySpan(
+            str(self.dir.parent),
+            KeyInterval(
+                min(iv.lo for iv in ivs),
+                max(iv.hi for iv in ivs),
+                min(iv.ts_lo for iv in ivs),
+                max(iv.ts_hi for iv in ivs),
+            ),
+            self.meta.get("unique_keys") is True,
         )
 
     def dict_index(self, tag: str) -> Mapping[bytes, int]:
@@ -556,4 +609,5 @@ class Part:
             dicts={t: self.dict_for(t) for t in tags},
             payloads=payloads,
             cache_key=key,
+            key_span=self.key_span(block_ids),
         )

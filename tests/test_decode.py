@@ -242,6 +242,44 @@ def test_narrow_int_dtype_edges():
     assert nd(np.array([np.inf])) is None
 
 
+def _narrow_whole_column(values):
+    """narrow_int_dtype as it was, over the whole column at once."""
+    if values.size == 0:
+        return np.dtype(np.int8)
+    if not np.isfinite(values).all():
+        return None
+    if not (values == np.rint(values)).all():
+        return None
+    if np.signbit(values[values == 0.0]).any():
+        return None
+    lo, hi = float(values.min()), float(values.max())
+    if -(1 << 7) <= lo and hi < 1 << 7:
+        return np.dtype(np.int8)
+    if -(1 << 15) <= lo and hi < 1 << 15:
+        return np.dtype(np.int16)
+    return None
+
+
+@pytest.mark.parametrize("where", ["first", "boundary", "last"])
+@pytest.mark.parametrize(
+    "odd_one",
+    [None, 127.0, 128.0, -129.0, 32767.0, 32768.0, -32769.0, 0.5, -0.0,
+     np.nan, -np.inf],
+    ids=str,
+)
+def test_narrow_int_dtype_blockwise_scan_is_the_whole_column_scan(
+    odd_one, where
+):
+    """The scan runs in blocks and leaves early; the one value that
+    decides may sit in any of them."""
+    n = 2 * encoded._SCAN_ROWS + 17
+    values = np.random.default_rng(5).integers(-100, 100, n).astype(np.float64)
+    if odd_one is not None:
+        at = {"first": 3, "boundary": encoded._SCAN_ROWS, "last": n - 1}
+        values[at[where]] = odd_one
+    assert encoded.narrow_int_dtype(values) == _narrow_whole_column(values)
+
+
 def test_pack_luts_shapes():
     out = encoded.pack_luts([])
     assert out.shape == (1, 1)
@@ -614,6 +652,52 @@ def test_zone_skip_never_resurrects_stale_versions(tmp_path, monkeypatch):
         monkeypatch.setenv("BYDB_ZONE_SKIP", flag)
         r = engine.query(req)
         assert r.values["count"] == [0.0], (flag, r.values)
+
+
+def test_rewrite_in_a_later_part_is_deduped_not_proven_away(tmp_path):
+    """The gather's dedup skip (measure_exec._dedup_components) rests on
+    the same key intervals: part A holds the keys at v1, part B rewrites
+    them at v2, part C is time-disjoint.  A and B intersect, so they are
+    sorted together and v2 alone answers; only C's rows skip the sort."""
+    from banyandb_tpu.api import Catalog, Group, ResourceOpts, SchemaRegistry
+    from banyandb_tpu.models.measure import MeasureEngine
+
+    reg = SchemaRegistry(tmp_path / "rw")
+    reg.create_group(Group("g", Catalog.MEASURE, ResourceOpts(shard_num=1)))
+    reg.create_measure(
+        Measure(
+            "g", "m", (TagSpec("svc", TagType.STRING),),
+            (FieldSpec("v", FieldType.INT),), Entity(("svc",)),
+        )
+    )
+    engine = MeasureEngine(reg, tmp_path / "rw" / "data")
+    n = 1000
+    for version, base, value in ((1, 0, 1.0), (2, 0, 100.0), (1, n, 1.0)):
+        engine.write_columns(
+            "g", "m", ts_millis=T0 + base + np.arange(n),
+            tags={"svc": ["s"] * n}, fields={"v": np.full(n, value)},
+            versions=np.full(n, version, dtype=np.int64),
+        )
+        engine.flush()
+    r = engine.query(
+        QueryRequest(
+            ("g",), "m", TimeRange(T0, T0 + 2 * n),
+            agg=Aggregation("sum", "v"), trace=True,
+        )
+    )
+    assert r.values["sum(v)"] == [100.0 * n + n]  # v1 of A never counts
+
+    def gather_tags(span):
+        if span["name"] == "gather":
+            return span["tags"]
+        for c in span["children"]:
+            tags = gather_tags(c)
+            if tags is not None:
+                return tags
+
+    tags = gather_tags(r.trace["span_tree"])
+    assert tags["proven_unique_share"] == round(100.0 / 3, 3)
+    assert tags["rows"] == 2 * n and tags["sources"] == 3
 
 
 def test_zone_skip_safety_gate_blocks_overlapping_marked_blocks(tmp_path):
