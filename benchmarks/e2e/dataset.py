@@ -5,7 +5,8 @@ buckets and the field distributions; `Dataset` draws the data from the
 seed as two [buckets, series] arrays (time-major, one point per series
 per bucket, timestamps aligned to the bucket).  `Dataset.answer` is the
 NumPy oracle for one query spec on that data, `check` the comparison
-that decides `correct`.  Nothing here imports the program or JAX.
+that decides `correct`, `gaps` the numbers it compares, each against its
+limit in LIMITS.  Nothing here imports the program or JAX.
 """
 
 from __future__ import annotations
@@ -18,6 +19,14 @@ HIST_BUCKETS = 512  # the device percentile histogram's width
 SUM_RTOL = 1e-5  # INT sums: f32 tile partials + Kahan (tests/test_precision.py)
 FLOAT_RTOL = 1e-9  # FLOAT sums/means: exact f64 on the host by design
 REHEARSAL_SERIES_CUT = 20  # BENCH_E2E_REHEARSE: series (and batches) / 20
+# what a run compares (`gaps`, and run.py's two counts), and the most each may
+# read in a correct run: the guarantees of configs/<name>.json, a value's gap
+# in units of its tolerance
+LIMITS = {
+    "readback_missing": 0, "unanswered": 0,
+    "groups_gap": 0, "count_gap": 0, "value_gap_tol": 1.0, "top_gap_tol": 1.0,
+}
+GAP_CAP = 1e30  # what a gap that is not a number reads: JSON holds it, no limit admits it
 
 
 class Dataset:
@@ -191,23 +200,70 @@ def answer_of(result: dict) -> dict:
     }
 
 
+def _tolerance(q: dict, want: dict) -> tuple[float, float]:
+    """(rtol, atol) of the path that computed `q`'s values."""
+    if q["agg"] == "percentile":
+        return 0.0, want["pct_tol"] * 1.001
+    if q["field"] == "hits" or q["agg"] in ("min", "max"):
+        return SUM_RTOL, 0.0
+    return FLOAT_RTOL, 0.0
+
+
+def _in_tolerances(diff, tol):
+    """diff / tol; a NaN, or a gap over a tolerance of 0, reads GAP_CAP."""
+    with np.errstate(divide="ignore", invalid="ignore", over="ignore"):
+        rel = np.asarray(diff, np.float64) / tol
+    return np.minimum(np.nan_to_num(rel, nan=GAP_CAP, posinf=GAP_CAP), GAP_CAP)
+
+
+def gaps(q: dict, got: dict, want: dict) -> dict:
+    """The numbers `check` compares, each the worst over the answer and
+    held to LIMITS: groups returned that the data does not hold plus
+    groups short of (or over) what is due, the widest count difference,
+    the widest value difference in units of its tolerance, and for TOP n
+    by how many tolerances the best group left out beats the worst
+    returned."""
+    rtol, atol = _tolerance(q, want)
+    index = {n: i for i, n in enumerate(want["names"])}
+    known = {g: v for g, v in got.items() if g in index}
+    top = want["top"]
+    due = min(top, len(index)) if top else len(index)
+    out = {"groups_gap": len(got) - len(known) + abs(len(got) - due), "count_gap": 0,
+           "value_gap_tol": 0.0}
+    at = np.array([index[g] for g in known], np.int64)
+    if known:
+        counts = np.array([c for c, _ in known.values()], np.int64)
+        values = np.array([v for _, v in known.values()], np.float64)
+        w = np.asarray(want["metric"], np.float64)[at]
+        diff, tol = np.abs(values - w), atol + rtol * np.abs(w)
+        rel = np.where(diff == 0, 0.0, _in_tolerances(diff, tol))
+        out["count_gap"] = int(np.abs(counts - np.asarray(want["count"])[at]).max())
+        out["value_gap_tol"] = float(rel.max())
+    if top:
+        metric = np.asarray(want["metric"], np.float64)
+        inside = np.zeros(metric.size, bool)
+        inside[at] = True
+        out["top_gap_tol"] = 0.0
+        if inside.any() and not inside.all():
+            worst_in, best_out = metric[inside].min(), metric[~inside].max()
+            if best_out > worst_in:
+                out["top_gap_tol"] = float(
+                    _in_tolerances(best_out - worst_in, rtol * abs(best_out) + atol)
+                )
+    return out
+
+
 def check(q: dict, got: dict, want: dict) -> str | None:
     """None when `got` (answer_of a reply) meets the configuration's
     guarantees against `want` (Dataset.answer), else what differs:
     counts exact, INT sums within SUM_RTOL, FLOAT sums within
     FLOAT_RTOL, percentiles within one histogram bucket, and TOP n
     membership exact except between groups closer than the tolerance."""
-    agg = q["agg"]
     index = {n: i for i, n in enumerate(want["names"])}
     unknown = [g for g in got if g not in index]
     if unknown:
         return f"groups the data does not hold: {sorted(unknown)[:5]}"
-    if agg == "percentile":
-        rtol, atol = 0.0, want["pct_tol"] * 1.001
-    elif q["field"] == "hits" or agg in ("min", "max"):
-        rtol, atol = SUM_RTOL, 0.0
-    else:
-        rtol, atol = FLOAT_RTOL, 0.0
+    rtol, atol = _tolerance(q, want)
     for g, (count, value) in got.items():
         i = index[g]
         if count != int(want["count"][i]):

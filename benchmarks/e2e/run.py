@@ -12,7 +12,9 @@ default flags on a fresh root and is the process that holds the chip.
              the cell's own shapes until two in a row compile nothing
     window : the traffic mix's closed-loop clients for --seconds
     after  : stop the server, check every answer against the oracle,
-             reduce spans / counters / the profiler trace
+             reduce spans / counters / the profiler trace, print each
+             number compared beside its limit (stderr, and `compared`
+             in the result line)
 
 `setup_s` is process start to window start.  The last stdout line is the
 one JSON result; everything else is on earlier lines and in
@@ -29,6 +31,7 @@ T_START = time.monotonic()  # setup_s counts from here
 
 import argparse  # noqa: E402
 import base64  # noqa: E402
+import gc  # noqa: E402
 import glob  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -368,7 +371,7 @@ class Run:
         reply = self.call("bydbql", {"ql": ql}, timeout=600.0)
         seen = sum(int(c) for c in reply["result"]["values"]["count"])
         self.setup["readback_s"] = time.monotonic() - t0
-        self.setup["readback_ok"] = seen == acked
+        self.setup["readback_seen"] = seen
         say(f"read back: {seen} of {acked} acked points in {self.setup['readback_s']:.1f}s")
 
     def wait_warm(self) -> None:
@@ -428,29 +431,30 @@ class Run:
     def warm_up(self, ds: dataset.Dataset) -> None:
         """The cell's own shapes, until nothing compiles.  Drawn panels
         run `warm_spread` queries spread evenly over their range of
-        starts, then more at random until a merge sweep has passed since
-        the load and two in a row load no new program (compiled or from
-        the cache); repeating panels run in
-        rounds until none is answered by a scan and the materialized
-        windows stand still."""
+        starts and one at each share of it in `warm_at`, then more at
+        random until a merge sweep has passed since the load and two in
+        a row load no new program (compiled or from the cache);
+        repeating panels run in rounds until none is answered by a scan
+        and the materialized windows stand still."""
         t0 = time.monotonic()
         mix = self.cell["mix"]
         panels = {n: mix["panels"][n] for n in dict.fromkeys(mix["cycle"])}
         rng = traffic.draws(self.args.seed, 0, warm=True)
         spread = int(mix.get("warm_spread", 4))
+        places = [(n + 0.5) / spread for n in range(spread)] + list(mix.get("warm_at", []))
         loaded = self.programs_loaded()
         for name, panel in panels.items():
             if traffic.repeats(panel):
                 continue
             quiet = n = 0
             while n < MAX_WARM_QUERIES:
-                if n >= spread and quiet >= 2:
+                if n >= len(places) and quiet >= 2:
                     wait = self.settled_at - time.monotonic()
                     if wait <= 0:
                         break
                     time.sleep(wait)
                     quiet = 0  # a merge may have changed the parts: two more
-                at = (n + 0.5) / spread if n < spread else None
+                at = places[n] if n < len(places) else None
                 rec = self.query(self.cli, traffic.spec(name, panel, ds, rng, at), "setup")
                 if "error" in rec:
                     raise BenchFailure(f"warm-up query failed: {rec['error']}\n{rec['ql']}")
@@ -539,13 +543,21 @@ class Run:
             threading.Thread(target=client, args=(k,), name=f"client-{k}")
             for k in range(int(mix["clients"]))
         ]
-        for t in threads:
-            t.start()
-        trace = None
-        if self.args.trace:
-            trace = self.trace_slice(t_open, seconds)
-        for t in threads:
-            t.join()
+        # the records kept for the oracle are millions of objects by the end of a
+        # window of 1,000-group answers, and each full collection over them would
+        # stop the clients for ~0.1 s, which no server made them wait (PERF.md
+        # section 6, PR 27); nothing the window allocates is cyclic
+        gc.disable()
+        try:
+            for t in threads:
+                t.start()
+            trace = None
+            if self.args.trace:
+                trace = self.trace_slice(t_open, seconds)
+            for t in threads:
+                t.join()
+        finally:
+            gc.enable()
         window_s = max(ends) - t_open  # all the work, all the time it took
         prom = {"before": before, "after": self.prom()}
         return {"window_s": window_s, "prom": prom, "trace": trace}
@@ -566,16 +578,26 @@ class Run:
         }
 
     # -- after the window --------------------------------------------------------------
-    def check_answers(self, ds: dataset.Dataset) -> None:
-        """Every answered query of the run against the oracle."""
+    def check_answers(self, ds: dataset.Dataset) -> dict:
+        """Every answered query of the run against the oracle ->
+        {name: [the worst the run read, its limit]} of what was compared."""
+        worst = {
+            "readback_missing": self.setup["points"] - self.setup["readback_seen"],
+            "unanswered": 0,
+        }
         for rec in self.records:
             if "error" in rec:
                 rec["ok"] = False
+                worst["unanswered"] += 1
                 continue
             want = ds.answer(rec["q"])
+            got = rec.pop("answer")
             rec["points"] = want["points"]
-            rec["wrong"] = dataset.check(rec["q"], rec.pop("answer"), want)
+            rec["wrong"] = dataset.check(rec["q"], got, want)
             rec["ok"] = rec["wrong"] is None
+            for k, v in dataset.gaps(rec["q"], got, want).items():
+                worst[k] = max(worst.get(k, 0), v)
+        return {k: [v, dataset.LIMITS[k]] for k, v in worst.items()}
 
     def reduce_trace(self, trace: dict, win: list[dict]) -> dict | None:
         files = glob.glob(os.path.join(self.trace_dir, "**", "*.xplane.pb"), recursive=True)
@@ -631,7 +653,7 @@ class Run:
         self.cli.close()  # before the server goes, or grpc logs a GOAWAY
         self.server.stop()
 
-        self.check_answers(ds)
+        compared = self.check_answers(ds)
         win = [r for r in self.records if r["phase"] == "window"]
         answered = [r for r in win if "error" not in r]
         failed = [r for r in win if not r["ok"]]
@@ -662,7 +684,7 @@ class Run:
         }
         result = {
             "correct": bool(answered) and not failed and not setup_wrong
-            and bool(self.setup.get("readback_ok")),
+            and compared["readback_missing"][0] == 0,
             "attempted": len(win),
             "failed": len(failed),
             "metrics": per_layer if self.args.trace else end_to_end,
@@ -674,6 +696,7 @@ class Run:
             result["breakdown"] = {
                 "device_ops": xplane["device_ops"], "idle_gaps": xplane["idle_gaps"],
             }
+        result["compared"] = compared  # the last key of the line
         served: dict[str, int] = {}
         for r in answered:
             served[str(r.get("served"))] = served.get(str(r.get("served")), 0) + 1
@@ -752,6 +775,8 @@ def main(argv=None) -> int:
     finally:
         if run is not None:
             run.close()
+    for k, (v, limit) in result["compared"].items():
+        print(f"compared {k} = {v:.6g} (limit {limit:g})", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -7,8 +7,10 @@ reduction against a hand-computed case and a recorded fixture, the
 oracle against a brute-force loop on a 2,000-point data set, `check`
 against answers spoiled on purpose, every reader kind on a canned span
 tree, and every file under configs/, traffic/, metrics/ and every entry
-of BENCHMARK.json for names, units and cross-references.  It is the
-rehearsal gate before a chip call, not a tier-1 test.
+of BENCHMARK.json for names, units and cross-references, and that no
+cell's panel asks for more groups than its LIMIT lets the server return.
+It is the rehearsal gate before a chip call; tests/ under this directory
+runs each check as a pytest case.
 """
 
 from __future__ import annotations
@@ -34,6 +36,9 @@ import traffic  # noqa: E402
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# groups a measure query returns when its text names no LIMIT
+# (banyandb_tpu/bydbql.py:232, as upstream's measure query defaults)
+DEFAULT_LIMIT = 100
 
 
 def load(*parts: str):
@@ -140,6 +145,11 @@ SMALL = {"data": {
 }}
 
 
+def over_limit(q: dict, got: dict, want: dict) -> list[str]:
+    """The numbers of `dataset.gaps` that read over their limit."""
+    return [k for k, v in dataset.gaps(q, got, want).items() if v > dataset.LIMITS[k]]
+
+
 def brute(ds: dataset.Dataset, q: dict) -> dict:
     """The query answered point by point: {group: (count, value)}."""
     rows = ds.rows(0, ds.points)
@@ -205,6 +215,7 @@ def check_oracle() -> None:
         expect(want["points"] == 24 * 50, f"points in range: {want['points']}")
         why = dataset.check(q, got, want)
         expect(why is None, f"oracle and brute force differ on {q}: {why}")
+        expect(not over_limit(q, got, want), f"gaps over their limit on {q}")
         if q.get("top"):
             i = {n: k for k, n in enumerate(want["names"])}
             top = sorted(want["names"], key=lambda g: (-want["metric"][i[g]], g))[: q["top"]]
@@ -222,10 +233,14 @@ def check_oracle() -> None:
     k = want["names"].index(outsider)
     bad_member = {g: v for g, v in good.items() if g != g0}
     bad_member[outsider] = (int(want["count"][k]), float(want["metric"][k]))
-    for name, bad in (("count", bad_count), ("value", bad_value), ("member", bad_member)):
-        expect(dataset.check(q, bad, want) is not None, f"a wrong {name} passed")
     short = {g: v for g, v in good.items() if g != g0}
-    expect(dataset.check(q, short, want) is not None, "a short TOP passed")
+    # each fault is refused by `check` and read over its limit by the number that is its own
+    for name, bad, number in (
+        ("count", bad_count, "count_gap"), ("value", bad_value, "value_gap_tol"),
+        ("member", bad_member, "top_gap_tol"), ("short TOP", short, "groups_gap"),
+    ):
+        expect(dataset.check(q, bad, want) is not None, f"a wrong {name} passed")
+        expect(number in over_limit(q, bad, want), f"a wrong {name}: {number} within its limit")
     reply = {"groups": [["r0"], ["r1"]], "values": {"count": [3, 4], "sum": [1.5, 2.5]}}
     expect(dataset.answer_of(reply) == {"r0": (3, 1.5), "r1": (4, 2.5)}, "answer_of")
 
@@ -249,10 +264,10 @@ def check_traffic() -> None:
             expect((q["lo"] - ds.t0) % ds.bucket_ms != 0, "a range starts on a bucket edge")
             ql = traffic.ql_of(q, "g", "m")
             expect(ql.startswith("SELECT ") and f"BETWEEN {q['lo']} AND {q['hi']}" in ql, ql)
-    q = {"agg": "percentile", "field": "value", "quantiles": [0.5, 0.99], "group_by": "svc",
-         "lo": 1, "hi": 2}
+    pctl = {"agg": "percentile", "field": "value", "quantiles": [0.5, 0.99], "group_by": "svc",
+            "lo": 1, "hi": 2}
     expect(
-        traffic.ql_of(q, "g", "m")
+        traffic.ql_of(pctl, "g", "m")
         == "SELECT PERCENTILE(value, 0.5, 0.99) FROM MEASURE m IN g "
         "TIME BETWEEN 1 AND 2 GROUP BY svc",
         "percentile text",
@@ -264,6 +279,20 @@ def check_traffic() -> None:
         == "SELECT sum(hits) FROM MEASURE m IN g TIME BETWEEN 1 AND 2 "
         "WHERE region != 'r3' GROUP BY svc TOP 10 BY hits",
         "topn text",
+    )
+    expect(
+        traffic.ql_of(dict(pctl, limit=1000), "g", "m")
+        == "SELECT PERCENTILE(value, 0.5, 0.99) FROM MEASURE m IN g "
+        "TIME BETWEEN 1 AND 2 GROUP BY svc LIMIT 1000",
+        "limit text",
+    )
+    panel = {"agg": "sum", "field": "hits", "group_by": "svc", "top": 10, "limit": 20,
+             "range_ms": 60000, "lo": "last"}
+    q = traffic.spec("p", panel, ds, traffic.draws(1, 0))
+    expect(q["limit"] == 20, "spec copies a panel's limit")
+    expect(
+        traffic.ql_of(q, "g", "m").endswith(" GROUP BY svc TOP 10 BY hits LIMIT 20"),
+        "the limit follows TOP",
     )
 
 
@@ -330,8 +359,28 @@ def check_readers() -> None:
 # -- the files ---------------------------------------------------------------------------------
 
 
-def check_files() -> None:
-    bench = load(CHECKOUT, "BENCHMARK.json")
+def truncated(cfg: dict, mix: dict) -> list[str]:
+    """The panels of `mix` that on deployment `cfg` can hold more
+    groups than the server returns: a panel without `top` gets back at
+    most its `limit` groups, DEFAULT_LIMIT when it names none, and the
+    oracle holds it to every group the data holds."""
+    groups = {"svc": cfg["data"]["series"], "region": cfg["data"]["regions"]}
+    out = []
+    for name in dict.fromkeys(mix["cycle"]):
+        panel = mix["panels"][name]
+        if panel.get("top"):
+            continue
+        n, limit = groups[panel["group_by"]], panel.get("limit") or DEFAULT_LIMIT
+        if n > limit:
+            out.append(f"{name}: {n} {panel['group_by']} groups, LIMIT {limit}")
+    return out
+
+
+def check_files(checkout: str = CHECKOUT) -> None:
+    """`checkout` holds BENCHMARK.json and benchmarks/e2e/ (a spoiled
+    copy, in the tests)."""
+    here = os.path.join(checkout, "benchmarks", "e2e")
+    bench = load(checkout, "BENCHMARK.json")
     expect(
         set(bench) == {"command", "paths", "run_seconds", "configs", "workloads",
                        "end_to_end", "per_layer"},
@@ -342,13 +391,14 @@ def check_files() -> None:
     expect("setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25, "setup_s and its bound")
     cells = {w["name"]: w for w in bench["workloads"]}
     configs = {c["name"]: c for c in bench["configs"]}
-    peaks = load(HERE, "peaks.json")
+    peaks = load(here, "peaks.json")
     expect("TPU v5 lite" in peaks and peaks["source"], "peaks.json")
 
+    deployments = {}
     for c in configs.values():
         expect(NAME.match(c["name"]) is not None, f"config name {c['name']!r}")
         expect(c["file"].startswith("benchmarks/e2e/configs/"), c["file"])
-        cfg = load(CHECKOUT, c["file"])
+        cfg = deployments[c["name"]] = load(checkout, c["file"])
         expect(
             cfg["name"] == c["name"] and cfg["source"] == c["source"],
             f"{c['file']}: name, source",
@@ -370,10 +420,12 @@ def check_files() -> None:
             expect(NAME.match(w[key]) is not None, f"cell {key} {w[key]!r}")
         expect(w["config"] in configs, f"cell {w['name']}: no config {w['config']}")
         expect(w["chips"] in (1, 4) and 0 < len(w["why"]) <= 200, f"cell {w['name']}")
-        mix = load(HERE, "traffic", w["traffic"] + ".json")
+        mix = load(here, "traffic", w["traffic"] + ".json")
         expect(mix["name"] == w["traffic"] and mix["loop"] == "closed", f"traffic {w['traffic']}")
         expect(all(p in mix["panels"] for p in mix["cycle"]), f"traffic {w['traffic']}: cycle")
-    for path in glob.glob(os.path.join(HERE, "traffic", "*.json")):
+        cut = truncated(deployments[w["config"]], mix)
+        expect(not cut, f"cell {w['name']}: the server would truncate {cut}")
+    for path in glob.glob(os.path.join(here, "traffic", "*.json")):
         expect(NAME.match(os.path.basename(path)[:-5]) is not None, path)
 
     def cells_of(m: dict) -> set:
@@ -390,7 +442,7 @@ def check_files() -> None:
             m["name"],
         )
     files = {}
-    for path in glob.glob(os.path.join(HERE, "metrics", "*.json")):
+    for path in glob.glob(os.path.join(here, "metrics", "*.json")):
         m = load(path)
         expect(os.path.basename(path) == m["name"] + ".json", path)
         expect(m["reader"]["kind"] in readers.READERS, f"{path}: reader kind")

@@ -11,6 +11,10 @@ maps a name to a query spec:
     where      optional {"tag", "op": "=" | "!=", "value"}; the value
                "draw" draws one of the deployment's regions per query
     top        optional n (TOP n BY field, descending)
+    limit      optional n (LIMIT n): the groups the panel asks for.  The
+               server's default is 100 groups (BydbQL, as upstream's
+               measure query), so a panel over more groups without
+               `top` must name its limit (selfcheck.py refuses it else)
     range_ms   length of the time range
     lo         "last": the range ends at the newest point (a repeating
                panel), or {"draw_ms": [a, b]}: the range starts a..b ms
@@ -21,7 +25,10 @@ maps a name to a query spec:
 `warm_spread` (optional, default 4) is how many warm-up queries set-up
 spreads evenly over each drawn panel's range of starts before it draws
 more at random, so that every count of parts and every padded size a
-start can meet is compiled before the window opens.
+start can meet is compiled before the window opens.  `warm_at` (optional)
+lists further places, as shares of that range, for the starts whose shape
+an even spread misses: pctl-6h's are the starts less than one bucket
+before a part boundary, which read one part per shard instead of two.
 
 `spec` turns a panel into one concrete query (spec dict with lo/hi and
 the drawn values, the BydbQL text from it); `stream` is a client's
@@ -52,6 +59,8 @@ def ql_of(q: dict, group: str, measure: str) -> str:
     ql += f" GROUP BY {q['group_by']}"
     if q.get("top"):
         ql += f" TOP {q['top']} BY {q['field']}"
+    if q.get("limit"):
+        ql += f" LIMIT {q['limit']}"
     return ql
 
 
@@ -70,7 +79,7 @@ def spec(
     it (the warm-up's even spread)."""
     q = {
         k: panel[k]
-        for k in ("agg", "field", "quantiles", "group_by", "top")
+        for k in ("agg", "field", "quantiles", "group_by", "top", "limit")
         if panel.get(k) is not None
     }
     q["panel"] = panel_name
