@@ -402,6 +402,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "jit_traces": frozenset(),
     "fault_injected": frozenset({"kind", "site"}),
     "gather_rows": frozenset({"dedup"}),
+    "group_reduce_rows": frozenset({"method"}),
     "kernel_dispatch_budget": frozenset({"signature"}),
     "lifecycle_stage_ms": frozenset({"stage"}),
     "measure_query_ms": frozenset(),

@@ -156,15 +156,24 @@ def select_group_method(nrows: int, num_groups: int) -> str:
     and the ``sort`` path is stable-sorted, keeping per-group
     accumulation in row order (bit-identical to ``scatter``).
 
-    The crossovers below are not measured on this installation: no
-    chip run of this code records which method wins at which (N, G).
-    What the routing encodes is what each method needs — TPU takes the
-    Pallas kernel for bounded group counts (8 group tiles at
-    GTILE=1024: each extra tile re-streams the whole input from HBM)
-    and XLA scatter above that; off-TPU pallas only interprets, so
-    one-hot matmul serves small operands and scatter the rest.  Above
-    SORT_GROUPS_THRESHOLD groups (either backend) segment-sort grouping
-    takes over per the 2411.13245 crossover.
+    Each TPU branch has a cell of the benchmark on its side, and the
+    ``reduce`` span reads them on one scale, gathered rows over the wait
+    for the device (``rows_per_ms``; ``reduce_rows_per_ms`` in
+    PERF_LEDGER.jsonl, builder's chip runs of PR 28 in PERF.md section 6
+    until the ledger has the line): ``pallas`` at G = 1,000 about
+    10,500 rows/ms (``svc1k.pctl-6h``), ``sort`` at G = 100,000 about
+    7,000 (``topn100k.topn-24h``), ``scatter`` at G = 9,000 about 6,600
+    (``ep9k.topn-6h``).  Those waits hold the whole plan program, scan-
+    order tracking and the device decode included, so they rank the
+    cells, not yet the methods: WHERE the crossovers lie is still not
+    measured (no cell runs two methods at one G; ROADMAP S4(b)).  What
+    the routing encodes is what each method needs: TPU takes the Pallas
+    kernel for bounded group counts (8 group tiles at GTILE=1024: each
+    extra tile re-streams the whole input from HBM) and XLA scatter
+    above that; off-TPU pallas only interprets, so one-hot matmul serves
+    small operands and scatter the rest.  Above SORT_GROUPS_THRESHOLD
+    groups (either backend) segment-sort grouping takes over per the
+    2411.13245 crossover.
     """
     if num_groups > SORT_GROUPS_THRESHOLD:
         return "sort"
@@ -175,8 +184,14 @@ def select_group_method(nrows: int, num_groups: int) -> str:
     return "scatter"
 
 
-# back-compat alias (pre-fused-executor name)
-_pick_method = select_group_method
+def resolve_group_method(method: str, nrows: int, num_groups: int) -> str:
+    """The method that runs for a plan asking for ``method``: ``auto``
+    through ``select_group_method``, any other name as given.  What
+    ``group_reduce`` dispatches on and what the ``reduce`` span and
+    ``group_reduce_rows{method}`` report, so the two cannot differ."""
+    if method == "auto":
+        return select_group_method(nrows, num_groups)
+    return method
 
 
 def _scatter_reduce(
@@ -226,8 +241,7 @@ def group_reduce(
     Invalid rows are routed to a spill group (index num_groups) and dropped,
     so padding never pollutes real groups.
     """
-    if method == "auto":
-        method = select_group_method(key.shape[-1], num_groups)
+    method = resolve_group_method(method, key.shape[-1], num_groups)
     # the device trace names the method that ran, not the one asked for
     with jax.named_scope(f"bydb.group_reduce.{method}"):
         return _group_reduce(

@@ -1214,6 +1214,15 @@ def _reduce_partials(
             _absorb(moved)
     device_s = leg.device_s
     _H_DEVICE.observe(device_s * 1000)
+    # the group-by method every chunk of this reduction ran (never "auto")
+    # and the rows it was given: gathered rows, before the predicate
+    group_method = ops.groupby.resolve_group_method(
+        spec.group_method, spec.nrows, spec.num_groups
+    )
+    if chunk_spans:
+        obs_metrics.global_meter().counter_add(
+            "group_reduce_rows", float(n), labels={"method": group_method}
+        )
     # -- decode stage attribution (ROADMAP item 3) ------------------------
     # host half = narrow pack + pad (pack_s) + H2D ship (h2d_s), overlapped
     # with device execution under BYDB_PIPELINE; the device half
@@ -1253,7 +1262,9 @@ def _reduce_partials(
             "host_ms", round(max(total_ms - device_s * 1000, 0.0), 3)
         ).tag("chunks", len(chunk_spans)).tag("path", path).tag(
             "dispatches", dispatches
-        )
+        ).tag("group_method", group_method).tag("groups", spec.num_groups)
+        if device_s > 0:
+            span.tag("rows_per_ms", round(n / (device_s * 1000), 3))
         if dev_cache is not None:
             if fused_cache_tag is not None:
                 span.tag("device_cache", fused_cache_tag)

@@ -23,6 +23,13 @@ def tpu_routing(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
+# the plan kernels' two trailing scalars (histogram lo, span)
+_SCALARS = (
+    jax.ShapeDtypeStruct((), jnp.float32),
+    jax.ShapeDtypeStruct((), jnp.float32),
+)
+
+
 def _lower_tpu(fn, *structs) -> str:
     return fn.trace(*structs).lower(lowering_platforms=("tpu",)).as_text()
 
@@ -77,10 +84,6 @@ def test_builtin_plans_lower_for_tpu(tpu_routing):
 
     spec = dict(precompile.builtin_plans())["measure/topn-dashboard"]
     fspec = dict(precompile.builtin_fused())["fused/topn-dashboard"]
-    scalars = (
-        jax.ShapeDtypeStruct((), jnp.float32),
-        jax.ShapeDtypeStruct((), jnp.float32),
-    )
     preds = precompile.pred_struct(spec)
     # fresh builds: never the executors' process-global kernel caches
     fused = fused_exec._build_kernel(fspec)
@@ -91,4 +94,35 @@ def test_builtin_plans_lower_for_tpu(tpu_routing):
         (staged, precompile.chunk_struct(spec)),
         (staged, precompile.decode_chunk_struct(spec)),
     ):
-        assert "tpu_custom_call" in _lower_tpu(kernel, chunk, preds, *scalars)
+        assert "tpu_custom_call" in _lower_tpu(kernel, chunk, preds, *_SCALARS)
+
+
+def test_ep9k_plan_lowers_for_tpu_as_xla_scatter(tpu_routing):
+    """`ep9k.topn-6h`'s plan (benchmarks/e2e: sum(hits) WHERE region != r
+    GROUP BY svc TOP 10, G = 9,000, four 1M-row chunks in one fused
+    program): over the Pallas limit and under the sort threshold, so on
+    a TPU it is the XLA scatter group-by and holds no Pallas kernel."""
+    from banyandb_tpu.ops import groupby
+    from banyandb_tpu.query import fused_exec, measure_exec
+
+    spec = measure_exec.PlanSpec(
+        tags_code=("region", "svc"),
+        fields=("hits",),
+        preds=(measure_exec._PredSpec("code", "region", "ne"),),
+        group_tags=("svc",),
+        radices=(9000,),
+        num_groups=9000,
+        want_minmax=False,
+        nrows=measure_exec.SCAN_CHUNK,
+        want_rep=True,
+    )
+    assert groupby.select_group_method(spec.nrows, spec.num_groups) == "scatter"
+    fspec = fused_exec.FusedSpec(plan=spec, num_chunks=4)
+    kernel = fused_exec._build_kernel(fspec)  # a fresh build, not the cache
+    for chunk in (
+        precompile.fused_decode_chunk_struct(fspec),
+        precompile.fused_chunk_struct(fspec),
+    ):
+        text = _lower_tpu(kernel, chunk, precompile.pred_struct(spec), *_SCALARS)
+        assert "tpu_custom_call" not in text
+        assert "stablehlo.scatter" in text and "stablehlo.sort" not in text
