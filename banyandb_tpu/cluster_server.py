@@ -356,7 +356,9 @@ class LiaisonServer:
         meter = global_meter()
         self.qos.export_gauges(meter)
         for tenant, st in partition_stats().items():
-            for k in ("hits", "misses", "evictions", "entries", "bytes"):
+            for k in (
+                "hits", "misses", "evictions", "refused", "entries", "bytes",
+            ):
                 meter.gauge_set(
                     f"serving_cache_{k}", float(st[k]), {"tenant": tenant}
                 )

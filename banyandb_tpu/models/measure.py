@@ -748,6 +748,7 @@ class MeasureEngine:
         )
         t_pg = time.perf_counter()  # stage metric covers ONLY part gather
         with t.span("part_gather") as gs:
+            read_stats: dict = {}
             if plan.leaf().kind == "IndexModeScan":
                 # Short-circuit: whole measure lives in the series index
                 # (SearchWithoutSeries, measure/query.go:506,559).
@@ -767,6 +768,7 @@ class MeasureEngine:
                                 else True
                             ),
                             zone_exclude=hidden,
+                            read_stats=read_stats,
                         )
                         break
                     except FileNotFoundError:
@@ -775,6 +777,8 @@ class MeasureEngine:
             gs.tag("sources", len(sources)).tag(
                 "rows", sum(int(s.ts.size) for s in sources)
             )
+            for k, v in read_stats.items():
+                gs.tag(k, v)
         t_gather = time.perf_counter()
         _H_PART_GATHER.observe((t_gather - t_pg) * 1000)
         analyzers = self._tag_analyzers(group, req.name)
@@ -930,15 +934,19 @@ class MeasureEngine:
                 )
         t_pg = time.perf_counter()  # stage metric covers ONLY part gather
         with t.span("part_gather") as gs:
+            read_stats: dict = {}
             sources = self.gather_query_sources(
                 req, shard_ids=shard_ids,
                 zone_prepass=(
                     decision.zone_prepass if decision is not None else True
                 ),
+                read_stats=read_stats,
             )
             gs.tag("sources", len(sources)).tag(
                 "rows", sum(int(s.ts.size) for s in sources)
             ).tag("shards", sorted(shard_ids) if shard_ids else "all")
+            for k, v in read_stats.items():
+                gs.tag(k, v)
         _H_PART_GATHER.observe((time.perf_counter() - t_pg) * 1000)
         analyzers = self._tag_analyzers(group, req.name)
         try:
@@ -1010,7 +1018,8 @@ class MeasureEngine:
         return out
 
     def gather_query_sources(
-        self, req, shard_ids=None, serial=False, zone_prepass=True
+        self, req, shard_ids=None, serial=False, zone_prepass=True,
+        read_stats=None,
     ):
         """Source selection for the map phase, shared by the host partial
         path, the mesh fast path (parallel/mesh_query.py) and the
@@ -1028,7 +1037,7 @@ class MeasureEngine:
             try:
                 return self._gather_sources(
                     db, m, req, shard_ids=shard_ids, serial=serial,
-                    zone_prepass=zone_prepass,
+                    zone_prepass=zone_prepass, read_stats=read_stats,
                 )
             except FileNotFoundError:
                 if attempt == 2:
@@ -1067,6 +1076,7 @@ class MeasureEngine:
         serial: bool = False,
         zone_prepass: bool = True,
         zone_exclude: set = frozenset(),
+        read_stats: Optional[dict] = None,
     ) -> list[ColumnData]:
         """Collect per-source decode thunks (metadata-only work: segment
         selection, series-index pruning, block selection), then evaluate
@@ -1074,7 +1084,9 @@ class MeasureEngine:
         on the prefetch thread while part *k*'s rows series-filter and
         append on this one.  Thunk order is the serial iteration order,
         so the concatenation (and every downstream dedup/accumulation)
-        is byte-identical to the strict-serial path (BYDB_PIPELINE=0)."""
+        is byte-identical to the strict-serial path (BYDB_PIPELINE=0).
+        Each part read comes back with its serving-cache outcome;
+        ``read_stats`` (the `part_gather` span's numeric tags) sums them."""
         from banyandb_tpu.storage.chunk_stream import prefetched
 
         from banyandb_tpu.storage import encoded as enc_mod
@@ -1168,13 +1180,15 @@ class MeasureEngine:
                 )
 
             def _read_part(part, blocks, filt):
+                outcome: list = []
                 src = part.read(
                     blocks,
                     tags=[t for t in tag_names if t in part.meta["tags"]],
                     fields=[f for f in field_names if f in part.meta["fields"]],
                     narrow_codes=narrow,
+                    outcome=outcome,
                 )
-                return filt(src, src.cache_key)
+                return filt(src, src.cache_key), outcome[0]
 
             for shard_idx, shard in enumerate(seg.shards):
                 if shard_ids is not None and shard_idx not in shard_ids:
@@ -1195,8 +1209,8 @@ class MeasureEngine:
                 ]
                 for mem_cols in hot_cols:
                     read_ops.append(
-                        lambda mc=mem_cols, filt=_series_rows: filt(
-                            mc, mc.cache_key
+                        lambda mc=mem_cols, filt=_series_rows: (
+                            filt(mc, mc.cache_key), None
                         )
                     )
                 shard_parts = [
@@ -1252,15 +1266,22 @@ class MeasureEngine:
         # entirely: results are byte-identical by the pipeline contract,
         # and at a few blocks of work the thread handoffs cost more
         # than the overlap buys — especially under write-saturated GIL
-        return [
-            src
-            for src in prefetched(
-                read_ops,
-                name="bydb-part-prefetch",
-                enabled=False if serial else None,
-            )
-            if src is not None
-        ]
+        sources = []
+        stats = {"cache_hits": 0, "cache_misses": 0, "decoded_bytes": 0}
+        for src, outcome in prefetched(
+            read_ops,
+            name="bydb-part-prefetch",
+            enabled=False if serial else None,
+        ):
+            if outcome is not None:  # a part read, not a memtable
+                how, decoded = outcome
+                stats["cache_hits" if how == "hit" else "cache_misses"] += 1
+                stats["decoded_bytes"] += decoded
+            if src is not None:
+                sources.append(src)
+        if read_stats is not None:
+            read_stats.update(stats)
+        return sources
 
 
 def _tag_to_bytes(value, tag_type: TagType) -> bytes:

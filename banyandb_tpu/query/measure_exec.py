@@ -751,28 +751,20 @@ def compute_partials(
     # time is the benchmark's gather_ms): phases are tags + annotations
     g = span.child("gather") if span is not None else None
     t_gather0 = _time.perf_counter()
-    gather_loaded: list = []  # loader ran -> serving-cache miss
     if gather_key is not None:
         from banyandb_tpu.storage.cache import global_cache
 
-        def _loader():
-            gather_loaded.append(1)
-            return _do_gather()
-
-        chunks_np = global_cache().get_or_load(gather_key, _loader)
+        # hit / miss / refused (gathered, handed over, not retained)
+        chunks_np, gather_cache = global_cache().fetch(gather_key, _do_gather)
     else:
-        gather_loaded.append(1)
-        chunks_np = _do_gather()
+        chunks_np, gather_cache = _do_gather(), "off"
     gather_ms = (_time.perf_counter() - t_gather0) * 1000
     _H_GATHER.observe(gather_ms)
     n = chunks_np["ts"].shape[0]
     if g is not None:
         g.finish()
         g.tag("rows", int(n)).tag("sources", len(sources)).tag(
-            "serving_cache",
-            ("off" if gather_key is None else "miss")
-            if gather_loaded
-            else "hit",
+            "serving_cache", gather_cache
         )
         for key, value in gather_tags.items():
             g.tag(key, value)

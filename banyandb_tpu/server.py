@@ -589,8 +589,8 @@ class StandaloneServer:
         ):
             st = cache.stats()
             for k in (
-                "hits", "misses", "evictions", "entries", "bytes",
-                "cap", "churn",
+                "hits", "misses", "evictions", "refused", "entries",
+                "bytes", "cap", "churn",
             ):
                 self.meter.gauge_set(f"{scope}_cache_{k}", float(st[k]))
         # materialized rolling-window plane (query/streamagg.py):
@@ -611,7 +611,9 @@ class StandaloneServer:
         from banyandb_tpu.storage.cache import partition_stats
 
         for tenant, st in partition_stats().items():
-            for k in ("hits", "misses", "evictions", "entries", "bytes"):
+            for k in (
+                "hits", "misses", "evictions", "refused", "entries", "bytes",
+            ):
                 self.meter.gauge_set(
                     f"serving_cache_{k}", float(st[k]), {"tenant": tenant}
                 )

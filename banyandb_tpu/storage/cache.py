@@ -7,11 +7,32 @@ query pipeline: the expensive host work on the read path is (1) reading
 one deduplicated global-code chunk for the device.  Both layers cache
 here, keyed on immutable identities (part directories never mutate —
 merges write NEW part dirs — so entries never go stale; deleted parts
-simply age out of the LRU).
+simply age out).
 
 One process-global cache with a byte budget (BYDB_SERVING_CACHE_BYTES,
-default 256 MiB), LRU eviction, and hit/miss counters that the query
+default 256 MiB) and hit/miss/eviction/refusal counters that the query
 trace spans and /metrics surface.
+
+Policy: admission and victim choice by observed reuse.  An entry is
+*proven* once its key has been asked for twice within the cache's
+memory — a hit while resident, or a miss whose key is still in the
+bounded ghost list of keys recently refused or evicted (hashes only, no
+values); everything else is *unproven*.  Victims are unproven entries
+in LRU order, then proven ones in LRU order.  A candidate never seen
+before that could only fit by evicting a proven entry is not retained:
+its caller gets the loaded value, its key goes to the ghost list, and a
+second request admits it as proven.  While there is room everything is
+admitted, and a cache of unproven entries only is a plain LRU.  Why:
+one plain LRU let an entry nobody asks for twice push out entries
+somebody does.  At upstream's 9,000-endpoint estate (`ep9k.topn-6h`)
+the decoded parts are ~280 MB, a query reads 140 - 234 MB of them and
+then offers its own 94.5 MB gather, whose key carries a range drawn at
+ms resolution and never repeats; admitting it evicted the parts the
+next query needed, 5.8 part decodes (187 MB, ~660 ms) a query (ledger,
+PR 28).  Evicting never-hit entries first is not enough: a part that
+was evicted and decoded again is itself never-hit when the next gather
+arrives, so the evidence of reuse has to outlive the entry — the ghost
+list.
 """
 
 from __future__ import annotations
@@ -40,24 +61,31 @@ def default_cap() -> int:
     return env_int("BYDB_SERVING_CACHE_CAP", 0)
 
 
-def _sizeof(obj) -> int:
+def sizeof(obj) -> int:
     """Approximate retained bytes of cached values (arrays dominate;
     covers numpy and jax arrays via nbytes)."""
     if isinstance(obj, np.ndarray) or hasattr(obj, "nbytes"):
         return int(obj.nbytes)
     if isinstance(obj, dict):
-        return 64 + sum(_sizeof(v) for v in obj.values())
+        return 64 + sum(sizeof(v) for v in obj.values())
     if isinstance(obj, (list, tuple)):
-        return 64 + sum(_sizeof(v) for v in obj)
+        return 64 + sum(sizeof(v) for v in obj)
     if isinstance(obj, (bytes, bytearray)):
         return len(obj)
     if hasattr(obj, "__dict__"):
-        return 64 + sum(_sizeof(v) for v in vars(obj).values())
+        return 64 + sum(sizeof(v) for v in vars(obj).values())
     return 64
 
 
+# Ghost list capacity, in keys: the cache's memory of what it refused or
+# evicted (one int a key).  A query leaves a handful of keys behind, so
+# this remembers the last several hundred queries' worth.
+_GHOST_KEYS = 4096
+
+
 class ServingCache:
-    """LRU byte-budget cache; values must be treated as immutable."""
+    """Byte-budget cache that keeps what is asked for twice (policy in
+    the module docstring); values must be treated as immutable."""
 
     def __init__(
         self,
@@ -69,79 +97,142 @@ class ServingCache:
         # BYDB_SERVING_CACHE_CAP env default, read now (construction)
         self.cap = default_cap() if max_entries is None else int(max_entries)
         self._lock = threading.Lock()
-        self._entries: OrderedDict[tuple, tuple[object, int]] = OrderedDict()
+        # key -> (value, size), each in LRU order; an entry lives in one
+        self._unproven: OrderedDict[tuple, tuple[object, int]] = OrderedDict()
+        self._proven: OrderedDict[tuple, tuple[object, int]] = OrderedDict()
+        self._unproven_bytes = 0
+        self._ghosts: OrderedDict[int, None] = OrderedDict()
         self.bytes = 0
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        self.refused = 0
 
     def set_cap(self, max_entries: int) -> None:
         """Reconfigure the entry cap live (server flag); evicts down to
         the new bound immediately."""
         with self._lock:
             self.cap = int(max_entries)
-            self._evict_locked()
+            self._make_room_locked(0, 0)
 
-    def _evict_locked(self) -> None:
-        while self._entries and (
-            self.bytes > self.budget
-            or (self.cap and len(self._entries) > self.cap)
+    def _remember_locked(self, key: tuple) -> None:
+        h = hash(key)
+        self._ghosts[h] = None
+        self._ghosts.move_to_end(h)
+        if len(self._ghosts) > _GHOST_KEYS:
+            self._ghosts.popitem(last=False)
+
+    def _drop_locked(self, key: tuple) -> bool:
+        """Remove `key` if resident; was it there."""
+        entry = self._unproven.pop(key, None)
+        if entry is not None:
+            self._unproven_bytes -= entry[1]
+        else:
+            entry = self._proven.pop(key, None)
+        if entry is None:
+            return False
+        self.bytes -= entry[1]
+        return True
+
+    def _make_room_locked(self, size: int, count: int) -> None:
+        """Evict until `size` more bytes in `count` more entries fit:
+        unproven entries in LRU order, then proven ones."""
+        while (self._unproven or self._proven) and (
+            self.bytes + size > self.budget
+            or (
+                self.cap
+                and len(self._unproven) + len(self._proven) + count > self.cap
+            )
         ):
-            _, (_, evicted) = self._entries.popitem(last=False)
-            self.bytes -= evicted
+            victim = next(iter(self._unproven or self._proven))
+            self._drop_locked(victim)
+            self._remember_locked(victim)
             self.evictions += 1
 
-    def get_or_load(self, key: tuple, loader: Callable[[], object]):
+    def fetch(self, key: tuple, loader: Callable[[], object]):
+        """(value, outcome): `hit` (resident), `miss` (loaded and
+        retained) or `refused` (loaded and handed to the caller only)."""
         with self._lock:
-            hit = self._entries.get(key)
-            if hit is not None:
-                self._entries.move_to_end(key)
+            entry = self._proven.get(key)
+            if entry is not None:
+                self._proven.move_to_end(key)
+            else:
+                entry = self._unproven.pop(key, None)
+                if entry is not None:  # asked for twice: proven
+                    self._unproven_bytes -= entry[1]
+                    self._proven[key] = entry
+            if entry is not None:
                 self.hits += 1
-                return hit[0]
+                return entry[0], "hit"
             self.misses += 1
         # Load outside the lock (disk reads can be slow); racing loaders
         # compute the same immutable value, last-insert wins harmlessly.
         value = loader()
-        size = _sizeof(value)
-        if size > self.budget:
-            return value  # too large to retain; serve uncached
+        size = sizeof(value)
         with self._lock:
-            prev = self._entries.pop(key, None)
-            if prev is not None:
-                self.bytes -= prev[1]
-            self._entries[key] = (value, size)
+            if size > self.budget:
+                self.refused += 1  # too large to retain; serve uncached
+                return value, "refused"
+            # asked for before: remembered, or a racing loader's insert
+            proven = self._drop_locked(key)
+            h = hash(key)
+            if h in self._ghosts:
+                del self._ghosts[h]
+                proven = True
+            if (
+                not proven
+                and self.bytes + size - self.budget > self._unproven_bytes
+            ):
+                # first sight, and room only at a proven entry's cost
+                self._remember_locked(key)
+                self.refused += 1
+                return value, "refused"
+            self._make_room_locked(size, 1)
+            if proven:
+                self._proven[key] = (value, size)
+            else:
+                self._unproven[key] = (value, size)
+                self._unproven_bytes += size
             self.bytes += size
-            self._evict_locked()
-        return value
+        return value, "miss"
+
+    def get_or_load(self, key: tuple, loader: Callable[[], object]):
+        return self.fetch(key, loader)[0]
 
     def invalidate_prefix(self, prefix: tuple) -> int:
         """Drop entries whose key starts with `prefix` (rarely needed —
         part identities are immutable — but retention tests use it)."""
         with self._lock:
             doomed = [
-                k for k in self._entries if k[: len(prefix)] == prefix
+                k
+                for k in (*self._unproven, *self._proven)
+                if k[: len(prefix)] == prefix
             ]
             for k in doomed:
-                _, size = self._entries.pop(k)
-                self.bytes -= size
+                self._drop_locked(k)
             return len(doomed)
 
     def clear(self) -> None:
         with self._lock:
-            self._entries.clear()
+            self._unproven.clear()
+            self._proven.clear()
+            self._ghosts.clear()
+            self._unproven_bytes = 0
             self.bytes = 0
 
     def stats(self) -> dict:
         with self._lock:
             lookups = self.hits + self.misses
             return {
-                "entries": len(self._entries),
+                "entries": len(self._unproven) + len(self._proven),
                 "bytes": self.bytes,
                 "budget": self.budget,
                 "cap": self.cap,
                 "hits": self.hits,
                 "misses": self.misses,
                 "evictions": self.evictions,
+                # loads handed to the caller and not retained
+                "refused": self.refused,
                 # eviction churn: evictions per lookup — the r06 squeeze
                 # signal (18102 evictions / 76k lookups) as one number
                 "churn": round(self.evictions / lookups, 4)

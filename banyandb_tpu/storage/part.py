@@ -482,6 +482,7 @@ class Part:
         want_payload: bool = False,
         cached: bool = True,
         narrow_codes: bool = False,
+        outcome: Optional[list] = None,
     ) -> ColumnData:
         """Decode the selected blocks' columns into host arrays.
 
@@ -498,8 +499,13 @@ class Part:
         widen + dictionary remap then run on device as the first stage
         of the plan kernel (ops.decode).  Code VALUES are identical
         either way; only the dtype differs.
+
+        ``outcome``, when given, gets this read's ``(how, decoded
+        bytes)`` appended: ``hit``, ``miss`` or ``refused`` as
+        ``ServingCache.fetch`` says (``off`` uncached), and the size of
+        what was decoded because the cache did not hold it.
         """
-        from banyandb_tpu.storage.cache import global_cache
+        from banyandb_tpu.storage.cache import global_cache, sizeof
 
         key = (
             "part_read",
@@ -510,18 +516,19 @@ class Part:
             bool(want_payload),
             bool(narrow_codes),
         )
-        if not cached:
+        def _decode() -> ColumnData:
             return self._read_uncached(
                 key, block_ids, tags=tags, fields=fields,
                 want_payload=want_payload, narrow_codes=narrow_codes,
             )
-        return global_cache().get_or_load(
-            key,
-            lambda: self._read_uncached(
-                key, block_ids, tags=tags, fields=fields,
-                want_payload=want_payload, narrow_codes=narrow_codes,
-            ),
-        )
+
+        if cached:
+            cols, how = global_cache().fetch(key, _decode)
+        else:
+            cols, how = _decode(), "off"
+        if outcome is not None:
+            outcome.append((how, 0 if how == "hit" else sizeof(cols)))
+        return cols
 
     def _read_uncached(
         self,

@@ -313,3 +313,266 @@ def test_partials_cache_keyed_by_rep_tags(engine):
     r4 = engine.query(_req(criteria=crit))
     assert not r4.rep_tags
     assert r3.groups == r4.groups
+
+
+# -- admission and victim choice by observed reuse (ISSUE 29) --------------
+
+
+def _load(c: ServingCache, key, nbytes: int):
+    """Ask `c` for `key`, a value of `nbytes`; -> (outcome, loader ran)."""
+    ran: list = []
+
+    def loader():
+        ran.append(1)
+        return np.zeros(nbytes, np.int8)
+
+    return c.fetch((key,), loader)[1], bool(ran)
+
+
+def _resident(c: ServingCache, key) -> bool:
+    """Is `key` held, read without touching recency or the counters."""
+    with c._lock:
+        return (key,) in c._proven or (key,) in c._unproven
+
+
+def test_proven_working_set_survives_one_shot_stream():
+    """The ep9k case in small: three parts asked for by every query fill
+    most of the budget, and each query brings an entry of its own that
+    nobody asks for again and that needs a part's room."""
+    c = ServingCache(budget_bytes=10_000)
+    for _ in range(2):  # second pass: hits, so the parts are proven
+        for part in "abc":
+            _load(c, part, 3_000)
+    for q in range(50):
+        outcome, ran = _load(c, ("gather", q), 2_500)
+        assert (outcome, ran) == ("refused", True)
+        assert c.stats()["bytes"] <= 10_000
+        for part in "abc":
+            assert _load(c, part, 3_000) == ("hit", False)
+    st = c.stats()
+    assert st["refused"] == 50
+    assert st["evictions"] == 0
+    assert st["entries"] == 3
+
+
+def test_refused_key_is_admitted_on_second_request():
+    c = ServingCache(budget_bytes=10_000)
+    for _ in range(2):
+        for part in "abc":
+            _load(c, part, 3_000)
+    assert _load(c, "new", 3_000) == ("refused", True)
+    assert not _resident(c, "new")
+    # the second request is the evidence: admitted, at the cost of the
+    # least recently used proven entry
+    assert _load(c, "new", 3_000) == ("miss", True)
+    assert _resident(c, "new") and not _resident(c, "a")
+    assert _load(c, "new", 3_000) == ("hit", False)
+    st = c.stats()
+    assert (st["refused"], st["evictions"]) == (1, 1)
+    assert st["bytes"] <= 10_000
+
+
+def test_evicted_proven_key_comes_back_proven():
+    """The trap of "evict never-hit entries first" alone: a part that was
+    evicted and decoded again has no hit yet when the next one-shot entry
+    arrives, and would be evicted for it again."""
+    c = ServingCache(budget_bytes=10_000)
+    for _ in range(2):
+        for part in "abcd":  # four parts of 3,000: one is always out
+            _load(c, part, 3_000)
+    assert not _resident(c, "a")  # pushed out by "d"'s second request
+    assert _load(c, "a", 3_000) == ("miss", True)  # back, and still proven
+    assert _load(c, ("gather", 0), 2_500) == ("refused", True)
+    assert _resident(c, "a")
+    assert c.stats()["bytes"] <= 10_000
+
+
+def test_unproven_entries_evict_in_plain_lru_order():
+    c = ServingCache(budget_bytes=10_000)
+    for i in range(25):
+        assert _load(c, i, 1_000) == ("miss", True)
+        # exactly the last ten, as one LRU keeps them
+        assert [k for k in range(i + 1) if _resident(c, k)] == list(
+            range(max(0, i - 9), i + 1)
+        )
+    st = c.stats()
+    assert (st["refused"], st["evictions"], st["bytes"]) == (0, 15, 10_000)
+
+
+def test_entry_cap_evicts_unproven_first_and_never_refuses():
+    c = ServingCache(budget_bytes=1 << 30, max_entries=4)
+    for _ in range(2):
+        for part in "abc":
+            _load(c, part, 10)
+    for q in range(10):  # one-shot entries take turns in the fourth slot
+        assert _load(c, ("gather", q), 10) == ("miss", True)
+    assert all(_resident(c, part) for part in "abc")
+    assert _resident(c, ("gather", 9)) and not _resident(c, ("gather", 8))
+    c.set_cap(2)  # the unproven entry goes first, then the LRU proven one
+    assert [p for p in "abc" if _resident(c, p)] == ["b", "c"]
+    # at the cap with proven entries only, a new key still gets in
+    assert _load(c, "new", 10) == ("miss", True)
+    assert _resident(c, "new") and not _resident(c, "b")
+    st = c.stats()
+    assert st["refused"] == 0 and st["entries"] == 2
+
+
+def test_ghost_list_is_bounded():
+    from banyandb_tpu.storage import cache as cache_mod
+
+    c = ServingCache(budget_bytes=100)
+    for part in "ab":
+        _load(c, part, 50)
+        _load(c, part, 50)
+    for q in range(cache_mod._GHOST_KEYS + 100):
+        assert _load(c, ("gather", q), 10)[0] == "refused"
+    assert len(c._ghosts) == cache_mod._GHOST_KEYS
+    # the oldest refusal is forgotten: first sight again
+    assert _load(c, ("gather", 0), 10)[0] == "refused"
+    assert _load(c, ("gather", cache_mod._GHOST_KEYS + 99), 10)[0] == "miss"
+
+
+def test_oversized_value_counts_as_refused():
+    c = ServingCache(budget_bytes=100)
+    assert _load(c, "big", 1_000) == ("refused", True)
+    assert _load(c, "big", 1_000) == ("refused", True)  # never admissible
+    st = c.stats()
+    assert (st["entries"], st["bytes"], st["refused"]) == (0, 0, 2)
+
+
+def test_distinct_ranges_stop_decoding_parts_again(tmp_path, monkeypatch):
+    """Engine level, the ep9k case: decoded parts that nearly fill the
+    budget, queried over ranges that never repeat.  One LRU decodes parts
+    again for every query, because each query's gather (never asked for
+    twice) evicts them; now the parts stay and the gather is refused."""
+    from banyandb_tpu.obs.tracer import Tracer
+
+    reg = SchemaRegistry(tmp_path)
+    reg.create_group(Group("g", Catalog.MEASURE, ResourceOpts(shard_num=2)))
+    reg.create_measure(
+        Measure(
+            group="g",
+            name="m",
+            tags=(
+                TagSpec("svc", TagType.STRING),
+                TagSpec("region", TagType.STRING),
+            ),
+            fields=(FieldSpec("lat", FieldType.FLOAT),),
+            entity=Entity(("svc",)),
+        )
+    )
+    eng = MeasureEngine(reg, tmp_path / "data")
+    rng = np.random.default_rng(0)
+    for batch in range(4):  # four parts a shard, 4,000 ms each
+        eng.write(
+            WriteRequest(
+                "g",
+                "m",
+                tuple(
+                    DataPointValue(
+                        ts_millis=T0 + batch * 4000 + i,
+                        tags={"svc": f"s{rng.integers(0, 8)}", "region": "eu"},
+                        fields={"lat": float(rng.gamma(2.0, 40.0))},
+                        version=1,
+                    )
+                    for i in range(4000)
+                ),
+            )
+        )
+        eng.flush()
+
+    decodes = []
+    orig = part_mod.Part._read_uncached
+
+    def counting(self, *a, **kw):
+        decodes.append(self.dir)
+        return orig(self, *a, **kw)
+
+    monkeypatch.setattr(part_mod.Part, "_read_uncached", counting)
+
+    def ask(i: int):
+        """Query i: every part in range, an end no other query has."""
+        tracer = Tracer("test")
+        res = eng.query(
+            _req(time_range=TimeRange(T0, T0 + 16_000 - i)), tracer=tracer
+        )
+        spans = {}
+        for s in tracer.finish()["children"]:
+            spans[s["name"]] = s
+            for c in s.get("children") or ():
+                spans[c["name"]] = c
+        return res, spans["part_gather"]["tags"], spans["gather"]["tags"]
+
+    # eight decoded parts ~560 kB, a gather of all rows ~450 kB
+    reset_global_cache(700_000)
+    _, pg, g = ask(0)
+    assert (pg["cache_hits"], pg["cache_misses"]) == (0, 8)
+    assert pg["decoded_bytes"] > 500_000 and len(decodes) == 8
+    assert g["serving_cache"] == "miss"  # room at unproven entries' cost
+    ask(1)  # the parts evicted for query 0's gather come back, proven
+    warm = len(decodes)
+
+    got = []
+    for i in range(2, 10):
+        res, pg, g = ask(i)
+        got.append(res)
+        assert (pg["cache_hits"], pg["cache_misses"]) == (8, 0)
+        assert pg["decoded_bytes"] == 0
+        assert g["serving_cache"] == "refused"
+        assert global_cache().stats()["bytes"] <= 700_000
+    assert len(decodes) == warm  # one LRU (the parent): 8 decodes a query
+    assert global_cache().stats()["refused"] >= 8
+
+    for i, res in zip(range(2, 10), got):
+        reset_global_cache()
+        cold, pg, g = ask(i)
+        assert pg["cache_misses"] == 8 and g["serving_cache"] == "miss"
+        assert cold.groups == res.groups
+        assert cold.values == res.values
+    eng.close()
+
+
+def test_concurrent_fetches_keep_the_byte_accounting():
+    """More threads than cores over a small key space, so hits, ghost
+    re-admissions, refusals, evictions and racing loads of one key
+    interleave; the budget and the two byte counts must hold."""
+    import sys
+    import threading
+
+    c = ServingCache(budget_bytes=20_000)
+    stop = threading.Event()
+    errors: list = []
+
+    def worker(seed: int):
+        rng = np.random.default_rng(seed)
+        try:
+            while not stop.is_set():
+                k = int(rng.integers(0, 40))
+                size = 500 + 100 * (k % 17)
+                v, how = c.fetch(("k", k), lambda: np.zeros(size, np.int8))
+                assert v.nbytes == size and how in ("hit", "miss", "refused")
+                assert c.stats()["bytes"] <= 20_000
+        except Exception as e:  # propagated to the main thread below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    threads = [threading.Thread(target=worker, args=(i,)) for i in range(16)]
+    try:
+        for t in threads:
+            t.start()
+        stop.wait(1.0)
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=30)
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[0]
+    st = c.stats()
+    assert st["hits"] and st["refused"] and st["evictions"]
+    with c._lock:
+        assert not set(c._proven) & set(c._unproven)
+        unproven = sum(s for _, s in c._unproven.values())
+        proven = sum(s for _, s in c._proven.values())
+        assert (c._unproven_bytes, c.bytes) == (unproven, unproven + proven)
