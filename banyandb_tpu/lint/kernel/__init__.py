@@ -78,12 +78,7 @@ def stored_entries(registry=None, limit: int = 16):
         KernelAudit,
         _rel_path,
     )
-    from banyandb_tpu.query import (
-        fused_exec,
-        measure_exec,
-        precompile,
-        stream_exec,
-    )
+    from banyandb_tpu.query import fused_exec, precompile, stream_exec
 
     if registry is None:
         registry = precompile.default_registry()
@@ -92,16 +87,9 @@ def stored_entries(registry=None, limit: int = 16):
     for i, (kind, spec) in enumerate(registry.signatures()[:limit]):
         try:
             if kind == "measure":
-                mod = measure_exec
-                fn = measure_exec._build_kernel(spec)
-                args = (
-                    precompile.chunk_struct(spec),
-                    precompile.pred_struct(spec),
-                    S((), jnp.float32),
-                    S((), jnp.float32),
-                )
-                anchor = measure_exec._build_kernel
-            elif kind == "fused":
+                # an evidence row runs as its plan's one-chunk program
+                spec = fused_exec.FusedSpec(plan=spec, num_chunks=1)
+            if kind in ("measure", "fused"):
                 mod = fused_exec
                 fn = fused_exec._build_kernel(spec)
                 args = (

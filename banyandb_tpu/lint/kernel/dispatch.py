@@ -2,12 +2,12 @@
 driving the REAL executor entry paths under an instrumented stub device.
 
 Zero device kernel execution: the per-signature kernel builders
-(``measure_exec._build_kernel`` / ``stream_exec._build_kernel``) are
+(``fused_exec._build_kernel`` / ``stream_exec._build_kernel``) are
 swapped for stubs that count the dispatch, derive the output pytree with
 ``jax.eval_shape`` (a pure trace) and return host zeros; ``jax.device_get``
 and ``jnp.asarray`` are wrapped with counting pass-throughs.  Everything
-else — gather, dedup, plan-signature resolution, the chunk loop, the
-prefetch pipeline — is the production code path, so the measured counts
+else — gather, dedup, plan-signature resolution, the batch loop, the
+pad pipeline — is the production code path, so the measured counts
 are the counts a real query pays:
 
 - **dispatches**  jitted kernel invocations (the fused executor's
@@ -119,12 +119,7 @@ def stub_device():
     import jax
     import jax.numpy as jnp
 
-    from banyandb_tpu.query import (
-        fused_exec,
-        measure_exec,
-        precompile,
-        stream_exec,
-    )
+    from banyandb_tpu.query import fused_exec, precompile, stream_exec
 
     counters = Counters()
     real_get = jax.device_get
@@ -141,8 +136,6 @@ def stub_device():
         return real_asarray(a, *args, **kwargs)
 
     saved = (
-        measure_exec._KERNEL_CACHE,
-        measure_exec._build_kernel,
         stream_exec._KERNEL_CACHE,
         stream_exec._build_kernel,
         fused_exec._KERNEL_CACHE,
@@ -151,17 +144,13 @@ def stub_device():
     )
     throwaway = precompile.PrecompileRegistry()
     try:
-        measure_exec._KERNEL_CACHE = {}
-        measure_exec._build_kernel = _stub_builder(
-            saved[1], counters, "measure"
-        )
         stream_exec._KERNEL_CACHE = {}
         stream_exec._build_kernel = _stub_builder(
-            saved[3], counters, "stream_mask"
+            saved[1], counters, "stream_mask"
         )
         fused_exec._KERNEL_CACHE = {}
         fused_exec._build_kernel = _stub_builder(
-            saved[5], counters, "fused"
+            saved[3], counters, "fused"
         )
         precompile.default_registry = lambda: throwaway
         jax.device_get = counting_get
@@ -171,8 +160,6 @@ def stub_device():
         jax.device_get = real_get
         jnp.asarray = real_asarray
         (
-            measure_exec._KERNEL_CACHE,
-            measure_exec._build_kernel,
             stream_exec._KERNEL_CACHE,
             stream_exec._build_kernel,
             fused_exec._KERNEL_CACHE,
@@ -221,8 +208,8 @@ def _measure_schema(tags, fields):
 
 
 def _measure_scenarios():
-    """(name, builtin PlanSpec, runner) per builtin measure plan.  Each
-    runner drives compute_partials so the resolved PlanSpec must equal
+    """(name, builtin FusedSpec, runner) per builtin measure plan.  Each
+    runner drives compute_partials so the resolved FusedSpec must equal
     the precompile registry's builtin signature."""
     from banyandb_tpu.api.model import (
         Aggregation,
@@ -237,7 +224,7 @@ def _measure_scenarios():
     from banyandb_tpu.query import precompile
     from banyandb_tpu.query.measure_exec import compute_partials
 
-    builtins = dict(precompile.builtin_plans())
+    builtins = dict(precompile.builtin_fused())
     rng = np.random.default_rng(7)
 
     def svc_dict(k: int):
@@ -374,11 +361,11 @@ def _measure_scenarios():
         compute_partials(m, req, [src])
 
     return [
-        ("measure/flat-count", builtins["measure/flat-count"], run_flat),
-        ("measure/group-eq-lut", builtins["measure/group-eq-lut"], run_grouped),
-        ("measure/percentile-hist", builtins["measure/percentile-hist"], run_pct),
-        ("measure/or-expr", builtins["measure/or-expr"], run_or),
-        ("measure/topn-dashboard", builtins["measure/topn-dashboard"], run_topn),
+        ("fused/flat-count", builtins["fused/flat-count"], run_flat),
+        ("fused/group-eq-lut", builtins["fused/group-eq-lut"], run_grouped),
+        ("fused/percentile-hist", builtins["fused/percentile-hist"], run_pct),
+        ("fused/or-expr", builtins["fused/or-expr"], run_or),
+        ("fused/topn-dashboard", builtins["fused/topn-dashboard"], run_topn),
     ]
 
 
@@ -543,37 +530,28 @@ def _env(overrides: Optional[dict]):
 def audit_dispatch() -> dict[str, DispatchTrace]:
     """Run every scenario under the stub device -> measured traces.
 
-    Each measure scenario runs THREE times: with ``BYDB_FUSED=0`` (the
-    staged per-chunk loop, the ``measure/*`` rows), with the fused
-    whole-plan executor on (the ``fused/*`` rows, pinned to the
-    precompile registry's builtin FusedSpecs at dispatches=1/gets=1),
-    and with fused + ``BYDB_DEVICE_DECODE=1`` (the ``fused+decode/*``
-    rows: the compressed ship form must STILL cost exactly one dispatch
-    and one batched get — the decode stage fuses into the plan program
-    or the whole point is lost), plus the multi-chunk staging tripwire.
-    The measure/fused rows pin ``BYDB_DEVICE_DECODE=0`` explicitly so
-    their put counts stay the dense-ship baseline regardless of the
-    ambient default."""
-    from banyandb_tpu.query import precompile
-
-    staged_env = {"BYDB_FUSED": "0", "BYDB_DEVICE_DECODE": "0"}
-    fused_env = {"BYDB_FUSED": "1", "BYDB_DEVICE_DECODE": "0"}
-    decode_env = {"BYDB_FUSED": "1", "BYDB_DEVICE_DECODE": "1"}
+    Each measure scenario runs TWICE: in the dense ship form (the
+    ``fused/*`` rows, pinned to the precompile registry's builtin
+    FusedSpecs at dispatches=1/gets=1) and with ``BYDB_DEVICE_DECODE=1``
+    (the ``fused+decode/*`` rows: the compressed ship form must STILL
+    cost exactly one dispatch and one batched get — the decode stage
+    fuses into the plan program or the whole point is lost), plus the
+    multi-chunk staging tripwire.  The ``fused/*`` rows pin
+    ``BYDB_DEVICE_DECODE=0`` explicitly so their put counts stay the
+    dense-ship baseline regardless of the ambient default."""
+    fused_env = {"BYDB_DEVICE_DECODE": "0"}
+    decode_env = {"BYDB_DEVICE_DECODE": "1"}
+    measure = _measure_scenarios()
     scenarios = [
-        (name, "measure", builtin, run, staged_env)
-        for name, builtin, run in _measure_scenarios()
+        (name, "measure", builtin, run, fused_env)
+        for name, builtin, run in measure
     ]
-    fused_builtins = dict(precompile.builtin_fused())
-    for name, _builtin, run in _measure_scenarios():
-        fname = name.replace("measure/", "fused/")
-        scenarios.append((fname, "measure", fused_builtins[fname], run, fused_env))
-    for name, _builtin, run in _measure_scenarios():
-        dname = name.replace("measure/", "fused+decode/")
-        # same builtin FusedSpec: the ship form changes the chunk
-        # pytree, never the plan signature
-        scenarios.append(
-            (dname, "measure", fused_builtins[name.replace("measure/", "fused/")], run, decode_env)
-        )
+    # same builtin FusedSpec: the ship form changes the chunk pytree,
+    # never the plan signature
+    scenarios += [
+        (name.replace("fused/", "fused+decode/"), "measure", builtin, run, decode_env)
+        for name, builtin, run in measure
+    ]
     scenarios.append(
         ("fused/multi-chunk", "measure", None, _multichunk_scenario(), fused_env)
     )

@@ -17,8 +17,8 @@ Columns (None = not measured for that row's kind):
 - ``dispatches``/``gets``/``puts``: jitted dispatches, batched
   device_get transfers, and host->device array ships per scenario run
   (dispatch.py's stub device; measure/stream scenarios are one
-  part-batch = one scan chunk).  The ql rows pin the trace/property
-  executors to ZERO device work.
+  part-batch, one scan chunk but for the multi-chunk rows).  The ql
+  rows pin the trace/property executors to ZERO device work.
 - ``widest``: widest dtype itemsize anywhere in the jaxpr (4 = the
   32-bit device contract; 8 would mean a 64-bit leak).
 - ``bytes_class``/``fusion_class``: power-of-two class
@@ -78,19 +78,12 @@ def _b(dispatches=None, gets=None, puts=None, widest=None,
 
 # fmt: off
 BUDGETS: dict[str, KernelBudget] = {
-    # the builtin measure plan matrix: 1 dispatch + 1 batched get per
-    # scan chunk; puts = padded chunk columns + traced predicate arrays.
     # columns: (dispatches, gets, puts, widest, bytes_class,
     #           fusion_class, collectives)
-    "measure/flat-count":      _b(1, 1, 5, 4, 20, 4, 0),
-    "measure/group-eq-lut":    _b(1, 1, 8, 4, 23, 5, 0),
-    "measure/percentile-hist": _b(1, 1, 6, 4, 25, 5, 0),
-    "measure/or-expr":         _b(1, 1, 7, 4, 20, 3, 0),
-    "measure/topn-dashboard":  _b(1, 1, 7, 4, 23, 5, 0),
-    # fused whole-plan twins (query/fused_exec): ONE dispatch + ONE
-    # batched get per part-batch regardless of chunk count — the
+    # the builtin measure plan matrix (query/fused_exec): ONE dispatch +
+    # ONE batched get per part-batch regardless of chunk count — the
     # executor's raison d'être, ratcheted so staging can never creep
-    # back; puts stay the staged column count (stacked ships).
+    # back; puts = stacked chunk columns + traced predicate arrays.
     "fused/flat-count":        _b(1, 1, 5, 4, 20, 4, 0),
     "fused/group-eq-lut":      _b(1, 1, 8, 4, 23, 5, 0),
     "fused/percentile-hist":   _b(1, 1, 6, 4, 25, 5, 0),
@@ -283,10 +276,12 @@ def dispatch_budget(kind: str = "measure") -> int:
     """The static per-part-batch dispatch budget for a signature family
     (max over its rows): the bound runtime ``device_execute`` span
     counts are asserted against."""
+    # a measure plan runs as its fused program, in either ship form
+    families = ("fused", "fused+decode") if kind == "measure" else (kind,)
     vals = [
         row.dispatches
         for name, row in BUDGETS.items()
-        if name.startswith(kind + "/") and row.dispatches is not None
+        if name.split("/")[0] in families and row.dispatches is not None
     ]
     if not vals:
         raise KeyError(f"no dispatch budgets for kind {kind!r}")

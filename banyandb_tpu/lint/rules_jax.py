@@ -9,7 +9,7 @@ Device-value taint is deliberately convention-driven: a call to any
 callable whose final name segment is ``kernel``, ``jitted`` or ``step``
 (or a name bound from ``jax.jit(...)`` / a ``@jax.jit`` function in the
 same module) is treated as producing device arrays.  The codebase names
-its compiled entry points exactly this way (measure_exec/stream_exec
+its compiled entry points exactly this way (fused_exec/stream_exec
 ``kernel``, dist_exec ``step``/``jitted``), which keeps the analysis
 local and false-positive-light; cross-module device returns are covered
 by the always-flagged explicit sync APIs (device_get/block_until_ready).
@@ -248,7 +248,7 @@ class RecompileHazardRule:
     ``jax.jit(lambda ...)`` and ``jax.jit(f)(...)`` build a fresh
     wrapper (and compile cache entry) per evaluation; a jit call inside
     a loop does so per iteration.  The blessed pattern is the module
-    cache keyed by a static PlanSpec (measure_exec._KERNEL_CACHE).
+    cache keyed by a static FusedSpec (fused_exec._KERNEL_CACHE).
     F-strings over traced parameters concretize under trace."""
 
     name = "recompile-hazard"

@@ -221,7 +221,7 @@ def default_entries() -> list[KernelAudit]:
     """The checked-in plan matrix for the banyandb_tpu query layer.
 
     The measure/stream kernel signatures come from the precompile
-    registry's builtin matrix (query/precompile.builtin_plans/_masks) —
+    registry's builtin matrix (query/precompile.builtin_fused/_masks) —
     ONE list feeds both warming and auditing, and the agreement is
     pinned by a meta-test (tests/test_cold_path.py), so a signature the
     server precompiles is exactly a signature this audit contracts."""
@@ -231,34 +231,14 @@ def default_entries() -> list[KernelAudit]:
     import jax.numpy as jnp
 
     from banyandb_tpu import ops
-    from banyandb_tpu.query import measure_exec, precompile, stream_exec
+    from banyandb_tpu.query import precompile, stream_exec
     from banyandb_tpu.query.measure_exec import PlanSpec
 
     S = jax.ShapeDtypeStruct
     f32, i32, b8 = jnp.float32, jnp.int32, jnp.bool_
 
-    mpath = _rel_path(inspect.getsourcefile(measure_exec))
-    mline = inspect.getsourcelines(measure_exec._build_kernel)[1]
     spath = _rel_path(inspect.getsourcefile(stream_exec))
     sline = inspect.getsourcelines(stream_exec._build_kernel)[1]
-
-    def measure_entry(
-        name: str, spec: PlanSpec, expect: dict[str, tuple[str, tuple]]
-    ) -> KernelAudit:
-        return KernelAudit(
-            name=name,
-            path=str(mpath),
-            line=mline,
-            fn=measure_exec._build_kernel(spec),
-            args=(
-                precompile.chunk_struct(spec),
-                precompile.pred_struct(spec),
-                S((), f32),
-                S((), f32),
-            ),
-            expect=expect,
-            cache_key=spec,
-        )
 
     def base_expect(spec: PlanSpec) -> dict[str, tuple[str, tuple]]:
         g = (spec.num_groups,)
@@ -277,9 +257,6 @@ def default_entries() -> list[KernelAudit]:
 
     entries: list[KernelAudit] = []
 
-    for name, spec in precompile.builtin_plans():
-        entries.append(measure_entry(name, spec, base_expect(spec)))
-
     for name, mspec in precompile.builtin_masks():
         entries.append(
             KernelAudit(
@@ -293,7 +270,7 @@ def default_entries() -> list[KernelAudit]:
             )
         )
 
-    # the fused whole-plan twins: same contract per chunk, stacked
+    # the measure plan programs: the per-chunk contract, stacked
     # [num_chunks, ...] outputs (one dispatch/one get per part-batch is
     # the kernel-dispatch half; here the shape/dtype contract is pinned)
     from banyandb_tpu.query import fused_exec

@@ -9,6 +9,7 @@ part columns and run the device executor (query/measure_exec.py).
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 from pathlib import Path
 from typing import Optional
@@ -754,26 +755,18 @@ class MeasureEngine:
                 # (SearchWithoutSeries, measure/query.go:506,559).
                 sources = self._index_sources(db, m, req, shard_ids)
             else:
-                # A concurrent merge can GC a part dir after we snapshot
-                # the part list; that read raises FileNotFoundError and we
-                # retry against the fresh snapshot (the reference's epoch
-                # contract).
-                for attempt in range(3):
-                    try:
-                        sources = self._gather_sources(
-                            db, m, req, shard_ids=shard_ids,
-                            zone_prepass=(
-                                decision.zone_prepass
-                                if decision is not None
-                                else True
-                            ),
-                            zone_exclude=hidden,
-                            read_stats=read_stats,
-                        )
-                        break
-                    except FileNotFoundError:
-                        if attempt == 2:
-                            raise
+                sources = _retry_merged_away(
+                    lambda: self._gather_sources(
+                        db, m, req, shard_ids=shard_ids,
+                        zone_prepass=(
+                            decision.zone_prepass
+                            if decision is not None
+                            else True
+                        ),
+                        zone_exclude=hidden,
+                        read_stats=read_stats,
+                    )
+                )
             gs.tag("sources", len(sources)).tag(
                 "rows", sum(int(s.ts.size) for s in sources)
             )
@@ -1033,15 +1026,12 @@ class MeasureEngine:
         db = self._tsdb(group)
         if m.index_mode:
             return self._index_sources(db, m, req, shard_ids)
-        for attempt in range(3):
-            try:
-                return self._gather_sources(
-                    db, m, req, shard_ids=shard_ids, serial=serial,
-                    zone_prepass=zone_prepass, read_stats=read_stats,
-                )
-            except FileNotFoundError:
-                if attempt == 2:
-                    raise
+        return _retry_merged_away(
+            lambda: self._gather_sources(
+                db, m, req, shard_ids=shard_ids, serial=serial,
+                zone_prepass=zone_prepass, read_stats=read_stats,
+            )
+        )
 
     def _index_sources(self, db, m, req, shard_ids):
         """Index-mode sources, optionally restricted to a shard subset
@@ -1282,6 +1272,38 @@ class MeasureEngine:
         if read_stats is not None:
             read_stats.update(stats)
         return sources
+
+
+def _retry_merged_away(read):
+    """Run ``read()`` (a gather over a snapshot of the part list) until
+    no part it names was merged away under it.
+
+    A concurrent merge can GC a part dir after the read snapshots the
+    part list; the read then raises FileNotFoundError and is made again
+    against the fresh snapshot, which holds the same rows in the merged
+    part (the reference's epoch contract).  Every retry is explained by
+    a DIFFERENT vanished directory — a part is merged away once — so the
+    loop ends when the merges do, however many run (a fixed count of
+    three gave up on a 24 h query right after a 10M-point load, while
+    four shards were still merging: chip run, PR 30).  The same
+    directory missing twice is not a merge: the fresh snapshot still
+    lists it, so the loss is real and is raised (an error that names no
+    file, after the old three attempts)."""
+    gone: set = set()
+    unnamed = 0
+    while True:
+        try:
+            return read()
+        except FileNotFoundError as e:
+            where = os.path.dirname(e.filename or "")
+            if where in gone:
+                raise
+            if where:
+                gone.add(where)
+            else:
+                unnamed += 1
+                if unnamed == 3:
+                    raise
 
 
 def _tag_to_bytes(value, tag_type: TagType) -> bytes:

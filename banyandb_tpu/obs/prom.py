@@ -1,7 +1,7 @@
 """Prometheus exposition parsing + histogram quantile recovery.
 
-The read side of obs/metrics: the bench (bench.py), the load probe
-(scripts/load.py) and tests scrape a RUNNING server's exposition text
+The read side of obs/metrics: the load probe (scripts/load.py) and
+tests scrape a RUNNING server's exposition text
 and recover stage latency quantiles from the ``_bucket`` series —
 using the same inversion the live handles use
 (obs.metrics.quantile_from_buckets), so scraped and in-process
@@ -133,7 +133,7 @@ def stage_breakdown_delta(
     """Per-stage attribution of ONLY the window between two scrapes.
 
     Cumulative bucket counts are diffed per (stage, le) so one phase of
-    a run — e.g. each leg of the bench's fused-vs-staged A/B — gets its
+    a run — e.g. the measured window after the warm-up — gets its
     own quantiles instead of the process-lifetime aggregate."""
     prior = histogram_series(before, metric)
     merged: dict[str, dict] = {}

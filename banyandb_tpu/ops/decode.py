@@ -112,13 +112,13 @@ def decode_chunk(chunk: dict) -> dict:
     """The device-side decode stage: encoded chunk pytree -> the
     canonical chunk the plan kernels consume.
 
-    Runs as the FIRST stage inside the fused per-chunk program
-    (measure_exec._build_kernel wraps the kernel body with it; the fused
-    executor applies it to the whole stacked ``[C, nrows]`` batch before
-    its lax.scan), so decode work fuses into the one dispatch per
-    part-batch instead of running as host numpy in the gather stage.
+    Runs as the FIRST stage inside the fused plan program
+    (query/fused_exec applies it to the whole stacked ``[C, nrows]``
+    batch before its lax.scan), so decode work fuses into the one
+    dispatch per part-batch instead of running as host numpy in the
+    gather stage.
 
-    Encoded chunks carry (pad/ship stage, measure_exec._device_chunk):
+    Encoded chunks carry (pad/ship stage, fused_exec._stacked_chunks):
 
     - ``tags_enc``  narrow local dict codes per tag column
     - ``tags_lut``  [S, L] local->global LUT per tag column
@@ -141,31 +141,11 @@ def decode_chunk(chunk: dict) -> dict:
         tags_code = dict(out.get("tags_code", {}))
         for t, codes in chunk.get("tags_enc", {}).items():
             tags_code[t] = dict_remap(
-                _maybe_pallas_widen(codes),
-                chunk["tags_lut"][t],
-                chunk["src_ord"],
+                codes, chunk["tags_lut"][t], chunk["src_ord"]
             )
         out["tags_code"] = tags_code
         fields = dict(out.get("fields", {}))
         for f, vals in chunk.get("fields_enc", {}).items():
-            fields[f] = ints_to_f32(_maybe_pallas_widen(vals))
+            fields[f] = ints_to_f32(vals)
         out["fields"] = fields
     return out
-
-
-def _maybe_pallas_widen(vals):
-    """Route the 1-D (staged-chunk) i8/i16 widen through the Pallas
-    decode kernel on TPU (ops/pallas_kernels.widen_narrow; speed not
-    measured on this installation); plain jnp elsewhere, which is what
-    the tests pin parity against."""
-    import jax
-
-    if jax.default_backend() != "tpu" or vals.ndim != 1:
-        return vals
-    if vals.dtype not in (jnp.int8, jnp.int16):
-        return vals
-    from banyandb_tpu.ops import pallas_kernels
-
-    if vals.shape[0] % pallas_kernels.TILE != 0:
-        return vals
-    return pallas_kernels.widen_narrow(vals)

@@ -9,8 +9,6 @@ of dictionary sizes.  Aggregation is then a dense segment reduction:
 - ``scatter``: jax.ops.segment_sum/min/max (XLA scatter).
 - ``matmul``: one-hot(keys) @ values on the MXU in one shot — for modest
   group counts (<= ~4096) and row counts that fit a single operand.
-- ``matmul_tiled``: lax.scan over row tiles of MXU one-hot contractions.
-  Kept as an oracle/fallback; ``auto`` never picks it.
 - ``pallas``: the hand-tiled Pallas kernel (ops.pallas_kernels) for
   count/sums; min/max still ride XLA scatter.
 - ``sort``: segment-sort grouping — stable sort by key, then the same
@@ -25,7 +23,7 @@ else scatter).
 
 Precision contract (tested by tests/test_precision.py): per-group sums
 accumulate in f32 *within* a bounded row tile (<= 65536 rows for scatter,
-8192 for matmul_tiled, 2048 for pallas); tile partials combine across
+2048 for pallas); tile partials combine across
 tiles with Kahan-compensated f32, so the cross-tile error is O(eps)
 independent of total row count. The one-shot ``matmul`` path is only
 selected for operands <= 2^25 elements (<= ~32k rows at G=1024), where a
@@ -150,11 +148,11 @@ SORT_GROUPS_THRESHOLD = 1 << 16
 def select_group_method(nrows: int, num_groups: int) -> str:
     """Per-signature group-by strategy (the ``method="auto"`` policy).
 
-    Both the staged and the fused whole-plan executor resolve through
-    this ONE function from the same (nrows, num_groups) signature
-    fields, so an A/B flip can never pair different reduction orders —
-    and the ``sort`` path is stable-sorted, keeping per-group
-    accumulation in row order (bit-identical to ``scatter``).
+    Every plan program resolves through this ONE function from the
+    signature's (nrows, num_groups), so two runs of one signature can
+    never pair different reduction orders — and the ``sort`` path is
+    stable-sorted, keeping per-group accumulation in row order
+    (bit-identical to ``scatter``).
 
     Each TPU branch has a cell of the benchmark on its side, and the
     ``reduce`` span reads them on one scale, gathered rows over the wait
@@ -271,27 +269,6 @@ def _group_reduce(
             name: ((col * validf) @ onehot)[:num_groups]
             for name, col in fields.items()
         }
-    elif method == "matmul_tiled":
-        # Large-N variant: scan over row tiles so each [TILE, G+1] one-hot
-        # stays VMEM-sized while sums still ride the MXU — the TPU
-        # alternative to scatter when N*G won't fit at once.  Tile partials
-        # combine with Kahan-compensated f32 (precision contract above).
-        groups = jax.lax.broadcasted_iota(jnp.int32, (num_groups + 1,), 0)
-
-        def mm_partial(k_t, v_t, f_t):
-            onehot = (k_t[:, None] == groups[None, :]).astype(jnp.float32)
-            return [v_t @ onehot] + [
-                f_t[i] @ onehot for i in range(f_t.shape[0])
-            ]
-
-        count, sums = _kahan_tiled_reduce(
-            safe_key,
-            validf,
-            {nm: col * validf for nm, col in fields.items()},
-            num_groups,
-            8192,
-            mm_partial,
-        )
     elif method == "scatter":
         count, sums = _scatter_reduce(
             safe_key,

@@ -201,40 +201,6 @@ def fused_group_multi(
     )
 
 
-# -- device-side decode kernel ------------------------------------------------
-# The compressed-ship path (storage/encoded.py + ops/decode.py) lands
-# narrow i8/i16 columns in HBM; this kernel widens them at VMEM tile
-# granularity on the staged (1-D chunk) path.  Its speed is not measured
-# on this installation; ops.decode.widen_codes (a plain astype) is what
-# runs off-TPU and what the parity tests pin.
-
-
-def _widen_kernel(x_ref, out_ref):
-    out_ref[:] = x_ref[:].astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def widen_narrow(x: jax.Array, *, interpret: bool = False) -> jax.Array:
-    """Narrow i8/i16 column [N] -> i32, tiled through VMEM.
-
-    N must be a TILE multiple (the pad/ship stage's power-of-two row
-    buckets guarantee this above TILE); callers with other shapes use
-    the jnp fallback."""
-    n = x.shape[0]
-    assert n % TILE == 0, f"N={n} must be a multiple of {TILE}"
-    x2 = x.reshape(1, n)
-    out = pl.pallas_call(
-        _widen_kernel,
-        grid=(n // TILE,),
-        in_specs=[pl.BlockSpec((1, TILE), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, TILE), lambda i: (0, i)),
-        out_shape=_out_struct((1, n), jnp.int32, x2),
-        interpret=interpret,
-        name="bydb_widen",
-    )(x2)
-    return out[0]
-
-
 @functools.partial(jax.jit, static_argnames=("num_groups", "interpret"))
 def fused_group_sum(
     codes: jax.Array,

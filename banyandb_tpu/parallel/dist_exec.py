@@ -4,7 +4,7 @@ Map-reduce with collectives instead of proto exchange:
 
   per device:  mask -> group key -> segment reduce  (the "map" on one
                shard/segment slice, same kernel family as
-               query/measure_exec._build_kernel)
+               query/measure_exec._kernel_body)
   collective:  psum(count/sums/hist), pmin/pmax over ('shard','seg')
                — replacing the liaison's partial-merge loop
                (banyand/dquery/measure.go:156)

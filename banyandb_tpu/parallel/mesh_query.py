@@ -164,25 +164,20 @@ class MeshExecutor:
             for c in conds
         }
 
-        from banyandb_tpu.query import fused_exec
-
-        # read the A/B flag ONCE per query so pack and aggregate can
-        # never disagree mid-flight on the chunk layout
-        use_fused = fused_exec.fused_enabled()
-        chunks, total, num_chunks = self._pack(
-            plan, per_node_cols, use_fused
-        )
+        chunks, total, num_chunks = self._pack(plan, per_node_cols)
         if total == 0:
             empty = self._to_partials(plan, gd, None, want_percentile)
             return measure_exec.finalize_partials(m, req, [empty])
 
         import jax
 
+        from banyandb_tpu.query import fused_exec
+
         # bdlint: disable=host-sync -- mesh result boundary: the whole
         # replicated pytree moves in one batched transfer
         out = jax.device_get(
-            self._aggregate(
-                plan, chunks, num_chunks, use_fused, pred_codes=pred_codes
+            fused_exec.fused_distributed_aggregate(
+                self.mesh, plan, num_chunks, chunks, pred_codes=pred_codes
             )
         )
         self.executions += 1
@@ -210,11 +205,11 @@ class MeshExecutor:
             )
             # bdlint: disable=host-sync -- second-pass result boundary
             out = jax.device_get(
-                self._aggregate(
+                fused_exec.fused_distributed_aggregate(
+                    self.mesh,
                     hist_plan,
-                    chunks,
                     num_chunks,
-                    use_fused,
+                    chunks,
                     pred_codes=pred_codes,
                     hist_lo=lo,
                     hist_span=span,
@@ -227,54 +222,16 @@ class MeshExecutor:
             partial = self._to_partials(plan, gd, out, False)
         return measure_exec.finalize_partials(m, req, [partial])
 
-    # -- execution ---------------------------------------------------------
-    def _aggregate(
-        self,
-        plan,
-        chunks,
-        num_chunks,
-        use_fused,
-        pred_codes=None,
-        hist_lo: float = 0.0,
-        hist_span: float = 1.0,
-    ):
-        """One collective reduce over the mesh: the fused chunked-scan
-        step when the A/B flag is on, the legacy single-width step
-        otherwise (both carry the identical psum/pmin/pmax set)."""
-        from banyandb_tpu.parallel import dist_exec
-        from banyandb_tpu.query import fused_exec
-
-        if use_fused:
-            return fused_exec.fused_distributed_aggregate(
-                self.mesh,
-                plan,
-                num_chunks,
-                chunks,
-                pred_codes=pred_codes,
-                hist_lo=hist_lo,
-                hist_span=hist_span,
-            )
-        return dist_exec.distributed_aggregate(
-            self.mesh,
-            plan,
-            chunks,
-            pred_codes=pred_codes,
-            hist_lo=hist_lo,
-            hist_span=hist_span,
-        )
-
     # -- packing -----------------------------------------------------------
-    def _pack(self, plan, per_node_cols, use_fused: bool = False):
+    def _pack(self, plan, per_node_cols):
         """Distribute all (already per-node deduped) rows over the mesh's
         device slots as [D, num_chunks * nrows] arrays.
 
-        Legacy (staged) layout is one chunk whose width is the
-        power-of-two bucket of the per-device row count — unbounded as
-        data grows, one XLA compile per new bucket.  The fused layout
-        caps the chunk width at _FUSED_DIST_CHUNK and buckets the CHUNK
-        COUNT instead (scanned on-device inside the one collective
-        program), bounding the compile-shape set; below the cap the two
-        layouts — and their math — are identical."""
+        Below _FUSED_DIST_CHUNK rows a device the layout is one chunk
+        whose width is the power-of-two bucket of the per-device row
+        count; above it the chunk width is capped there and the CHUNK
+        COUNT is bucketed instead (scanned on-device inside the one
+        collective program), bounding the compile-shape set."""
         d = int(self.mesh.devices.size)
         if per_node_cols:
             tags = {
@@ -298,7 +255,7 @@ class MeshExecutor:
         per = max(math.ceil(total / d) if total else 1, 1)
         nrows = max(1 << (per - 1).bit_length(), _MIN_CHUNK_ROWS)
         num_chunks = 1
-        if use_fused and nrows > _FUSED_DIST_CHUNK:
+        if nrows > _FUSED_DIST_CHUNK:
             from banyandb_tpu.query import fused_exec
 
             num_chunks = fused_exec.chunk_count_bucket(

@@ -1,28 +1,26 @@
 """Fused whole-plan executor: one XLA program per plan signature.
 
-The staged executor (query/measure_exec) dispatches the per-chunk plan
-kernel once per scan chunk with a batched device_get trailing each
-dispatch — N accelerator round-trips per part-batch.  Tailwind (arXiv
-2604.28079) argues the accelerator win comes from compiling the *whole*
-query, not offloading operators; this module is that compiler for the
-measure plan family: filter + group-by + aggregate + the rank inputs
-(TopN metric vectors, percentile histograms) execute as ONE jitted
-program per plan signature, so a part-batch crosses the accelerator
-boundary exactly once — one dispatch in, one batched device_get out.
+Tailwind (arXiv 2604.28079) argues the accelerator win comes from
+compiling the *whole* query, not offloading operators; this module is
+that compiler for the measure plan family: filter + group-by +
+aggregate + the rank inputs (TopN metric vectors, percentile
+histograms) execute as ONE jitted program per plan signature, so a
+part-batch crosses the accelerator boundary exactly once — one dispatch
+in, one batched device_get out.  It is the only executor of a measure
+plan (``measure_exec._reduce_partials`` always comes here).
 
-How parity is guaranteed (the A/B contract, ``BYDB_FUSED=0`` restores
-the staged path):
-
-- the fused program ``lax.scan``s the SAME per-chunk body the staged
-  path jits (``measure_exec._kernel_body``) over a ``[C, nrows]``
-  stacked chunk batch, and returns the per-chunk f32 partials stacked
-  ``[C, ...]`` — the host then folds them into the f64 accumulators in
-  scan order exactly like the staged loop.  Same per-chunk graph, same
-  absorb order => byte-identical partials and results.
+- the program ``lax.scan``s the per-chunk body
+  (``measure_exec._kernel_body``) over a ``[C, nrows]`` stacked chunk
+  batch and returns the per-chunk f32 partials stacked ``[C, ...]`` —
+  the host then folds them into the f64 accumulators in scan order.
+- a scan whose stacked footprint passes the device budget
+  (``BYDB_FUSED_MAX_MB``) runs the SAME program over consecutive chunk
+  batches, one after another (``plan_batches``): same per-chunk graph,
+  same absorb order => byte-identical partials and results whatever the
+  batching.
 - group-by strategy (hash/scatter vs segment-sort, per arXiv
   2411.13245) resolves through ``ops.groupby.select_group_method`` from
-  the signature's (nrows, num_groups) in BOTH paths, so an A/B flip can
-  never pair different reduction orders.
+  the signature's (nrows, num_groups).
 
 Signature lifecycle: the chunk-count bucket rides the jit key
 (``FusedSpec = PlanSpec + num_chunks``, power-of-two buckets keep the
@@ -51,21 +49,15 @@ import numpy as np
 
 from banyandb_tpu.obs import tracer
 from banyandb_tpu.query.measure_exec import DeviceLeg, PlanSpec, _kernel_body
-from banyandb_tpu.utils.envflag import env_flag, env_int
-
-
-def fused_enabled() -> bool:
-    """The A/B flag: default on, ``BYDB_FUSED=0`` restores the staged
-    per-chunk loop (read per query so operators can flip it live)."""
-    return env_flag("BYDB_FUSED", default=True)
+from banyandb_tpu.utils.envflag import env_int
 
 
 def max_fused_mb() -> int:
-    """Device-footprint ceiling for one fused part-batch (stacked input
-    columns + stacked per-chunk partials).  Plans whose one-shot
+    """Device-footprint ceiling for one fused dispatch (stacked input
+    columns + stacked per-chunk partials).  A scan whose one-shot
     footprint exceeds it (e.g. a huge-G percentile over many chunks,
-    where the stacked [C, G, 512] histogram explodes) fall back to the
-    staged loop instead of OOMing the device."""
+    where the stacked [C, G, 512] histogram explodes) runs in chunk
+    batches (``plan_batches``) instead of OOMing the device."""
     return env_int("BYDB_FUSED_MAX_MB", 1024)
 
 
@@ -100,10 +92,9 @@ def _build_kernel(fspec: FusedSpec):
     inside this same program: ops.decode.decode_chunk widens/remaps the
     whole stacked ``[C, nrows]`` batch (the remap LUTs are per-batch,
     not per-chunk, so decoding before the scan avoids broadcasting them
-    down the scanned axis), then the scan body sees exactly the
-    canonical chunks the staged kernel decodes per chunk — elementwise
-    integer decode, so fused-vs-staged stays byte-identical in either
-    ship form."""
+    down the scanned axis), then the scan body sees canonical chunks —
+    elementwise integer decode, so the two ship forms stay
+    byte-identical."""
     from banyandb_tpu.ops import decode as ops_decode
 
     body = _kernel_body(fspec.plan)
@@ -136,9 +127,9 @@ def estimate_bytes(spec: PlanSpec, num_chunks: int) -> int:
     buffers, the i16 src-ordinal column) are resident ALONGSIDE the
     decoded i32/f32 copies the in-program decode stage materializes
     before the scan, so the ceiling accounts both — else a batch sized
-    at ``BYDB_FUSED_MAX_MB`` would OOM instead of taking the intended
-    staged fallback.  (The [S, L] remap LUTs are a rounding error next
-    to the per-row columns and ride the same conservative margin.)"""
+    at ``BYDB_FUSED_MAX_MB`` would OOM instead of splitting.  (The
+    [S, L] remap LUTs are a rounding error next to the per-row columns
+    and ride the same conservative margin.)"""
     from banyandb_tpu.storage import encoded as enc_mod
 
     g = spec.num_groups
@@ -169,14 +160,31 @@ def _resolve_bucket(n_chunks: int, min_bucket: int | None) -> int:
     return bucket
 
 
-def eligible(
-    spec: PlanSpec, n_chunks: int, min_bucket: int | None = None
-) -> bool:
-    """Fused path taken for this part-batch?  Flag + footprint budget."""
-    if n_chunks < 1 or not fused_enabled():
-        return False
-    bucket = _resolve_bucket(n_chunks, min_bucket)
-    return estimate_bytes(spec, bucket) <= max_fused_mb() * (1 << 20)
+def plan_batches(
+    spec: PlanSpec,
+    chunk_spans: list[tuple[int, int]],
+    min_bucket: int | None = None,
+) -> tuple[int, list[list[tuple[int, int]]]]:
+    """How a scan's chunks reach the device: -> (chunk-count bucket,
+    consecutive batches of spans), each batch ONE dispatch of the
+    bucket's program.
+
+    A scan whose bucket fits the device budget (``max_fused_mb``) is
+    one batch.  A scan over it runs in batches of ``b`` chunks, ``b``
+    the largest power of two whose footprint fits and never under 1 (a
+    single chunk over the budget still runs); every batch, a short last
+    one too, uses the ``b``-chunk program.  The planner's ``min_bucket``
+    hint is honoured only where the rounded-up bucket fits."""
+    if not chunk_spans:
+        return 1, []
+    budget = max_fused_mb() * (1 << 20)
+    bucket = _resolve_bucket(len(chunk_spans), min_bucket)
+    if estimate_bytes(spec, bucket) <= budget:
+        return bucket, [chunk_spans]
+    b = 1
+    while b < len(chunk_spans) and estimate_bytes(spec, 2 * b) <= budget:
+        b *= 2
+    return b, [chunk_spans[i : i + b] for i in range(0, len(chunk_spans), b)]
 
 
 def _stacked_chunks(
@@ -191,13 +199,12 @@ def _stacked_chunks(
 ) -> dict:
     """Pad the gathered columns into ``[C, nrows]`` device arrays.
 
-    Chunk layout (per-row dtypes, padding, the epoch-relative int32 ts,
-    the global row index) matches measure_exec._device_chunk exactly —
-    the scan body sees per-chunk inputs identical to the staged
-    kernel's, in EITHER ship form: compressed snapshots
-    (``BYDB_DEVICE_DECODE``) stack the narrow local tag codes, the
-    per-row source ordinals and exact-int fields, plus the per-batch
-    [S, L] remap LUTs the in-program decode stage consumes.  Per-column
+    THE padded chunk layout (per-row dtypes, zero padding, the
+    epoch-relative int32 ts, the global row index), in either ship
+    form: compressed snapshots (``BYDB_DEVICE_DECODE``) stack the narrow
+    local tag codes, the per-row source ordinals and exact-int fields,
+    plus the per-batch [S, L] remap LUTs the in-program decode stage
+    consumes; dense ones the i32 global codes and f32 fields.  Per-column
     pad work rides the chunk_stream prefetch worker (BYDB_PIPELINE
     honored) so padding column j+1 overlaps shipping column j.
     ``pack_s`` collects the pad thunks' seconds (worker thread),
@@ -322,8 +329,8 @@ def _stacked_chunks(
         else:
             out[path[0]][path[1]] = dev
     # canonical keys (tags_code/fields) stay present even when empty —
-    # the pre-decode chunk structure the staged path and the precompile
-    # warm args share; the compressed-only keys appear only when used
+    # the pre-decode chunk structure the precompile warm args share;
+    # the compressed-only keys appear only when used
     for key in ("tags_enc", "tags_lut", "fields_enc"):
         if not out[key]:
             del out[key]
@@ -342,25 +349,26 @@ def run_fused(
     hist_span,
     epoch: int,
     *,
+    num_chunks: int,
+    leg: DeviceLeg,
     gather_key=None,
     dev_cache=None,
     pack_s: list | None = None,
     h2d_s: list | None = None,
     ship_stats: list | None = None,
-    min_bucket: int | None = None,
     decode_span=None,
-) -> tuple[list[dict], DeviceLeg, str]:
-    """Execute one part-batch through the fused program.
+) -> tuple[list[dict], str]:
+    """Execute one chunk batch (``plan_batches``) through the fused
+    program of its ``num_chunks`` bucket.
 
-    -> (per-chunk host partials in scan order for the staged f64 absorb
-    loop, the device leg's timings, input-cache outcome tag).  Exactly
-    one kernel dispatch and one batched device_get regardless of chunk
-    count.  ``min_bucket`` (planner hint) rounds the chunk-count bucket
-    up — see ``_resolve_bucket``.  ``decode_span`` (open, or None) is
-    finished when the stacked inputs are on the device: it covers the
-    pad + ship loop and nothing of the dispatch.
+    -> (per-chunk host partials in scan order for the f64 absorb loop,
+    input-cache outcome tag).  Exactly one kernel dispatch and one
+    batched device_get regardless of chunk count; their host-clock
+    times and what the dispatch compiled add to ``leg`` (one leg per
+    reduction, summed over its batches).  ``decode_span`` (open, or
+    None) is finished when the stacked inputs are on the device: it
+    covers the pad + ship loop and nothing of this dispatch.
     """
-    num_chunks = _resolve_bucket(len(chunk_spans), min_bucket)
     fspec = FusedSpec(plan=spec, num_chunks=num_chunks)
     kernel = _KERNEL_CACHE.get(fspec)
     if kernel is None:
@@ -380,12 +388,14 @@ def run_fused(
         )
 
     if dev_cache is not None:
-        # stacked inputs depend only on (gathered data, bucket, columns):
-        # keep them device-resident so repeat queries skip pad+ship too
-        # (the fused twin of the staged per-chunk device cache)
+        # stacked inputs depend only on (gathered data, the batch's row
+        # span, bucket, columns): keep them device-resident so repeat
+        # queries skip pad+ship too
         ck = (
             "fused_chunks",
             gather_key,
+            chunk_spans[0][0],
+            chunk_spans[-1][1],
             num_chunks,
             spec.nrows,
             spec.tags_code,
@@ -398,22 +408,21 @@ def run_fused(
     if decode_span is not None:
         decode_span.finish()
 
-    leg = DeviceLeg()
     with leg.paid:  # what this dispatch traces or compiles
         t0 = time.perf_counter()
         out = kernel(dev_chunks, pred_vals, hist_lo, hist_span)
-        leg.dispatch_s = time.perf_counter() - t0
+        leg.dispatch_s += time.perf_counter() - t0
     t0 = time.perf_counter()
     # bdlint: disable=host-sync -- THE result boundary of the fused
-    # plan: the whole part-batch's stacked partials move in one batched
-    # transfer (1 get per part-batch, ratcheted by kernel_budgets)
+    # plan: the whole batch's stacked partials move in one batched
+    # transfer (1 get per dispatch, ratcheted by kernel_budgets)
     moved = jax.device_get(out)
-    leg.get_s = time.perf_counter() - t0
+    leg.get_s += time.perf_counter() - t0
     chunks_out = [
         jax.tree_util.tree_map(lambda a, k=k: a[k], moved)
         for k in range(len(chunk_spans))
     ]
-    return chunks_out, leg, ("built" if built else "hit")
+    return chunks_out, ("built" if built else "hit")
 
 
 # ---------------------------------------------------------------------------

@@ -4,9 +4,10 @@ Pipeline (SURVEY.md §3.3 data-node hot loop, rebuilt TPU-first):
 
   host:   sources (memtable + part blocks) -> global tag dictionaries ->
           code remap -> version dedup (lexsort) -> 8192-row chunks
-  device: one jitted kernel per plan signature: time/tag masks ->
-          mixed-radix group key -> segment reduce (count/sum/min/max) ->
-          [+ histogram for percentile] ... executed per chunk
+  device: one jitted program per plan signature (query/fused_exec):
+          time/tag masks -> mixed-radix group key -> segment reduce
+          (count/sum/min/max) -> [+ histogram for percentile], scanned
+          over the stacked chunks inside the one program
   host:   combine tiny per-chunk partials, invert histograms, top-N, limit
 
 The jit cache is keyed by a static PlanSpec, so repeated queries with the
@@ -119,9 +120,6 @@ class PlanSpec:
     expr: tuple = ()
 
 
-_KERNEL_CACHE: dict[PlanSpec, object] = {}
-
-
 class DeviceLeg:
     """Host-clock times at one reduction's accelerator boundaries: the
     jitted calls returning (``dispatch_s``) and the ``device_get`` waits
@@ -155,11 +153,11 @@ class DeviceLeg:
 def _kernel_body(spec: PlanSpec):
     """The un-jitted per-chunk partial computation for `spec`.
 
-    Shared verbatim between the staged executor (jitted per chunk by
-    `_build_kernel`) and the fused whole-plan executor
-    (query/fused_exec scans it over a stacked chunk batch inside ONE
-    program) — one trace graph per chunk either way, which is what
-    makes the staged/fused A/B byte-identical."""
+    query/fused_exec scans it over a stacked chunk batch inside ONE
+    jitted program (after ops.decode.decode_chunk has widened a
+    compressed batch): one trace graph per chunk however the scan is
+    batched, which is what keeps partials byte-identical across
+    batchings."""
 
     def kernel(chunk: dict, pred_vals: dict, hist_lo, hist_span):
         valid = chunk["valid"]
@@ -261,25 +259,6 @@ def _kernel_body(spec: PlanSpec):
         return out
 
     return kernel
-
-
-def _build_kernel(spec: PlanSpec):
-    """Construct + jit the per-chunk partial computation for `spec`.
-
-    The device-side decode stage (ops.decode.decode_chunk) runs FIRST
-    inside the jitted program: chunks arriving in the compressed ship
-    form (narrow dict codes + [S, L] remap LUTs + narrow int fields,
-    ``BYDB_DEVICE_DECODE``) widen/remap on device, fused into the same
-    dispatch; canonical (pre-decoded) chunks pass through untouched, so
-    one jitted kernel serves both ship forms (jit re-specializes per
-    chunk pytree structure)."""
-    body = _kernel_body(spec)
-
-    # the name is the device trace's module line: jit_bydb_chunk_plan
-    def bydb_chunk_plan(chunk: dict, pred_vals: dict, hist_lo, hist_span):
-        return body(ops.decode_chunk(chunk), pred_vals, hist_lo, hist_span)
-
-    return jax.jit(bydb_chunk_plan)
 
 
 class GlobalDicts:
@@ -559,8 +538,8 @@ class Partials:
     def content_bytes(self) -> bytes:
         """Canonical byte serialization of every numeric/representative
         component — THE byte-parity oracle the A/B contracts
-        (``BYDB_FUSED``, ``BYDB_DEVICE_DECODE``, ``BYDB_PIPELINE``)
-        are asserted against (tests/test_fused_exec.py,
+        (``BYDB_DEVICE_DECODE``, ``BYDB_PIPELINE``, one dispatch against
+        chunk batches) are asserted against (tests/test_fused_exec.py,
         tests/test_decode.py, scripts/decode_smoke.py all compare this
         one serialization, so a new Partials field added here is
         parity-pinned everywhere at once)."""
@@ -627,10 +606,9 @@ def compute_partials(
     `plan_hints` (query/planner.PlanDecision or None): the cost-based
     planner's result-preserving refinements — a group-method override
     when the estimated distinct group count crosses the hash/sort
-    crossover on the other side of the static radix product, a minimum
-    fused chunk-count bucket (signature stability), and a
-    prefer-staged routing when the estimated footprint exceeds the
-    fused budget.  ``actual_rows`` is written back for the planner
+    crossover on the other side of the static radix product and a
+    minimum fused chunk-count bucket (signature stability).
+    ``actual_rows`` is written back for the planner
     span's est-vs-actual tag.
     """
     import time as _time
@@ -862,9 +840,6 @@ def compute_partials(
         want_rep=want_rep,
         rep_desc=rep_desc,
     )
-    kernel = _KERNEL_CACHE.get(spec)
-    if kernel is None:
-        kernel = _KERNEL_CACHE[spec] = _build_kernel(spec)
     # function-local import: precompile imports this module's builders
     from banyandb_tpu.query.precompile import default_registry
 
@@ -918,7 +893,7 @@ def compute_partials(
     def _reduce() -> Partials:
         reduce_loaded.append(1)
         return _reduce_partials(
-            measure, chunks_np, conds, expr, pred_vals, spec, kernel,
+            measure, chunks_np, conds, expr, pred_vals, spec,
             group_values, rep_tags, rep_desc, want_rep, gd, dict_state,
             hist_lo, hist_span, want_percentile, epoch, gather_key, agg,
             span=rspan, plan_hints=plan_hints,
@@ -952,7 +927,6 @@ def _reduce_partials(
     expr,
     pred_vals,
     spec,
-    kernel,
     group_values,
     rep_tags,
     rep_desc,
@@ -973,10 +947,9 @@ def _reduce_partials(
     `span` gets the device/host attribution tags: device_ms is the time
     spent at the two accelerator boundaries (dispatch_ms: the jitted
     calls returning; get_ms: the batched device_get), host_ms the rest
-    of the reduction.  Its `decode` child is open while chunks are
-    padded and shipped (pack_ms + h2d_ms = its host_ms; on the staged
-    path that work overlaps the dispatches, so it is NOT a subset of
-    the wall duration)."""
+    of the reduction, all summed over the scan's chunk batches.  Its
+    `decode` child is open while chunks are padded and shipped
+    (pack_ms + h2d_ms = its host_ms)."""
     import contextlib
     import time as _time
 
@@ -1062,65 +1035,16 @@ def _reduce_partials(
             rep_ts_acc = np.where(better, rts, rep_ts_acc)
             rep_row_acc = np.where(better, rrow, rep_row_acc)
 
-    # Gather/compute pipeline, two overlaps stacked per chunk:
-    # (1) while the device executes chunk k, a prefetch thread pads and
-    #     ships chunk k+1 (storage/chunk_stream; BYDB_PIPELINE=0 forces
-    #     the strict-serial path — results are byte-identical either
-    #     way because chunks are absorbed in scan order regardless);
-    # (2) chunk k's device->host transfer happens AFTER chunk k+1's
-    #     kernel is dispatched, so transfer overlaps compute.  The whole
-    #     result pytree moves in a single batched device_get per chunk
-    #     instead of one blocking np.asarray per column (the 29-site
-    #     host-sync audit that motivated bdlint).
-    from banyandb_tpu.storage.chunk_stream import prefetched
-
-    # pack (pad) / h2d (ship) accumulation crosses into the prefetch
-    # worker thread: plain list appends (GIL-atomic), summed by the owner
-    # below — Span objects themselves are single-owner and never touched
-    # off-thread
+    # pack (pad) / h2d (ship) accumulation crosses into the pad worker
+    # thread (fused_exec._stacked_chunks): plain list appends
+    # (GIL-atomic), summed by the owner below — Span objects themselves
+    # are single-owner and never touched off-thread
     pack_s: list = []
     h2d_s: list = []
-    chunks_built: list = []
-    # (shipped, dense) bytes per built chunk: the decode span's
-    # compression evidence (dense = what the decoded i32/f32 ship form
-    # would have moved for the same columns)
+    # (shipped, dense) bytes per batch: the decode span's compression
+    # evidence (dense = what the decoded i32/f32 ship form would have
+    # moved for the same columns)
     ship_stats: list = []
-
-    lut_cache: dict = {}  # remap LUTs ship once per reduction
-
-    def _build_chunk(start: int, end: int):
-        t0 = _time.perf_counter()
-        chunks_built.append(1)
-        shipped: list = []  # this chunk's jnp.asarray seconds
-        try:
-            with tracer.annotate("decode.chunk"):
-                return _device_chunk(
-                    chunks_np, start, end, spec, epoch,
-                    ship_stats=ship_stats, lut_cache=lut_cache, h2d_s=shipped,
-                )
-        finally:
-            h2d = sum(shipped)
-            h2d_s.append(h2d)
-            pack_s.append(_time.perf_counter() - t0 - h2d)
-
-    def _make_chunk(start: int, end: int):
-        if dev_cache is not None:
-            # Chunks depend only on (gathered data, shape, columns): keep
-            # the padded device arrays resident so repeat queries skip
-            # host->HBM transfer too.
-            ck = (
-                "device_chunk",
-                gather_key,
-                start,
-                end,
-                spec.nrows,
-                spec.tags_code,
-                spec.fields,
-            )
-            return dev_cache.get_or_load(
-                ck, lambda: _build_chunk(start, end)
-            )
-        return _build_chunk(start, end)
 
     chunk_spans = []
     for start in range(0, max(n, 1), spec.nrows):
@@ -1129,81 +1053,52 @@ def _reduce_partials(
             break
         chunk_spans.append((start, end))
 
-    # Fused whole-plan path (query/fused_exec, BYDB_FUSED=0 restores
-    # the staged loop below): the SAME per-chunk body scans over a
-    # stacked [C, nrows] batch inside ONE program — one dispatch in, one
-    # batched device_get out per part-batch — and the per-chunk partials
-    # come back stacked for the identical f64 absorb loop.
+    # The one executor (query/fused_exec): the per-chunk body scans over
+    # a stacked [C, nrows] batch inside ONE program — one dispatch in,
+    # one batched device_get out — and the per-chunk partials come back
+    # stacked for the f64 absorb loop.  A scan over the device budget
+    # runs the same program in consecutive chunk batches, strictly one
+    # after another (pad, ship, dispatch, get, absorb, next): two
+    # resident batches would be twice the budget the split respects.
     from banyandb_tpu.query import fused_exec
 
-    leg = DeviceLeg()  # time at the accelerator boundaries (dispatch + get)
-    dispatches = 0
-    # opened BEFORE the pad + ship work it covers, tagged after
+    leg = DeviceLeg()  # time at the accelerator boundaries, all batches
+    # opened BEFORE the pad + ship work it covers, tagged after; finished
+    # when the last batch's inputs are on the device
     dspan = span.child("decode") if span is not None else None
-    fused_cache_tag = None
-    # planner hints (query/planner): prefer_staged routes an estimated-
-    # over-budget batch straight to the staged loop; min_bucket rounds
-    # the chunk-count bucket UP to the estimate's bucket (padding chunks
-    # are fully invalid — byte-identical, one compiled program for a
-    # part population oscillating around a bucket boundary)
-    min_bucket = None
-    hinted_staged = False
-    if plan_hints is not None:
-        min_bucket = plan_hints.chunk_bucket
-        hinted_staged = plan_hints.prefer_staged
-    if not hinted_staged and fused_exec.eligible(
-        spec, len(chunk_spans), min_bucket=min_bucket
-    ):
-        path = "fused"
-        moved_chunks, leg, fused_cache_tag = fused_exec.run_fused(
+    # planner hint (query/planner): min_bucket rounds the chunk-count
+    # bucket UP to the estimate's bucket (padding chunks are fully
+    # invalid — byte-identical, one compiled program for a part
+    # population oscillating around a bucket boundary)
+    bucket, batches = fused_exec.plan_batches(
+        spec,
+        chunk_spans,
+        min_bucket=plan_hints.chunk_bucket if plan_hints is not None else None,
+    )
+    cache_tags = []
+    for i, batch in enumerate(batches):
+        moved_chunks, cache_tag = fused_exec.run_fused(
             chunks_np,
-            chunk_spans,
+            batch,
             spec,
             pred_vals,
             hist_lo_dev,
             hist_span_dev,
             epoch,
+            num_chunks=bucket,
+            leg=leg,
             gather_key=gather_key,
             dev_cache=dev_cache,
             pack_s=pack_s,
             h2d_s=h2d_s,
             ship_stats=ship_stats,
-            min_bucket=min_bucket,
-            decode_span=dspan,
+            decode_span=dspan if i == len(batches) - 1 else None,
         )
-        dispatches = 1
+        cache_tags.append(cache_tag)
         for moved in moved_chunks:
             _absorb(moved)
-    else:
-        path = "staged"
-        pending = None
-        # what the chunk dispatches trace or compile on this thread
-        with leg.paid:
-            for chunk in prefetched(
-                [lambda s=s, e=e: _make_chunk(s, e) for s, e in chunk_spans],
-                name="bydb-chunk-prefetch",
-            ):
-                t_d = _time.perf_counter()
-                out = kernel(chunk, pred_vals, hist_lo_dev, hist_span_dev)
-                leg.dispatch_s += _time.perf_counter() - t_d
-                dispatches += 1
-                if pending is not None:
-                    t_d = _time.perf_counter()
-                    # bdlint: disable=host-sync -- the result boundary: one
-                    # batched transfer per chunk, overlapped with dispatch
-                    # above
-                    moved = jax.device_get(pending)
-                    leg.get_s += _time.perf_counter() - t_d
-                    _absorb(moved)
-                pending = out
-        if dspan is not None:
-            dspan.finish()  # the last chunk is padded and shipped
-        if pending is not None:
-            t_d = _time.perf_counter()
-            # bdlint: disable=host-sync -- final chunk's result boundary
-            moved = jax.device_get(pending)
-            leg.get_s += _time.perf_counter() - t_d
-            _absorb(moved)
+    if dspan is not None and not batches:
+        dspan.finish()  # an empty scan pads and ships nothing
     device_s = leg.device_s
     _H_DEVICE.observe(device_s * 1000)
     # the group-by method every chunk of this reduction ran (never "auto")
@@ -1216,8 +1111,8 @@ def _reduce_partials(
             "group_reduce_rows", float(n), labels={"method": group_method}
         )
     # -- decode stage attribution (ROADMAP item 3) ------------------------
-    # host half = narrow pack + pad (pack_s) + H2D ship (h2d_s), overlapped
-    # with device execution under BYDB_PIPELINE; the device half
+    # host half = narrow pack + pad (pack_s) + H2D ship (h2d_s): column
+    # j+1 pads while column j ships under BYDB_PIPELINE; the device half
     # (widen/remap/f32 convert) is fused into the plan dispatch and is
     # deliberately part of device_execute.  Byte counters attribute the
     # compression ratio independently of the platform.
@@ -1252,20 +1147,15 @@ def _reduce_partials(
         leg.tag(span)
         span.tag(
             "host_ms", round(max(total_ms - device_s * 1000, 0.0), 3)
-        ).tag("chunks", len(chunk_spans)).tag("path", path).tag(
-            "dispatches", dispatches
+        ).tag("chunks", len(chunk_spans)).tag("path", "fused").tag(
+            "dispatches", len(batches)
         ).tag("group_method", group_method).tag("groups", spec.num_groups)
         if device_s > 0:
             span.tag("rows_per_ms", round(n / (device_s * 1000), 3))
-        if dev_cache is not None:
-            if fused_cache_tag is not None:
-                span.tag("device_cache", fused_cache_tag)
-            else:
-                span.tag(
-                    "device_cache",
-                    f"{len(chunk_spans) - len(chunks_built)} hit / "
-                    f"{len(chunks_built)} built",
-                )
+        if dev_cache is not None and batches:
+            span.tag(
+                "device_cache", "built" if "built" in cache_tags else "hit"
+            )
 
     # --- dense [G] arrays -> nonempty-group records (codes stay dense
     # int32 rows; value tuples materialize lazily, Partials.groups) -------
@@ -1788,123 +1678,6 @@ def _materialize_tag_codes(cols: dict, tags: Sequence[str]) -> dict:
     out = dict(cols)
     out["tags_code"] = {t: _host_tag_codes(cols, t) for t in tags}
     return out
-
-
-def _device_chunk(
-    cols: dict,
-    start: int,
-    end: int,
-    spec: PlanSpec,
-    epoch: int,
-    ship_stats: Optional[list] = None,
-    lut_cache: Optional[dict] = None,
-    h2d_s: Optional[list] = None,
-) -> dict:
-    """Pad one row range into the fixed chunk shape, ship to device.
-
-    Compressed-form snapshots (``src_ord`` present, BYDB_DEVICE_DECODE)
-    ship tag columns at their narrow local width plus the small [S, L]
-    remap LUTs, and exact-int fields at i8/i16 — the device decode
-    stage (ops.decode.decode_chunk, fused into the plan kernel) widens
-    them back; PCIe traffic shrinks by the width ratio.
-    ``ship_stats`` (list, GIL-atomic appends from the prefetch worker)
-    collects (shipped_bytes, dense_bytes) per chunk for the decode span
-    and the ``decode_ship_bytes_total`` counters — dense is what the
-    decoded i32/f32 form would have shipped for the same columns.
-    ``h2d_s`` collects the seconds of each host-to-device ship (the
-    decode span's ``h2d_ms``; the rest of this function is ``pack_ms``).
-    """
-    import time as _time
-
-    n = end - start
-    nb = spec.nrows
-    compressed = "src_ord" in cols
-
-    def ship(a: np.ndarray):
-        t0 = _time.perf_counter()
-        dev = jnp.asarray(a)
-        if h2d_s is not None:
-            h2d_s.append(_time.perf_counter() - t0)
-        return dev
-
-    def pad(a: np.ndarray, dtype):
-        out = np.zeros((nb,), dtype=dtype)
-        out[:n] = a[start:end]
-        return ship(out)
-
-    valid = np.zeros((nb,), dtype=bool)
-    valid[:n] = True
-    # ts offsets relative to the global-min epoch keep int32 exact; range
-    # masks are applied on absolute millis host-side during block pruning,
-    # so the residual in-chunk mask only needs relative comparisons.
-    ts_off = cols["ts"][start:end] - epoch
-    ts = np.zeros((nb,), dtype=np.int64)
-    ts[:n] = ts_off
-    chunk = {
-        "ts": ship(ts.astype(np.int32)),
-        "series": pad(cols["series"] % (2**31), np.int32),
-        "valid": ship(valid),
-    }
-    shipped = dense = 0
-    if compressed:
-        from banyandb_tpu.storage import encoded as enc_mod
-
-        if spec.tags_code:
-            chunk["tags_enc"] = {
-                t: pad(cols["tags_enc"][t], cols["tags_enc"][t].dtype)
-                for t in spec.tags_code
-            }
-            # the [S, L] remap LUTs are per part-batch, not per chunk:
-            # pack + ship once and share the device buffer across the
-            # staged loop's chunks (lut_cache lives for one reduction;
-            # the single prefetch worker builds chunks sequentially)
-            luts = {}
-            for t in spec.tags_code:
-                dev = None if lut_cache is None else lut_cache.get(t)
-                if dev is None:
-                    dev = ship(enc_mod.pack_luts(cols["tags_lut"][t]))
-                    if lut_cache is not None:
-                        lut_cache[t] = dev
-                    shipped += dev.nbytes
-                luts[t] = dev
-            chunk["tags_lut"] = luts
-            chunk["src_ord"] = pad(cols["src_ord"], enc_mod.SRC_ORD_DTYPE)
-            shipped += chunk["src_ord"].nbytes
-            for t in spec.tags_code:
-                shipped += chunk["tags_enc"][t].nbytes
-                dense += nb * 4
-        fields_enc = {}
-        fields_f32 = {}
-        for f in spec.fields:
-            ndt = cols["fields_narrow"].get(f)
-            if ndt is not None:
-                fields_enc[f] = pad(cols["fields"][f], ndt)
-                shipped += fields_enc[f].nbytes
-            else:
-                fields_f32[f] = pad(cols["fields"][f], np.float32)
-                shipped += fields_f32[f].nbytes
-            dense += nb * 4
-        if fields_enc:
-            chunk["fields_enc"] = fields_enc
-        chunk["fields"] = fields_f32
-    else:
-        chunk["tags_code"] = {
-            t: pad(cols["tags_code"][t], np.int32) for t in spec.tags_code
-        }
-        chunk["fields"] = {
-            f: pad(cols["fields"][f], np.float32) for f in spec.fields
-        }
-        dense = (len(spec.tags_code) + len(spec.fields)) * nb * 4
-        shipped = dense
-    # always present: the device-chunk cache is keyed by (gather, shape,
-    # columns) and shared across plan variants — a chunk built for a
-    # rep-less plan must still serve a rep-tracking one
-    row = np.zeros((nb,), dtype=np.int32)
-    row[:n] = np.arange(start, end, dtype=np.int32)
-    chunk["row"] = ship(row)
-    if ship_stats is not None:
-        ship_stats.append((shipped, dense))
-    return chunk
 
 
 def combine_partials(partials: list[Partials]) -> Partials:

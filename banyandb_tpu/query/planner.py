@@ -25,9 +25,8 @@ Two cooperating halves:
    - pick the fused chunk schedule: the chunk-count bucket is rounded
      UP to the estimate's bucket (signature stability — a dashboard
      whose part population oscillates around a bucket boundary keeps
-     ONE compiled program), and a part-batch whose *estimated*
-     stacked footprint exceeds ``BYDB_FUSED_MAX_MB`` is routed
-     straight to the staged loop,
+     ONE compiled program); whether the scan fits the device budget
+     is the executor's call alone (``fused_exec.plan_batches``),
    - skip the zone-map pre-pass entirely when estimated selectivity
      is ~1 (``ZONE_SKIP_MIN_SELECTIVITY``): lowering predicates onto
      every part dictionary and interval-checking every block is pure
@@ -178,7 +177,6 @@ class ScanEstimate:
     surviving_rows: int = 0  # est rows surviving predicates
     groups: int = 1  # est distinct group count
     static_groups: int = 1  # the radix product the executor would use
-    bytes: int = 0  # est surviving column bytes shipped
     selectivity: float = 1.0  # surviving_rows / rows
     parts: int = 0
     blocks: int = 0
@@ -192,11 +190,10 @@ class PlanDecision:
     default means "keep the executor's own choice"."""
 
     est: ScanEstimate = field(default_factory=ScanEstimate)
-    path: str = "scan"  # materialized | fused | staged | raw
+    path: str = "scan"  # materialized | fused | raw
     group_method: Optional[str] = None  # select_group_method override
     zone_prepass: bool = True  # lower zone preds + run the block pre-pass
     chunk_bucket: Optional[int] = None  # min fused chunk-count bucket
-    prefer_staged: bool = False  # est footprint exceeds the fused budget
     actual_rows: Optional[int] = None  # written back by compute_partials
 
     def span_tags(self, span) -> None:
@@ -351,15 +348,6 @@ def estimate_scan(engine, db, m, req) -> ScanEstimate:
     est.static_groups = static
     # distinct groups can never exceed surviving rows
     est.groups = max(min(groups, max(est.surviving_rows, 1)), 1)
-    # ship bytes: 4 B/row per column (i32 codes / f32 fields) over the
-    # predicate+group tag set and the aggregate field, sized by what
-    # the gather will actually materialize (predicates mask on device,
-    # they don't shrink the ship) — the planner only needs the ORDER
-    # of magnitude for the fused-footprint call
-    ncols = 4 + len(
-        {c for c, _ in zone_conds} | set(group_tags)
-    ) + 1
-    est.bytes = est.scan_rows * 4 * ncols
     return est
 
 
@@ -399,24 +387,21 @@ def plan_scan(engine, db, m, req, span=None) -> Optional[PlanDecision]:
     if est_method != static_method:
         d.group_method = est_method
 
-    # fused chunk schedule from estimated surviving bytes
+    # fused chunk schedule from the estimated scan rows
     from banyandb_tpu.query import fused_exec
 
     est_chunks = max(
         -(-max(est.scan_rows, 1) // measure_exec.SCAN_CHUNK), 1
     )
     d.chunk_bucket = fused_exec.chunk_count_bucket(est_chunks)
-    d.prefer_staged = (
-        est.bytes > fused_exec.max_fused_mb() * (1 << 20)
-    )
-    d.path = "staged" if d.prefer_staged else "fused"
+    d.path = "fused"
     d.span_tags(span)
     return d
 
 
 def record_decision(path: str) -> None:
     """``planner_decisions_total{path}``: one increment per planned
-    query, path ∈ materialized|fused|staged|raw|off."""
+    query, path ∈ materialized|fused|raw|off."""
     from banyandb_tpu.obs import metrics as obs_metrics
 
     obs_metrics.global_meter().counter_add(

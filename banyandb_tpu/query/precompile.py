@@ -18,10 +18,10 @@ Three cooperating pieces close the cold-start compile gap:
    exact production shapes/dtypes — so the first real query finds a
    warm jit cache instead of paying XLA compilation.
 
-``builtin_plans()``/``builtin_fused()``/``builtin_masks()`` are the
-checked-in dashboard kernel matrix.  The lint plan auditor
-(``lint/whole_program/plan_audit.py``) eval_shape-audits EXACTLY this
-list — a meta-test pins the agreement, so a signature added here is
+``builtin_fused()`` (the programs of ``builtin_plans()``'s signatures)
+and ``builtin_masks()`` are the checked-in dashboard kernel matrix.  The
+lint plan auditor (``lint/whole_program/plan_audit.py``)
+eval_shape-audits EXACTLY this list — a meta-test pins the agreement, so a signature added here is
 automatically contract-checked and a signature audited is automatically
 precompiled.
 
@@ -141,10 +141,10 @@ def builtin_masks():
 
 
 def builtin_fused():
-    """(name, FusedSpec) pairs: the fused whole-plan twins of the builtin
-    measure matrix (query/fused_exec).  One-chunk buckets — the shape a
+    """(name, FusedSpec) pairs: the programs of the builtin measure
+    matrix (query/fused_exec).  One-chunk buckets — the shape a
     dashboard part-batch resolves — warmed, plan-audited and budget-
-    ratcheted alongside their staged counterparts."""
+    ratcheted."""
     from banyandb_tpu.query.fused_exec import FusedSpec
 
     return tuple(
@@ -154,23 +154,6 @@ def builtin_fused():
 
 
 # -- shape/dtype argument builders (shared with the lint plan auditor) -------
-
-
-def chunk_struct(spec) -> dict:
-    """ShapeDtypeStruct pytree matching _device_chunk's output exactly."""
-    import jax
-    import jax.numpy as jnp
-
-    S = jax.ShapeDtypeStruct
-    n = spec.nrows
-    return {
-        "ts": S((n,), jnp.int32),
-        "series": S((n,), jnp.int32),
-        "valid": S((n,), jnp.bool_),
-        "row": S((n,), jnp.int32),
-        "tags_code": {t: S((n,), jnp.int32) for t in spec.tags_code},
-        "fields": {f: S((n,), jnp.float32) for f in spec.fields},
-    }
 
 
 def pred_struct(spec) -> dict:
@@ -213,45 +196,28 @@ def _zeros_like_structs(tree):
     )
 
 
-def measure_warm_args(spec) -> tuple:
-    """Zero-filled production-shaped args for one measure plan kernel."""
-    import jax.numpy as jnp
-
-    return (
-        _zeros_like_structs(chunk_struct(spec)),
-        _zeros_like_structs(pred_struct(spec)),
-        jnp.float32(0.0),
-        jnp.float32(1.0),
-    )
-
-
-def measure_decode_warm_args(spec) -> tuple:
-    """Warm args for the COMPRESSED staged ship form (the production
-    default under ``BYDB_DEVICE_DECODE=1``) at the canonical widths."""
-    import jax.numpy as jnp
-
-    return (
-        _zeros_like_structs(decode_chunk_struct(spec)),
-        _zeros_like_structs(pred_struct(spec)),
-        jnp.float32(0.0),
-        jnp.float32(1.0),
-    )
-
-
 def mask_warm_args(mspec) -> tuple:
     cols, vals = mask_structs(mspec)
     return (_zeros_like_structs(cols), _zeros_like_structs(vals))
 
 
 def fused_chunk_struct(fspec) -> dict:
-    """ShapeDtypeStruct pytree matching fused_exec._stacked_chunks."""
+    """ShapeDtypeStruct pytree matching fused_exec._stacked_chunks in the
+    dense ship form."""
     import jax
+    import jax.numpy as jnp
 
-    base = chunk_struct(fspec.plan)
-    c = fspec.num_chunks
-    return jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct((c,) + s.shape, s.dtype), base
-    )
+    S = jax.ShapeDtypeStruct
+    spec = fspec.plan
+    shape = (fspec.num_chunks, spec.nrows)
+    return {
+        "ts": S(shape, jnp.int32),
+        "series": S(shape, jnp.int32),
+        "valid": S(shape, jnp.bool_),
+        "row": S(shape, jnp.int32),
+        "tags_code": {t: S(shape, jnp.int32) for t in spec.tags_code},
+        "fields": {f: S(shape, jnp.float32) for f in spec.fields},
+    }
 
 
 def _decode_lut_len(spec, t: str) -> int:
@@ -277,47 +243,6 @@ def _decode_code_dtype(spec, t: str):
         if tag == t:
             return jnp.dtype(enc_mod.code_dtype(int(radix)))
     return jnp.dtype(_np.int8)
-
-
-def decode_chunk_struct(spec) -> dict:
-    """ShapeDtypeStruct pytree for the COMPRESSED ship form of one
-    STAGED chunk (measure_exec._device_chunk's compressed branch) at
-    the canonical single-source shape of fused_decode_chunk_struct;
-    the staged form never carries a ``tags_code`` key (the fused
-    stacker keeps an empty one)."""
-    import jax
-    import jax.numpy as jnp
-
-    S = jax.ShapeDtypeStruct
-    n = spec.nrows
-    out = {
-        "ts": S((n,), jnp.int32),
-        "series": S((n,), jnp.int32),
-        "valid": S((n,), jnp.bool_),
-        "row": S((n,), jnp.int32),
-        "fields": {
-            f: S((n,), jnp.float32)
-            for f in spec.fields
-            if f == spec.hist_field
-        },
-    }
-    if spec.tags_code:
-        out["tags_enc"] = {
-            t: S((n,), _decode_code_dtype(spec, t)) for t in spec.tags_code
-        }
-        out["tags_lut"] = {
-            t: S((1, _decode_lut_len(spec, t)), jnp.int32)
-            for t in spec.tags_code
-        }
-        out["src_ord"] = S((n,), jnp.int16)
-    enc = {
-        f: S((n,), jnp.int16)
-        for f in spec.fields
-        if f != spec.hist_field
-    }
-    if enc:
-        out["fields_enc"] = enc
-    return out
 
 
 def fused_decode_chunk_struct(fspec) -> dict:
@@ -627,24 +552,15 @@ class PrecompileRegistry:
     def _compile_one(self, kind: str, spec) -> None:
         import jax
 
-        from banyandb_tpu.query import fused_exec, measure_exec, stream_exec
-
+        from banyandb_tpu.query import fused_exec, stream_exec
         from banyandb_tpu.storage import encoded as enc_mod
 
-        # measure/fused kernels trace per chunk-pytree STRUCTURE, and
-        # the compressed ship form (BYDB_DEVICE_DECODE, default on) is a
+        # fused kernels trace per chunk-pytree STRUCTURE, and the
+        # compressed ship form (BYDB_DEVICE_DECODE, default on) is a
         # different structure from the dense one — warm the form(s)
         # production queries will actually resolve, at the canonical
         # decode widths
-        if kind == "measure":
-            cache, build = (
-                measure_exec._KERNEL_CACHE,
-                measure_exec._build_kernel,
-            )
-            args_list = [measure_warm_args(spec)]
-            if enc_mod.device_decode_enabled():
-                args_list.append(measure_decode_warm_args(spec))
-        elif kind == "fused":
+        if kind == "fused":
             cache, build = (
                 fused_exec._KERNEL_CACHE,
                 fused_exec._build_kernel,
@@ -674,18 +590,30 @@ class PrecompileRegistry:
             jax.block_until_ready(kernel(*args))
 
     def warm(self, include_builtin: bool = True, sigs=None) -> int:
-        """Compile signatures now (callers wanting async use warm_async)."""
+        """Compile signatures now (callers wanting async use warm_async).
+
+        Only what the server dispatches is compiled: ``fused`` and
+        ``stream_mask`` signatures.  A ``measure`` row is the autoreg's
+        evidence (query/planner); it warms as the one-chunk program of
+        its plan only where no ``fused`` row of that plan is in the list
+        (a store written before every resolution recorded one)."""
+        from banyandb_tpu.query.fused_exec import FusedSpec
+
         if sigs is None:
             sigs = list(self.signatures())
             if include_builtin:
-                sigs += [("measure", s) for _, s in builtin_plans()]
                 sigs += [("fused", s) for _, s in builtin_fused()]
                 sigs += [("stream_mask", s) for _, s in builtin_masks()]
+        fused_plans = {s.plan for kind, s in sigs if kind == "fused"}
         done = 0
         seen = set()
         for kind, spec in sigs:
             if self._cancel.is_set():
                 break  # shutdown: stop at a kernel boundary, never mid-compile
+            if kind == "measure":
+                if spec in fused_plans:
+                    continue
+                kind, spec = "fused", FusedSpec(plan=spec, num_chunks=1)
             if (kind, spec) in seen:
                 continue
             seen.add((kind, spec))

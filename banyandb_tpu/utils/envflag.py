@@ -68,8 +68,7 @@ FLAGS: dict[str, str] = {
     "BYDB_DEVICE_CACHE_BYTES": "int: device-resident block cache budget",
     "BYDB_DEVICE_DECODE": "bool: decode encoded blocks on-device",
     "BYDB_FAULTS": "str: fault-injection schedule spec (cluster/faults)",
-    "BYDB_FUSED": "bool: fused scan->aggregate execution",
-    "BYDB_FUSED_MAX_MB": "int: fused-exec working-set ceiling",
+    "BYDB_FUSED_MAX_MB": "int: device-memory budget of one fused dispatch",
     "BYDB_MAX_PERSISTENT_GROUPS": "int: persistent group-by cardinality cap",
     "BYDB_PARTIALS_FRAME_V1": "bool: columnar v1 partials wire frame",
     "BYDB_PIPELINE": "bool: decode/compute pipelining",

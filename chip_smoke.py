@@ -78,7 +78,7 @@ def parse_args(argv=None) -> argparse.Namespace:
     return args
 
 
-# -- dataset (bench.py's e2e dataset, from --seed) -----------------------------
+# -- dataset (from --seed) -----------------------------
 
 
 def make_dataset(seed: int, rows: int) -> dict:

@@ -43,14 +43,9 @@ echo "== cold-path smoke =="
 # populated + persisted, compile cache active (docs/performance.md)
 env JAX_PLATFORMS=cpu python scripts/cold_smoke.py || fail=1
 
-echo "== fused-executor smoke =="
-# multi-chunk part-batch = ONE fused dispatch, BYDB_FUSED=0 staged flip
-# byte-identical, fused signature recorded + round-tripped
-# (docs/performance.md "Fused whole-plan executor")
-env JAX_PLATFORMS=cpu python scripts/fused_smoke.py || fail=1
-
 echo "== device-decode smoke =="
-# compressed-ship A/B byte parity on a real multi-block part, zone-map
+# compressed-ship A/B byte parity on a real multi-block part (in one
+# dispatch and in the over-budget route's chunk batches), zone-map
 # block skipping with identical results, decode span + shipped-bytes
 # counters, fused+decode budget agreement
 # (docs/performance.md "Device-side decode & zone maps")

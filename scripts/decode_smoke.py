@@ -7,7 +7,8 @@ decode contract (docs/performance.md "Device-side decode & zone maps"):
 1. ``BYDB_DEVICE_DECODE=1`` (compressed ship: narrow codes + remap LUTs
    + narrow int fields, decoded on device inside the plan kernel) is
    byte-identical to ``=0`` on partials bytes AND result JSON, on a
-   part-backed multi-block source — in BOTH fused and staged modes;
+   part-backed multi-block source — in one dispatch AND in the
+   over-budget route's chunk batches (``BYDB_FUSED_MAX_MB=0``);
 2. the compressed form ships strictly fewer bytes than the dense form
    (the decode span's shipped/dense counters, and the
    ``decode_ship_bytes_total`` meter counters);
@@ -113,9 +114,12 @@ def main() -> int:
             agg=Aggregation("sum", "v"),
         )
 
-        def run(decode: bool, fused: bool = True):
+        def run(decode: bool, batches: bool = False):
             os.environ["BYDB_DEVICE_DECODE"] = "1" if decode else "0"
-            os.environ["BYDB_FUSED"] = "1" if fused else "0"
+            if batches:
+                os.environ["BYDB_FUSED_MAX_MB"] = "0"
+            else:
+                os.environ.pop("BYDB_FUSED_MAX_MB", None)
             blocks = part.select_blocks(T0, T0 + n)
             src = part.read(
                 blocks, tags=["svc"], fields=["v"], narrow_codes=decode
@@ -128,14 +132,16 @@ def main() -> int:
             )
             return p, res, tr.finish()
 
-        # 1. A/B parity, fused and staged
+        # 1. A/B parity, in chunk batches and in one dispatch
         p_dense, res_dense, _ = run(decode=False)
-        for fused in (True, False):
-            p_dec, res_dec, tree = run(decode=True, fused=fused)
+        for batches in (True, False):
+            p_dec, res_dec, tree = run(decode=True, batches=batches)
             assert _partial_bytes(p_dec) == _partial_bytes(p_dense), (
-                f"partials bytes diverged (fused={fused})"
+                f"partials bytes diverged (batches={batches})"
             )
-            assert res_dec == res_dense, f"result JSON diverged (fused={fused})"
+            assert res_dec == res_dense, (
+                f"result JSON diverged (batches={batches})"
+            )
         print("# parity: compressed == dense on partials bytes + result JSON")
 
         # 2. decode span + compression evidence

@@ -16,8 +16,7 @@ plane's end-to-end invariants (docs/observability.md):
    — the measured plane and the predicted plane agree, which is the
    ratchet the fused whole-plan executor (ROADMAP item 2) tightens;
 5. the fused whole-plan executor costs EXACTLY 1 device_execute
-   dispatch per part-batch (reduce-span `path`/`dispatches` tags), and
-   `BYDB_FUSED=0` restores the staged loop with byte-identical results.
+   dispatch per part-batch (reduce-span `path`/`dispatches` tags).
 
 Exit 0 on success; any assertion prints a diagnostic and exits 1.
 """
@@ -177,7 +176,7 @@ def main() -> int:
     published = kernel_budgets.publish_to_meter()
     assert published > 0, "no dispatch budgets published to the meter"
     text = global_meter().prometheus_text()
-    assert 'kernel_dispatch_budget{signature="measure/' in text, (
+    assert 'kernel_dispatch_budget{signature="fused/' in text, (
         "kernel_dispatch_budget gauges missing from the exposition"
     )
     budget = kernel_budgets.dispatch_budget("measure")
@@ -194,11 +193,9 @@ def main() -> int:
     )
 
     # -- 5: fused whole-plan executor: 1 dispatch per part-batch -----------
-    # The default (fused) query must show EXACTLY one device_execute
-    # dispatch per part-batch on every node's reduce span, and flipping
-    # BYDB_FUSED=0 (the staged per-chunk loop) must return byte-identical
-    # results — the A/B contract of docs/performance.md "Fused whole-plan
-    # executor".
+    # The query must show EXACTLY one device_execute dispatch per
+    # part-batch on every node's reduce span (docs/performance.md "Fused
+    # whole-plan executor").
     for st in subtrees:
         tags = find_span(st, "reduce")["tags"]
         assert tags.get("path") == "fused", f"{st['name']}: path tag {tags}"
@@ -206,35 +203,7 @@ def main() -> int:
             f"{st['name']}: fused part-batch cost {tags.get('dispatches')} "
             f"device_execute dispatches, want exactly 1 {tags}"
         )
-    from banyandb_tpu.storage.cache import device_cache, global_cache
-
-    os.environ["BYDB_FUSED"] = "0"
-    try:
-        # bust the serving/device caches so the staged run recomputes
-        # instead of replaying the fused run's cached partials
-        global_cache().clear()
-        device_cache().clear()
-        res_staged = liaison.query_measure(req)
-    finally:
-        os.environ.pop("BYDB_FUSED", None)
-    j_staged = result_to_json(res_staged)
-    j_staged.pop("trace", None)
-    assert json.dumps(j_staged, sort_keys=True) == b_on, (
-        "staged (BYDB_FUSED=0) results differ from the fused path"
-    )
-    staged_tree = (res_staged.trace or {}).get("span_tree")
-    staged_reduce = [
-        find_span(s, "reduce")["tags"]
-        for s in iter_spans(staged_tree)
-        if str(s.get("name", "")).startswith("data:")
-    ]
-    assert staged_reduce and all(
-        t.get("path") == "staged" for t in staged_reduce
-    ), f"BYDB_FUSED=0 did not restore the staged path: {staged_reduce}"
-    print(
-        f"# fused A/B: 1 dispatch/part-batch on {len(subtrees)} nodes, "
-        "staged flip byte-identical"
-    )
+    print(f"# fused: 1 dispatch/part-batch on {len(subtrees)} nodes")
 
     # -- 6: multi-process data plane graft (docs/performance.md) ----------
     # a BYDB_WORKERS=2 standalone server produces ONE merged tree whose

@@ -152,7 +152,7 @@ def main() -> int:
         os.environ["BYDB_STREAMAGG"] = "1"
         text = render_explain(reply)
         assert "estimated rows:" in text and "actual rows:" in text, text
-        assert "path: fused" in text or "path: staged" in text, text
+        assert "path: fused" in text, text
         print("# explain renders plan + est-vs-actual rows")
 
         # -- 3: BYDB_PLANNER A/B byte parity --------------------------

@@ -25,7 +25,23 @@ import re
 import time
 from pathlib import Path
 
+import numpy as np
 import pytest
+
+
+def numpy_exec(mask, group_cols, values) -> dict:
+    """The plain NumPy reduction the executor parity tests compare with
+    (tests/test_fuzz_parity.py, tests/test_fused_exec.py): the values of
+    the rows ``mask`` selects, by group tuple (``()`` when ungrouped),
+    in row order.  One Python step per selected row, nothing shared with
+    the code under test."""
+    if not group_cols:
+        return {(): np.asarray(values)[mask]}
+    out: dict = {}
+    for i in np.nonzero(mask)[0]:
+        out.setdefault(tuple(c[i] for c in group_cols), []).append(values[i])
+    return {k: np.asarray(v) for k, v in out.items()}
+
 
 grpc = pytest.importorskip("grpc")
 yaml = pytest.importorskip("yaml")

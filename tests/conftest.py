@@ -19,8 +19,8 @@ os.environ.setdefault("BYDB_PRECOMPILE", "0")
 # population timing-dependent (tests/test_planner.py builds explicit
 # AutoRegistrar instances and drives ticks deterministically)
 os.environ.setdefault("BYDB_AUTOREG", "0")
-# no shard-worker subprocesses in the general suite (the BYDB_FUSED-
-# style A/B contract is pinned explicitly by tests/test_workers.py,
+# no shard-worker subprocesses in the general suite (the workers-on /
+# workers-off A/B contract is pinned explicitly by tests/test_workers.py,
 # which passes workers=N to the server; everything else runs the
 # single-process layout it was written against)
 os.environ.setdefault("BYDB_WORKERS", "0")

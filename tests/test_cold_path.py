@@ -402,17 +402,17 @@ def test_registry_records_and_roundtrips(store, monkeypatch, tmp_path):
 
 
 def test_registry_warm_populates_kernel_cache(monkeypatch):
-    from banyandb_tpu.query import measure_exec, precompile, stream_exec
+    from banyandb_tpu.query import fused_exec, precompile, stream_exec
 
     monkeypatch.setenv("BYDB_PRECOMPILE", "1")
     r = precompile.PrecompileRegistry()
     sigs = [
-        ("measure", precompile.builtin_plans()[0][1]),
+        ("fused", precompile.builtin_fused()[0][1]),
         ("stream_mask", precompile.builtin_masks()[0][1]),
     ]
     done = r.warm(sigs=sigs)
     assert done == 2 and r.errors == 0
-    assert sigs[0][1] in measure_exec._KERNEL_CACHE
+    assert sigs[0][1] in fused_exec._KERNEL_CACHE
     assert sigs[1][1] in stream_exec._KERNEL_CACHE
 
 
@@ -446,13 +446,13 @@ def test_warm_async_queues_round_for_midwarm_signatures(monkeypatch):
 
     monkeypatch.setattr(r, "_compile_one", fake_compile)
     spec0, spec1 = (
-        precompile.builtin_plans()[0][1],
-        precompile.builtin_plans()[1][1],
+        precompile.builtin_fused()[0][1],
+        precompile.builtin_fused()[1][1],
     )
-    r.record("measure", spec0)
+    r.record("fused", spec0)
     t1 = r.warm_async(include_builtin=False)
     assert started.wait(10)
-    r.record("measure", spec1)  # lands mid-round
+    r.record("fused", spec1)  # lands mid-round
     assert r.warm_async(include_builtin=False) is t1  # queued, not dropped
     release.set()
     t1.join(15)
@@ -468,11 +468,12 @@ def test_shutdown_stops_warm_at_kernel_boundary(monkeypatch):
 
     monkeypatch.setenv("BYDB_PRECOMPILE", "1")
     r = precompile.PrecompileRegistry()
+    from banyandb_tpu.query.fused_exec import FusedSpec
+
     base = precompile.builtin_plans()[0][1]
     for i in range(50):
-        r._recorded[
-            ("measure", dataclasses.replace(base, num_groups=i + 2))
-        ] = 1
+        plan = dataclasses.replace(base, num_groups=i + 2)
+        r._recorded[("fused", FusedSpec(plan=plan, num_chunks=1))] = 1
     started = threading.Event()
 
     def slow_compile(kind, spec):
@@ -513,8 +514,7 @@ def test_registry_and_plan_audit_agree():
 
     audit_names = {e.name for e in default_entries()}
     builtin_names = (
-        {n for n, _ in precompile.builtin_plans()}
-        | {n for n, _ in precompile.builtin_fused()}
+        {n for n, _ in precompile.builtin_fused()}
         | {n for n, _ in precompile.builtin_fused_decode()}
         | {n for n, _ in precompile.builtin_masks()}
     )
@@ -549,10 +549,10 @@ def _spy(name, value):
 jax.config.update = _spy
 from banyandb_tpu.utils import compile_cache
 active = compile_cache.enable()
-from banyandb_tpu.query.precompile import builtin_plans, PrecompileRegistry
-name, spec = builtin_plans()[0]  # measure/flat-count: the smallest plan
+from banyandb_tpu.query.precompile import builtin_fused, PrecompileRegistry
+name, fspec = builtin_fused()[0]  # fused/flat-count: the smallest plan
 r = PrecompileRegistry()
-assert r.warm(sigs=[("measure", spec)]) == 1 and r.errors == 0
+assert r.warm(sigs=[("fused", fspec)]) == 1 and r.errors == 0
 print(json.dumps({
     **compile_cache.stats(), "active": active, "updates": updates,
     "jax_dir": jax.config.jax_compilation_cache_dir,

@@ -5,14 +5,13 @@ Covers:
   delta_decode (empty/single-row/boundary deltas), dod_decode,
   dict_gather's OOB clip guard, dict_remap, widen_codes, ints_to_f32,
   decode_chunk pass-through vs compressed decode;
-- the Pallas decode kernel (widen_narrow, interpret mode) bit-identical
-  to the jnp fallback;
 - narrow width decisions: encode/decode_dict_codes_narrow at the
   i8/i16/i32 downcast boundaries, storage/encoded.narrow_int_dtype
   edges (non-integral, NaN, +-2^7/2^15 boundaries);
 - ``BYDB_DEVICE_DECODE`` A/B byte-parity (partials bytes + result JSON)
   over multi-source gathers with mixed dictionary widths, absent tag
-  columns (schema evolution) and part-backed sources, staged and fused;
+  columns (schema evolution) and part-backed sources, in one dispatch
+  and in chunk batches;
 - zone maps: written at flush AND merge, select_blocks skipping with
   identical results, the ``blocks_skipped_total{reason=zone}`` counter,
   whole-part exclusion, OR criteria disabling pruning;
@@ -191,21 +190,6 @@ def test_decode_chunk_passthrough_and_compressed():
     assert np.asarray(out["fields"]["v"]).tolist() == [1.0, -2.0, 3.0, 4.0]
 
 
-# -- Pallas decode kernel (interpret mode) ----------------------------------
-
-
-def test_pallas_widen_narrow_matches_jnp():
-    import jax.numpy as jnp
-
-    from banyandb_tpu.ops import pallas_kernels as pk
-
-    rng = np.random.default_rng(5)
-    x = rng.integers(-128, 128, 2 * pk.TILE).astype(np.int8)
-    out = np.asarray(pk.widen_narrow(jnp.asarray(x), interpret=True))
-    assert out.dtype == np.int32
-    assert np.array_equal(out, x.astype(np.int32))
-
-
 # -- narrow widths -----------------------------------------------------------
 
 
@@ -335,8 +319,10 @@ def _result_json(m, req, p) -> str:
     )
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_decode_parity_multi_source_mixed_widths(fused, monkeypatch):
+@pytest.mark.parametrize(
+    "batches", [False, True], ids=["one-batch", "chunk-batches"]
+)
+def test_decode_parity_multi_source_mixed_widths(batches, monkeypatch):
     """3 sources with i8/i16/i32-wide dictionaries, real remap, absent
     column in one source: compressed ship == dense ship byte-for-byte."""
     m = _measure()
@@ -354,7 +340,11 @@ def test_decode_parity_multi_source_mixed_widths(fused, monkeypatch):
         agg=Aggregation("sum", "v"),
         limit=7,
     )
-    monkeypatch.setenv("BYDB_FUSED", "1" if fused else "0")
+    if batches:  # 6,700 rows over the budget: four one-chunk batches
+        monkeypatch.setenv("BYDB_FUSED_MAX_MB", "0")
+        from banyandb_tpu.query import measure_exec
+
+        monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
     monkeypatch.setenv("BYDB_DEVICE_DECODE", "0")
     p_dense = compute_partials(m, req, srcs)
     monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
@@ -812,8 +802,8 @@ def test_cli_dump_reports_zone_presence(tmp_path, capsys):
 
 def test_warm_structs_match_production_compressed_chunks(monkeypatch):
     """The cold-start contract under the default flag: the canonical
-    compressed warm structs (precompile.decode_chunk_struct /
-    fused_decode_chunk_struct) must have EXACTLY the pytree structure,
+    compressed warm structs (precompile.fused_decode_chunk_struct) must
+    have EXACTLY the pytree structure,
     shapes and dtypes the pad/ship stage produces for canonical-width
     data — else warming compiles a trace production never hits."""
     import jax
@@ -842,19 +832,11 @@ def test_warm_structs_match_production_compressed_chunks(monkeypatch):
     cols = _gather_rows(
         [src], ["region", "svc"], ["v"], gd, T0, T0 + n, device_decode=True
     )
-    from banyandb_tpu.query.measure_exec import _device_chunk
 
     def spec_of(tree):
         return jax.tree_util.tree_map(
             lambda a: (tuple(a.shape), str(a.dtype)), tree
         )
-
-    chunk = _device_chunk(cols, 0, n, spec, T0)
-    want = jax.tree_util.tree_map(
-        lambda s: (tuple(s.shape), str(s.dtype)),
-        precompile.decode_chunk_struct(spec),
-    )
-    assert spec_of(chunk) == want
 
     fspec = fused_exec.FusedSpec(plan=spec, num_chunks=1)
     stacked = fused_exec._stacked_chunks(cols, [(0, n)], spec, 1, T0)
@@ -867,23 +849,22 @@ def test_warm_structs_match_production_compressed_chunks(monkeypatch):
 
 def test_warm_dispatches_both_ship_forms(monkeypatch):
     """warm() under BYDB_DEVICE_DECODE=1 compiles the dense AND the
-    compressed form of each measure/fused builtin (jit re-specializes
-    per pytree structure, so both need a boot-time trace)."""
+    compressed form of each builtin program (jit re-specializes per
+    pytree structure, so both need a boot-time trace); a ``measure``
+    evidence row of the same plan compiles nothing of its own."""
     from banyandb_tpu.query import fused_exec, precompile
-    from banyandb_tpu.query import measure_exec as me
 
     monkeypatch.setenv("BYDB_PRECOMPILE", "1")
     monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
-    monkeypatch.setattr(me, "_KERNEL_CACHE", {})
     monkeypatch.setattr(fused_exec, "_KERNEL_CACHE", {})
     r = precompile.PrecompileRegistry()
     spec = precompile.builtin_plans()[0][1]
     fspec = precompile.builtin_fused()[0][1]
-    assert r.warm(sigs=[("measure", spec), ("fused", fspec)]) == 2
+    assert r.warm(sigs=[("measure", spec), ("fused", fspec)]) == 1
     assert r.errors == 0
-    for kernel in (me._KERNEL_CACHE[spec], fused_exec._KERNEL_CACHE[fspec]):
-        # one compiled entry per ship form
-        assert kernel._cache_size() == 2
+    assert list(fused_exec._KERNEL_CACHE) == [fspec]
+    # one compiled entry per ship form
+    assert fused_exec._KERNEL_CACHE[fspec]._cache_size() == 2
 
 
 # -- decode span + counters --------------------------------------------------

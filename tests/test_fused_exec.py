@@ -1,24 +1,29 @@
-"""Fused whole-plan executor (ISSUE 8): one XLA program per plan
-signature (query/fused_exec).
+"""Fused whole-plan executor (ISSUE 8, ISSUE 30): one XLA program per
+plan signature (query/fused_exec), the only executor of a measure plan.
 
 Covers:
-- byte-parity staged vs fused (partials array bytes AND finalized
-  result JSON) across EVERY builtin plan signature, single- and
-  multi-chunk part-batches, incl. a high-radix plan that selects the
-  segment-sort group-by;
+- byte-parity of a scan run as ONE dispatch against the same scan run in
+  chunk batches (the over-budget route, forced by
+  ``BYDB_FUSED_MAX_MB=0``: batches of one chunk) — partials array bytes
+  AND finalized result JSON — across EVERY builtin plan signature,
+  single- and multi-chunk part-batches, incl. a high-radix plan that
+  selects the segment-sort group-by; single-chunk scans (where the two
+  sides are one program) are also held to a plain NumPy reduction;
 - hash- vs sort-based group-by selection pinned per builtin signature
   (ops.groupby.select_group_method) and the sort method's bitwise
   equality with the hash/scatter path;
-- mid-stream decode-error propagation parity between the two paths;
-- the ``BYDB_FUSED=0`` fallback and the footprint-budget fallback;
-- fused-signature precompile-registry round-trip, store persistence and
-  warming into the fused kernel cache;
+- mid-stream decode-error propagation on both routes;
+- ``plan_batches``: the budget decides the batching, batches never share
+  a device-cache entry;
+- fused-signature precompile-registry round-trip, store persistence,
+  warming into the fused kernel cache, and a store written before every
+  resolution recorded a ``fused`` row;
 - the mesh fused dist step (chunked collective program) agreeing with
   the legacy single-width step.
 """
 
+import dataclasses
 import json
-import os
 
 import numpy as np
 import pytest
@@ -43,6 +48,7 @@ from banyandb_tpu.api.schema import (
 from banyandb_tpu.query import fused_exec, measure_exec
 from banyandb_tpu.query.measure_exec import compute_partials, finalize_partials
 from banyandb_tpu.storage.part import ColumnData
+from tests._golden_infra import numpy_exec
 
 T0 = 1_700_000_000_000
 
@@ -238,13 +244,26 @@ def _result_json(m, req, partial) -> str:
     return json.dumps(result_to_json(res), sort_keys=True)
 
 
-def _run(m, req, srcs, fused: bool, monkeypatch):
+# the two routes of one scan, as the device budget in MB that selects
+# them: the default (the whole scan in one dispatch), or nothing
+# (batches of one chunk)
+ONE_BATCH, CHUNK_BATCHES = None, 0
+SIDES = [
+    pytest.param(ONE_BATCH, id="one-batch"),
+    pytest.param(CHUNK_BATCHES, id="chunk-batches"),
+]
+
+
+def _run(m, req, srcs, max_mb, monkeypatch, **kw):
     from banyandb_tpu.obs.tracer import Tracer
 
-    monkeypatch.setenv("BYDB_FUSED", "1" if fused else "0")
+    if max_mb is None:
+        monkeypatch.delenv("BYDB_FUSED_MAX_MB", raising=False)
+    else:
+        monkeypatch.setenv("BYDB_FUSED_MAX_MB", str(max_mb))
     tr = Tracer("t")
     with tr.span("q") as sp:
-        p = compute_partials(m, req, srcs, span=sp)
+        p = compute_partials(m, req, srcs, span=sp, **kw)
     tags = _reduce_tags(tr.finish())
     return p, tags
 
@@ -259,41 +278,108 @@ def _reduce_tags(tree: dict):
     return None
 
 
+def _tag_values(src, tag):
+    """Decoded values of one tag column (INT tags as ints)."""
+    vals = [
+        v.decode() if len(v) != 8 else int.from_bytes(v, "little", signed=True)
+        for v in src.dicts[tag]
+    ]
+    return np.asarray(vals, dtype=object)[src.tags[tag]]
+
+
+def _numpy_mask(c, src) -> np.ndarray:
+    if c is None:
+        return np.ones(len(src.ts), dtype=bool)
+    if isinstance(c, LogicalExpression):
+        left, right = _numpy_mask(c.left, src), _numpy_mask(c.right, src)
+        return left & right if c.op == "and" else left | right
+    col = _tag_values(src, c.name)
+    if c.op == "in":
+        return np.isin(col, list(c.value))
+    return {
+        "eq": lambda: col == c.value,
+        "ne": lambda: col != c.value,
+        "le": lambda: col <= c.value,
+    }[c.op]()
+
+
+def _assert_matches_numpy(m, req, srcs, p):
+    """Hold one single-source partial to the plain NumPy reduction of
+    the same rows: groups and counts exact, INT sums rtol 1e-5, min/max
+    to f32, a percentile within one histogram bucket (chip_smoke.py's
+    oracle holds a served answer to the same)."""
+    (src,) = srcs
+    group_tags = tuple(req.group_by.tag_names) if req.group_by else ()
+    in_range = (src.ts >= req.time_range.begin_millis) & (
+        src.ts < req.time_range.end_millis
+    )
+    mask = in_range & _numpy_mask(req.criteria, src)
+    (field,) = p.sums
+    want = numpy_exec(
+        mask,
+        [src.dicts[t] and np.asarray(src.dicts[t], dtype=object)[src.tags[t]]
+         for t in group_tags],
+        src.fields[field],
+    )
+    want = {k: v for k, v in want.items() if len(v) or not group_tags}
+    got = {g: i for i, g in enumerate(p.groups)}
+    assert set(got) == set(want)
+    for g, vals in want.items():
+        i = got[g]
+        assert p.count[i] == len(vals)
+        np.testing.assert_allclose(p.sums[field][i], vals.sum(), rtol=1e-5)
+        if len(vals) and np.isfinite(p.mins[field][i]):
+            assert p.mins[field][i] == np.float32(vals.min())
+            assert p.maxs[field][i] == np.float32(vals.max())
+    if req.agg is not None and req.agg.function == "percentile":
+        res = finalize_partials(m, req, [p])
+        bucket = p.hist_span / measure_exec._NUM_HIST_BUCKETS
+        (name,) = [k for k in res.values if k != "count"]
+        for g, estimates in zip(res.groups, res.values[name]):
+            vals = np.sort(want[tuple(v.encode() for v in g)])
+            for q, est in zip(req.agg.quantiles, estimates):
+                rank = min(max(int(np.ceil(q * len(vals))), 1), len(vals))
+                assert abs(est - vals[rank - 1]) <= bucket * 1.001
+
+
 @pytest.mark.parametrize(
     "name", [s[0] for s in _scenarios()]
 )
 def test_parity_all_builtin_signatures(name, monkeypatch):
-    """Byte-identical partials + result JSON, staged vs fused, for every
-    builtin plan signature."""
+    """Byte-identical partials + result JSON, one dispatch vs chunk
+    batches, for every builtin plan signature; each is one chunk, so the
+    two sides run one program and the NumPy reduction is the judge."""
     m, req, srcs = next(
         (m, r, s) for n, m, r, s in _scenarios() if n == name
     )
-    p_staged, t_staged = _run(m, req, srcs, fused=False, monkeypatch=monkeypatch)
-    p_fused, t_fused = _run(m, req, srcs, fused=True, monkeypatch=monkeypatch)
-    assert t_staged["path"] == "staged" and t_fused["path"] == "fused"
-    assert t_fused["dispatches"] == 1
-    assert _partial_bytes(p_staged) == _partial_bytes(p_fused)
-    assert _result_json(m, req, p_staged) == _result_json(m, req, p_fused)
+    p_one, t_one = _run(m, req, srcs, ONE_BATCH, monkeypatch)
+    p_bat, t_bat = _run(m, req, srcs, CHUNK_BATCHES, monkeypatch)
+    assert t_one["path"] == "fused" and t_bat["path"] == "fused"
+    assert t_one["chunks"] == 1
+    assert t_one["dispatches"] == 1 and t_bat["dispatches"] == 1
+    assert _partial_bytes(p_one) == _partial_bytes(p_bat)
+    assert _result_json(m, req, p_one) == _result_json(m, req, p_bat)
+    _assert_matches_numpy(m, req, srcs, p_one)
 
 
 @pytest.mark.parametrize("name", [s[0] for s in _scenarios()])
-@pytest.mark.parametrize("fused", [False, True])
-def test_device_decode_parity_all_builtin_signatures(name, fused, monkeypatch):
+@pytest.mark.parametrize("max_mb", SIDES)
+def test_device_decode_parity_all_builtin_signatures(name, max_mb, monkeypatch):
     """``BYDB_DEVICE_DECODE=1`` (compressed ship + in-kernel decode,
     ISSUE 9) is byte-identical to ``=0`` on partials bytes AND result
-    JSON for every builtin plan signature, in both executors — the same
-    A/B contract BYDB_FUSED carries."""
+    JSON for every builtin plan signature, on both routes, and equal to
+    the NumPy reduction."""
     m, req, srcs = next(
         (m, r, s) for n, m, r, s in _scenarios() if n == name
     )
     monkeypatch.setenv("BYDB_DEVICE_DECODE", "0")
-    p_dense, _ = _run(m, req, srcs, fused=fused, monkeypatch=monkeypatch)
+    p_dense, _ = _run(m, req, srcs, max_mb, monkeypatch)
     monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
-    p_dec, t_dec = _run(m, req, srcs, fused=fused, monkeypatch=monkeypatch)
-    if fused:
-        assert t_dec["dispatches"] == 1  # decode fused into the one program
+    p_dec, t_dec = _run(m, req, srcs, max_mb, monkeypatch)
+    assert t_dec["dispatches"] == 1  # decode fused into the one program
     assert _partial_bytes(p_dense) == _partial_bytes(p_dec)
     assert _result_json(m, req, p_dense) == _result_json(m, req, p_dec)
+    _assert_matches_numpy(m, req, srcs, p_dec)
 
 
 def test_device_decode_multichunk_parity(monkeypatch):
@@ -302,30 +388,46 @@ def test_device_decode_multichunk_parity(monkeypatch):
     monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
     name, m, req, srcs = _scenarios()[1]
     monkeypatch.setenv("BYDB_DEVICE_DECODE", "0")
-    p_dense, _ = _run(m, req, srcs, fused=True, monkeypatch=monkeypatch)
+    p_dense, _ = _run(m, req, srcs, ONE_BATCH, monkeypatch)
     monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
-    p_dec, t_dec = _run(m, req, srcs, fused=True, monkeypatch=monkeypatch)
+    p_dec, t_dec = _run(m, req, srcs, ONE_BATCH, monkeypatch)
     assert t_dec["chunks"] == 4 and t_dec["dispatches"] == 1
     assert _partial_bytes(p_dense) == _partial_bytes(p_dec)
 
 
 def test_multichunk_parity_one_dispatch(monkeypatch):
-    """A part-batch spanning several scan chunks fuses into ONE dispatch
-    with byte-identical results."""
+    """A part-batch spanning several scan chunks fuses into ONE dispatch;
+    over the budget it is one dispatch a chunk, byte-identical."""
     monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
     name, m, req, srcs = _scenarios()[1]  # grouped eq+lut, n=8192
-    p_staged, t_staged = _run(m, req, srcs, fused=False, monkeypatch=monkeypatch)
-    p_fused, t_fused = _run(m, req, srcs, fused=True, monkeypatch=monkeypatch)
-    assert t_staged["chunks"] == 4 and t_staged["dispatches"] == 4
-    assert t_fused["chunks"] == 4 and t_fused["dispatches"] == 1
-    assert _partial_bytes(p_staged) == _partial_bytes(p_fused)
-    assert _result_json(m, req, p_staged) == _result_json(m, req, p_fused)
+    p_one, t_one = _run(m, req, srcs, ONE_BATCH, monkeypatch)
+    p_bat, t_bat = _run(m, req, srcs, CHUNK_BATCHES, monkeypatch)
+    assert t_one["path"] == "fused" and t_bat["path"] == "fused"
+    assert t_one["chunks"] == 4 and t_one["dispatches"] == 1
+    assert t_bat["chunks"] == 4 and t_bat["dispatches"] == 4
+    assert _partial_bytes(p_one) == _partial_bytes(p_bat)
+    assert _result_json(m, req, p_one) == _result_json(m, req, p_bat)
+    _assert_matches_numpy(m, req, srcs, p_one)
 
 
-def test_nonbucket_chunk_count_parity(monkeypatch):
-    """3 real chunks ride a 4-chunk bucket: the padded all-invalid chunk
-    must not perturb results."""
+@pytest.mark.parametrize("name", [s[0] for s in _scenarios()])
+def test_multichunk_batches_parity_all_builtin_signatures(name, monkeypatch):
+    """Every builtin signature over several chunks: the over-budget
+    route gives byte-identical partials and result JSON, one dispatch a
+    chunk."""
     monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
+    m, req, srcs = next(
+        (m, r, s) for n, m, r, s in _scenarios() if n == name
+    )
+    p_one, t_one = _run(m, req, srcs, ONE_BATCH, monkeypatch)
+    p_bat, t_bat = _run(m, req, srcs, CHUNK_BATCHES, monkeypatch)
+    assert t_one["chunks"] > 1 and t_one["dispatches"] == 1
+    assert t_bat["dispatches"] == t_bat["chunks"] == t_one["chunks"]
+    assert _partial_bytes(p_one) == _partial_bytes(p_bat)
+    assert _result_json(m, req, p_one) == _result_json(m, req, p_bat)
+
+
+def _three_chunk_scan():
     rng = np.random.default_rng(3)
     n = 3 * 2048
     m = _measure([("svc", TagType.STRING)], [("v", FieldType.INT)])
@@ -342,10 +444,20 @@ def test_nonbucket_chunk_count_parity(monkeypatch):
         group_by=GroupBy(("svc",)),
         agg=Aggregation("sum", "v"),
     )
-    p_staged, _ = _run(m, req, [src], fused=False, monkeypatch=monkeypatch)
-    p_fused, t_fused = _run(m, req, [src], fused=True, monkeypatch=monkeypatch)
-    assert t_fused["chunks"] == 3 and t_fused["dispatches"] == 1
-    assert _partial_bytes(p_staged) == _partial_bytes(p_fused)
+    return m, req, [src]
+
+
+def test_nonbucket_chunk_count_parity(monkeypatch):
+    """3 real chunks ride a 4-chunk bucket: the padded all-invalid chunk
+    must not perturb results."""
+    monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
+    m, req, srcs = _three_chunk_scan()
+    p_one, t_one = _run(m, req, srcs, ONE_BATCH, monkeypatch)
+    p_bat, t_bat = _run(m, req, srcs, CHUNK_BATCHES, monkeypatch)
+    assert t_one["chunks"] == 3 and t_one["dispatches"] == 1
+    assert t_bat["chunks"] == 3 and t_bat["dispatches"] == 3
+    assert _partial_bytes(p_one) == _partial_bytes(p_bat)
+    _assert_matches_numpy(m, req, srcs, p_one)
 
 
 # -- group-by strategy selection ---------------------------------------------
@@ -407,7 +519,8 @@ def test_sort_method_bitwise_matches_scatter():
 
 def test_high_radix_sort_plan_parity(monkeypatch):
     """A plan whose group cardinality crosses SORT_GROUPS_THRESHOLD
-    resolves the sort strategy in BOTH paths and stays byte-identical."""
+    resolves the sort strategy on both routes, byte-identical, and
+    agrees with the NumPy reduction."""
     from banyandb_tpu.ops.groupby import SORT_GROUPS_THRESHOLD
 
     rng = np.random.default_rng(13)
@@ -433,31 +546,51 @@ def test_high_radix_sort_plan_parity(monkeypatch):
         agg=Aggregation("sum", "v"),
         limit=32,
     )
-    p_staged, _ = _run(m, req, [src], fused=False, monkeypatch=monkeypatch)
-    p_fused, _ = _run(m, req, [src], fused=True, monkeypatch=monkeypatch)
-    assert _partial_bytes(p_staged) == _partial_bytes(p_fused)
-    assert _result_json(m, req, p_staged) == _result_json(m, req, p_fused)
+    p_one, t_one = _run(m, req, [src], ONE_BATCH, monkeypatch)
+    p_bat, _ = _run(m, req, [src], CHUNK_BATCHES, monkeypatch)
+    assert t_one["group_method"] == "sort"
+    assert _partial_bytes(p_one) == _partial_bytes(p_bat)
+    assert _result_json(m, req, p_one) == _result_json(m, req, p_bat)
+    _assert_matches_numpy(m, req, [src], p_one)
 
 
-# -- fallbacks ---------------------------------------------------------------
+# -- the device budget ---------------------------------------------------------------
 
 
-def test_flag_off_falls_back_to_staged(monkeypatch):
-    name, m, req, srcs = _scenarios()[0]
-    monkeypatch.setattr(fused_exec, "_KERNEL_CACHE", {})
-    p, tags = _run(m, req, srcs, fused=False, monkeypatch=monkeypatch)
-    assert tags["path"] == "staged"
-    assert fused_exec._KERNEL_CACHE == {}  # fused program never built
+def test_over_budget_runs_in_chunk_batches(monkeypatch):
+    """A scan whose stacked footprint passes ``BYDB_FUSED_MAX_MB`` runs
+    the same program in batches of the largest power-of-two chunk count
+    that fits: here 4 chunks of ~2 MB against 4 MB = 2 dispatches of 2."""
+    monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 65536)
+    rng = np.random.default_rng(23)
+    n = 4 * 65536
+    m = _measure([("svc", TagType.STRING)], [("v", FieldType.INT)])
+    src = _source(
+        n,
+        1,
+        {"svc": ([b"a", b"b", b"c"], rng.integers(0, 3, n).astype(np.int32))},
+        {"v": rng.integers(0, 100, n).astype(np.float64)},
+    )
+    req = QueryRequest(
+        ("g",),
+        "m",
+        TimeRange(T0, T0 + n),
+        group_by=GroupBy(("svc",)),
+        agg=Aggregation("sum", "v"),
+    )
+    p_one, t_one = _run(m, req, [src], ONE_BATCH, monkeypatch)
+    assert t_one["chunks"] == 4 and t_one["dispatches"] == 1
+    p_two, t_two = _run(m, req, [src], 4, monkeypatch)
+    assert t_two["path"] == "fused"
+    assert t_two["chunks"] == 4 and t_two["dispatches"] == 2
+    assert _partial_bytes(p_one) == _partial_bytes(p_two)
+    assert _result_json(m, req, p_one) == _result_json(m, req, p_two)
+    _assert_matches_numpy(m, req, [src], p_two)
 
 
-def test_footprint_budget_falls_back_to_staged(monkeypatch):
-    name, m, req, srcs = _scenarios()[0]
-    monkeypatch.setenv("BYDB_FUSED_MAX_MB", "0")
-    p, tags = _run(m, req, srcs, fused=True, monkeypatch=monkeypatch)
-    assert tags["path"] == "staged"
-
-
-def test_eligibility_is_flag_and_budget():
+def test_plan_batches_follow_the_budget(monkeypatch):
+    """One function decides the batching, from the footprint estimate
+    and the budget alone."""
     spec = measure_exec.PlanSpec(
         tags_code=(),
         fields=("v",),
@@ -466,20 +599,72 @@ def test_eligibility_is_flag_and_budget():
         radices=(),
         num_groups=1,
         want_minmax=True,
-        nrows=8192,
+        nrows=1 << 20,
     )
-    os.environ["BYDB_FUSED"] = "1"
-    try:
-        assert fused_exec.eligible(spec, 1)
-        assert not fused_exec.eligible(spec, 0)
-        os.environ["BYDB_FUSED"] = "0"
-        assert not fused_exec.eligible(spec, 1)
-    finally:
-        os.environ.pop("BYDB_FUSED", None)
+    spans = [(k << 20, (k + 1) << 20) for k in range(5)]
     # footprint estimate grows with the chunk bucket
-    assert fused_exec.estimate_bytes(spec, 8) > fused_exec.estimate_bytes(
-        spec, 1
+    per_chunk = fused_exec.estimate_bytes(spec, 1)
+    assert fused_exec.estimate_bytes(spec, 8) == 8 * per_chunk
+    assert 16 << 20 < per_chunk < 32 << 20
+
+    assert fused_exec.plan_batches(spec, []) == (1, [])
+    # under the budget: one batch, today's bucket rule and hint
+    assert fused_exec.plan_batches(spec, spans) == (8, [spans])
+    assert fused_exec.plan_batches(spec, spans[:3], min_bucket=8) == (
+        8,
+        [spans[:3]],
     )
+    # over it: the largest power of two that fits, a short last batch
+    monkeypatch.setenv("BYDB_FUSED_MAX_MB", "64")
+    assert fused_exec.plan_batches(spec, spans) == (
+        2,
+        [spans[0:2], spans[2:4], spans[4:5]],
+    )
+    # the hint is dropped where the rounded-up bucket would not fit
+    assert fused_exec.plan_batches(spec, spans[:2], min_bucket=4) == (
+        2,
+        [spans[:2]],
+    )
+    # a single chunk over the budget still runs
+    monkeypatch.setenv("BYDB_FUSED_MAX_MB", "0")
+    assert fused_exec.plan_batches(spec, spans) == (
+        1,
+        [[sp] for sp in spans],
+    )
+
+
+def test_batches_do_not_share_a_device_cache_entry(monkeypatch):
+    """Two batches of one bucket are two device-cache entries (the key
+    carries the batch's row span): a second run reads every batch's own
+    rows back from the cache."""
+    from banyandb_tpu.storage import cache
+
+    monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
+    m, req, srcs = _three_chunk_scan()
+    srcs = [dataclasses.replace(srcs[0], cache_key=("part", "p1"))]
+    cache.reset_global_cache()
+    dicts = measure_exec.DictState()  # one gather identity for both runs
+    try:
+        p_one, _ = _run(m, req, srcs, ONE_BATCH, monkeypatch)
+        p_bat, t_bat = _run(
+            m, req, srcs, CHUNK_BATCHES, monkeypatch, dict_state=dicts
+        )
+        assert t_bat["dispatches"] == 3 and t_bat["device_cache"] == "built"
+        dev = cache.device_cache()
+        keys = [
+            k for k in (*dev._unproven, *dev._proven) if k[0] == "fused_chunks"
+        ]
+        assert len(keys) == 3 and len({k[2:4] for k in keys}) == 3
+        # the reduce again (its partials dropped), inputs from the cache
+        cache.global_cache().clear()
+        p_again, t_again = _run(
+            m, req, srcs, CHUNK_BATCHES, monkeypatch, dict_state=dicts
+        )
+    finally:
+        cache.reset_global_cache()
+    assert _partial_bytes(p_bat) == _partial_bytes(p_one)
+    assert t_again["dispatches"] == 3 and t_again["device_cache"] == "hit"
+    assert _partial_bytes(p_again) == _partial_bytes(p_one)
 
 
 def test_chunk_count_bucket_powers_of_two():
@@ -506,8 +691,8 @@ class _ExplodingCol(np.ndarray):
         return super().__getitem__(item)
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_midstream_decode_error_propagates_identically(fused, monkeypatch):
+@pytest.mark.parametrize("max_mb", SIDES)
+def test_midstream_decode_error_propagates_identically(max_mb, monkeypatch):
     monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
     rng = np.random.default_rng(5)
     n = 8192
@@ -532,7 +717,8 @@ def test_midstream_decode_error_propagates_identically(fused, monkeypatch):
         return cols
 
     monkeypatch.setattr(measure_exec, "_gather_rows", exploding_gather)
-    monkeypatch.setenv("BYDB_FUSED", "1" if fused else "0")
+    if max_mb is not None:
+        monkeypatch.setenv("BYDB_FUSED_MAX_MB", str(max_mb))
     with pytest.raises(ValueError, match="decode failed mid-stream"):
         compute_partials(m, req, [src])
 
@@ -547,7 +733,7 @@ def test_fused_signature_recorded_and_persisted(monkeypatch, tmp_path):
     r = precompile.PrecompileRegistry()
     monkeypatch.setattr(precompile, "_registry", r)
     name, m, req, srcs = _scenarios()[0]
-    _run(m, req, srcs, fused=True, monkeypatch=monkeypatch)
+    _run(m, req, srcs, ONE_BATCH, monkeypatch)
     fused_sigs = [s for kind, s in r.signatures() if kind == "fused"]
     assert len(fused_sigs) == 1
     assert isinstance(fused_sigs[0], fused_exec.FusedSpec)
@@ -574,6 +760,41 @@ def test_registry_warm_compiles_fused_kernel(monkeypatch):
     fspec = precompile.builtin_fused()[0][1]
     assert r.warm(sigs=[("fused", fspec)]) == 1 and r.errors == 0
     assert fspec in fused_exec._KERNEL_CACHE
+
+
+def test_registry_loads_parent_measure_rows(monkeypatch, tmp_path):
+    """A ``plan-registry.json`` written before ISSUE 30 holds ``kind:
+    "measure"`` rows: they load, stay the autoreg's evidence, and warm as
+    their plan's one-chunk program only where no ``fused`` row of the
+    plan is stored."""
+    from banyandb_tpu.query import precompile
+
+    monkeypatch.setenv("BYDB_PRECOMPILE", "1")
+    monkeypatch.setattr(fused_exec, "_KERNEL_CACHE", {})
+    plans = dict(precompile.builtin_plans())
+    alone = plans["measure/flat-count"]
+    paired = plans["measure/or-expr"]
+    paired_fused = fused_exec.FusedSpec(plan=paired, num_chunks=2)
+    store = tmp_path / "plan-registry.json"
+    store.write_text(json.dumps({"signatures": [
+        {**precompile.spec_to_json("measure", alone), "count": 7,
+         "last_hit_ms": 1, "context": ["g", "m"]},
+        {**precompile.spec_to_json("measure", paired), "count": 5,
+         "last_hit_ms": 1, "context": ["g", "m"]},
+        {**precompile.spec_to_json("fused", paired_fused), "count": 5,
+         "last_hit_ms": 1},
+    ]}))
+    r = precompile.PrecompileRegistry()
+    r.attach_store(store)
+    assert [(k, s, c) for k, s, _n, c in r.evidence() if k == "measure"] == [
+        ("measure", alone, ("g", "m")),
+        ("measure", paired, ("g", "m")),
+    ]
+    assert r.warm(include_builtin=False) == 2 and r.errors == 0
+    assert set(fused_exec._KERNEL_CACHE) == {
+        fused_exec.FusedSpec(plan=alone, num_chunks=1),
+        paired_fused,
+    }
 
 
 def test_builtin_fused_mirror_builtin_plans():

@@ -30,6 +30,7 @@ from banyandb_tpu.api import (
     WriteRequest,
 )
 from banyandb_tpu.models.measure import MeasureEngine
+from tests._golden_infra import numpy_exec
 
 T0 = 1_700_000_000_000
 N = 3000
@@ -117,20 +118,11 @@ def _numpy_exec(data, lo, hi, conds, group_by, fn):
             cmp = {"lt": np.less, "le": np.less_equal,
                    "gt": np.greater, "ge": np.greater_equal}[c.op]
             mask &= cmp(data["code"], c.value)
-    out = {}
-    if group_by is None:
-        sel = data["v"][mask]
-        out[()] = sel
-        return out
     keys = {
         "svc": np.char.add("s", data["svc"].astype(str)),
         "region": np.char.add("r", data["region"].astype(str)),
     }
-    idx = np.nonzero(mask)[0]
-    for i in idx:
-        k = tuple(keys[t][i] for t in group_by)
-        out.setdefault(k, []).append(data["v"][i])
-    return {k: np.asarray(v) for k, v in out.items()}
+    return numpy_exec(mask, [keys[t] for t in group_by or ()], data["v"])
 
 
 def test_fuzz_device_vs_numpy(dataset):

@@ -173,12 +173,12 @@ def test_dispatch_stub_device_restores_patches():
     import jax
     import jax.numpy as jnp
 
-    from banyandb_tpu.query import measure_exec, stream_exec
+    from banyandb_tpu.query import fused_exec, stream_exec
 
     before = (
         jax.device_get,
         jnp.asarray,
-        measure_exec._build_kernel,
+        fused_exec._build_kernel,
         stream_exec._build_kernel,
     )
     with kdispatch.stub_device():
@@ -187,7 +187,7 @@ def test_dispatch_stub_device_restores_patches():
     after = (
         jax.device_get,
         jnp.asarray,
-        measure_exec._build_kernel,
+        fused_exec._build_kernel,
         stream_exec._build_kernel,
     )
     assert before == after
@@ -197,12 +197,12 @@ def test_dispatch_planted_extra_dispatch_fails_budget():
     """The seeded regression: one extra jitted dispatch on a signature
     whose budget says 1 must fail the kernel-budget gate."""
     traces = kdispatch.audit_dispatch()
-    t = traces["measure/flat-count"]
+    t = traces["fused/flat-count"]
     planted = dataclasses.replace(t, dispatches=t.dispatches + 1)
     fs = kernel_budgets.audit_budgets(
-        traces={"measure/flat-count": planted},
+        traces={"fused/flat-count": planted},
         budgets={
-            "measure/flat-count": kernel_budgets.BUDGETS["measure/flat-count"]
+            "fused/flat-count": kernel_budgets.BUDGETS["fused/flat-count"]
         },
     )
     assert any(
@@ -215,11 +215,14 @@ def test_dispatch_planted_extra_dispatch_fails_budget():
 
 def test_dispatch_signature_drift_flagged():
     traces = kdispatch.audit_dispatch()
-    t = traces["measure/flat-count"]
+    t = traces["fused/flat-count"]
     drifted = dataclasses.replace(
-        t, builtin=dataclasses.replace(t.builtin, num_groups=2)
+        t,
+        builtin=dataclasses.replace(
+            t.builtin, plan=dataclasses.replace(t.builtin.plan, num_groups=2)
+        ),
     )
-    fs = kdispatch.dispatch_findings({"measure/flat-count": drifted})
+    fs = kdispatch.dispatch_findings({"fused/flat-count": drifted})
     assert len(fs) == 1 and "plan signature drift" in fs[0].message
     assert "num_groups" in fs[0].message
 
@@ -264,12 +267,12 @@ def test_budget_loosened_entry_fails_stale():
     """The ratchet's other half: loosening a budget row (or landing an
     improvement without tightening) fails until the row matches."""
     loose = {
-        "measure/flat-count": dataclasses.replace(
-            kernel_budgets.BUDGETS["measure/flat-count"], dispatches=2
+        "fused/flat-count": dataclasses.replace(
+            kernel_budgets.BUDGETS["fused/flat-count"], dispatches=2
         )
     }
     traces = {
-        "measure/flat-count": kdispatch.audit_dispatch()["measure/flat-count"]
+        "fused/flat-count": kdispatch.audit_dispatch()["fused/flat-count"]
     }
     fs = kernel_budgets.audit_budgets(traces=traces, budgets=loose)
     assert any(
@@ -280,7 +283,7 @@ def test_budget_loosened_entry_fails_stale():
 
 def test_budget_missing_row_and_unmeasured_row_fail():
     traces = {
-        "measure/flat-count": kdispatch.audit_dispatch()["measure/flat-count"]
+        "fused/flat-count": kdispatch.audit_dispatch()["fused/flat-count"]
     }
     fs = kernel_budgets.audit_budgets(
         traces=traces,
@@ -295,13 +298,8 @@ def test_budget_table_row_count_pinned():
     """The reviewed budget-table shape: one row per audited signature.
     Adding a kernel forces a row (the table is total); dropping one
     forces deleting the row AND this pin."""
-    assert len(kernel_budgets.BUDGETS) == 25
+    assert len(kernel_budgets.BUDGETS) == 20
     assert set(kernel_budgets.BUDGETS) == {
-        "measure/flat-count",
-        "measure/group-eq-lut",
-        "measure/percentile-hist",
-        "measure/or-expr",
-        "measure/topn-dashboard",
         "fused/flat-count",
         "fused/group-eq-lut",
         "fused/percentile-hist",
@@ -393,8 +391,8 @@ def test_failed_measurement_does_not_cascade_into_budget_findings():
     only — no 'tighten widest to 0' / 'stale row' guidance on top."""
     fs = kernel_budgets.audit_budgets(
         traces={},
-        budgets={"measure/flat-count": kernel_budgets.BUDGETS["measure/flat-count"]},
-        failed={"measure/flat-count"},
+        budgets={"fused/flat-count": kernel_budgets.BUDGETS["fused/flat-count"]},
+        failed={"fused/flat-count"},
     )
     assert fs == [], [f.message for f in fs]
 
@@ -480,7 +478,7 @@ def test_publish_budgets_to_meter():
         if r.dispatches is not None
     )
     text = meter.prometheus_text()
-    assert 'kernel_dispatch_budget{signature="measure/flat-count"} 1' in text
+    assert 'kernel_dispatch_budget{signature="fused/flat-count"} 1' in text
     assert kernel_budgets.dispatch_budget("measure") == 1
     assert kernel_budgets.dispatch_budget("ql") == 0
     with pytest.raises(KeyError):

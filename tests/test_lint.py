@@ -491,12 +491,12 @@ def test_tree_is_bdlint_clean():
     assert findings == [], "\n".join(f.render() for f in findings)
     # every suppression in the tree is a documented decision; pin the
     # exact count so adding (or dropping) one forces a reviewed edit here
-    # 13 = 9 pre-fused + the fused executor's single batched device_get
+    # 11 = 7 pre-fused + the fused executor's single batched device_get
     # result boundary (query/fused_exec.run_fused) + the worker pool's
     # two lifetime handles (per-worker log file + the worker's parent
     # socket, both closed by their owners' teardown paths) + the
     # exhaustive read-failover walk (cluster/liaison._scatter): every
     # round dials a DIFFERENT replica, so inter-round backoff would
     # only burn the query's deadline budget
-    assert stats["suppressed"] == 13
+    assert stats["suppressed"] == 11
     assert stats["files"] > 90

@@ -89,7 +89,7 @@ def test_masks():
     )
 
 
-@pytest.mark.parametrize("method", ["scatter", "matmul", "matmul_tiled"])
+@pytest.mark.parametrize("method", ["scatter", "matmul", "sort"])
 def test_group_reduce_matches_numpy(method):
     n, g = 1024, 12
     key = RNG.integers(0, g, size=n).astype(np.int32)
@@ -117,16 +117,17 @@ def test_group_reduce_matches_numpy(method):
             )
 
 
-def test_group_reduce_matmul_tiled_multi_tile():
-    """n > TILE with a non-divisible remainder: exercises the scan carry
-    and pad path (a single-tile case would not)."""
-    n, g = 20_000, 7
+def test_group_reduce_sort_multi_tile():
+    """n > the 65,536-row span bound with a non-divisible remainder:
+    exercises the scan carry and pad path of the sorted runs (a
+    single-tile case would not)."""
+    n, g = 150_000, 7
     key = RNG.integers(0, g, size=n).astype(np.int32)
     valid = RNG.random(n) > 0.1
     vals = RNG.normal(size=n).astype(np.float32)
     res = ops.group_reduce(
         jnp.asarray(key), jnp.asarray(valid), {"v": jnp.asarray(vals)},
-        g, method="matmul_tiled",
+        g, method="sort",
     )
     for gi in range(g):
         sel = (key == gi) & valid

@@ -300,7 +300,7 @@ def test_planner_span_est_vs_actual(tmp_path, monkeypatch):
     span = find_span(res.trace["span_tree"], "planner")
     assert span is not None
     tags = span["tags"]
-    assert tags["path"] in ("fused", "staged")
+    assert tags["path"] == "fused"
     assert tags["actual_rows"] == 2000  # eq masks on device, gather=all
     assert tags["est_rows"] == 2000
     assert 0 < tags["est_surviving"] <= 2000
@@ -672,6 +672,4 @@ def test_explain_live_engine_round_trip(tmp_path, monkeypatch):
     reply = {"result": result_to_json(res), "served": "scan"}
     out = render_explain(reply)
     assert "actual rows: 1000" in out
-    assert "path: fused (served: scan)" in out or (
-        "path: staged (served: scan)" in out
-    )
+    assert "path: fused (served: scan)" in out

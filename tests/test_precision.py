@@ -37,7 +37,7 @@ def _exact(key, vals):
     "method,n",
     [
         ("scatter", 4 << 20),  # the bench's mega-chunk shape
-        ("matmul_tiled", 1 << 20),
+        ("sort", 1 << 20),
         ("pallas", 1 << 15),  # interpret mode on CPU: keep it small
     ],
 )
@@ -64,7 +64,7 @@ def test_methods_agree():
     n = 1 << 17
     key, vals = _mk(n, seed=5)
     outs = {}
-    for m in ("scatter", "matmul_tiled", "pallas"):
+    for m in ("scatter", "sort", "pallas"):
         r = group_reduce(
             jnp.asarray(key),
             jnp.asarray(np.ones(n, bool)),
@@ -74,5 +74,5 @@ def test_methods_agree():
             method=m,
         )
         outs[m] = np.asarray(r.sums["v"], dtype=np.float64)
-    np.testing.assert_allclose(outs["scatter"], outs["matmul_tiled"], rtol=1e-5)
+    np.testing.assert_allclose(outs["scatter"], outs["sort"], rtol=1e-5)
     np.testing.assert_allclose(outs["scatter"], outs["pallas"], rtol=1e-5)

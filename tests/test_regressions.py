@@ -206,3 +206,46 @@ def test_raw_query_typo_tag_raises(tmp_path):
             QueryRequest(("g",), "m", TimeRange(T0, T0 + 100),
                          criteria=Condition("svcc", "eq", "s"))
         )
+
+
+# -- a gather retried while merges remove the parts it names (PR 30) -----------
+
+
+def _vanishing(paths):
+    """A read that finds each of `paths` gone once, in turn, then works."""
+    todo = list(paths)
+
+    def read():
+        if todo:
+            raise FileNotFoundError(2, "No such file or directory", todo.pop(0))
+        return "rows"
+
+    return read
+
+
+def test_gather_retries_as_long_as_another_part_was_merged_away():
+    from banyandb_tpu.models.measure import _retry_merged_away
+
+    # five merges under one query (the old fixed count gave up at three)
+    parts = [f"/d/shard-{k}/part-{k:016x}/timestamps.bin" for k in range(5)]
+    assert _retry_merged_away(_vanishing(parts)) == "rows"
+
+
+def test_gather_raises_when_the_same_part_is_missing_twice():
+    from banyandb_tpu.models.measure import _retry_merged_away
+
+    # the fresh snapshot still lists it: a loss, not a merge
+    lost = "/d/shard-0/part-0000000000000003"
+    read = _vanishing([f"{lost}/timestamps.bin", f"{lost}/meta.json"])
+    with pytest.raises(FileNotFoundError):
+        _retry_merged_away(read)
+    # an error that names no file gets the old three attempts
+    calls = []
+
+    def unnamed():
+        calls.append(1)
+        raise FileNotFoundError("gone")
+
+    with pytest.raises(FileNotFoundError):
+        _retry_merged_away(unnamed)
+    assert len(calls) == 3

@@ -2,8 +2,7 @@
 
 CPU runs pick `matmul`/`scatter` and interpret Pallas, so the code a TPU
 actually traces — `pallas` group-reduce inside the plan kernels and
-inside `jax.shard_map`, the Pallas narrow-widen on the staged decode
-path — is never reached by the rest of the suite.  Here the backend
+inside `jax.shard_map` — is never reached by the rest of the suite.  Here the backend
 check is forced to "tpu" and each program is lowered for the `tpu`
 platform from the CPU: shard_map typing (`vma`) errors and primitives
 the Pallas TPU lowering does not implement surface at trace/lower time.
@@ -78,21 +77,17 @@ def test_mesh_steps_lower_for_tpu_with_pallas_inside_shard_map(tpu_routing):
 
 
 def test_builtin_plans_lower_for_tpu(tpu_routing):
-    """The TopN dashboard plan (G=1024 -> pallas) in every form the
-    server dispatches: fused and staged, dense and compressed ship."""
-    from banyandb_tpu.query import fused_exec, measure_exec
+    """The TopN dashboard plan (G=1024 -> pallas) in both forms the
+    server dispatches: the dense and the compressed ship."""
+    from banyandb_tpu.query import fused_exec
 
-    spec = dict(precompile.builtin_plans())["measure/topn-dashboard"]
     fspec = dict(precompile.builtin_fused())["fused/topn-dashboard"]
-    preds = precompile.pred_struct(spec)
-    # fresh builds: never the executors' process-global kernel caches
-    fused = fused_exec._build_kernel(fspec)
-    staged = measure_exec._build_kernel(spec)
-    for kernel, chunk in (
-        (fused, precompile.fused_chunk_struct(fspec)),
-        (fused, precompile.fused_decode_chunk_struct(fspec)),
-        (staged, precompile.chunk_struct(spec)),
-        (staged, precompile.decode_chunk_struct(spec)),
+    preds = precompile.pred_struct(fspec.plan)
+    # a fresh build: never the executor's process-global kernel cache
+    kernel = fused_exec._build_kernel(fspec)
+    for chunk in (
+        precompile.fused_chunk_struct(fspec),
+        precompile.fused_decode_chunk_struct(fspec),
     ):
         assert "tpu_custom_call" in _lower_tpu(kernel, chunk, preds, *_SCALARS)
 

@@ -209,18 +209,31 @@ def test_no_hook_no_annotation():
 # -- gather / decode are open while their work runs ----------------------------
 
 
-@pytest.mark.parametrize("fused", ["1", "0"], ids=["fused", "staged"])
+def _route(monkeypatch, batches: int, scan_chunk: int) -> None:
+    """``batches`` 1: the whole scan in one dispatch.  4: over the
+    device budget, in four one-chunk batches of ``scan_chunk`` rows."""
+    if batches > 1:
+        monkeypatch.setenv("BYDB_FUSED_MAX_MB", "0")
+        monkeypatch.setattr(measure_exec, "SCAN_CHUNK", scan_chunk)
+
+
+ROUTES = pytest.mark.parametrize(
+    "batches", [1, 4], ids=["one-batch", "chunk-batches"]
+)
+
+
+@ROUTES
 def test_gather_and_decode_open_while_their_work_runs(
-    annotations, monkeypatch, fused
+    annotations, monkeypatch, batches
 ):
-    monkeypatch.setenv("BYDB_FUSED", fused)
+    _route(monkeypatch, batches, 1024)  # of 4,096 rows
     _run("topn")
     a = annotations
     for phase in ("select", "concat", "dedup", "take"):
         name = f"bydb:gather.{phase}"
         assert a.index("enter", "bydb:gather") < a.index("enter", name)
         assert a.index("leave", name) < a.index("leave", "bydb:gather")
-    work = "bydb:decode.pack" if fused == "1" else "bydb:decode.chunk"
+    work = "bydb:decode.pack"
     assert a.index("enter", "bydb:decode") < a.index("enter", work)
     assert a.index("leave", work, last=True) < a.index("leave", "bydb:decode")
     # every span of the tree was left exactly once
@@ -231,10 +244,10 @@ def test_gather_and_decode_open_while_their_work_runs(
 # -- B: phase tags --------------------------------------------------------------
 
 
-@pytest.mark.parametrize("fused", ["1", "0"], ids=["fused", "staged"])
+@ROUTES
 @pytest.mark.parametrize("kind", ["topn", "percentile"])
-def test_phase_tags_sum_to_their_span(monkeypatch, fused, kind):
-    monkeypatch.setenv("BYDB_FUSED", fused)
+def test_phase_tags_sum_to_their_span(monkeypatch, batches, kind):
+    _route(monkeypatch, batches, 65536)  # of 200,000 rows
     tree = _run(kind, n=200_000, sources=4)
     g = find_span(tree, "gather")["tags"]
     phases = [g[f"{p}_ms"] for p in ("select", "concat", "dedup", "take")]
@@ -248,7 +261,8 @@ def test_phase_tags_sum_to_their_span(monkeypatch, fused, kind):
     for key in ("shipped_bytes", "dense_bytes", "ratio", "mode"):
         assert key in d
     r = find_span(tree, "reduce")["tags"]
-    assert r["path"] == ("fused" if fused == "1" else "staged")
+    assert r["path"] == "fused"
+    assert r["dispatches"] == batches and r["chunks"] == batches
     assert r["dispatch_ms"] > 0 and r["get_ms"] > 0
     assert r["dispatch_ms"] + r["get_ms"] == pytest.approx(
         r["device_ms"], abs=0.01
@@ -371,17 +385,17 @@ def test_lowered_plan_names_every_stage(kind, scopes):
     assert f"bydb.group_reduce.{method}" in text
 
 
-def test_staged_and_pallas_names():
+def test_plan_and_pallas_names():
     import jax
     import jax.numpy as jnp
 
     from banyandb_tpu.ops import pallas_kernels
     from banyandb_tpu.query import precompile
 
-    spec = next(iter(reversed(list(fused_exec._KERNEL_CACHE)))).plan
-    staged = measure_exec._build_kernel(spec)
-    text = staged.lower(*precompile.measure_warm_args(spec)).as_text(debug_info=True)
-    assert "jit_bydb_chunk_plan" in text and "bydb.filter" in text
+    fspec = next(iter(reversed(list(fused_exec._KERNEL_CACHE))))
+    plan = fused_exec._build_kernel(fspec)
+    text = plan.lower(*precompile.fused_warm_args(fspec)).as_text(debug_info=True)
+    assert "jit_bydb_fused_plan" in text and "bydb.filter" in text
     n = pallas_kernels.TILE
     low = jax.jit(
         lambda c, v: pallas_kernels.fused_group_multi(
@@ -390,10 +404,6 @@ def test_staged_and_pallas_names():
         )
     ).lower(jnp.zeros(n, jnp.int32), jnp.zeros((1, n), jnp.float32))
     assert "bydb_group_multi" in low.as_text(debug_info=True)
-    widen = jax.jit(
-        lambda x: pallas_kernels.widen_narrow(x, interpret=True)
-    ).lower(jnp.zeros(n, jnp.int8))
-    assert "bydb_widen" in widen.as_text(debug_info=True)
 
 
 def test_dist_step_names_collective_and_topk():
