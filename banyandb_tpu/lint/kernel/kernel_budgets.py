@@ -87,7 +87,7 @@ BUDGETS: dict[str, KernelBudget] = {
     "fused/flat-count":        _b(1, 1, 5, 4, 20, 4, 0),
     "fused/group-eq-lut":      _b(1, 1, 8, 4, 23, 5, 0),
     "fused/percentile-hist":   _b(1, 1, 6, 4, 25, 5, 0),
-    "fused/or-expr":           _b(1, 1, 7, 4, 20, 3, 0),
+    "fused/or-expr":           _b(1, 1, 7, 4, 20, 4, 0),
     "fused/topn-dashboard":    _b(1, 1, 7, 4, 23, 5, 0),
     # the staging tripwire: a 2-chunk part-batch, still 1 dispatch/get
     # (dispatch columns only: the bucket is synthesized per run, so it
@@ -100,11 +100,11 @@ BUDGETS: dict[str, KernelBudget] = {
     # puts grow by the LUT/ordinal ships, bytes_class is pinned so the
     # in-program decode can never double the traffic class, and
     # widest=4 proves the i8->i32 widen never leaks 64-bit
-    "fused+decode/flat-count":      _b(1, 1, 5, 4, 20, 4, 0),
+    "fused+decode/flat-count":      _b(1, 1, 5, 4, 20, 5, 0),
     "fused+decode/group-eq-lut":    _b(1, 1, 11, 4, 23, 5, 0),
     "fused+decode/percentile-hist": _b(1, 1, 8, 4, 25, 5, 0),
-    "fused+decode/or-expr":         _b(1, 1, 9, 4, 20, 3, 0),
-    "fused+decode/topn-dashboard":  _b(1, 1, 10, 4, 24, 5, 0),
+    "fused+decode/or-expr":         _b(1, 1, 9, 4, 20, 4, 0),
+    "fused+decode/topn-dashboard":  _b(1, 1, 10, 4, 23, 5, 0),
     # compressed multi-chunk tripwire: staging AND decode-stage
     # de-fusion both show up here first
     "fused+decode/multi-chunk":     _b(1, 1, 5),

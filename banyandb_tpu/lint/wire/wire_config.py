@@ -401,6 +401,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "jit_compile_seconds": frozenset(),
     "jit_traces": frozenset(),
     "fault_injected": frozenset({"kind", "site"}),
+    "fused_chunks": frozenset({"kind"}),
     "gather_rows": frozenset({"dedup"}),
     "group_reduce_rows": frozenset({"method"}),
     "kernel_dispatch_budget": frozenset({"signature"}),

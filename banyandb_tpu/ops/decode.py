@@ -112,16 +112,17 @@ def decode_chunk(chunk: dict) -> dict:
     """The device-side decode stage: encoded chunk pytree -> the
     canonical chunk the plan kernels consume.
 
-    Runs as the FIRST stage inside the fused plan program
-    (query/fused_exec applies it to the whole stacked ``[C, nrows]``
-    batch before its lax.scan), so decode work fuses into the one
-    dispatch per part-batch instead of running as host numpy in the
-    gather stage.
+    Runs inside the fused plan program's scan step (query/fused_exec
+    applies it to ONE ``[nrows]`` chunk, in the branch a chunk with a
+    valid row takes), so decode work fuses into the one dispatch per
+    part-batch instead of running as host numpy in the gather stage,
+    and a padding chunk of the bucket is not widened.
 
     Encoded chunks carry (pad/ship stage, fused_exec._stacked_chunks):
 
     - ``tags_enc``  narrow local dict codes per tag column
-    - ``tags_lut``  [S, L] local->global LUT per tag column
+    - ``tags_lut``  [S, L] local->global LUT per tag column (one per
+      batch: the scan closes over it, every chunk sees the same)
     - ``src_ord``   per-row source ordinal (shared by all tag columns)
     - ``fields_enc``  narrow exact-int field columns
 

@@ -13,6 +13,8 @@ plan (``measure_exec._reduce_partials`` always comes here).
   (``measure_exec._kernel_body``) over a ``[C, nrows]`` stacked chunk
   batch and returns the per-chunk f32 partials stacked ``[C, ...]`` —
   the host then folds them into the f64 accumulators in scan order.
+  A chunk with no valid row (the bucket's padding) is branched past on
+  the device: no decode, no body.
 - a scan whose stacked footprint passes the device budget
   (``BYDB_FUSED_MAX_MB``) runs the SAME program over consecutive chunk
   batches, one after another (``plan_batches``): same per-chunk graph,
@@ -73,8 +75,9 @@ class FusedSpec:
 def chunk_count_bucket(n_chunks: int) -> int:
     """Power-of-two chunk-count buckets: the compiled-shape set stays
     O(log max_chunks); chunks beyond the real count are fully invalid
-    (valid=False everywhere) so absorbing them would be a numeric no-op
-    — the host still only absorbs the real ones."""
+    (valid=False everywhere): the program branches past them on the
+    device (``_build_kernel``) and the host absorbs only the real
+    ones, so the padding costs the host's pad + ship and nothing else."""
     b = 1
     while b < n_chunks:
         b <<= 1
@@ -88,26 +91,45 @@ def _build_kernel(fspec: FusedSpec):
     """jit the whole-plan program: scan the shared per-chunk body over
     the stacked chunk axis, emitting stacked per-chunk partials.
 
-    Compressed part-batches (``BYDB_DEVICE_DECODE``) decode FIRST,
-    inside this same program: ops.decode.decode_chunk widens/remaps the
-    whole stacked ``[C, nrows]`` batch (the remap LUTs are per-batch,
-    not per-chunk, so decoding before the scan avoids broadcasting them
-    down the scanned axis), then the scan body sees canonical chunks —
-    elementwise integer decode, so the two ship forms stay
-    byte-identical."""
+    A chunk that holds no valid row (the padding chunks of the
+    chunk-count bucket) does no work: the step branches on what it
+    observes in its input (``lax.cond`` on ``any(valid)``: control flow
+    on the device, not a select) and a padding chunk's partials are
+    zeros the host never reads (``run_fused`` absorbs real chunks only).
+
+    Compressed part-batches (``BYDB_DEVICE_DECODE``) decode inside the
+    step's taken branch: ops.decode.decode_chunk widens/remaps ONE
+    chunk's per-row leaves (the per-batch ``[S, L]`` remap LUTs are
+    closed over: loop invariants, not scanned leaves), then the body
+    sees a canonical chunk.  Elementwise integer decode, so the two
+    ship forms stay byte-identical and a padding chunk is not widened
+    either."""
     from banyandb_tpu.ops import decode as ops_decode
 
     body = _kernel_body(fspec.plan)
 
     # the name is the device trace's module line: jit_bydb_fused_plan
     def bydb_fused_plan(chunks: dict, pred_vals: dict, hist_lo, hist_span):
-        chunks = ops_decode.decode_chunk(chunks)
+        per_row = {k: v for k, v in chunks.items() if k != "tags_lut"}
+        per_batch = {k: v for k, v in chunks.items() if k == "tags_lut"}
+
+        def real_chunk(chunk):
+            chunk = ops_decode.decode_chunk({**chunk, **per_batch})
+            return body(chunk, pred_vals, hist_lo, hist_span)
+
+        def padding_chunk(chunk):
+            return jax.tree.map(
+                lambda s: jnp.zeros(s.shape, s.dtype),
+                jax.eval_shape(real_chunk, chunk),
+            )
 
         def step(carry, chunk):
-            return carry, body(chunk, pred_vals, hist_lo, hist_span)
+            return carry, jax.lax.cond(
+                jnp.any(chunk["valid"]), real_chunk, padding_chunk, chunk
+            )
 
         with jax.named_scope("bydb.fused_scan"):
-            _, stacked = jax.lax.scan(step, None, chunks)
+            _, stacked = jax.lax.scan(step, None, per_row)
         return stacked
 
     return jax.jit(bydb_fused_plan)
@@ -123,13 +145,14 @@ def estimate_bytes(spec: PlanSpec, num_chunks: int) -> int:
     """Device footprint of one fused part-batch: stacked input columns
     plus the stacked per-chunk partials pytree.
 
-    Under ``BYDB_DEVICE_DECODE`` the compressed inputs (narrow tag/field
-    buffers, the i16 src-ordinal column) are resident ALONGSIDE the
-    decoded i32/f32 copies the in-program decode stage materializes
-    before the scan, so the ceiling accounts both — else a batch sized
-    at ``BYDB_FUSED_MAX_MB`` would OOM instead of splitting.  (The
-    [S, L] remap LUTs are a rounding error next to the per-row columns
-    and ride the same conservative margin.)"""
+    Under ``BYDB_DEVICE_DECODE`` the ceiling accounts the compressed
+    inputs (narrow tag/field buffers, the i16 src-ordinal column)
+    ALONGSIDE a decoded i32/f32 copy of every chunk.  Conservative
+    since the decode moved into the scan step (one chunk's decoded copy
+    is live at a time) and left so: no scan is near the budget, and
+    moving the estimate moves the split points.  (The [S, L] remap
+    LUTs are a rounding error next to the per-row columns and ride the
+    same margin.)"""
     from banyandb_tpu.storage import encoded as enc_mod
 
     g = spec.num_groups
@@ -150,7 +173,8 @@ def estimate_bytes(spec: PlanSpec, num_chunks: int) -> int:
 def _resolve_bucket(n_chunks: int, min_bucket: int | None) -> int:
     """The chunk-count bucket for a part-batch, honoring the planner's
     minimum-bucket hint.  The hint only ever rounds UP (padding chunks
-    are fully invalid, the host absorbs only real ones — byte-identical)
+    are fully invalid: skipped on the device, never absorbed by the
+    host — byte-identical)
     and is capped at one doubling of the actual bucket: the hint exists
     for part populations oscillating around a bucket boundary, not to
     pad a 1-chunk batch into a 64-chunk program."""

@@ -154,10 +154,10 @@ def _kernel_body(spec: PlanSpec):
     """The un-jitted per-chunk partial computation for `spec`.
 
     query/fused_exec scans it over a stacked chunk batch inside ONE
-    jitted program (after ops.decode.decode_chunk has widened a
-    compressed batch): one trace graph per chunk however the scan is
-    batched, which is what keeps partials byte-identical across
-    batchings."""
+    jitted program (each step first widens its compressed chunk with
+    ops.decode.decode_chunk, and skips a chunk that holds no valid
+    row): one trace graph per chunk however the scan is batched, which
+    is what keeps partials byte-identical across batchings."""
 
     def kernel(chunk: dict, pred_vals: dict, hist_lo, hist_span):
         valid = chunk["valid"]
@@ -1068,8 +1068,9 @@ def _reduce_partials(
     dspan = span.child("decode") if span is not None else None
     # planner hint (query/planner): min_bucket rounds the chunk-count
     # bucket UP to the estimate's bucket (padding chunks are fully
-    # invalid — byte-identical, one compiled program for a part
-    # population oscillating around a bucket boundary)
+    # invalid and skipped on the device — byte-identical, one compiled
+    # program for a part population oscillating around a bucket
+    # boundary)
     bucket, batches = fused_exec.plan_batches(
         spec,
         chunk_spans,
@@ -1106,9 +1107,19 @@ def _reduce_partials(
     group_method = ops.groupby.resolve_group_method(
         spec.group_method, spec.nrows, spec.num_groups
     )
+    # chunks the device branched past: the padding of the chunk-count
+    # bucket, still padded and shipped by the host (fused_exec)
+    chunks_skipped = bucket * len(batches) - len(chunk_spans)
     if chunk_spans:
-        obs_metrics.global_meter().counter_add(
+        meter = obs_metrics.global_meter()
+        meter.counter_add(
             "group_reduce_rows", float(n), labels={"method": group_method}
+        )
+        meter.counter_add(
+            "fused_chunks", float(len(chunk_spans)), labels={"kind": "run"}
+        )
+        meter.counter_add(
+            "fused_chunks", float(chunks_skipped), labels={"kind": "skipped"}
         )
     # -- decode stage attribution (ROADMAP item 3) ------------------------
     # host half = narrow pack + pad (pack_s) + H2D ship (h2d_s): column
@@ -1147,9 +1158,11 @@ def _reduce_partials(
         leg.tag(span)
         span.tag(
             "host_ms", round(max(total_ms - device_s * 1000, 0.0), 3)
-        ).tag("chunks", len(chunk_spans)).tag("path", "fused").tag(
-            "dispatches", len(batches)
-        ).tag("group_method", group_method).tag("groups", spec.num_groups)
+        ).tag("chunks", len(chunk_spans)).tag(
+            "chunks_skipped", chunks_skipped
+        ).tag("path", "fused").tag("dispatches", len(batches)).tag(
+            "group_method", group_method
+        ).tag("groups", spec.num_groups)
         if device_s > 0:
             span.tag("rows_per_ms", round(n / (device_s * 1000), 3))
         if dev_cache is not None and batches:

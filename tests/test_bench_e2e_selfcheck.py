@@ -19,9 +19,11 @@ name for as long as their modules are being executed, and no longer.
 
 import glob
 import importlib.util
+import json
 import os
 import sys
 
+import pytest
 from _pytest.fixtures import FixtureFunctionDefinition
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -77,3 +79,35 @@ def test_the_fast_files_were_adopted():
     assert {"test_selfcheck.py", "test_control.py", "test_control_ep9k.py"} <= set(ADOPTED)
     assert not SLOW & set(ADOPTED)
     assert any(k.startswith("test_selfcheck__test_selfcheck") for k in globals())
+
+
+# -- metric files a program PR added as data alone ------------------------------------
+
+def _reduce_tree(tags: dict) -> dict:
+    return {"name": "measure-query", "children": [{"name": "execute", "children": [
+        {"name": "gather", "tags": {"rows": 3240000}},
+        {"name": "reduce", "tags": tags, "children": [{"name": "decode", "tags": {}}]},
+    ]}]}
+
+
+@pytest.mark.parametrize(
+    "tags, want",
+    [
+        # 4 real chunks the planner's hint rounds up to the 8-bucket
+        pytest.param({"chunks": 4, "chunks_skipped": 4, "dispatches": 1}, 4.0, id="tagged"),
+        pytest.param({"chunks": 1, "chunks_skipped": 0, "dispatches": 1}, 0.0, id="nothing-skipped"),
+        # a program from before the tag (the parent of ISSUE 31): left out
+        pytest.param({"chunks": 4, "dispatches": 1}, None, id="no-tag-left-out"),
+    ],
+)
+def test_skipped_chunks_per_query_reads_the_reduce_span(tags, want):
+    """`metrics/skipped_chunks_per_query.json` is data for the `span_tag`
+    reader that is there: it reads the `reduce` span's `chunks_skipped`,
+    and where the program has no such tag it returns nothing, so the
+    line leaves the metric out and does not raise."""
+    readers = _load(os.path.join(CHECKOUT, "benchmarks", "e2e", "readers.py"), "bench_e2e_readers")
+    with open(os.path.join(CHECKOUT, "benchmarks", "e2e", "metrics", "skipped_chunks_per_query.json")) as f:
+        metric = json.load(f)
+    assert metric["reader"] == {"kind": "span_tag", "span": "reduce", "tag": "chunks_skipped"}
+    rec = {"queries": [{"served": "scan", "tree": _reduce_tree(tags)} for _ in range(3)]}
+    assert readers.read(metric, rec) == want

@@ -47,6 +47,7 @@ from banyandb_tpu.api.schema import (
 )
 from banyandb_tpu.query import fused_exec, measure_exec
 from banyandb_tpu.query.measure_exec import compute_partials, finalize_partials
+from banyandb_tpu.query.planner import PlanDecision
 from banyandb_tpu.storage.part import ColumnData
 from tests._golden_infra import numpy_exec
 
@@ -458,6 +459,150 @@ def test_nonbucket_chunk_count_parity(monkeypatch):
     assert t_bat["chunks"] == 3 and t_bat["dispatches"] == 3
     assert _partial_bytes(p_one) == _partial_bytes(p_bat)
     _assert_matches_numpy(m, req, srcs, p_one)
+
+
+# -- padding chunks are skipped on the device (ISSUE 31) ----------------------
+
+
+def _padded_scan(name):
+    """-> (m, req, srcs, kw, chunks, skipped): a scan at 2,048 rows a
+    chunk whose bucket holds more chunks than the scan has."""
+    if name == "3in4":  # 3 real chunks ride the 4-bucket
+        return (*_three_chunk_scan(), {}, 3, 1)
+    _, m, req, srcs = _scenarios()[1]  # grouped eq+lut, n=8192
+    if name == "4in8":  # the planner's hint rounds the 4-bucket up
+        return m, req, srcs, {"plan_hints": PlanDecision(chunk_bucket=8)}, 4, 4
+    _, m, req, srcs = _scenarios()[0]
+    return m, req, srcs, {}, 4, 0  # "4in4": nothing to skip
+
+
+PADDED = ["3in4", "4in8", "4in4"]
+
+
+@pytest.mark.parametrize("name", PADDED)
+def test_scan_body_runs_once_per_real_chunk(name, monkeypatch):
+    """The per-chunk body (decode + filter + group + aggregate) runs for
+    the chunks that hold a row and for no other: the padding of the
+    bucket is branched past on the device, not computed and masked."""
+    import jax
+
+    calls = []
+    real_body = fused_exec._kernel_body
+
+    def counting_body(spec):
+        body = real_body(spec)
+
+        def counted(chunk, pred_vals, hist_lo, hist_span):
+            jax.debug.callback(lambda: calls.append(1))
+            return body(chunk, pred_vals, hist_lo, hist_span)
+
+        return counted
+
+    monkeypatch.setattr(fused_exec, "_kernel_body", counting_body)
+    monkeypatch.setattr(fused_exec, "_KERNEL_CACHE", {})
+    monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
+    m, req, srcs, kw, chunks, skipped = _padded_scan(name)
+    _, tags = _run(m, req, srcs, ONE_BATCH, monkeypatch, **kw)
+    jax.effects_barrier()
+    assert tags["dispatches"] == 1 and tags["chunks"] == chunks
+    (fspec,) = fused_exec._KERNEL_CACHE
+    assert fspec.num_chunks == chunks + skipped
+    assert len(calls) == chunks
+
+
+@pytest.mark.parametrize("name", PADDED)
+def test_reduce_span_and_counter_count_skipped_chunks(name, monkeypatch):
+    """Span ``reduce`` carries ``chunks_skipped`` beside ``chunks`` and
+    ``fused_chunks{kind}`` counts the same chunks."""
+    from banyandb_tpu.obs import metrics as obs_metrics
+
+    def counted(kind):
+        return obs_metrics.global_meter().snapshot()["counters"].get(
+            ("fused_chunks", (("kind", kind),)), 0.0
+        )
+
+    monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
+    m, req, srcs, kw, chunks, skipped = _padded_scan(name)
+    before = counted("run"), counted("skipped")
+    _, tags = _run(m, req, srcs, ONE_BATCH, monkeypatch, **kw)
+    assert (tags["chunks"], tags["chunks_skipped"]) == (chunks, skipped)
+    assert counted("run") - before[0] == chunks
+    assert counted("skipped") - before[1] == skipped
+    # over the budget the hint is dropped and every batch is one real chunk
+    _, tags = _run(m, req, srcs, CHUNK_BATCHES, monkeypatch, **kw)
+    assert (tags["chunks"], tags["chunks_skipped"]) == (chunks, 0)
+
+
+def _spoil_padding(monkeypatch):
+    """Have ``_stacked_chunks`` fill every per-row column of the padding
+    chunks with garbage (``valid`` stays False there) -> the number of
+    chunks spoiled, one entry a batch."""
+    import jax.numpy as jnp
+
+    real_stacked = fused_exec._stacked_chunks
+    spoiled = []
+
+    def stacked(cols, spans, spec, num_chunks, *args, **kwargs):
+        rng = np.random.default_rng(99)
+
+        def spoil(arr):
+            arr = np.array(arr)
+            pad = arr[len(spans):]
+            if arr.dtype.kind == "f":
+                pad[...] = rng.normal(0, 1e6, pad.shape)
+            else:
+                info = np.iinfo(arr.dtype)
+                pad[...] = rng.integers(
+                    info.min, info.max, pad.shape, dtype=arr.dtype
+                )
+            return jnp.asarray(arr)
+
+        out = real_stacked(cols, spans, spec, num_chunks, *args, **kwargs)
+        spoiled.append(num_chunks - len(spans))
+        for key, leaf in out.items():
+            if key in ("valid", "tags_lut"):  # the predicate; per batch
+                continue
+            out[key] = (
+                {k: spoil(v) for k, v in leaf.items()}
+                if isinstance(leaf, dict)
+                else spoil(leaf)
+            )
+        return out
+
+    monkeypatch.setattr(fused_exec, "_stacked_chunks", stacked)
+    return spoiled
+
+
+@pytest.mark.parametrize("decode", ["0", "1"], ids=["dense", "compressed"])
+@pytest.mark.parametrize(
+    "plan", ["matmul", "scatter", "sort", "pallas", "percentile"]
+)
+def test_garbage_in_padding_chunks_changes_nothing(plan, decode, monkeypatch):
+    """4 real chunks in the 8-bucket whose 4 padding chunks hold garbage
+    in every column: partials byte-identical to the same scan run as
+    exact one-chunk batches, for each group-by method and a percentile
+    plan, in both ship forms."""
+    monkeypatch.setenv("BYDB_DEVICE_DECODE", decode)
+    if plan == "percentile":
+        monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 16384)
+        _, m, req, srcs = _scenarios()[2]
+        hints = PlanDecision(chunk_bucket=8)
+    else:
+        monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
+        _, m, req, srcs = _scenarios()[1]
+        hints = PlanDecision(chunk_bucket=8, group_method=plan)
+    p_exact, t_exact = _run(
+        m, req, srcs, CHUNK_BATCHES, monkeypatch, plan_hints=hints
+    )
+    assert t_exact["dispatches"] == 4 and t_exact["chunks_skipped"] == 0
+    spoiled = _spoil_padding(monkeypatch)
+    p_pad, t_pad = _run(m, req, srcs, ONE_BATCH, monkeypatch, plan_hints=hints)
+    assert spoiled == [4] and t_pad["chunks_skipped"] == 4
+    assert t_pad["dispatches"] == 1
+    if plan != "percentile":
+        assert t_pad["group_method"] == t_exact["group_method"] == plan
+    assert _partial_bytes(p_pad) == _partial_bytes(p_exact)
+    assert _result_json(m, req, p_pad) == _result_json(m, req, p_exact)
 
 
 # -- group-by strategy selection ---------------------------------------------
