@@ -397,6 +397,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "blocks_skipped": frozenset({"reason"}),
     "compile_cache_enabled": frozenset(),
     "decode_ship_bytes": frozenset({"form"}),
+    "dict_state_resets": frozenset(),
     "failover_attempts": frozenset(),
     "jit_compile_seconds": frozenset(),
     "jit_traces": frozenset(),
@@ -426,6 +427,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "rss_bytes": frozenset(),
     "selftrace_dropped": frozenset(),
     "selftrace_spans": frozenset(),
+    "source_lut_entries": frozenset(),
     "stale_epoch_rejected": frozenset({"site"}),
     "streamagg_invalidated": frozenset(),
     "streamagg_late_dropped": frozenset(),

@@ -442,6 +442,7 @@ def run_fused(
     # transfer (1 get per dispatch, ratcheted by kernel_budgets)
     moved = jax.device_get(out)
     leg.get_s += time.perf_counter() - t0
+    leg.get_bytes += sum(a.nbytes for a in jax.tree_util.tree_leaves(moved))
     chunks_out = [
         jax.tree_util.tree_map(lambda a, k=k: a[k], moved)
         for k in range(len(chunk_spans))

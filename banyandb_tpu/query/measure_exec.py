@@ -125,13 +125,16 @@ class DeviceLeg:
     jitted calls returning (``dispatch_s``) and the ``device_get`` waits
     (``get_s``) — a dispatch that blocks (a trace, a compile, a
     synchronous transfer) and a device that works read differently —
-    plus what the dispatches traced or compiled on this thread."""
+    the bytes those gets brought back (``get_bytes``: the stacked
+    per-chunk partials, a padding chunk's zeros included), plus what the
+    dispatches traced or compiled on this thread."""
 
-    __slots__ = ("dispatch_s", "get_s", "paid")
+    __slots__ = ("dispatch_s", "get_s", "get_bytes", "paid")
 
     def __init__(self):
         self.dispatch_s = 0.0
         self.get_s = 0.0
+        self.get_bytes = 0
         # entered around the dispatches (`with leg.paid:`)
         self.paid = compile_cache.watch()
 
@@ -143,7 +146,9 @@ class DeviceLeg:
         """The ``reduce`` span's device tags; device_ms stays the sum."""
         span.tag("device_ms", round(self.device_s * 1000, 3)).tag(
             "dispatch_ms", round(self.dispatch_s * 1000, 3)
-        ).tag("get_ms", round(self.get_s * 1000, 3))
+        ).tag("get_ms", round(self.get_s * 1000, 3)).tag(
+            "partials_bytes", self.get_bytes
+        )
         if self.paid.compiled:
             span.tag("compiled", self.paid.compiled).tag(
                 "compile_ms", round(self.paid.seconds * 1000, 3)
@@ -670,6 +675,7 @@ def compute_partials(
     # cap-triggered reset swaps dict_state.dicts/token together, and all
     # cache writes below guard on `dict_state.dicts is gd` so an in-flight
     # query can never poison the post-reset caches with old codes.
+    dict_reset = False
     if dict_state is None:
         gd = GlobalDicts(sorted(tags_code))
         token = None
@@ -682,10 +688,15 @@ def compute_partials(
                 prod *= max(len(dict_state.dicts.maps.get(t, ())), 1)
             if prod > _MAX_PERSISTENT_GROUPS:
                 dict_state._reset_locked()
+                dict_reset = True
             gd = dict_state.dicts
             token = dict_state.token
             for t in tags_code:
                 gd.ensure(t)
+    if dict_reset:
+        # this query rebuilds every dictionary and remap LUT from nothing,
+        # and its new token orphans what the caches hold under the old one
+        obs_metrics.global_meter().counter_add("dict_state_resets")
 
     # the compressed-ship flag is read ONCE per query and pinned into the
     # gather cache key: the two ship forms produce differently-shaped
@@ -743,7 +754,7 @@ def compute_partials(
         g.finish()
         g.tag("rows", int(n)).tag("sources", len(sources)).tag(
             "serving_cache", gather_cache
-        )
+        ).tag("dict_reset", dict_reset)
         for key, value in gather_tags.items():
             g.tag(key, value)
     # epoch = global min ts keeps chunk-relative int32 offsets
@@ -946,8 +957,10 @@ def _reduce_partials(
 
     `span` gets the device/host attribution tags: device_ms is the time
     spent at the two accelerator boundaries (dispatch_ms: the jitted
-    calls returning; get_ms: the batched device_get), host_ms the rest
-    of the reduction, all summed over the scan's chunk batches.  Its
+    calls returning; get_ms: the batched device_get, partials_bytes what
+    it brought back), host_ms the rest of the reduction (absorb_ms of
+    it the f64 fold of the chunks' partials), all summed over the
+    scan's chunk batches.  Its
     `decode` child is open while chunks are padded and shipped
     (pack_ms + h2d_ms = its host_ms)."""
     import contextlib
@@ -1077,6 +1090,7 @@ def _reduce_partials(
         min_bucket=plan_hints.chunk_bucket if plan_hints is not None else None,
     )
     cache_tags = []
+    absorb_s = 0.0  # the host fold of the chunks' partials, all batches
     for i, batch in enumerate(batches):
         moved_chunks, cache_tag = fused_exec.run_fused(
             chunks_np,
@@ -1096,8 +1110,10 @@ def _reduce_partials(
             decode_span=dspan if i == len(batches) - 1 else None,
         )
         cache_tags.append(cache_tag)
+        t_absorb0 = _time.perf_counter()
         for moved in moved_chunks:
             _absorb(moved)
+        absorb_s += _time.perf_counter() - t_absorb0
     if dspan is not None and not batches:
         dspan.finish()  # an empty scan pads and ships nothing
     device_s = leg.device_s
@@ -1158,7 +1174,9 @@ def _reduce_partials(
         leg.tag(span)
         span.tag(
             "host_ms", round(max(total_ms - device_s * 1000, 0.0), 3)
-        ).tag("chunks", len(chunk_spans)).tag(
+        ).tag("absorb_ms", round(absorb_s * 1000, 3)).tag(
+            "chunks", len(chunk_spans)
+        ).tag(
             "chunks_skipped", chunks_skipped
         ).tag("path", "fused").tag("dispatches", len(batches)).tag(
             "group_method", group_method
@@ -1372,28 +1390,31 @@ def _host_float_partials(
 
 def _source_lut(
     src: ColumnData, tag: str, gd: GlobalDicts, dict_state: Optional[DictState]
-) -> np.ndarray:
-    """local-code -> global-code LUT, cached by immutable part identity."""
+) -> tuple[np.ndarray, int]:
+    """-> (local-code -> global-code LUT, cached by immutable part
+    identity; the dictionary entries ``add_source`` walked to build it,
+    0 when it came from ``dict_state.remaps``)."""
+    d = src.dicts.get(tag, ())
     if dict_state is None:
-        return gd.add_source(tag, list(src.dicts.get(tag, [])))
+        return gd.add_source(tag, list(d)), len(d)
     if src.cache_key is None:
         with dict_state.lock:
-            return gd.add_source(tag, list(src.dicts.get(tag, [])))
+            return gd.add_source(tag, list(d)), len(d)
     # (source identity, tag, dict length): part dicts are immutable, but
     # memtable snapshots reuse one generation id while their dict grows
     # append-only — the length pins WHICH prefix this LUT covers, so a
     # grown dict gets a fresh (longer) LUT instead of a stale short one
-    rk = (src.cache_key[1], tag, len(src.dicts.get(tag, ())))
+    rk = (src.cache_key[1], tag, len(d))
     with dict_state.lock:
         if dict_state.dicts is not gd:
             # state was reset mid-query: codes from the old gd must not
             # enter the new remap cache
-            return gd.add_source(tag, list(src.dicts.get(tag, [])))
+            return gd.add_source(tag, list(d)), len(d)
         lut = dict_state.remaps.get(rk)
-        if lut is None:
-            lut = gd.add_source(tag, list(src.dicts.get(tag, [])))
-            dict_state.remaps[rk] = lut
-        return lut
+        if lut is not None:
+            return lut, 0
+        lut = dict_state.remaps[rk] = gd.add_source(tag, list(d))
+        return lut, len(d)
 
 
 def _dedup_components(spans: list) -> list[list[int]]:
@@ -1456,8 +1477,12 @@ def _gather_rows(
     filter, the interval tests, column reads, remap), ``concat_ms``,
     ``dedup_ms`` (the component dedups: 0 when none ran) and ``take_ms``
     (the ``[keep]`` takes, skipped when no row was dropped, and the
-    narrow-dtype scan) — and ``proven_unique_share``, the percent of the
-    selected rows that skipped the dedup.
+    narrow-dtype scan) — ``proven_unique_share``, the percent of the
+    selected rows that skipped the dedup, and, of ``select_ms``, the
+    remap tables: ``lut_ms`` (time inside ``_source_lut``) and
+    ``lut_entries`` (dictionary entries walked to build tables that
+    ``dict_state.remaps`` did not hold; ``source_lut_entries`` counts the
+    same entries on /metrics).
 
     ``device_decode`` (ROADMAP item 3, ``BYDB_DEVICE_DECODE``): the
     gathered snapshot keeps tag columns in the COMPRESSED ship form —
@@ -1478,6 +1503,7 @@ def _gather_rows(
     lut_l: dict[str, list] = {t: [] for t in tags_code}
     ord_l: list = []
     f_l: dict[str, list] = {f: [] for f in fields}
+    lut_s, lut_entries = 0.0, 0
     t_select0 = _time.perf_counter()
     with tracer.annotate("gather.select"):
         selected = []  # (source, its rows in range, how many)
@@ -1517,7 +1543,10 @@ def _gather_rows(
                     else:
                         tc_l[t].append(np.full(nsel, absent, dtype=np.int32))
                 else:
-                    lut = _source_lut(src, t, gd, dict_state)
+                    t_lut0 = _time.perf_counter()
+                    lut, walked = _source_lut(src, t, gd, dict_state)
+                    lut_s += _time.perf_counter() - t_lut0
+                    lut_entries += walked
                     codes = col[rng]
                     if device_decode:
                         if lut.size:
@@ -1541,6 +1570,12 @@ def _gather_rows(
                     f_l[f].append(col[rng])
         del selected  # the masks
     select_s = _time.perf_counter() - t_select0
+    obs_metrics.global_meter().counter_add(
+        "source_lut_entries", float(lut_entries)
+    )
+    if tags_out is not None:
+        tags_out["lut_ms"] = round(lut_s * 1000, 3)
+        tags_out["lut_entries"] = lut_entries
 
     if not ts_l:
         if tags_out is not None:

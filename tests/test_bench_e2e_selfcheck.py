@@ -76,16 +76,23 @@ ADOPTED = _adopt()
 
 
 def test_the_fast_files_were_adopted():
-    assert {"test_selfcheck.py", "test_control.py", "test_control_ep9k.py"} <= set(ADOPTED)
+    assert {
+        "test_selfcheck.py", "test_control.py", "test_control_ep9k.py", "test_control_ep400k.py",
+    } <= set(ADOPTED)
     assert not SLOW & set(ADOPTED)
     assert any(k.startswith("test_selfcheck__test_selfcheck") for k in globals())
 
 
 # -- metric files a program PR added as data alone ------------------------------------
 
-def _reduce_tree(tags: dict) -> dict:
+def _bench_json(*parts: str):
+    with open(os.path.join(CHECKOUT, *parts)) as f:
+        return json.load(f)
+
+
+def _reduce_tree(tags: dict, gather: dict | None = None) -> dict:
     return {"name": "measure-query", "children": [{"name": "execute", "children": [
-        {"name": "gather", "tags": {"rows": 3240000}},
+        {"name": "gather", "tags": dict({"rows": 3240000}, **(gather or {}))},
         {"name": "reduce", "tags": tags, "children": [{"name": "decode", "tags": {}}]},
     ]}]}
 
@@ -106,8 +113,86 @@ def test_skipped_chunks_per_query_reads_the_reduce_span(tags, want):
     and where the program has no such tag it returns nothing, so the
     line leaves the metric out and does not raise."""
     readers = _load(os.path.join(CHECKOUT, "benchmarks", "e2e", "readers.py"), "bench_e2e_readers")
-    with open(os.path.join(CHECKOUT, "benchmarks", "e2e", "metrics", "skipped_chunks_per_query.json")) as f:
-        metric = json.load(f)
+    metric = _bench_json("benchmarks", "e2e", "metrics", "skipped_chunks_per_query.json")
     assert metric["reader"] == {"kind": "span_tag", "span": "reduce", "tag": "chunks_skipped"}
     rec = {"queries": [{"served": "scan", "tree": _reduce_tree(tags)} for _ in range(3)]}
     assert readers.read(metric, rec) == want
+
+
+# ISSUE 33: four files for the tags `ep400k.topn-7d` is read by; name ->
+# (the reader the file must hold, [(case, {span: tags}, what it reads)])
+SPAN_TAG_FILES = {
+    "gather_lut_ms": (
+        {"kind": "span_tag", "span": "gather", "tag": "lut_ms"},
+        [("tagged", {"gather": {"select_ms": 2266.1, "lut_ms": 2235.4}}, 2235.4),
+         ("no-tag", {"gather": {"select_ms": 2266.1}}, None)],
+    ),
+    "lut_entries_per_query": (
+        {"kind": "span_tag", "span": "gather", "tag": "lut_entries"},
+        # 28 sources x (400,000 names + 8 regions); 0 under the cap
+        [("tagged", {"gather": {"lut_entries": 28 * 400008, "dict_reset": True}}, 11200224.0),
+         ("under-the-cap", {"gather": {"lut_entries": 0, "dict_reset": False}}, 0.0),
+         ("no-tag", {}, None)],
+    ),
+    "partials_mb_per_query": (
+        {"kind": "span_tag", "span": "reduce", "tag": "partials_bytes", "scale": 1e-06},
+        # the 4-bucket x G = 400,000 x 16 B
+        [("tagged", {"reduce": {"partials_bytes": 4 * 400000 * 16, "get_ms": 264.0}}, 25.6),
+         ("no-tag", {"reduce": {"get_ms": 264.0}}, None)],
+    ),
+    "absorb_ms": (
+        {"kind": "span_tag", "span": "reduce", "tag": "absorb_ms"},
+        [("tagged", {"reduce": {"absorb_ms": 12.532, "host_ms": 139.172}}, 12.532),
+         ("no-tag", {"reduce": {"host_ms": 139.172}}, None)],
+    ),
+}
+
+
+@pytest.mark.parametrize(
+    "name, tags, want",
+    [
+        pytest.param(name, tags, want, id=f"{name}-{case}")
+        for name, (_, cases) in SPAN_TAG_FILES.items()
+        for case, tags, want in cases
+    ],
+)
+def test_a_metric_file_added_as_data_reads_its_span_tag(name, tags, want):
+    """Each file is data for the `span_tag` reader that is there; where
+    the program has no such tag (the parent of ISSUE 33) it returns
+    nothing and does not raise, and BENCHMARK.json's entry agrees with
+    the file and names no `workloads`: every cell reports it."""
+    readers = _load(os.path.join(CHECKOUT, "benchmarks", "e2e", "readers.py"), "bench_e2e_readers")
+    metric = _bench_json("benchmarks", "e2e", "metrics", name + ".json")
+    assert metric["reader"] == SPAN_TAG_FILES[name][0]
+    assert "cells" not in metric and metric["better"] == "lower"
+    (entry,) = [m for m in _bench_json("BENCHMARK.json")["per_layer"] if m["name"] == name]
+    assert entry == {k: metric[k] for k in ("name", "unit", "better", "source", "layer", "moves")}
+    assert entry["moves"] == "query_p50_ms"
+    tree = _reduce_tree(tags.get("reduce", {}), tags.get("gather"))
+    rec = {"queries": [{"served": "scan", "tree": tree} for _ in range(3)]}
+    got = readers.read(metric, rec)
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def test_ep400k_is_a_cell_at_issue_33s_size():
+    """The deployment as ISSUE 33 names it: one configuration, one cell
+    on one chip, `ep9k`'s guarantees word for word, and a size over
+    `BYDB_MAX_PERSISTENT_GROUPS`' default, one day a message."""
+    bench = _bench_json("BENCHMARK.json")
+    (entry,) = [c for c in bench["configs"] if c["name"] == "ep400k"]
+    (cell,) = [w for w in bench["workloads"] if w["config"] == "ep400k"]
+    assert (cell["name"], cell["traffic"], cell["chips"]) == ("ep400k.topn-7d", "topn-7d", 1)
+    assert all(w["chips"] == 1 for w in bench["workloads"])
+    cfg = _bench_json(entry["file"])
+    assert cfg["guarantees"] == _bench_json("benchmarks", "e2e", "configs", "ep9k.json")["guarantees"]
+    assert cfg["source"] == entry["source"] and len(entry["source"]) <= 200
+    assert sorted(cfg["reduced"]) == sorted(entry["reduced"]) == ["buckets", "measures", "series"]
+    assert "HIDES" in cfg["reduced"]["series"]
+    data = cfg["data"]
+    assert data["series"] == 400000 > 1 << 18
+    assert (data["batch_rows"], data["snapshot_every_rows"], data["buckets"]) == (400000, 800000, 8)
+    assert cfg["schema"]["shards"] == 4 and cfg["schema"]["segment_interval_days"] == 15
+    mix = _bench_json("benchmarks", "e2e", "traffic", "topn-7d.json")
+    panel = mix["panels"]["topn"]
+    assert mix["clients"] == 1 and panel["top"] == 10
+    assert panel["range_ms"] == 7 * data["bucket_ms"]
