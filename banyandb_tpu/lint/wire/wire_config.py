@@ -397,6 +397,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "blocks_skipped": frozenset({"reason"}),
     "compile_cache_enabled": frozenset(),
     "decode_ship_bytes": frozenset({"form"}),
+    "dict_state_kept": frozenset(),
     "dict_state_resets": frozenset(),
     "failover_attempts": frozenset(),
     "jit_compile_seconds": frozenset(),

@@ -319,11 +319,20 @@ class DictState:
     concurrently on server threads.
 
     Growth bound: group cardinality is the product of all-time dict
-    sizes, so tag churn under retention would inflate kernels without
-    bound.  reset() discards the state (new token orphans old cache
-    entries, which simply LRU out) — compute_partials calls it when the
-    group space exceeds BYDB_MAX_PERSISTENT_GROUPS, rebounding
-    cardinality to the live data on the next gather.
+    sizes, so tag churn under retention (values no live part holds any
+    more) would inflate kernels without bound.  reset() discards the
+    state (new token orphans old cache entries, which simply LRU out),
+    rebounding cardinality to the live data on the next gather.
+    BYDB_MAX_PERSISTENT_GROUPS is the group space above which
+    compute_partials CHECKS the state, and it resets it for the dead
+    values it holds, not for its size: a gather that leaves the group
+    space over the bound counts, per group-by tag, the distinct global
+    codes its sources' remap tables reference (note_live) and
+    `live_seen` keeps the largest count since the last reset; the state
+    is reset when its group space passes the bound AND passes
+    _DEAD_FACTOR times the product of `live_seen`.  A store whose live
+    group space is over the bound therefore keeps its dictionaries and
+    tables, and G over the bound is never more than _DEAD_FACTOR x live.
     """
 
     def __init__(self):
@@ -344,6 +353,12 @@ class DictState:
         # without a per-query Python sort over 100k groups)
         self.values_cache: dict[str, list] = {}
         self.rank_cache: dict[str, np.ndarray] = {}
+        # tag -> the most distinct codes one gather's sources referenced
+        # since this reset (the largest, not the latest: a narrow query
+        # between two wide ones must not make the state look bloated),
+        # and tag -> (remaps keys, count) of the last count taken
+        self.live_seen: dict[str, int] = {}
+        self.live_memo: dict[str, tuple[tuple, int]] = {}
 
     def reset(self):
         with self.lock:
@@ -359,6 +374,27 @@ class DictState:
             cached = self.dicts.values(tag)
             self.values_cache[tag] = cached
         return cached
+
+    def note_live(self, tables: dict[str, list]) -> None:
+        """Record what one gather found live; caller holds self.lock and
+        has checked that the gather's GlobalDicts is still self.dicts.
+
+        `tables`: tag -> [(remaps key or None, local -> global LUT)] of
+        the gather's sources.  The count is one boolean mark array of
+        the dictionary's size and one vectorised pass a source; a query
+        over the same parts as the last takes it from `live_memo`."""
+        for tag, used in tables.items():
+            keys = tuple(rk for rk, _ in used)
+            memo = self.live_memo.get(tag)
+            if memo is not None and None not in keys and memo[0] == keys:
+                live = memo[1]
+            else:
+                mark = np.zeros(len(self.dicts.maps[tag]), dtype=bool)
+                for _, lut in used:
+                    mark[lut] = True
+                live = int(np.count_nonzero(mark))
+                self.live_memo[tag] = (keys, live)
+            self.live_seen[tag] = max(self.live_seen.get(tag, 0), live)
 
     def rank_lut(self, tag: str, values: list) -> np.ndarray:
         """code -> bytes-lexicographic rank over at least `values`.
@@ -393,6 +429,22 @@ def _build_rank_lut(values: list) -> np.ndarray:
 
 
 _MAX_PERSISTENT_GROUPS = env_int("BYDB_MAX_PERSISTENT_GROUPS", 1 << 18)
+# Over the bound a DictState is reset when its group space passes this
+# many times what its queries found live: the reset then halves G at
+# least and the next doubling pays for it (a doubling array's
+# amortisation).  A constant, not a flag.
+_DEAD_FACTOR = 2
+
+
+def _group_space(dict_state: "DictState", group_tags) -> tuple[int, int]:
+    """-> (product of the group-by tags' dictionary sizes, product of
+    their `live_seen`, 0 while a tag has no reading); caller holds
+    dict_state.lock."""
+    size = live = 1
+    for t in group_tags:
+        size *= max(len(dict_state.dicts.maps.get(t, ())), 1)
+        live *= dict_state.live_seen.get(t, 0)
+    return size, live
 
 
 def _tag_value_bytes(v) -> bytes:
@@ -675,18 +727,22 @@ def compute_partials(
     # cap-triggered reset swaps dict_state.dicts/token together, and all
     # cache writes below guard on `dict_state.dicts is gd` so an in-flight
     # query can never poison the post-reset caches with old codes.
-    dict_reset = False
+    dict_reset = dict_over_bound = False
     if dict_state is None:
         gd = GlobalDicts(sorted(tags_code))
         token = None
     else:
         with dict_state.lock:
-            # Growth bound: reset bloated state (tag churn under
-            # retention) so cardinality re-bounds to live data.
-            prod = 1
-            for t in group_tags:
-                prod *= max(len(dict_state.dicts.maps.get(t, ())), 1)
-            if prod > _MAX_PERSISTENT_GROUPS:
+            # Growth bound (DictState): over the bound, reset a state
+            # more than half of whose group space is dead for every
+            # query it has served (tag churn under retention), so
+            # cardinality re-bounds to live data; a tag with no reading
+            # since the last reset counts as dead.  A state whose LIVE
+            # group space is over the bound is kept: resetting it would
+            # only rebuild the same dictionaries and tables.
+            size, live = _group_space(dict_state, group_tags)
+            dict_over_bound = size > _MAX_PERSISTENT_GROUPS
+            if dict_over_bound and size > _DEAD_FACTOR * live:
                 dict_state._reset_locked()
                 dict_reset = True
             gd = dict_state.dicts
@@ -697,6 +753,8 @@ def compute_partials(
         # this query rebuilds every dictionary and remap LUT from nothing,
         # and its new token orphans what the caches hold under the old one
         obs_metrics.global_meter().counter_add("dict_state_resets")
+    elif dict_over_bound:
+        obs_metrics.global_meter().counter_add("dict_state_kept")
 
     # the compressed-ship flag is read ONCE per query and pinned into the
     # gather cache key: the two ship forms produce differently-shaped
@@ -720,8 +778,10 @@ def compute_partials(
             device_decode,
         )
 
-    # span tags of the gather that runs (none on a serving-cache hit)
+    # span tags of the gather that runs (none on a serving-cache hit),
+    # and the remap tables it used for the group-by tags
     gather_tags: dict = {}
+    gather_luts: dict = {t: [] for t in group_tags}
 
     def _do_gather():
         return _gather_rows(
@@ -734,6 +794,7 @@ def compute_partials(
             dict_state=dict_state,
             device_decode=device_decode,
             tags_out=gather_tags,
+            luts_out=gather_luts,
         )
 
     # opened BEFORE the work it covers; no child spans under it (its self
@@ -747,6 +808,18 @@ def compute_partials(
         chunks_np, gather_cache = global_cache().fetch(gather_key, _do_gather)
     else:
         chunks_np, gather_cache = _do_gather(), "off"
+    dict_live_share = None
+    if dict_state is not None:
+        with dict_state.lock:
+            # judged from the sizes the gather LEFT (the first query of a
+            # process and the one after a reset fill an empty state);
+            # under the bound a few len() and a compare, no count taken
+            size, live = _group_space(dict_state, group_tags)
+            if dict_state.dicts is gd and size > _MAX_PERSISTENT_GROUPS:
+                if gather_cache != "hit":  # a hit ran no gather: no reading
+                    dict_state.note_live(gather_luts)
+                    live = _group_space(dict_state, group_tags)[1]
+                dict_live_share = round(100.0 * live / size, 3)
     gather_ms = (_time.perf_counter() - t_gather0) * 1000
     _H_GATHER.observe(gather_ms)
     n = chunks_np["ts"].shape[0]
@@ -754,7 +827,9 @@ def compute_partials(
         g.finish()
         g.tag("rows", int(n)).tag("sources", len(sources)).tag(
             "serving_cache", gather_cache
-        ).tag("dict_reset", dict_reset)
+        ).tag("dict_reset", dict_reset).tag("dict_over_bound", dict_over_bound)
+        if dict_live_share is not None:
+            g.tag("dict_live_share", dict_live_share)
         for key, value in gather_tags.items():
             g.tag(key, value)
     # epoch = global min ts keeps chunk-relative int32 offsets
@@ -1390,16 +1465,17 @@ def _host_float_partials(
 
 def _source_lut(
     src: ColumnData, tag: str, gd: GlobalDicts, dict_state: Optional[DictState]
-) -> tuple[np.ndarray, int]:
+) -> tuple[np.ndarray, int, Optional[tuple]]:
     """-> (local-code -> global-code LUT, cached by immutable part
     identity; the dictionary entries ``add_source`` walked to build it,
-    0 when it came from ``dict_state.remaps``)."""
+    0 when it came from ``dict_state.remaps``; its key there, None for a
+    table ``remaps`` does not hold)."""
     d = src.dicts.get(tag, ())
     if dict_state is None:
-        return gd.add_source(tag, list(d)), len(d)
+        return gd.add_source(tag, list(d)), len(d), None
     if src.cache_key is None:
         with dict_state.lock:
-            return gd.add_source(tag, list(d)), len(d)
+            return gd.add_source(tag, list(d)), len(d), None
     # (source identity, tag, dict length): part dicts are immutable, but
     # memtable snapshots reuse one generation id while their dict grows
     # append-only — the length pins WHICH prefix this LUT covers, so a
@@ -1409,12 +1485,12 @@ def _source_lut(
         if dict_state.dicts is not gd:
             # state was reset mid-query: codes from the old gd must not
             # enter the new remap cache
-            return gd.add_source(tag, list(d)), len(d)
+            return gd.add_source(tag, list(d)), len(d), None
         lut = dict_state.remaps.get(rk)
         if lut is not None:
-            return lut, 0
+            return lut, 0, rk
         lut = dict_state.remaps[rk] = gd.add_source(tag, list(d))
-        return lut, len(d)
+        return lut, len(d), rk
 
 
 def _dedup_components(spans: list) -> list[list[int]]:
@@ -1462,6 +1538,7 @@ def _gather_rows(
     dict_state: Optional[DictState] = None,
     device_decode: bool = False,
     tags_out: Optional[dict] = None,
+    luts_out: Optional[dict] = None,
 ) -> dict:
     """Concatenate sources with row-exact time filtering, global-code remap
     and version dedup (block pruning upstream is only block-granular).
@@ -1484,6 +1561,10 @@ def _gather_rows(
     ``dict_state.remaps`` did not hold; ``source_lut_entries`` counts the
     same entries on /metrics).
 
+    ``luts_out`` (dict or None): for each tag it names, receives the
+    ``(dict_state.remaps key or None, table)`` of every source's remap
+    table, the input of ``DictState.note_live``.
+
     ``device_decode`` (ROADMAP item 3, ``BYDB_DEVICE_DECODE``): the
     gathered snapshot keeps tag columns in the COMPRESSED ship form —
     per-row narrow LOCAL codes (``tags_enc``), the per-source
@@ -1504,6 +1585,8 @@ def _gather_rows(
     ord_l: list = []
     f_l: dict[str, list] = {f: [] for f in fields}
     lut_s, lut_entries = 0.0, 0
+    if luts_out is None:
+        luts_out = {}
     t_select0 = _time.perf_counter()
     with tracer.annotate("gather.select"):
         selected = []  # (source, its rows in range, how many)
@@ -1534,19 +1617,24 @@ def _gather_rows(
                             absent = gd.absent_code(t)
                     else:
                         absent = gd.absent_code(t)
+                    absent_lut = np.asarray([absent], dtype=np.int32)
                     if device_decode:
                         # compressed form: a one-entry LUT row and local
                         # code 0 everywhere — the device remap lands the
                         # same global absent code the dense path bakes in
                         tc_l[t].append(np.zeros(nsel, dtype=np.int8))
-                        lut_l[t].append(np.asarray([absent], dtype=np.int32))
+                        lut_l[t].append(absent_lut)
                     else:
                         tc_l[t].append(np.full(nsel, absent, dtype=np.int32))
+                    if t in luts_out:
+                        luts_out[t].append((None, absent_lut))
                 else:
                     t_lut0 = _time.perf_counter()
-                    lut, walked = _source_lut(src, t, gd, dict_state)
+                    lut, walked, rk = _source_lut(src, t, gd, dict_state)
                     lut_s += _time.perf_counter() - t_lut0
                     lut_entries += walked
+                    if t in luts_out:
+                        luts_out[t].append((rk, lut))
                     codes = col[rng]
                     if device_decode:
                         if lut.size:

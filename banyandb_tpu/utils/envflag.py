@@ -69,7 +69,10 @@ FLAGS: dict[str, str] = {
     "BYDB_DEVICE_DECODE": "bool: decode encoded blocks on-device",
     "BYDB_FAULTS": "str: fault-injection schedule spec (cluster/faults)",
     "BYDB_FUSED_MAX_MB": "int: device-memory budget of one fused dispatch",
-    "BYDB_MAX_PERSISTENT_GROUPS": "int: persistent group-by cardinality cap",
+    "BYDB_MAX_PERSISTENT_GROUPS": (
+        "int: group space above which a persistent group-by state is "
+        "checked for dead values"
+    ),
     "BYDB_PARTIALS_FRAME_V1": "bool: columnar v1 partials wire frame",
     "BYDB_PIPELINE": "bool: decode/compute pipelining",
     "BYDB_PLANNER": "bool: cost-based adaptive planner",

@@ -4,10 +4,12 @@ benchmarks/e2e/configs/ep400k.json is one data node of the 10M-series
 estate BASELINE.json names: 400,000 series at day step on 4 shards, one
 day a message, asked for the top endpoints of the last 7 days.  Its
 group space lies over `BYDB_MAX_PERSISTENT_GROUPS` (262,144), where
-`measure_exec.compute_partials` resets the persistent `DictState` before
-every query but the first and every per-source remap table is built
-again (ROADMAP S13), and over `SORT_GROUPS_THRESHOLD` (65,536), so the
-group-by is `sort`.  Here both bounds are monkeypatched DOWN (1,024 and
+`measure_exec.compute_partials` checks the persistent `DictState` before
+every query and resets it only when more than half of its group space is
+dead for every query it has served (ISSUE 34; until then it reset it
+before every query but the first and every per-source remap table was
+built again, ROADMAP S13), and over `SORT_GROUPS_THRESHOLD` (65,536), so
+the group-by is `sort`.  Here both bounds are monkeypatched DOWN (1,024 and
 2,048) and an in-process engine holds 3,000 series x 8 daily buckets on
 4 shards in 15-day segments, so the small size is on the big size's side
 of both.  It is loaded as the benchmark loads it: through the columnar
@@ -191,26 +193,31 @@ def _counted(name: str) -> float:
 
 @pytest.mark.parametrize("region, b0, nb", DRAWS)
 def test_top10_is_the_references_query_after_query(store, region, b0, nb):
-    """The reset regime: every query but the store's first finds the
-    group space over the bound, resets the `DictState` and walks every
-    entry of every source's two dictionaries again; a part holds one
-    shard's rows of one day and its message's whole dictionary, so the
-    entries are sources x (names + regions).  The answer is the
-    reference's every time."""
+    """The kept state: every query finds the group space over the bound,
+    and all of it live (a part holds one shard's rows of one day and its
+    message's whole dictionary, so every source references every name):
+    the `DictState` is kept, every table comes from `dict_state.remaps`
+    and no dictionary entry is walked.  The answer is the reference's
+    every time."""
     eng, hits = store
-    serve(eng, ql_of(region, b0, nb, off=90))  # whichever test runs first: the state is full
-    resets, walked = _counted("dict_state_resets"), _counted("source_lut_entries")
-    got, spans = serve(eng, ql_of(region, b0, nb))
+    # whichever test runs first, the state is full; a range of its own a
+    # draw, as in the cell: the kept token would serve a repeat from the cache
+    serve(eng, ql_of(region, b0, nb, off=90 + region))
+    resets, kept = _counted("dict_state_resets"), _counted("dict_state_kept")
+    walked = _counted("source_lut_entries")
+    got, spans = serve(eng, ql_of(region, b0, nb, off=1 + region))
     compare(got, reference(hits, region, b0, nb), top=10)
     gather, reduce_ = spans["gather"], spans["reduce"]
     assert reduce_["groups"] == SERIES and reduce_["path"] == "fused"
     assert gather["rows"] == nb * SERIES and gather["sources"] == nb * SHARDS
-    assert gather["dict_reset"] is True and gather["serving_cache"] != "hit"
-    assert gather["lut_entries"] == gather["sources"] * (SERIES + REGIONS)
-    assert 0 < gather["lut_ms"] <= gather["select_ms"] <= gather["duration_ms"]
+    assert gather["dict_reset"] is False and gather["dict_over_bound"] is True
+    assert gather["serving_cache"] != "hit"
+    assert gather["lut_entries"] == 0 and gather["dict_live_share"] == 100.0
+    assert 0 <= gather["lut_ms"] <= gather["select_ms"] <= gather["duration_ms"]
     # /metrics counts what the span says
-    assert _counted("dict_state_resets") - resets == 1
-    assert _counted("source_lut_entries") - walked == gather["lut_entries"]
+    assert _counted("dict_state_resets") - resets == 0
+    assert _counted("dict_state_kept") - kept == 1
+    assert _counted("source_lut_entries") - walked == 0
 
 
 @pytest.mark.parametrize("region, b0, nb", DRAWS[:2])
@@ -239,26 +246,80 @@ def test_the_method_taken_is_sort(store):
 
 
 def test_under_the_bound_the_second_query_builds_no_table(store, monkeypatch):
-    """Under `BYDB_MAX_PERSISTENT_GROUPS` the state the last reset left is
-    kept: the first query over new sources builds their tables, the
-    second takes them from `dict_state.remaps` and walks nothing.  The
-    reset must not change an answer: same groups, same order, same
-    values, text for text."""
+    """Over `BYDB_MAX_PERSISTENT_GROUPS` as under it, a state whose group
+    space is live is kept: the first query over new sources builds their
+    tables, the next takes them from `dict_state.remaps` and walks
+    nothing.  Under the bound no count of live codes is taken and no
+    `dict_live_share` tagged.  The bound must not change an answer: same
+    groups, same order, same values, text for text."""
     eng, _ = store
     region, b0, nb = DRAWS[0]
     tails = ["TOP 10 BY hits", f"LIMIT {SERIES}"]
+    counts = []
+    note_live = measure_exec.DictState.note_live
+
+    def counting(self, tables):
+        counts.append(1)
+        note_live(self, tables)
+
+    monkeypatch.setattr(measure_exec.DictState, "note_live", counting)
+    eng._dict_state("g", "m").reset()
     over = [serve(eng, ql_of(region, b0, nb, tail, off=21 + i)) for i, tail in enumerate(tails)]
-    assert all(spans["gather"]["dict_reset"] is True for _, spans in over)
-    assert all(
-        spans["gather"]["lut_entries"] == nb * SHARDS * (SERIES + REGIONS) for _, spans in over
-    )
+    tags = [spans["gather"] for _, spans in over]
+    assert [t["lut_entries"] for t in tags] == [nb * SHARDS * (SERIES + REGIONS), 0]
+    # the first found an empty state and left it over the bound
+    assert [t["dict_over_bound"] for t in tags] == [False, True]
+    assert [t["dict_live_share"] for t in tags] == [100.0, 100.0] and len(counts) == 2
     monkeypatch.setattr(measure_exec, "_MAX_PERSISTENT_GROUPS", 1 << 18)
     under = [serve(eng, ql_of(region, b0, nb, tail, off=31 + i)) for i, tail in enumerate(tails)]
-    assert all(spans["gather"]["dict_reset"] is False for _, spans in under)
+    assert all(spans["gather"]["dict_reset"] is False for _, spans in over + under)
     assert all(spans["gather"]["serving_cache"] != "hit" for _, spans in over + under)
     assert [spans["gather"]["lut_entries"] for _, spans in under] == [0, 0]
+    assert all(spans["gather"]["dict_over_bound"] is False for _, spans in under)
+    assert all("dict_live_share" not in spans["gather"] for _, spans in under)
+    assert len(counts) == 2  # no count taken under the bound
     for (got_over, _), (got_under, _) in zip(over, under):
         assert list(got_over.items()) == list(got_under.items())
+
+
+def test_a_state_more_than_half_dead_is_reset_once(store):
+    """Churn: values that no source holds any more pile up in the
+    append-only dictionary.  At twice the live size the state is still
+    kept (and the answer right with G twice the live size); one value
+    more and the next query over the bound resets it, exactly once: the
+    query after it keeps its state, walks nothing, and G is the live
+    size again."""
+    eng, hits = store
+    region, b0, nb = DRAWS[1]
+    want = reference(hits, region, b0, nb)
+    st = eng._dict_state("g", "m")
+    st.reset()
+    serve(eng, ql_of(region, b0, nb, off=41))
+    assert st.live_seen["svc"] == SERIES
+    token = st.token
+    with st.lock:
+        st.dicts.add_source("svc", [b"gone_%06d" % i for i in range(SERIES)])
+    resets = _counted("dict_state_resets")
+    got, spans = serve(eng, ql_of(region, b0, nb, off=42))
+    compare(got, want, top=10)
+    assert spans["reduce"]["groups"] == 2 * SERIES and st.token == token
+    assert spans["gather"]["dict_reset"] is False and spans["gather"]["dict_live_share"] == 50.0
+    with st.lock:
+        st.dicts.add_source("svc", [b"gone_too"])
+    got, spans = serve(eng, ql_of(region, b0, nb, off=43))
+    compare(got, want, top=10)
+    gather = spans["gather"]
+    assert gather["dict_reset"] is True and gather["dict_over_bound"] is True
+    assert gather["lut_entries"] == nb * SHARDS * (SERIES + REGIONS)
+    assert spans["reduce"]["groups"] == SERIES and st.token != token
+    assert gather["dict_live_share"] == 100.0
+    token = st.token
+    got, spans = serve(eng, ql_of(region, b0, nb, off=44))
+    compare(got, want, top=10)
+    gather = spans["gather"]
+    assert gather["dict_reset"] is False and gather["dict_over_bound"] is True
+    assert gather["lut_entries"] == 0 and spans["reduce"]["groups"] == SERIES
+    assert st.token == token and _counted("dict_state_resets") - resets == 1
 
 
 @pytest.mark.parametrize("scan_chunk, chunks, skipped", [(None, 1, 0), (8192, 3, 1), (4096, 6, 2)])

@@ -22,6 +22,7 @@ from banyandb_tpu.api import (
     TagSpec,
     TagType,
     TimeRange,
+    Top,
     WriteRequest,
 )
 from banyandb_tpu.models.measure import MeasureEngine
@@ -248,16 +249,186 @@ def test_concurrent_queries_with_dict_growth(engine):
     assert not errors, errors[0]
 
 
+def _resets() -> float:
+    from banyandb_tpu.obs import metrics as obs_metrics
+
+    counters = obs_metrics.global_meter().snapshot()["counters"]
+    return sum(v for k, v in counters.items() if k[0] == "dict_state_resets")
+
+
+def _flush_part(engine, names, at):
+    """One more flush: a point a name from T0 + `at` on, so the parts it
+    makes hold `names` alone in their dictionaries."""
+    engine.write(
+        WriteRequest(
+            "g",
+            "m",
+            tuple(
+                DataPointValue(
+                    ts_millis=T0 + at + i,
+                    tags={"svc": n, "region": "eu"},
+                    fields={"lat": float(i + 1)},
+                    version=1,
+                )
+                for i, n in enumerate(names)
+            ),
+        )
+    )
+    engine.flush()
+
+
+def _rows(r):
+    return list(zip(r.groups, r.values["count"], r.values["sum(lat)"]))
+
+
 def test_persistent_group_cap_resets_state(engine, monkeypatch):
+    """Over the bound a state is reset for the dead values it holds: 8
+    live svc values over a bound of 2 stay, 9 dead ones beside them (more
+    than half of the group space) go, in one reset."""
     from banyandb_tpu.query import measure_exec
 
+    monkeypatch.setattr(measure_exec, "_MAX_PERSISTENT_GROUPS", 2)
     st = engine._dict_state("g", "m")
     engine.query(_req())
-    token_before = st.token
-    monkeypatch.setattr(measure_exec, "_MAX_PERSISTENT_GROUPS", 2)
-    r = engine.query(_req())  # 8 svc values > cap -> reset + fresh build
-    assert st.token != token_before
+    token_before, resets = st.token, _resets()
+    engine.query(_req(time_range=TimeRange(T0, T0 + 9_000_000)))
+    assert st.token == token_before and st.live_seen["svc"] == 8
+    with st.lock:  # values no live part holds any more
+        st.dicts.add_source("svc", [b"gone%d" % i for i in range(9)])
+    r = engine.query(_req(time_range=TimeRange(T0, T0 + 8_000_000)))
+    assert st.token != token_before and _resets() - resets == 1
+    assert len(st.dicts.maps["svc"]) == 8
     assert sum(r.values["count"]) == 4000  # results still correct
+
+
+def test_a_narrow_query_between_wide_ones_resets_nothing(engine, monkeypatch):
+    """`live_seen` keeps the largest reading since the last reset, not
+    the latest: a query over one part (8 of 48 values) must not make the
+    state look bloated and set off a reset the next wide query undoes."""
+    from banyandb_tpu.query import measure_exec
+
+    monkeypatch.setattr(measure_exec, "_MAX_PERSISTENT_GROUPS", 16)
+    _flush_part(engine, [f"w{i}" for i in range(40)], at=100_000)
+    st = engine._dict_state("g", "m")
+    wide = engine.query(_req())
+    token, resets = st.token, _resets()
+    assert st.live_seen["svc"] == 48
+    narrow = engine.query(_req(time_range=TimeRange(T0, T0 + 50_000)))
+    assert len(narrow.groups) == 8 and st.live_memo["svc"][1] == 8
+    assert st.live_seen["svc"] == 48
+    again = engine.query(_req(time_range=TimeRange(T0, T0 + 9_000_000)))
+    assert st.token == token and _resets() == resets
+    assert _rows(again) == _rows(wide)
+
+
+def test_answers_do_not_depend_on_the_states_history(engine, monkeypatch):
+    """Global codes are first-seen order, so a kept state's differ from a
+    fresh one's; the rows that come back do not: the same query against
+    a fresh state and against one that first served a wider and a
+    differently-ordered set of sources."""
+    from banyandb_tpu.query import measure_exec
+
+    monkeypatch.setattr(measure_exec, "_MAX_PERSISTENT_GROUPS", 16)
+    _flush_part(engine, [f"w{i}" for i in reversed(range(40))], at=100_000)
+    _flush_part(engine, [f"x{i}" for i in range(30)], at=200_000)
+    st = engine._dict_state("g", "m")
+    two_parts = TimeRange(T0, T0 + 150_000)
+    top5 = _req(time_range=two_parts, top=Top(5, "lat"))
+    fresh = engine.query(_req(time_range=two_parts))
+    fresh_top = engine.query(top5)
+    fresh_codes = dict(st.dicts.maps["svc"])
+    st.reset()
+    engine.query(_req(time_range=TimeRange(T0 + 200_000, T0 + 300_000)))
+    engine.query(_req(time_range=TimeRange(T0 + 100_000, T0 + 300_000)))
+    engine.query(_req())  # the wider set: 78 values
+    token = st.token
+    kept = engine.query(_req(time_range=two_parts))
+    kept_top = engine.query(top5)
+    assert st.token == token and len(st.dicts.maps["svc"]) == 78
+    assert {v: st.dicts.maps["svc"][v] for v in fresh_codes} != fresh_codes
+    assert len(fresh.groups) == 48 and _rows(kept) == _rows(fresh)
+    assert len(fresh_top.groups) == 5 and _rows(kept_top) == _rows(fresh_top)
+
+
+@pytest.mark.parametrize("when", ["before_gather", "after_gather"])
+def test_a_query_across_a_reset_leaves_the_new_generation_clean(
+    engine, monkeypatch, when
+):
+    """A query holding a pre-reset `gd` writes nothing into the new
+    generation: no remap table, no `live_seen`, no memo, though the
+    group space it gathered is over the bound."""
+    from banyandb_tpu.query import measure_exec
+
+    monkeypatch.setattr(measure_exec, "_MAX_PERSISTENT_GROUPS", 2)
+    st = engine._dict_state("g", "m")
+    gather = measure_exec._gather_rows
+
+    def racing(*args, **kw):
+        if when == "before_gather":
+            st.reset()  # another query's reset, while this one is in flight
+        out = gather(*args, **kw)
+        if when == "after_gather":
+            st.reset()
+        return out
+
+    monkeypatch.setattr(measure_exec, "_gather_rows", racing)
+    r = engine.query(_req())
+    assert sum(r.values["count"]) == 4000 and len(r.groups) == 8
+    assert st.live_seen == {} and st.live_memo == {} and st.remaps == {}
+    assert all(len(m) == 0 for m in st.dicts.maps.values())
+
+
+def test_concurrent_queries_over_the_bound_while_values_churn(engine, monkeypatch):
+    """More readers than cores share one DictState over the bound while
+    dead values pile into it and set off resets under their feet: every
+    answer is right, nothing raises, and once the churn stops the state
+    is the live size again."""
+    import sys
+    import threading
+
+    from banyandb_tpu.query import measure_exec
+
+    monkeypatch.setattr(measure_exec, "_MAX_PERSISTENT_GROUPS", 2)
+    st = engine._dict_state("g", "m")
+    errors: list[Exception] = []
+    stop = threading.Event()
+
+    def reader(k: int):
+        try:
+            n = 0
+            while not stop.is_set():
+                n += 1
+                r = engine.query(
+                    _req(time_range=TimeRange(T0, T0 + 5_000_000 + 16 * n + k))
+                )
+                assert sum(r.values["count"]) == 4000
+                assert sorted(g[0] for g in r.groups) == [f"s{i}" for i in range(8)]
+        except Exception as e:  # propagated to the main thread below
+            errors.append(e)
+
+    threads = [threading.Thread(target=reader, args=(k,)) for k in range(12)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for round_ in range(10):
+            with st.lock:
+                st.dicts.ensure("svc")
+                st.dicts.add_source(
+                    "svc", [b"gone%d_%d" % (round_, i) for i in range(20)]
+                )
+            stop.wait(0.05)
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=60)
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[0]
+    engine.query(_req())
+    engine.query(_req(time_range=TimeRange(T0, T0 + 4_999_999)))
+    assert len(st.dicts.maps["svc"]) == 8 and st.live_seen["svc"] == 8
 
 
 def test_dict_codes_stable_across_queries(engine):
