@@ -21,6 +21,21 @@ from banyandb_tpu.cluster.bus import LocalBus
 from banyandb_tpu.obs import metrics as obs_metrics
 
 _METHOD = "/banyandb.Bus/Call"
+# handlers a GrpcBusServer runs at once: its thread pool's size.  A
+# request that arrives while all of them run waits in the pool's queue,
+# where no span is open (the client sees the wait as wire time)
+_BUS_WORKERS = 8
+# the handler this thread is running: how many of its server's handlers
+# ran when it started (handler_busy)
+_HANDLER = threading.local()
+
+
+def handler_busy() -> int:
+    """Handlers of the bus server that were running when the handler on
+    THIS thread started, itself included (the ``qos`` span's
+    ``rpc_busy``); 0 on a thread that runs none (a LocalTransport call).
+    ``_BUS_WORKERS`` means the pool was full from then on."""
+    return getattr(_HANDLER, "busy", 0)
 
 
 def _observe_rpc(side: str, topic: str, t0: float) -> None:
@@ -159,10 +174,13 @@ class GrpcBusServer:
         import grpc
 
         self.bus = bus
+        self._busy = 0  # handlers running now, under _busy_lock
+        self._busy_lock = threading.Lock()
 
         def call_behavior(request: bytes, context) -> bytes:
             msg = json.loads(request)
             t0 = time.perf_counter()
+            _HANDLER.busy = self._enter_handler()
             try:
                 reply = self.bus.handle(msg["topic"], msg["envelope"])
                 return json.dumps({"ok": True, "reply": reply}).encode()
@@ -175,6 +193,8 @@ class GrpcBusServer:
                     }
                 ).encode()
             finally:
+                _HANDLER.busy = 0
+                self._leave_handler()
                 _observe_rpc("server", msg.get("topic", "?"), t0)
 
         handler = grpc.method_handlers_generic_handler(
@@ -200,27 +220,32 @@ class GrpcBusServer:
         wr = _pb.model_write_pb2
 
         def send_behavior(req_iter, context):
-            for req in req_iter:
-                try:
-                    reply = self.bus.handle(
-                        req.topic, json.loads(req.body or b"{}")
-                    )
-                    yield cl.SendResponse(
-                        message_id=req.message_id,
-                        body=json.dumps(reply).encode(),
-                        status=wr.STATUS_SUCCEED,
-                    )
-                except Exception as e:  # noqa: BLE001 - errors cross the wire
-                    shed = type(e).__name__ in _SHED_TYPES
-                    yield cl.SendResponse(
-                        message_id=req.message_id,
-                        error=f"{type(e).__name__}: {e}",
-                        status=(
-                            wr.STATUS_INTERNAL_ERROR
-                            if not shed
-                            else wr.STATUS_DISK_FULL
-                        ),
-                    )
+            # a stream holds its worker for as long as it is open
+            self._enter_handler()
+            try:
+                for req in req_iter:
+                    try:
+                        reply = self.bus.handle(
+                            req.topic, json.loads(req.body or b"{}")
+                        )
+                        yield cl.SendResponse(
+                            message_id=req.message_id,
+                            body=json.dumps(reply).encode(),
+                            status=wr.STATUS_SUCCEED,
+                        )
+                    except Exception as e:  # noqa: BLE001 - errors cross the wire
+                        shed = type(e).__name__ in _SHED_TYPES
+                        yield cl.SendResponse(
+                            message_id=req.message_id,
+                            error=f"{type(e).__name__}: {e}",
+                            status=(
+                                wr.STATUS_INTERNAL_ERROR
+                                if not shed
+                                else wr.STATUS_DISK_FULL
+                            ),
+                        )
+            finally:
+                self._leave_handler()
 
         def health_behavior(req, context):
             known = req.service_name in self.bus.topics() or not req.service_name
@@ -250,7 +275,7 @@ class GrpcBusServer:
         # caller-provided executor down — idle worker threads would
         # otherwise outlive every stopped server, a leak the bdsan
         # thread-parity check catches)
-        self._pool = futures.ThreadPoolExecutor(max_workers=8)
+        self._pool = futures.ThreadPoolExecutor(max_workers=_BUS_WORKERS)
         self._server = grpc.server(
             self._pool,
             options=[("grpc.max_receive_message_length", 64 * 1024 * 1024),
@@ -278,6 +303,26 @@ class GrpcBusServer:
         else:
             self.port = self._server.add_insecure_port(f"{host}:{port}")
         self.addr = f"{host}:{self.port}"
+
+    def _enter_handler(self) -> int:
+        """-> handlers running now, this one included.  One that takes
+        the pool's last worker counts in ``rpc_pool_full``: while it
+        runs, a new request waits for a worker."""
+        with self._busy_lock:
+            self._busy += 1
+            busy = self._busy
+        if busy >= _BUS_WORKERS:
+            obs_metrics.global_meter().counter_add("rpc_pool_full")
+        return busy
+
+    def _leave_handler(self) -> None:
+        with self._busy_lock:
+            self._busy -= 1
+
+    def handlers_busy(self) -> int:
+        """Handlers running now (/metrics ``rpc_handlers_busy``)."""
+        with self._busy_lock:
+            return self._busy
 
     def start(self) -> None:
         prespawn_pool(self._pool)

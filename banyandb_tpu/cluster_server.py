@@ -31,7 +31,7 @@ from banyandb_tpu.cluster.bus import LocalBus, Topic
 from banyandb_tpu.cluster.data_node import DataNode
 from banyandb_tpu.cluster.discovery import FileDiscovery
 from banyandb_tpu.cluster.liaison import Liaison
-from banyandb_tpu.cluster.rpc import GrpcBusServer, GrpcTransport
+from banyandb_tpu.cluster.rpc import GrpcBusServer, GrpcTransport, handler_busy
 
 
 class DataServer:
@@ -553,6 +553,8 @@ class LiaisonServer:
             with tracer.span("qos") as sp:
                 sp.tag("tenant", adm.tenant)
                 sp.tag("queued_ms", round(adm.queued_ms, 3))
+                sp.tag("inflight", adm.inflight)
+                sp.tag("rpc_busy", handler_busy())
             t0 = _time.perf_counter()
             if catalog == "measure":
                 res = self.liaison.query_measure(req, tracer=tracer)

@@ -404,6 +404,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "jit_traces": frozenset(),
     "fault_injected": frozenset({"kind", "site"}),
     "fused_chunks": frozenset({"kind"}),
+    "fused_dispatches_outstanding": frozenset(),
     "gather_rows": frozenset({"dedup"}),
     "group_reduce_rows": frozenset({"method"}),
     "kernel_dispatch_budget": frozenset({"signature"}),
@@ -418,6 +419,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "qos_query_active": frozenset({"tenant"}),
     "qos_query_waiting": frozenset({"tenant"}),
     "qos_queue_ms": frozenset({"tenant"}),
+    "queries_inflight": frozenset(),
     "query_degraded": frozenset({"engine"}),
     "query_ms": frozenset({"engine"}),
     "query_stage_ms": frozenset({"stage"}),
@@ -425,6 +427,8 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "rebalance_parts_planned": frozenset(),
     "rebalance_shards_to_move": frozenset(),
     "repair_parts_shipped": frozenset(),
+    "rpc_handlers_busy": frozenset(),
+    "rpc_pool_full": frozenset(),
     "rss_bytes": frozenset(),
     "selftrace_dropped": frozenset(),
     "selftrace_spans": frozenset(),

@@ -213,8 +213,8 @@ class QosPlane:
     def admit_query(self, group: str, deadline_s: Optional[float] = None):
         """Context manager holding one query slot for ``group``'s tenant;
         entering may queue (deadline-aware) and raises ServerBusy when
-        the wait budget runs out.  ``.tenant`` / ``.queued_ms`` are
-        readable after entry (the ``qos`` span tags)."""
+        the wait budget runs out.  ``.tenant`` / ``.queued_ms`` /
+        ``.inflight`` are readable after entry (the ``qos`` span tags)."""
         return _QueryTicket(self, tenant_of_group(group), deadline_s)
 
     def _eligible_locked(self, tenant: str, cap: int) -> bool:
@@ -238,15 +238,22 @@ class QosPlane:
 
     def _acquire_query(
         self, tenant: str, deadline_s: Optional[float]
-    ) -> float:
-        """-> queued milliseconds.  Raises ServerBusy on wait-budget
-        exhaustion (the explicit retryable rejection)."""
+    ) -> tuple[float, int]:
+        """-> (queued milliseconds, queries admitted and not yet
+        released once this one is, itself included).  Raises ServerBusy
+        on wait-budget exhaustion (the explicit retryable rejection).
+        ``_active`` is kept whether or not a cap is set: it is what
+        ``queries_inflight`` and the ``qos`` span's ``inflight`` read (a
+        plane that is off counts nothing: 0)."""
         if not self.enabled:
-            return 0.0
+            return 0.0, 0
         cap = self.limits(tenant).max_concurrent
         if cap <= 0 and self.query_global_max <= 0:
+            with self._lock:
+                self._active[tenant] = self._active.get(tenant, 0) + 1
+                inflight = sum(self._active.values())
             self._count(tenant, "query_admitted")
-            return 0.0
+            return 0.0, inflight
         budget = self.max_queue_s
         if deadline_s is not None:
             budget = max(min(budget, deadline_s), 0.0)
@@ -284,6 +291,7 @@ class QosPlane:
                         f"tenant {tenant!r} query admission queue timed "
                         f"out after {budget:.2f}s; retry after backoff"
                     )
+            inflight = sum(self._active.values())
         queued_ms = (time.monotonic() - t0) * 1000.0
         if queued:
             self._count(tenant, "query_queued")
@@ -291,13 +299,10 @@ class QosPlane:
                 "qos_queue_ms", queued_ms, {"tenant": tenant}
             )
         self._count(tenant, "query_admitted")
-        return queued_ms
+        return queued_ms, inflight
 
     def _release_query(self, tenant: str) -> None:
         if not self.enabled:
-            return
-        cap = self.limits(tenant).max_concurrent
-        if cap <= 0 and self.query_global_max <= 0:
             return
         with self._cond:
             n = self._active.get(tenant, 1) - 1
@@ -306,6 +311,12 @@ class QosPlane:
             else:
                 self._active.pop(tenant, None)
             self._cond.notify_all()
+
+    def inflight(self) -> int:
+        """Queries admitted and not yet released, all tenants
+        (/metrics ``queries_inflight``)."""
+        with self._lock:
+            return sum(self._active.values())
 
     # -- streamagg registrations --------------------------------------------
     def admit_streamagg(self, group: str, existing: int) -> str:
@@ -378,17 +389,20 @@ class QosPlane:
 class _QueryTicket:
     """The admit_query context manager (one query slot)."""
 
-    __slots__ = ("_plane", "tenant", "_deadline_s", "queued_ms", "_held")
+    __slots__ = (
+        "_plane", "tenant", "_deadline_s", "queued_ms", "inflight", "_held",
+    )
 
     def __init__(self, plane: QosPlane, tenant: str, deadline_s):
         self._plane = plane
         self.tenant = tenant
         self._deadline_s = deadline_s
         self.queued_ms = 0.0
+        self.inflight = 0
         self._held = False
 
     def __enter__(self) -> "_QueryTicket":
-        self.queued_ms = self._plane._acquire_query(
+        self.queued_ms, self.inflight = self._plane._acquire_query(
             self.tenant, self._deadline_s
         )
         self._held = True

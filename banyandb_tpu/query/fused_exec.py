@@ -41,6 +41,7 @@ unbounded-width kernel per row-count bucket.
 
 from __future__ import annotations
 
+import threading
 import time
 from dataclasses import dataclass
 from functools import partial
@@ -52,6 +53,20 @@ import numpy as np
 from banyandb_tpu.obs import tracer
 from banyandb_tpu.query.measure_exec import DeviceLeg, PlanSpec, _kernel_body
 from banyandb_tpu.utils.envflag import env_int
+
+
+# fused dispatches issued and not yet fetched, over every query of the
+# process: one chip runs them one after another, so a dispatch issued
+# behind others waits for theirs inside its own device_get
+_OUTSTANDING = 0
+_OUTSTANDING_LOCK = threading.Lock()
+
+
+def dispatches_outstanding() -> int:
+    """Fused dispatches issued and not yet fetched, now (/metrics
+    ``fused_dispatches_outstanding``)."""
+    with _OUTSTANDING_LOCK:
+        return _OUTSTANDING
 
 
 def max_fused_mb() -> int:
@@ -388,8 +403,9 @@ def run_fused(
     -> (per-chunk host partials in scan order for the f64 absorb loop,
     input-cache outcome tag).  Exactly one kernel dispatch and one
     batched device_get regardless of chunk count; their host-clock
-    times and what the dispatch compiled add to ``leg`` (one leg per
-    reduction, summed over its batches).  ``decode_span`` (open, or
+    times, what the dispatch compiled and how many dispatches of other
+    queries were outstanding when it was issued add to ``leg`` (one leg
+    per reduction, summed over its batches).  ``decode_span`` (open, or
     None) is finished when the stacked inputs are on the device: it
     covers the pad + ship loop and nothing of this dispatch.
     """
@@ -432,16 +448,26 @@ def run_fused(
     if decode_span is not None:
         decode_span.finish()
 
-    with leg.paid:  # what this dispatch traces or compiles
+    global _OUTSTANDING
+    with _OUTSTANDING_LOCK:
+        # a reduction's own batches run one after another, so what is
+        # outstanding here is other queries': get_s holds their programs
+        leg.dispatches_ahead = max(leg.dispatches_ahead, _OUTSTANDING)
+        _OUTSTANDING += 1
+    try:
+        with leg.paid:  # what this dispatch traces or compiles
+            t0 = time.perf_counter()
+            out = kernel(dev_chunks, pred_vals, hist_lo, hist_span)
+            leg.dispatch_s += time.perf_counter() - t0
         t0 = time.perf_counter()
-        out = kernel(dev_chunks, pred_vals, hist_lo, hist_span)
-        leg.dispatch_s += time.perf_counter() - t0
-    t0 = time.perf_counter()
-    # bdlint: disable=host-sync -- THE result boundary of the fused
-    # plan: the whole batch's stacked partials move in one batched
-    # transfer (1 get per dispatch, ratcheted by kernel_budgets)
-    moved = jax.device_get(out)
-    leg.get_s += time.perf_counter() - t0
+        # bdlint: disable=host-sync -- THE result boundary of the fused
+        # plan: the whole batch's stacked partials move in one batched
+        # transfer (1 get per dispatch, ratcheted by kernel_budgets)
+        moved = jax.device_get(out)
+        leg.get_s += time.perf_counter() - t0
+    finally:
+        with _OUTSTANDING_LOCK:
+            _OUTSTANDING -= 1
     leg.get_bytes += sum(a.nbytes for a in jax.tree_util.tree_leaves(moved))
     chunks_out = [
         jax.tree_util.tree_map(lambda a, k=k: a[k], moved)

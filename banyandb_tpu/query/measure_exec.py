@@ -24,6 +24,8 @@ row count (tests/test_precision.py).
 from __future__ import annotations
 
 import os
+import threading
+import time
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -126,15 +128,21 @@ class DeviceLeg:
     (``get_s``) — a dispatch that blocks (a trace, a compile, a
     synchronous transfer) and a device that works read differently —
     the bytes those gets brought back (``get_bytes``: the stacked
-    per-chunk partials, a padding chunk's zeros included), plus what the
+    per-chunk partials, a padding chunk's zeros included), the most
+    fused dispatches of other queries that were issued and not yet
+    fetched when one of these was issued (``dispatches_ahead``: the
+    device runs them first, and ``get_s`` holds them), plus what the
     dispatches traced or compiled on this thread."""
 
-    __slots__ = ("dispatch_s", "get_s", "get_bytes", "paid")
+    __slots__ = (
+        "dispatch_s", "get_s", "get_bytes", "dispatches_ahead", "paid",
+    )
 
     def __init__(self):
         self.dispatch_s = 0.0
         self.get_s = 0.0
         self.get_bytes = 0
+        self.dispatches_ahead = 0
         # entered around the dispatches (`with leg.paid:`)
         self.paid = compile_cache.watch()
 
@@ -148,7 +156,7 @@ class DeviceLeg:
             "dispatch_ms", round(self.dispatch_s * 1000, 3)
         ).tag("get_ms", round(self.get_s * 1000, 3)).tag(
             "partials_bytes", self.get_bytes
-        )
+        ).tag("dispatches_ahead", self.dispatches_ahead)
         if self.paid.compiled:
             span.tag("compiled", self.paid.compiled).tag(
                 "compile_ms", round(self.paid.seconds * 1000, 3)
@@ -307,6 +315,33 @@ class GlobalDicts:
         return out
 
 
+class _WaitTimedLock:
+    """A lock for ``with`` that adds what each acquisition waited to the
+    acquiring thread's total (``waited_s``).  Queries on other server
+    threads hold DictState.lock while they fill dictionaries and build
+    remap tables; compute_partials reads the total before and after to
+    say how long THIS query waited for them (the ``gather`` span's
+    ``dict_lock_wait_ms``)."""
+
+    __slots__ = ("_lock", "_waited")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._waited = threading.local()
+
+    def __enter__(self):
+        t0 = time.perf_counter()
+        self._lock.acquire()
+        self._waited.s = self.waited_s() + time.perf_counter() - t0
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+    def waited_s(self) -> float:
+        """Seconds this thread has waited for the lock, all told."""
+        return getattr(self._waited, "s", 0.0)
+
+
 class DictState:
     """Per-(engine, measure) persistent dictionary + remap state.
 
@@ -336,9 +371,7 @@ class DictState:
     """
 
     def __init__(self):
-        import threading
-
-        self.lock = threading.Lock()
+        self.lock = _WaitTimedLock()
         self._reset_locked()
 
     def _reset_locked(self):
@@ -728,6 +761,7 @@ def compute_partials(
     # cache writes below guard on `dict_state.dicts is gd` so an in-flight
     # query can never poison the post-reset caches with old codes.
     dict_reset = dict_over_bound = False
+    lock_waited0 = dict_state.lock.waited_s() if dict_state is not None else 0.0
     if dict_state is None:
         gd = GlobalDicts(sorted(tags_code))
         token = None
@@ -888,6 +922,15 @@ def compute_partials(
             }
         else:
             group_values = {t: gd.values(t) for t in group_tags}
+    if g is not None:
+        # what this query waited for DictState.lock up to here: the three
+        # acquisitions above and the gather's (one a source a tag)
+        lock_waited = (
+            dict_state.lock.waited_s() - lock_waited0
+            if dict_state is not None
+            else 0.0
+        )
+        g.tag("dict_lock_wait_ms", round(lock_waited * 1000, 3))
     num_groups = 1
     for r in radices:
         num_groups *= r

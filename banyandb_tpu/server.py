@@ -26,7 +26,7 @@ from banyandb_tpu.admin.accesslog import AccessLog
 from banyandb_tpu.admin.metrics import SelfMeasureSink
 from banyandb_tpu.obs.tracer import attach_tree
 from banyandb_tpu.admin.protector import MemoryProtector
-from banyandb_tpu.cluster.rpc import GrpcBusServer
+from banyandb_tpu.cluster.rpc import GrpcBusServer, handler_busy
 from banyandb_tpu.models.measure import MeasureEngine
 from banyandb_tpu.models.property import Property, PropertyEngine
 from banyandb_tpu.models.stream import Stream, StreamEngine
@@ -473,12 +473,17 @@ class StandaloneServer:
 
     @staticmethod
     def _tag_qos(tracer, adm) -> None:
-        """The ``qos`` span on the obs plane: which tenant ran, and how
+        """The ``qos`` span on the obs plane: which tenant ran, how
         long admission took (always a number: microseconds when it did
-        not queue)."""
+        not queue), and what ran beside it when it started: ``inflight``
+        queries admitted and not yet released, ``rpc_busy`` handlers of
+        the bus server running (``cluster/rpc.py``), itself included in
+        both."""
         with tracer.span("qos") as sp:
             sp.tag("tenant", adm.tenant)
             sp.tag("queued_ms", round(adm.queued_ms, 3))
+            sp.tag("inflight", adm.inflight)
+            sp.tag("rpc_busy", handler_busy())
 
     def _measure_query(self, env):
         from banyandb_tpu.obs import Tracer
@@ -608,6 +613,18 @@ class StandaloneServer:
         # partitions (tenant-labeled rows; the default tenant keeps its
         # original unlabeled series — no renames)
         self.qos.export_gauges(self.meter)
+        # what runs at once: queries admitted and not yet released, bus
+        # handlers running (this scrape's own among them when it came
+        # over the bus), fused dispatches issued and not yet fetched
+        from banyandb_tpu.query.fused_exec import dispatches_outstanding
+
+        self.meter.gauge_set("queries_inflight", float(self.qos.inflight()))
+        self.meter.gauge_set(
+            "rpc_handlers_busy", float(self.grpc.handlers_busy())
+        )
+        self.meter.gauge_set(
+            "fused_dispatches_outstanding", float(dispatches_outstanding())
+        )
         from banyandb_tpu.storage.cache import partition_stats
 
         for tenant, st in partition_stats().items():

@@ -78,6 +78,7 @@ ADOPTED = _adopt()
 def test_the_fast_files_were_adopted():
     assert {
         "test_selfcheck.py", "test_control.py", "test_control_ep9k.py", "test_control_ep400k.py",
+        "test_control_r1ep9k.py",
     } <= set(ADOPTED)
     assert not SLOW & set(ADOPTED)
     assert any(k.startswith("test_selfcheck__test_selfcheck") for k in globals())
@@ -90,11 +91,14 @@ def _bench_json(*parts: str):
         return json.load(f)
 
 
-def _reduce_tree(tags: dict, gather: dict | None = None) -> dict:
-    return {"name": "measure-query", "children": [{"name": "execute", "children": [
-        {"name": "gather", "tags": dict({"rows": 3240000}, **(gather or {}))},
-        {"name": "reduce", "tags": tags, "children": [{"name": "decode", "tags": {}}]},
-    ]}]}
+def _reduce_tree(tags: dict, gather: dict | None = None, qos: dict | None = None) -> dict:
+    return {"name": "measure-query", "children": [
+        {"name": "qos", "tags": dict({"tenant": "default", "queued_ms": 0.004}, **(qos or {}))},
+        {"name": "execute", "children": [
+            {"name": "gather", "tags": dict({"rows": 3240000}, **(gather or {}))},
+            {"name": "reduce", "tags": tags, "children": [{"name": "decode", "tags": {}}]},
+        ]},
+    ]}
 
 
 @pytest.mark.parametrize(
@@ -119,7 +123,8 @@ def test_skipped_chunks_per_query_reads_the_reduce_span(tags, want):
     assert readers.read(metric, rec) == want
 
 
-# ISSUE 33: four files for the tags `ep400k.topn-7d` is read by; name ->
+# ISSUE 33: four files for the tags `ep400k.topn-7d` is read by, and
+# ISSUE 35: four for what ran beside a query (`r1ep9k.topn-15m-c50`); name ->
 # (the reader the file must hold, [(case, {span: tags}, what it reads)])
 SPAN_TAG_FILES = {
     "gather_lut_ms": (
@@ -145,7 +150,33 @@ SPAN_TAG_FILES = {
         [("tagged", {"reduce": {"absorb_ms": 12.532, "host_ms": 139.172}}, 12.532),
          ("no-tag", {"reduce": {"host_ms": 139.172}}, None)],
     ),
+    "inflight_per_query": (
+        {"kind": "span_tag", "span": "qos", "tag": "inflight"},
+        # the eight bus workers' queries; 1 for a query alone
+        [("tagged", {"qos": {"inflight": 8, "rpc_busy": 8}}, 8.0),
+         ("alone", {"qos": {"inflight": 1, "rpc_busy": 1}}, 1.0),
+         ("no-tag", {}, None)],
+    ),
+    "rpc_busy_per_query": (
+        {"kind": "span_tag", "span": "qos", "tag": "rpc_busy"},
+        [("tagged", {"qos": {"inflight": 7, "rpc_busy": 8}}, 8.0),
+         ("no-tag", {}, None)],
+    ),
+    "dispatches_ahead_per_query": (
+        {"kind": "span_tag", "span": "reduce", "tag": "dispatches_ahead"},
+        [("tagged", {"reduce": {"dispatches_ahead": 3, "get_ms": 40.0}}, 3.0),
+         ("alone", {"reduce": {"dispatches_ahead": 0, "get_ms": 10.0}}, 0.0),
+         ("no-tag", {"reduce": {"get_ms": 10.0}}, None)],
+    ),
+    "dict_lock_wait_ms": (
+        {"kind": "span_tag", "span": "gather", "tag": "dict_lock_wait_ms"},
+        [("tagged", {"gather": {"dict_lock_wait_ms": 9.317}}, 9.317),
+         ("no-tag", {"gather": {"select_ms": 4.2}}, None)],
+    ),
 }
+# the count of requests the server works on at once is better higher; a
+# wait, and everything of ISSUE 33's, lower
+BETTER_HIGHER = {"inflight_per_query", "rpc_busy_per_query"}
 
 
 @pytest.mark.parametrize(
@@ -159,16 +190,18 @@ SPAN_TAG_FILES = {
 def test_a_metric_file_added_as_data_reads_its_span_tag(name, tags, want):
     """Each file is data for the `span_tag` reader that is there; where
     the program has no such tag (the parent of ISSUE 33) it returns
-    nothing and does not raise, and BENCHMARK.json's entry agrees with
-    the file and names no `workloads`: every cell reports it."""
+    the program has no such tag (the parent of ISSUE 33, of ISSUE 35) it
+    returns nothing and does not raise, and BENCHMARK.json's entry agrees
+    with the file and names no `workloads`: every cell reports it."""
     readers = _load(os.path.join(CHECKOUT, "benchmarks", "e2e", "readers.py"), "bench_e2e_readers")
     metric = _bench_json("benchmarks", "e2e", "metrics", name + ".json")
     assert metric["reader"] == SPAN_TAG_FILES[name][0]
-    assert "cells" not in metric and metric["better"] == "lower"
+    assert "cells" not in metric
+    assert metric["better"] == ("higher" if name in BETTER_HIGHER else "lower")
     (entry,) = [m for m in _bench_json("BENCHMARK.json")["per_layer"] if m["name"] == name]
     assert entry == {k: metric[k] for k in ("name", "unit", "better", "source", "layer", "moves")}
     assert entry["moves"] == "query_p50_ms"
-    tree = _reduce_tree(tags.get("reduce", {}), tags.get("gather"))
+    tree = _reduce_tree(tags.get("reduce", {}), tags.get("gather"), tags.get("qos"))
     rec = {"queries": [{"served": "scan", "tree": tree} for _ in range(3)]}
     got = readers.read(metric, rec)
     assert got == (want if want is None else pytest.approx(want))
@@ -196,3 +229,45 @@ def test_ep400k_is_a_cell_at_issue_33s_size():
     panel = mix["panels"]["topn"]
     assert mix["clients"] == 1 and panel["top"] == 10
     assert panel["range_ms"] == 7 * data["bucket_ms"]
+
+
+def test_r1ep9k_is_a_cell_at_issue_35s_size():
+    """The deployment as ISSUE 35 names it: upstream's published query
+    condition on `ep9k`'s estate: `ep9k`'s schema, distributions and
+    guarantees word for word, 6 h held in quarter-hour messages, and
+    fifty closed-loop clients over drawn quarter hours of `topn-6h`'s
+    text."""
+    bench = _bench_json("BENCHMARK.json")
+    (entry,) = [c for c in bench["configs"] if c["name"] == "r1ep9k"]
+    (cell,) = [w for w in bench["workloads"] if w["config"] == "r1ep9k"]
+    assert (cell["name"], cell["traffic"], cell["chips"]) == (
+        "r1ep9k.topn-15m-c50", "topn-15m-c50", 1,
+    )
+    cfg = _bench_json(entry["file"])
+    ep9k = _bench_json("benchmarks", "e2e", "configs", "ep9k.json")
+    assert cfg["schema"] == ep9k["schema"]
+    assert cfg["guarantees"][:7] == ep9k["guarantees"] and len(cfg["guarantees"]) == 8
+    assert cfg["source"] == entry["source"] and len(entry["source"]) <= 200
+    assert "50 concurrent" in entry["source"] and "15 min" in entry["source"]
+    assert sorted(cfg["reduced"]) == sorted(entry["reduced"]) == ["buckets", "measures"]
+    assert "HIDES" in cfg["reduced"]["buckets"]
+    data = cfg["data"]
+    same = ("series", "regions", "bucket_ms", "t0_ms", "order", "hits", "value")
+    assert {k: data[k] for k in same} == {k: ep9k["data"][k] for k in same}
+    assert (data["series"], data["regions"], data["buckets"]) == (9000, 8, 360)
+    assert data["batch_rows"] == data["snapshot_every_rows"] == 15 * data["series"]
+    mix = _bench_json("benchmarks", "e2e", "traffic", "topn-15m-c50.json")
+    panel = dict(mix["panels"]["topn"])
+    assert (mix["loop"], mix["clients"], mix["cycle"]) == ("closed", 50, ["topn"])
+    assert mix["warm_spread"] >= 12
+    assert panel.pop("range_ms") == 15 * data["bucket_ms"]
+    # any quarter hour of the six held, and the text of `topn-6h` but for the range
+    assert panel.pop("lo") == {"draw_ms": [0, (data["buckets"] - 16) * data["bucket_ms"]]}
+    other = dict(_bench_json("benchmarks", "e2e", "traffic", "topn-6h.json")["panels"]["topn"])
+    del other["range_ms"], other["lo"]
+    assert panel == other
+    # the four cells it joined are still there, and still one client each
+    older = ("topn-24h", "pctl-6h", "topn-6h", "topn-7d")
+    assert [w["traffic"] for w in bench["workloads"][:4]] == list(older)
+    for name in older:
+        assert _bench_json("benchmarks", "e2e", "traffic", name + ".json")["clients"] == 1
