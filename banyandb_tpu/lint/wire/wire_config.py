@@ -413,6 +413,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "measure_write_points": frozenset(),
     "placement_epoch": frozenset(),
     "planner_decisions": frozenset({"path"}),
+    "plans_scan_order": frozenset({"mode"}),
     "qos_enabled": frozenset(),
     "qos_inflight_bytes": frozenset({"tenant"}),
     "qos_inflight_shed": frozenset({"tenant"}),

@@ -732,11 +732,16 @@ def compute_partials(
             rep_list.append(t)
             tags_code.add(t)
         rep_tags = tuple(dict.fromkeys(rep_list))
-    rep_desc = request.order_by_ts == "desc"
-    # scan-order tracking serves grouped ordering AND the global-agg
-    # representative row (a no-group aggregate's output row carries the
-    # first scanned row's projected tags)
-    want_rep = bool(group_tags) or bool(rep_tags)
+    # scan-order tracking runs for what reads the key: a listing's
+    # first-appearance emission order (and its LIMIT / OFFSET pages), and
+    # the representative row of projected-but-not-grouped tags (a
+    # no-group aggregate's output row carries the first scanned row's).
+    # A Top-N that projects no such tag reads neither: its ranking
+    # replaces the order and ties at the cut resolve by group key
+    # (_finalize_partials_inner), so its plan drops the tracking, and
+    # ORDER BY time DESC does not split that one program into two
+    want_rep = bool(rep_tags) or (bool(group_tags) and not request.top)
+    rep_desc = want_rep and request.order_by_ts == "desc"
     # Projection names that aren't schema fields (e.g. tags from a QL
     # SELECT list) are dropped — they'd only materialize zero columns.
     # Raw (string/binary) fields never ride the device path either: they
@@ -872,7 +877,7 @@ def compute_partials(
     # ordering there instead of silently corrupting
     epoch = int(chunks_np["ts"].min()) if n else 0
     if n and int(chunks_np["ts"].max()) - epoch >= 2**31:
-        want_rep = False
+        want_rep = rep_desc = False
         rep_tags = ()
 
     # --- plan signature ---------------------------------------------------
@@ -1023,7 +1028,7 @@ def compute_partials(
         reduce_loaded.append(1)
         return _reduce_partials(
             measure, chunks_np, conds, expr, pred_vals, spec,
-            group_values, rep_tags, rep_desc, want_rep, gd, dict_state,
+            group_values, rep_tags, gd, dict_state,
             hist_lo, hist_span, want_percentile, epoch, gather_key, agg,
             span=rspan, plan_hints=plan_hints,
         )
@@ -1058,8 +1063,6 @@ def _reduce_partials(
     spec,
     group_values,
     rep_tags,
-    rep_desc,
-    want_rep,
     gd,
     dict_state,
     hist_lo,
@@ -1089,6 +1092,7 @@ def _reduce_partials(
     group_tags = spec.group_tags
     radices = spec.radices
     want_minmax = spec.want_minmax
+    want_rep, rep_desc = spec.want_rep, spec.rep_desc
     # --- exact-f64 host path for FLOAT-field aggregation ------------------
     # The reference aggregates float64 fields in full f64 and its goldens
     # compare exactly (852.0409999999999 etc.); the device kernel's f32
@@ -1106,7 +1110,7 @@ def _reduce_partials(
         out = _host_float_partials(
             measure, None, _materialize_tag_codes(chunks_np, spec.tags_code),
             conds, expr, pred_vals, spec,
-            group_values, rep_tags, rep_desc, want_rep, gd, dict_state,
+            group_values, rep_tags, gd, dict_state,
         )
         if span is not None:
             # exact-f64 host reduction: no device leg by design
@@ -1255,6 +1259,11 @@ def _reduce_partials(
         meter.counter_add(
             "fused_chunks", float(chunks_skipped), labels={"kind": "skipped"}
         )
+        # plans by whether their program tracked scan order (bydb.rep)
+        meter.counter_add(
+            "plans_scan_order",
+            labels={"mode": "tracked" if want_rep else "skipped"},
+        )
     # -- decode stage attribution (ROADMAP item 3) ------------------------
     # host half = narrow pack + pad (pack_s) + H2D ship (h2d_s): column
     # j+1 pads while column j ships under BYDB_PIPELINE; the device half
@@ -1298,7 +1307,9 @@ def _reduce_partials(
             "chunks_skipped", chunks_skipped
         ).tag("path", "fused").tag("dispatches", len(batches)).tag(
             "group_method", group_method
-        ).tag("groups", spec.num_groups)
+        ).tag("groups", spec.num_groups).tag(
+            "scan_order_tracked", int(want_rep)
+        )
         if device_s > 0:
             span.tag("rows_per_ms", round(n / (device_s * 1000), 3))
         if dev_cache is not None and batches:
@@ -1375,8 +1386,6 @@ def _host_float_partials(
     spec: PlanSpec,
     group_values: dict,
     rep_tags: tuple,
-    rep_desc: bool,
-    want_rep: bool,
     gd: GlobalDicts,
     dict_state,
 ) -> Partials:
@@ -1387,6 +1396,7 @@ def _host_float_partials(
     with numpy f64 arithmetic so float goldens compare exactly."""
     n = chunks["ts"].shape[0]
     G = spec.num_groups
+    want_rep, rep_desc = spec.want_rep, spec.rep_desc
 
     def pred_mask(i: int) -> np.ndarray:
         p = spec.preds[i]

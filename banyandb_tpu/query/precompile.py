@@ -59,7 +59,8 @@ def builtin_plans():
     Mirrors the shapes real consoles issue: flat count tiles, grouped
     eq+LUT filters with scan-order tracking, the two-pass percentile
     histogram, OR criteria trees, and the TopN ranking shape (grouped
-    mean/minmax + representative tracking at a scan-chunk bucket)."""
+    mean/minmax at a scan-chunk bucket; a Top-N that projects no tag
+    tracks no scan order)."""
     from banyandb_tpu.query.measure_exec import PlanSpec, _PredSpec
 
     flat = PlanSpec(
@@ -120,7 +121,6 @@ def builtin_plans():
         num_groups=1024,
         want_minmax=True,
         nrows=65536,
-        want_rep=True,
     )
     return (
         ("measure/flat-count", flat),

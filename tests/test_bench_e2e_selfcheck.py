@@ -123,8 +123,9 @@ def test_skipped_chunks_per_query_reads_the_reduce_span(tags, want):
     assert readers.read(metric, rec) == want
 
 
-# ISSUE 33: four files for the tags `ep400k.topn-7d` is read by, and
-# ISSUE 35: four for what ran beside a query (`r1ep9k.topn-15m-c50`); name ->
+# ISSUE 33: four files for the tags `ep400k.topn-7d` is read by,
+# ISSUE 35: four for what ran beside a query (`r1ep9k.topn-15m-c50`), and
+# ISSUE 36: whether the plan's program tracked scan order; name ->
 # (the reader the file must hold, [(case, {span: tags}, what it reads)])
 SPAN_TAG_FILES = {
     "gather_lut_ms": (
@@ -173,6 +174,13 @@ SPAN_TAG_FILES = {
         [("tagged", {"gather": {"dict_lock_wait_ms": 9.317}}, 9.317),
          ("no-tag", {"gather": {"select_ms": 4.2}}, None)],
     ),
+    "scan_order_tracked_per_query": (
+        {"kind": "span_tag", "span": "reduce", "tag": "scan_order_tracked"},
+        # a listing (`svc1k.pctl-6h`) tracks; a TOP 10 that projects no tag does not
+        [("listing", {"reduce": {"scan_order_tracked": 1, "groups": 1000}}, 1.0),
+         ("topn", {"reduce": {"scan_order_tracked": 0, "groups": 9000}}, 0.0),
+         ("no-tag", {"reduce": {"groups": 9000}}, None)],
+    ),
 }
 # the count of requests the server works on at once is better higher; a
 # wait, and everything of ISSUE 33's, lower
@@ -189,8 +197,7 @@ BETTER_HIGHER = {"inflight_per_query", "rpc_busy_per_query"}
 )
 def test_a_metric_file_added_as_data_reads_its_span_tag(name, tags, want):
     """Each file is data for the `span_tag` reader that is there; where
-    the program has no such tag (the parent of ISSUE 33) it returns
-    the program has no such tag (the parent of ISSUE 33, of ISSUE 35) it
+    the program has no such tag (the parent of ISSUE 33, 35 or 36) it
     returns nothing and does not raise, and BENCHMARK.json's entry agrees
     with the file and names no `workloads`: every cell reports it."""
     readers = _load(os.path.join(CHECKOUT, "benchmarks", "e2e", "readers.py"), "bench_e2e_readers")

@@ -322,24 +322,47 @@ def test_a_state_more_than_half_dead_is_reset_once(store):
     assert st.token == token and _counted("dict_state_resets") - resets == 1
 
 
+@pytest.mark.parametrize("top, per_group", [(None, 16), (10, 8)], ids=["listing", "top10"])
 @pytest.mark.parametrize("scan_chunk, chunks, skipped", [(None, 1, 0), (8192, 3, 1), (4096, 6, 2)])
-def test_partials_bytes_is_the_bucket_times_g_times_16(store, monkeypatch, scan_chunk, chunks, skipped):
-    """What the `device_get` brings back for a TopN sum: count,
-    sum(hits) and the two scan-order arrays, four `[C, G]` arrays of 4 B,
-    for every chunk of the bucket, a padding chunk's zeros included (the
-    cell: 3 real 1M-row chunks in the 4-bucket x 400,000 x 16 = 25.6 MB);
-    `absorb_ms`, the f64 fold of the real ones, is a part of `host_ms`.
-    The answer is the one-chunk answer whatever the chunking."""
+def test_partials_bytes_is_the_bucket_times_g_times_16(
+    store, monkeypatch, scan_chunk, chunks, skipped, top, per_group,
+):
+    """What the `device_get` brings back for a sum: count and sum(hits),
+    two `[C, G]` arrays of 4 B, and for a listing, which emits its
+    groups in first-appearance order, the two scan-order arrays beside
+    them; the cell's `TOP 10` reads no scan order and fetches none
+    (ISSUE 36), so 8 B a group a chunk beside the listing's 16, for
+    every chunk of the bucket, a padding chunk's zeros included (the
+    cell: 3 real 1M-row chunks in the 4-bucket x 400,000 x 8 = 12.8 MB;
+    25.6 until ISSUE 36); `absorb_ms`, the f64 fold of the real ones, is
+    a part of `host_ms`.  The answer is the one-chunk answer whatever
+    the chunking."""
     eng, hits = store
     if scan_chunk is not None:
         monkeypatch.setattr(measure_exec, "SCAN_CHUNK", scan_chunk)
-    got, spans = serve(eng, ql_of(6, 1, 7, tail=f"LIMIT {SERIES}", off=61 + chunks))
-    compare(got, reference(hits, 6, 1, 7), top=None)
+    tail = f"LIMIT {SERIES}" if top is None else f"TOP {top} BY hits"
+    got, spans = serve(eng, ql_of(6, 1, 7, tail=tail, off=61 + chunks + (top or 0)))
+    compare(got, reference(hits, 6, 1, 7), top=top)
     tags = spans["reduce"]
     assert (tags["chunks"], tags["chunks_skipped"], tags["dispatches"]) == (chunks, skipped, 1)
-    assert tags["partials_bytes"] == (chunks + skipped) * SERIES * 16
+    assert tags["scan_order_tracked"] == (top is None)
+    assert tags["partials_bytes"] == (chunks + skipped) * SERIES * per_group
     assert 0 < tags["absorb_ms"] <= tags["host_ms"]
     assert spans["merge"]["groups"] == SERIES - SERIES // REGIONS
+
+
+def test_top10_is_the_same_across_chunkings(store, monkeypatch):
+    """The plan that tracks no scan order answers group for group, in
+    order, value for value, the same in 1, 3 and 6 chunks."""
+    eng, _ = store
+    answers = []
+    for i, scan_chunk in enumerate((None, 8192, 4096)):
+        if scan_chunk is not None:
+            monkeypatch.setattr(measure_exec, "SCAN_CHUNK", scan_chunk)
+        got, spans = serve(eng, ql_of(6, 1, 7, off=81 + i))
+        assert spans["reduce"]["scan_order_tracked"] == 0
+        answers.append(list(got.items()))
+    assert answers[0] == answers[1] == answers[2] and len(answers[0]) == 10
 
 
 def bf16(a: np.ndarray) -> np.ndarray:

@@ -366,7 +366,7 @@ def _lowered_text(kind: str, decode: bool) -> str:
     "kind,scopes",
     [
         ("topn", ("bydb.decode", "bydb.filter", "bydb.group_key",
-                  "bydb.group_reduce.", "bydb.rep", "bydb.fused_scan")),
+                  "bydb.group_reduce.", "bydb.fused_scan")),
         ("percentile", ("bydb.decode", "bydb.filter", "bydb.group_key",
                         "bydb.group_reduce.", "bydb.histogram", "bydb.rep",
                         "bydb.fused_scan")),
@@ -377,6 +377,9 @@ def test_lowered_plan_names_every_stage(kind, scopes):
     assert "jit_bydb_fused_plan" in text
     for scope in scopes:
         assert scope in text, scope
+    # scan order is tracked for the listing, which emits its groups in
+    # first-appearance order; a TOP n that projects no tag reads none
+    assert ("bydb.rep" in text) == (kind == "percentile")
     # the method that ran is part of the name
     m, req, srcs = _query(kind)
     from banyandb_tpu.ops.groupby import select_group_method
