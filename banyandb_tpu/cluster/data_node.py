@@ -686,7 +686,7 @@ class DataNode:
             return None
         from banyandb_tpu.obs.tracer import Tracer
 
-        return Tracer(f"data:{self.name}")
+        return Tracer(f"data:{self.name}", usage=bool(req.trace))
 
     @staticmethod
     def _tenant_scope(env: dict, group: str):

@@ -1156,7 +1156,7 @@ class Liaison:
         `trace=true` responses carry ONE cluster-wide span tree."""
         own_tracer = tracer is None and req.trace
         if own_tracer:
-            tracer = Tracer("liaison:measure")
+            tracer = Tracer("liaison:measure", usage=True)
         t = tracer if tracer is not None else NOOP_TRACER
         group = req.groups[0]
         m = self.registry.get_measure(group, req.name)
@@ -1349,7 +1349,7 @@ class Liaison:
     def query_stream(self, req: QueryRequest, tracer=None) -> QueryResult:
         own_tracer = tracer is None and req.trace
         if own_tracer:
-            tracer = Tracer("liaison:stream")
+            tracer = Tracer("liaison:stream", usage=True)
         t = tracer if tracer is not None else NOOP_TRACER
         guard = _QueryGuard(self.query_budget_s)
         assignment = self._shard_assignment(
@@ -1511,7 +1511,7 @@ class Liaison:
 
         own_tracer = tracer is None and req.trace
         if own_tracer:
-            tracer = Tracer("liaison:trace")
+            tracer = Tracer("liaison:trace", usage=True)
         t = tracer if tracer is not None else NOOP_TRACER
         group = req.groups[0]
         tid_tag = self.registry.get_trace(group, req.name).trace_id_tag

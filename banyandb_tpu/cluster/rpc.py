@@ -22,11 +22,13 @@ from banyandb_tpu.obs import metrics as obs_metrics
 
 _METHOD = "/banyandb.Bus/Call"
 # handlers a GrpcBusServer runs at once: its thread pool's size.  A
-# request that arrives while all of them run waits in the pool's queue,
-# where no span is open (the client sees the wait as wire time)
+# request that arrives while all of them run waits in the pool's queue
+# (_TimedPool): the wait is its ``qos`` span's ``pool_wait_ms`` and an
+# observation of /metrics ``rpc_pool_wait_ms``
 _BUS_WORKERS = 8
 # the handler this thread is running: how many of its server's handlers
-# ran when it started (handler_busy)
+# ran when it started (handler_busy) and what it waited for this worker
+# (handler_pool_wait_ms)
 _HANDLER = threading.local()
 
 
@@ -36,6 +38,29 @@ def handler_busy() -> int:
     ``rpc_busy``); 0 on a thread that runs none (a LocalTransport call).
     ``_BUS_WORKERS`` means the pool was full from then on."""
     return getattr(_HANDLER, "busy", 0)
+
+
+def handler_pool_wait_ms() -> float:
+    """What the handler on THIS thread waited for its worker, from
+    grpc's hand-over to the pool to the worker starting it (the ``qos``
+    span's ``pool_wait_ms``); 0.0 on a thread that runs none."""
+    return getattr(_HANDLER, "pool_wait_ms", 0.0)
+
+
+def tag_qos(tracer, adm) -> None:
+    """The ``qos`` span on the obs plane: which tenant ran, how long
+    admission took (always a number: microseconds when it did not
+    queue), what the request waited for a worker of the bus server
+    before any span was open (``pool_wait_ms``, always a number), and
+    what ran beside it when it started: ``inflight`` queries admitted
+    and not yet released, ``rpc_busy`` handlers of the bus server
+    running, itself included in both."""
+    with tracer.span("qos") as sp:
+        sp.tag("tenant", adm.tenant)
+        sp.tag("queued_ms", round(adm.queued_ms, 3))
+        sp.tag("inflight", adm.inflight)
+        sp.tag("rpc_busy", handler_busy())
+        sp.tag("pool_wait_ms", round(handler_pool_wait_ms(), 3))
 
 
 def _observe_rpc(side: str, topic: str, t0: float) -> None:
@@ -147,6 +172,45 @@ def prespawn_pool(pool) -> None:
         barrier.wait(timeout=10)
     except _t.BrokenBarrierError:  # pragma: no cover - degraded start
         pass
+
+
+class _TimedPool(futures.ThreadPoolExecutor):
+    """The bus server's worker pool with a clock on its queue.  grpc
+    hands every RPC to the executor when it arrives, so submit -> the
+    worker calling the function is exactly the wait for a worker: it
+    lands in the worker's ``_HANDLER`` (handler_pool_wait_ms) and in
+    /metrics ``rpc_pool_wait_ms``; ``queued`` counts the calls submitted
+    and not yet started, under the server's ``_busy_lock``."""
+
+    def __init__(self, max_workers: int, lock: threading.Lock):
+        super().__init__(max_workers=max_workers)
+        self._queued_lock = lock
+        self.queued = 0
+
+    def submit(self, fn, /, *args, **kwargs):
+        t0 = time.perf_counter()
+
+        def started(*a, **kw):
+            wait_ms = (time.perf_counter() - t0) * 1000
+            with self._queued_lock:
+                self.queued -= 1
+            obs_metrics.global_meter().histogram("rpc_pool_wait_ms").observe(
+                wait_ms
+            )
+            _HANDLER.pool_wait_ms = wait_ms
+            try:
+                return fn(*a, **kw)
+            finally:
+                _HANDLER.pool_wait_ms = 0.0
+
+        with self._queued_lock:
+            self.queued += 1
+        try:
+            return super().submit(started, *args, **kwargs)
+        except BaseException:  # refused (shut down): it never queued
+            with self._queued_lock:
+                self.queued -= 1
+            raise
 
 
 class GrpcBusServer:
@@ -275,7 +339,7 @@ class GrpcBusServer:
         # caller-provided executor down — idle worker threads would
         # otherwise outlive every stopped server, a leak the bdsan
         # thread-parity check catches)
-        self._pool = futures.ThreadPoolExecutor(max_workers=_BUS_WORKERS)
+        self._pool = _TimedPool(_BUS_WORKERS, self._busy_lock)
         self._server = grpc.server(
             self._pool,
             options=[("grpc.max_receive_message_length", 64 * 1024 * 1024),
@@ -305,15 +369,10 @@ class GrpcBusServer:
         self.addr = f"{host}:{self.port}"
 
     def _enter_handler(self) -> int:
-        """-> handlers running now, this one included.  One that takes
-        the pool's last worker counts in ``rpc_pool_full``: while it
-        runs, a new request waits for a worker."""
+        """-> handlers running now, this one included."""
         with self._busy_lock:
             self._busy += 1
-            busy = self._busy
-        if busy >= _BUS_WORKERS:
-            obs_metrics.global_meter().counter_add("rpc_pool_full")
-        return busy
+            return self._busy
 
     def _leave_handler(self) -> None:
         with self._busy_lock:
@@ -323,6 +382,11 @@ class GrpcBusServer:
         """Handlers running now (/metrics ``rpc_handlers_busy``)."""
         with self._busy_lock:
             return self._busy
+
+    def pool_queued(self) -> int:
+        """Calls waiting for a worker now (/metrics ``rpc_pool_queued``)."""
+        with self._busy_lock:
+            return self._pool.queued
 
     def start(self) -> None:
         prespawn_pool(self._pool)

@@ -31,7 +31,7 @@ from banyandb_tpu.cluster.bus import LocalBus, Topic
 from banyandb_tpu.cluster.data_node import DataNode
 from banyandb_tpu.cluster.discovery import FileDiscovery
 from banyandb_tpu.cluster.liaison import Liaison
-from banyandb_tpu.cluster.rpc import GrpcBusServer, GrpcTransport, handler_busy
+from banyandb_tpu.cluster.rpc import GrpcBusServer, GrpcTransport, tag_qos
 
 
 class DataServer:
@@ -539,7 +539,7 @@ class LiaisonServer:
         # always-on liaison-side tracer (node subtrees only attach when
         # req.trace rode the scatter): slow distributed queries land in
         # the flight recorder with whatever tree exists
-        tracer = Tracer(f"liaison:{catalog}")
+        tracer = Tracer(f"liaison:{catalog}", usage=bool(env.get("trace")))
         deadline_ms = env.get("deadline_ms")
         adm = self.qos.admit_query(
             req.groups[0] if req.groups else "",
@@ -550,11 +550,7 @@ class LiaisonServer:
         from banyandb_tpu.qos import tenant_scope
 
         with adm, tenant_scope(adm.tenant):
-            with tracer.span("qos") as sp:
-                sp.tag("tenant", adm.tenant)
-                sp.tag("queued_ms", round(adm.queued_ms, 3))
-                sp.tag("inflight", adm.inflight)
-                sp.tag("rpc_busy", handler_busy())
+            tag_qos(tracer, adm)
             t0 = _time.perf_counter()
             if catalog == "measure":
                 res = self.liaison.query_measure(req, tracer=tracer)

@@ -429,7 +429,8 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "rebalance_shards_to_move": frozenset(),
     "repair_parts_shipped": frozenset(),
     "rpc_handlers_busy": frozenset(),
-    "rpc_pool_full": frozenset(),
+    "rpc_pool_queued": frozenset(),
+    "rpc_pool_wait_ms": frozenset(),
     "rss_bytes": frozenset(),
     "selftrace_dropped": frozenset(),
     "selftrace_spans": frozenset(),

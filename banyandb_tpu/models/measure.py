@@ -691,7 +691,7 @@ class MeasureEngine:
 
         own_tracer = tracer is None and req.trace
         if own_tracer:
-            tracer = Tracer("measure:query")
+            tracer = Tracer("measure:query", usage=True)
         t = tracer if tracer is not None else NOOP_TRACER
 
         t_start = time.perf_counter()

@@ -185,7 +185,7 @@ class StreamEngine:
 
         own_tracer = tracer is None and req.trace
         if own_tracer:
-            tracer = Tracer("stream:query")
+            tracer = Tracer("stream:query", usage=True)
         t = tracer if tracer is not None else NOOP_TRACER
         t0 = _time.perf_counter()
         try:

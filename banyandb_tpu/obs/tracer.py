@@ -28,6 +28,20 @@ at boot, so ``obs/`` imports no jax.  Whenever a device trace is running
 in its host plane; with no hook a span costs what it did before.
 ``annotate(name)`` is the bare form for work off the owner thread.
 
+Ran or waited: a span that reads its thread's clock (the root of every
+tracer, and every span of a tracer made with ``usage=True``: the request
+asked for its tree) and is finished on the thread that opened it carries
+``off_cpu_ms`` (its wall time less the CPU time that thread was given
+between open and finish: a wait for the interpreter lock, a lock, a
+queue, a ``device_get``; NumPy's C loops and the kernel's work on a
+first touch of fresh pages count as ON the CPU), ``minflt`` (that
+thread's minor page faults inside the span) and ``tid`` (its native
+id).  One finished on another thread has none of the three: no one
+thread's clock covers it.  Each reading is one ``getrusage`` call, which
+is why only the root takes it for a request that did not ask: where the
+kernel is a sandbox's (the benchmark's machine) a system call costs
+~6 µs, fifteen times what it does on Linux itself.
+
 Tracing off must cost nothing: callers thread ``None`` (executors skip
 span work on a ``None`` span) or ``NOOP_TRACER`` (handlers keep one
 code path); both avoid allocation on the hot path.
@@ -37,6 +51,8 @@ from __future__ import annotations
 
 import contextlib
 import os
+import resource
+import threading
 import time
 from typing import Callable, Optional
 
@@ -61,6 +77,19 @@ def annotate(name: str):
     return _annotate("bydb:" + name)
 
 
+def thread_usage() -> tuple[float, int]:
+    """-> (CPU seconds, user + system; minor page faults) of the calling
+    thread so far: one ``getrusage(RUSAGE_THREAD)``.  The kernel brings
+    the CPU time up to date at its scheduler tick, so a difference of
+    two readings is right to a tick (1 - 4 ms on Linux; 10 ms under the
+    sandboxed kernel of the benchmark's machine, which also reports no
+    page faults at all): unbiased over many spans, coarse for one, and
+    never clamped: a span that ran through a tick reads an ``off_cpu_ms``
+    below zero by up to that tick."""
+    ru = resource.getrusage(resource.RUSAGE_THREAD)
+    return ru.ru_utime + ru.ru_stime, ru.ru_minflt
+
+
 class Span:
     """One timed node of the trace tree.  Not thread-safe: a span is
     owned by the thread that created it (worker-side timings are
@@ -68,10 +97,10 @@ class Span:
 
     __slots__ = (
         "name", "t0", "t1", "tags", "children", "error_msg", "trace_id",
-        "_ann",
+        "_ann", "usage", "_tid", "_cpu0", "_flt0",
     )
 
-    def __init__(self, name: str, trace_id: str = ""):
+    def __init__(self, name: str, trace_id: str = "", usage: bool = True):
         self.name = name
         self.trace_id = trace_id
         self.tags: dict = {}
@@ -82,6 +111,12 @@ class Span:
         if _annotate is not None:
             self._ann = _annotate("bydb:" + name, trace_id=trace_id)
             self._ann.__enter__()
+        # usage: the children this span makes read their thread's clock
+        # (ran or waited); _tid: this span itself has, on that thread
+        self.usage = usage
+        self._tid = threading.get_ident() if usage else None
+        if usage:
+            self._cpu0, self._flt0 = thread_usage()
         self.t0 = time.perf_counter()
 
     # -- building -----------------------------------------------------------
@@ -94,7 +129,7 @@ class Span:
         return self
 
     def child(self, name: str) -> "Span":
-        s = Span(name, self.trace_id)
+        s = Span(name, self.trace_id, self.usage)
         self.children.append(s)
         return s
 
@@ -114,6 +149,19 @@ class Span:
             # across requests); many roots run queries, but no two roots
             # ever hold the same Span instance
             self.t1 = time.perf_counter()
+            if self._tid is not None and threading.get_ident() == self._tid:
+                # ran or waited: this thread's own clock covers the span
+                cpu, flt = thread_usage()
+                # not clamped at 0: the CPU clock moves a whole tick at a
+                # time, and only the unclamped difference is unbiased
+                waited = (self.t1 - self.t0) - (cpu - self._cpu0)
+                # bdlint: disable=wp-shared-state -- as t1 above: one
+                # query's Span, finished by one thread
+                self.tags.update(
+                    off_cpu_ms=round(waited * 1000.0, 3),
+                    minflt=flt - self._flt0,
+                    tid=threading.current_thread().native_id,
+                )
             if self._ann is not None:  # left once: t1 guards re-entry
                 self._ann.__exit__(None, None, None)
         return self
@@ -178,9 +226,14 @@ class Tracer:
 
     __slots__ = ("root", "_stack", "start_unix_ms")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, usage: bool = False):
+        """``usage``: the request asked for its tree, so every span says
+        whether its thread ran or waited; the root says so always (two
+        ``getrusage`` calls a request: what a slow query's tree in the
+        recorder has to go on)."""
         self.start_unix_ms = time.time() * 1000.0
         self.root = Span(name, os.urandom(8).hex())
+        self.root.usage = usage  # of the children; the root itself has read
         self._stack: list[Span] = [self.root]
 
     def current(self) -> Span:
@@ -203,6 +256,7 @@ class _NoopSpan:
     """Absorbs the whole Span surface at near-zero cost."""
 
     __slots__ = ()
+    usage = False  # reads no clock, nor do the executors under it
 
     def tag(self, key, value):
         return self

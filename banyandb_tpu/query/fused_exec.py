@@ -235,6 +235,7 @@ def _stacked_chunks(
     pack_s: list | None = None,
     h2d_s: list | None = None,
     ship_stats: list | None = None,
+    pack_use: list | None = None,
 ) -> dict:
     """Pad the gathered columns into ``[C, nrows]`` device arrays.
 
@@ -247,7 +248,10 @@ def _stacked_chunks(
     pad work rides the chunk_stream prefetch worker (BYDB_PIPELINE
     honored) so padding column j+1 overlaps shipping column j.
     ``pack_s`` collects the pad thunks' seconds (worker thread),
-    ``h2d_s`` the ``jnp.asarray`` ships' (this thread); ``ship_stats``
+    ``h2d_s`` the ``jnp.asarray`` ships' (this thread); ``pack_use``
+    (given when the query's spans read their threads' clocks) one
+    (seconds off the CPU, minor page faults) pair a pad thunk: whether
+    the worker ran or waited (``obs/tracer.thread_usage``); ``ship_stats``
     one (shipped, dense) byte pair for the whole part-batch (decode-span
     attribution).
     """
@@ -335,13 +339,19 @@ def _stacked_chunks(
 
     def timed(fn):
         def pad_thunk():  # host-side work on the prefetch worker
+            if pack_use is not None:
+                cpu0, flt0 = tracer.thread_usage()
             t0 = time.perf_counter()
             try:
                 with tracer.annotate("decode.pack"):
                     return fn()
             finally:
+                dt = time.perf_counter() - t0
                 if pack_s is not None:
-                    pack_s.append(time.perf_counter() - t0)
+                    pack_s.append(dt)
+                if pack_use is not None:
+                    cpu, flt = tracer.thread_usage()
+                    pack_use.append((dt - (cpu - cpu0), flt - flt0))
 
         return pad_thunk
 
@@ -396,6 +406,7 @@ def run_fused(
     h2d_s: list | None = None,
     ship_stats: list | None = None,
     decode_span=None,
+    pack_use: list | None = None,
 ) -> tuple[list[dict], str]:
     """Execute one chunk batch (``plan_batches``) through the fused
     program of its ``num_chunks`` bucket.
@@ -424,7 +435,7 @@ def run_fused(
         built.append(1)
         return _stacked_chunks(
             chunks_np, chunk_spans, spec, num_chunks, epoch, pack_s, h2d_s,
-            ship_stats=ship_stats,
+            ship_stats=ship_stats, pack_use=pack_use,
         )
 
     if dev_cache is not None:

@@ -871,16 +871,23 @@ def compute_partials(
             g.tag("dict_live_share", dict_live_share)
         for key, value in gather_tags.items():
             g.tag(key, value)
+    # --- plan signature ---------------------------------------------------
+    # everything between the gather and the reduce, under one span with no
+    # child (its self time is the benchmark's signature_ms): the epoch,
+    # the predicate values, the spec, the precompile registry, the
+    # histogram range, the partials key; phases are tags
+    sig = span.child("signature") if span is not None else None
     # epoch = global min ts keeps chunk-relative int32 offsets
     # nonnegative for the scan-order key; spans >= 2^31 ms (~24.8 days)
     # would wrap the int32 cast, so rep tracking degrades to canonical
     # ordering there instead of silently corrupting
+    t_phase0 = _time.perf_counter()
     epoch = int(chunks_np["ts"].min()) if n else 0
     if n and int(chunks_np["ts"].max()) - epoch >= 2**31:
         want_rep = rep_desc = False
         rep_tags = ()
+    epoch_ms = (_time.perf_counter() - t_phase0) * 1000
 
-    # --- plan signature ---------------------------------------------------
     # All gd reads happen under the DictState lock (concurrent queries
     # mutate the same dicts); group value lists are snapshotted here for
     # the decode step below.
@@ -888,6 +895,7 @@ def compute_partials(
 
     pred_specs = []
     pred_vals: dict[str, jax.Array] = {}
+    t_phase0 = _time.perf_counter()
     with dict_state.lock if dict_state is not None else contextlib.nullcontext():
         for i, c in enumerate(conds):
             if c.op in range_ops or c.op == "match":
@@ -927,6 +935,7 @@ def compute_partials(
             }
         else:
             group_values = {t: gd.values(t) for t in group_tags}
+    preds_ms = (_time.perf_counter() - t_phase0) * 1000
     if g is not None:
         # what this query waited for DictState.lock up to here: the three
         # acquisitions above and the gather's (one a source a tag)
@@ -1021,6 +1030,10 @@ def compute_partials(
             h.hexdigest(),
         )
 
+    if sig is not None:
+        sig.tag("epoch_ms", round(epoch_ms, 3)).tag(
+            "preds_ms", round(preds_ms, 3)
+        ).finish()
     rspan = span.child("reduce") if span is not None else None
     reduce_loaded: list = []
 
@@ -1052,6 +1065,12 @@ def compute_partials(
                     "host_ms", round(rspan.duration_ms, 3)
                 )
             rspan.finish()
+            # the gathered rows go back to the allocator under a span of
+            # their own: left to this frame's teardown, a gather the
+            # serving cache did not keep (~95 MB) costs 2.5 - 4.3 ms between
+            # `reduce` and `merge`, in no span (PERF.md section 6, PR 37)
+            with span.child("release"):
+                chunks_np = None
 
 
 def _reduce_partials(
@@ -1176,6 +1195,9 @@ def _reduce_partials(
     # are single-owner and never touched off-thread
     pack_s: list = []
     h2d_s: list = []
+    # whether the pad thunks ran or waited, one (off-CPU seconds, minor
+    # faults) pair a thunk: read when this query's spans read their clocks
+    pack_use: list | None = [] if span is not None and span.usage else None
     # (shipped, dense) bytes per batch: the decode span's compression
     # evidence (dense = what the decoded i32/f32 ship form would have
     # moved for the same columns)
@@ -1230,6 +1252,7 @@ def _reduce_partials(
             h2d_s=h2d_s,
             ship_stats=ship_stats,
             decode_span=dspan if i == len(batches) - 1 else None,
+            pack_use=pack_use,
         )
         cache_tags.append(cache_tag)
         t_absorb0 = _time.perf_counter()
@@ -1296,6 +1319,12 @@ def _reduce_partials(
             "ratio",
             round(dense_bytes / shipped_bytes, 2) if shipped_bytes else 1.0,
         )
+        if pack_use is not None:
+            # of pack_ms, what the pad thread did not run (it waited for
+            # the interpreter), and its minor page faults in the thunks
+            dspan.tag(
+                "pack_off_cpu_ms", round(sum(w for w, _ in pack_use) * 1000, 3)
+            ).tag("pack_minflt", sum(f for _, f in pack_use))
     if span is not None:
         total_ms = (_time.perf_counter() - t_reduce0) * 1000
         leg.tag(span)

@@ -26,7 +26,7 @@ from banyandb_tpu.admin.accesslog import AccessLog
 from banyandb_tpu.admin.metrics import SelfMeasureSink
 from banyandb_tpu.obs.tracer import attach_tree
 from banyandb_tpu.admin.protector import MemoryProtector
-from banyandb_tpu.cluster.rpc import GrpcBusServer, handler_busy
+from banyandb_tpu.cluster.rpc import GrpcBusServer, tag_qos
 from banyandb_tpu.models.measure import MeasureEngine
 from banyandb_tpu.models.property import Property, PropertyEngine
 from banyandb_tpu.models.stream import Stream, StreamEngine
@@ -471,20 +471,6 @@ class StandaloneServer:
             ),
         )
 
-    @staticmethod
-    def _tag_qos(tracer, adm) -> None:
-        """The ``qos`` span on the obs plane: which tenant ran, how
-        long admission took (always a number: microseconds when it did
-        not queue), and what ran beside it when it started: ``inflight``
-        queries admitted and not yet released, ``rpc_busy`` handlers of
-        the bus server running (``cluster/rpc.py``), itself included in
-        both."""
-        with tracer.span("qos") as sp:
-            sp.tag("tenant", adm.tenant)
-            sp.tag("queued_ms", round(adm.queued_ms, 3))
-            sp.tag("inflight", adm.inflight)
-            sp.tag("rpc_busy", handler_busy())
-
     def _measure_query(self, env):
         from banyandb_tpu.obs import Tracer
 
@@ -492,12 +478,14 @@ class StandaloneServer:
         # sub-microsecond): slow queries land in the flight recorder with
         # their full tree whether or not the client asked for trace=true;
         # the tree only rides the RESPONSE when req.trace is set
-        tracer = Tracer("standalone:measure")
+        tracer = Tracer(
+            "standalone:measure", usage=bool(env["request"].get("trace"))
+        )
         with tracer.span("wire_decode"):
             req = serde.query_request_from_json(env["request"])
         adm = self._admit_query(req, env)
         with adm, tenant_scope(adm.tenant):
-            self._tag_qos(tracer, adm)
+            tag_qos(tracer, adm)
             t0 = time.perf_counter()
             if self.pool is not None:
                 res = self.pool.query_measure(req, tracer=tracer)
@@ -622,6 +610,8 @@ class StandaloneServer:
         self.meter.gauge_set(
             "rpc_handlers_busy", float(self.grpc.handlers_busy())
         )
+        # calls waiting in front of the server for one of its workers
+        self.meter.gauge_set("rpc_pool_queued", float(self.grpc.pool_queued()))
         self.meter.gauge_set(
             "fused_dispatches_outstanding", float(dispatches_outstanding())
         )
@@ -824,10 +814,10 @@ class StandaloneServer:
         from banyandb_tpu.obs import Tracer
 
         req = serde.query_request_from_json(env["request"])
-        tracer = Tracer("standalone:stream")
+        tracer = Tracer("standalone:stream", usage=bool(req.trace))
         adm = self._admit_query(req, env)
         with adm, tenant_scope(adm.tenant):
-            self._tag_qos(tracer, adm)
+            tag_qos(tracer, adm)
             t0 = time.perf_counter()
             if self.pool is not None:
                 res = self.pool.query_stream(req, tracer=tracer)
@@ -902,7 +892,7 @@ class StandaloneServer:
 
         # the tracer is made at handler entry so the root covers the
         # BydbQL parse (its own span); the catalog names the root after
-        tracer = Tracer("standalone:ql")
+        tracer = Tracer("standalone:ql", usage=bool(env.get("trace")))
         with tracer.span("parse"):
             catalog, req = bydbql.parse_with_catalog(
                 env["ql"], env.get("params", ())
@@ -917,7 +907,7 @@ class StandaloneServer:
             req = _dc.replace(req, trace=True)
         adm = self._admit_query(req, env)
         with adm, tenant_scope(adm.tenant):
-            self._tag_qos(tracer, adm)
+            tag_qos(tracer, adm)
             t0 = time.perf_counter()
             if catalog == "stream":
                 if self.pool is not None:

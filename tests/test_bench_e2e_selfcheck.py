@@ -91,12 +91,14 @@ def _bench_json(*parts: str):
         return json.load(f)
 
 
-def _reduce_tree(tags: dict, gather: dict | None = None, qos: dict | None = None) -> dict:
+def _reduce_tree(
+    tags: dict, gather: dict | None = None, qos: dict | None = None, decode: dict | None = None,
+) -> dict:
     return {"name": "measure-query", "children": [
         {"name": "qos", "tags": dict({"tenant": "default", "queued_ms": 0.004}, **(qos or {}))},
         {"name": "execute", "children": [
             {"name": "gather", "tags": dict({"rows": 3240000}, **(gather or {}))},
-            {"name": "reduce", "tags": tags, "children": [{"name": "decode", "tags": {}}]},
+            {"name": "reduce", "tags": tags, "children": [{"name": "decode", "tags": decode or {}}]},
         ]},
     ]}
 
@@ -125,7 +127,10 @@ def test_skipped_chunks_per_query_reads_the_reduce_span(tags, want):
 
 # ISSUE 33: four files for the tags `ep400k.topn-7d` is read by,
 # ISSUE 35: four for what ran beside a query (`r1ep9k.topn-15m-c50`), and
-# ISSUE 36: whether the plan's program tracked scan order; name ->
+# ISSUE 36: whether the plan's program tracked scan order,
+# ISSUE 37: six for where a request waited (the bus pool's queue; whether
+# the gather's and the pad thunks' threads ran or waited; their page
+# faults); name ->
 # (the reader the file must hold, [(case, {span: tags}, what it reads)])
 SPAN_TAG_FILES = {
     "gather_lut_ms": (
@@ -181,6 +186,38 @@ SPAN_TAG_FILES = {
          ("topn", {"reduce": {"scan_order_tracked": 0, "groups": 9000}}, 0.0),
          ("no-tag", {"reduce": {"groups": 9000}}, None)],
     ),
+    "pool_wait_ms": (
+        {"kind": "span_tag", "span": "qos", "tag": "pool_wait_ms"},
+        # 42 of 50 clients wait in front of 8 workers; a request alone finds one free
+        [("queued", {"qos": {"rpc_busy": 8, "pool_wait_ms": 652.318}}, 652.318),
+         ("alone", {"qos": {"rpc_busy": 1, "pool_wait_ms": 0.041}}, 0.041),
+         ("no-tag", {"qos": {"rpc_busy": 8}}, None)],
+    ),
+    "gather_off_cpu_ms": (
+        {"kind": "span_tag", "span": "gather", "tag": "off_cpu_ms"},
+        [("waited", {"gather": {"off_cpu_ms": 38.2, "minflt": 12, "tid": 4711}}, 38.2),
+         ("ran", {"gather": {"off_cpu_ms": 0.0, "minflt": 22100, "tid": 4711}}, 0.0),
+         ("no-tag", {"gather": {"select_ms": 4.2}}, None)],
+    ),
+    "gather_minor_faults_per_query": (
+        {"kind": "span_tag", "span": "gather", "tag": "minflt"},
+        # a first touch of 91 MB is ~22,000 faults of 4,096 B; pages handed back none
+        [("first-touch", {"gather": {"off_cpu_ms": 0.3, "minflt": 22216}}, 22216.0),
+         ("reused", {"gather": {"off_cpu_ms": 0.3, "minflt": 0}}, 0.0),
+         ("no-tag", {"gather": {"select_ms": 4.2}}, None)],
+    ),
+    "pack_off_cpu_ms": (
+        {"kind": "span_tag", "span": "decode", "tag": "pack_off_cpu_ms"},
+        [("waited", {"decode": {"pack_ms": 17.7, "pack_off_cpu_ms": 11.4, "pack_minflt": 480}}, 11.4),
+         ("ran", {"decode": {"pack_ms": 6.0, "pack_off_cpu_ms": 0.0, "pack_minflt": 480}}, 0.0),
+         ("no-tag", {"decode": {"pack_ms": 6.0}}, None)],
+    ),
+    "pack_minor_faults_per_query": (
+        {"kind": "span_tag", "span": "decode", "tag": "pack_minflt"},
+        [("tagged", {"decode": {"pack_ms": 61.6, "pack_minflt": 14400}}, 14400.0),
+         ("reused", {"decode": {"pack_ms": 27.5, "pack_minflt": 0}}, 0.0),
+         ("no-tag", {"decode": {"pack_ms": 27.5}}, None)],
+    ),
 }
 # the count of requests the server works on at once is better higher; a
 # wait, and everything of ISSUE 33's, lower
@@ -197,7 +234,7 @@ BETTER_HIGHER = {"inflight_per_query", "rpc_busy_per_query"}
 )
 def test_a_metric_file_added_as_data_reads_its_span_tag(name, tags, want):
     """Each file is data for the `span_tag` reader that is there; where
-    the program has no such tag (the parent of ISSUE 33, 35 or 36) it
+    the program has no such tag (the parent of ISSUE 33, 35, 36 or 37) it
     returns nothing and does not raise, and BENCHMARK.json's entry agrees
     with the file and names no `workloads`: every cell reports it."""
     readers = _load(os.path.join(CHECKOUT, "benchmarks", "e2e", "readers.py"), "bench_e2e_readers")
@@ -208,8 +245,68 @@ def test_a_metric_file_added_as_data_reads_its_span_tag(name, tags, want):
     (entry,) = [m for m in _bench_json("BENCHMARK.json")["per_layer"] if m["name"] == name]
     assert entry == {k: metric[k] for k in ("name", "unit", "better", "source", "layer", "moves")}
     assert entry["moves"] == "query_p50_ms"
-    tree = _reduce_tree(tags.get("reduce", {}), tags.get("gather"), tags.get("qos"))
+    tree = _reduce_tree(
+        tags.get("reduce", {}), tags.get("gather"), tags.get("qos"), tags.get("decode"),
+    )
     rec = {"queries": [{"served": "scan", "tree": tree} for _ in range(3)]}
+    got = readers.read(metric, rec)
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def _ql_tree(signature: bool) -> dict:
+    """A `bydbql` answer's tree as the server builds it; without
+    `signature` as the parent of ISSUE 37 does, where the plan signature
+    ran inside `execute` under no span."""
+    under_execute = [
+        {"name": "gather", "start_ms": 3.0, "duration_ms": 40.0, "children": []},
+        {"name": "reduce", "start_ms": 50.0, "duration_ms": 30.0,
+         "children": [{"name": "decode", "duration_ms": 12.0, "children": []}]},
+        {"name": "merge", "start_ms": 80.5, "duration_ms": 1.0, "children": []},
+    ]
+    if signature:
+        under_execute.insert(
+            1, {"name": "signature", "start_ms": 43.0, "duration_ms": 6.5, "children": []}
+        )
+    return {"name": "standalone:measure", "start_ms": 0.0, "duration_ms": 85.0, "children": [
+        {"name": "parse", "duration_ms": 0.1, "children": []},
+        {"name": "qos", "duration_ms": 0.02, "children": []},
+        {"name": "analyze", "duration_ms": 0.05, "children": []},
+        {"name": "planner", "duration_ms": 0.4, "children": []},
+        {"name": "part_gather", "duration_ms": 1.2, "children": []},
+        {"name": "execute", "start_ms": 2.5, "duration_ms": 82.0, "children": under_execute},
+    ]}
+
+
+# ISSUE 37: the milliseconds that lay in no span; name -> (the span whose
+# self time the file reads, what it reads with the `signature` span, and
+# on the parent's tree)
+SPAN_SELF_FILES = {
+    "signature_ms": ("signature", 6.5, None),
+    # 82 - (40 + 6.5 + 30 + 1): the parent's holds the signature's 6.5 too
+    "execute_self_ms": ("execute", 4.5, 11.0),
+    # 85 - (0.1 + 0.02 + 0.05 + 0.4 + 1.2 + 82): what no child of the handler covers
+    "root_self_ms": ("standalone:measure", 1.23, 1.23),
+}
+
+
+@pytest.mark.parametrize("signature", [True, False], ids=["with-signature", "parent"])
+@pytest.mark.parametrize("name", sorted(SPAN_SELF_FILES))
+def test_a_metric_file_added_as_data_reads_its_span_self_time(name, signature):
+    """Each file is data for the `span_self_ms` reader that is there.
+    `execute_self_ms` and `root_self_ms` read on the parent of ISSUE 37
+    too (its before and after: `execute`'s self time falls by what
+    `signature` now covers); `signature_ms` finds no such span there,
+    returns nothing and does not raise."""
+    readers = _load(os.path.join(CHECKOUT, "benchmarks", "e2e", "readers.py"), "bench_e2e_readers")
+    metric = _bench_json("benchmarks", "e2e", "metrics", name + ".json")
+    span, with_sig, on_parent = SPAN_SELF_FILES[name]
+    assert metric["reader"] == {"kind": "span_self_ms", "span": span}
+    assert "cells" not in metric and metric["better"] == "lower" and metric["unit"] == "ms"
+    (entry,) = [m for m in _bench_json("BENCHMARK.json")["per_layer"] if m["name"] == name]
+    assert entry == {k: metric[k] for k in ("name", "unit", "better", "source", "layer", "moves")}
+    assert entry["moves"] == "query_p50_ms" and entry["source"] == "program_span"
+    rec = {"queries": [{"served": "scan", "tree": _ql_tree(signature)} for _ in range(3)]}
+    want = with_sig if signature else on_parent
     got = readers.read(metric, rec)
     assert got == (want if want is None else pytest.approx(want))
 

@@ -6,7 +6,9 @@ ran beside a query read what each test arranged: the ``qos`` span's
 ``inflight`` / ``rpc_busy``, the ``reduce`` span's ``dispatches_ahead``,
 the ``gather`` span's ``dict_lock_wait_ms``, and /metrics
 ``queries_inflight``, ``rpc_handlers_busy``,
-``fused_dispatches_outstanding``, ``rpc_pool_full``.  CPU, small size,
+``fused_dispatches_outstanding``, ``rpc_pool_queued`` and the histogram
+``rpc_pool_wait_ms`` (ISSUE 37: the ``qos`` span's ``pool_wait_ms``, the
+wait for a worker of the bus server's pool).  CPU, small size,
 seeded data; every wait has a timeout."""
 
 import base64
@@ -179,6 +181,7 @@ def test_concurrent_answers_match_serial_and_numpy(estate, threads):
     assert _gauge(srv, "queries_inflight") == 0
     assert _gauge(srv, "fused_dispatches_outstanding") == 0
     assert _gauge(srv, "rpc_handlers_busy") == 0  # this scrape came past the bus server
+    assert _gauge(srv, "rpc_pool_queued") == 0  # no lost update left a call "waiting"
 
 
 def test_inflight_counts_the_queries_held_in_their_handlers(estate, monkeypatch):
@@ -293,17 +296,24 @@ def test_dict_lock_wait_is_the_time_another_thread_held_the_lock(estate):
     assert 150.0 <= find_span(tree, "gather")["tags"]["dict_lock_wait_ms"] < 5000.0
 
 
-def test_rpc_busy_stops_at_the_pool_and_pool_full_counts_who_filled_it():
+def _pool_wait_count() -> int:
+    return obs_metrics.global_meter().histogram("rpc_pool_wait_ms").snapshot()[0]
+
+
+def test_rpc_busy_stops_at_the_pool_and_the_queued_four_say_how_long_they_waited():
     """Twelve RPCs at once against a bus server of eight workers, each
     held until the test lets one go: eight handlers start (busy 1..8,
-    the eighth takes the last worker), four wait where no handler runs
-    and each starts into a full pool as one before it leaves."""
+    the eighth takes the last worker) and waited for no worker; four
+    wait in the pool's queue for as long as the gate stays shut
+    (`rpc_pool_queued` 4), each starts into a full pool as one before it
+    leaves, and reports that wait as its `handler_pool_wait_ms()`."""
     bus = LocalBus()
-    seen: list[int] = []
+    seen: list[tuple[int, float]] = []
     started, gate = threading.Semaphore(0), threading.Semaphore(0)
+    hold_s = 0.3
 
     def hold(env):
-        seen.append(rpc.handler_busy())
+        seen.append((rpc.handler_busy(), rpc.handler_pool_wait_ms()))
         started.release()
         assert gate.acquire(timeout=WAIT_S)
         return {"n": env["n"]}
@@ -313,8 +323,7 @@ def test_rpc_busy_stops_at_the_pool_and_pool_full_counts_who_filled_it():
     server.start()
     transport = rpc.GrpcTransport()
     n, workers = 12, rpc._BUS_WORKERS
-    key = ("rpc_pool_full", ())
-    full0 = obs_metrics.global_meter().snapshot()["counters"].get(key, 0.0)
+    observed0 = _pool_wait_count()  # after start: its prespawn calls are in
     replies: list = []
     threads = [
         threading.Thread(
@@ -330,10 +339,15 @@ def test_rpc_busy_stops_at_the_pool_and_pool_full_counts_who_filled_it():
         for _ in range(workers):
             assert started.acquire(timeout=WAIT_S)
         assert server.handlers_busy() == workers
-        assert not started.acquire(timeout=0.3)  # the other four wait for a worker
+        assert not started.acquire(timeout=hold_s)  # the other four wait for a worker
+        deadline = time.monotonic() + WAIT_S
+        while server.pool_queued() < n - workers and time.monotonic() < deadline:
+            time.sleep(0.01)  # grpc has handed all four to the pool by now, or soon
+        assert server.pool_queued() == n - workers
         for _ in range(n - workers):
             gate.release()  # one leaves, one of the waiting starts
             assert started.acquire(timeout=WAIT_S)
+        assert server.pool_queued() == 0
         for _ in range(workers):
             gate.release()
         for t in threads:
@@ -343,8 +357,37 @@ def test_rpc_busy_stops_at_the_pool_and_pool_full_counts_who_filled_it():
             gate.release()
         server.stop()
     assert sorted(r["n"] for r in replies) == list(range(n))
-    assert sorted(seen[:workers]) == list(range(1, workers + 1))
-    assert seen[workers:] == [workers] * (n - workers) and max(seen) == workers
-    full = obs_metrics.global_meter().snapshot()["counters"].get(key, 0.0)
-    assert full - full0 == 1 + (n - workers)
+    busy = [b for b, _ in seen]
+    assert sorted(busy[:workers]) == list(range(1, workers + 1))
+    assert busy[workers:] == [workers] * (n - workers) and max(busy) == workers
+    # the eight found a worker free (far under the hold; a loaded machine
+    # may take some ms to wake one), the four waited the gate out
+    assert all(0.0 <= w < hold_s * 500 for _, w in seen[:workers])
+    assert all(hold_s * 1000 <= w < WAIT_S * 1000 for _, w in seen[workers:])
+    assert _pool_wait_count() - observed0 == n
     assert server.handlers_busy() == 0
+    assert rpc.handler_pool_wait_ms() == 0.0  # this thread runs no handler
+
+
+def test_qos_span_past_the_bus_server_waited_for_no_worker(estate):
+    """A call that did not come through the gRPC bus server's pool (the
+    in-process transport) carries `pool_wait_ms` 0.0: always a number."""
+    srv, _ = estate
+    transport = rpc.LocalTransport()
+    addr = transport.register("standalone", srv.bus)
+    reply = transport.call(addr, "bydbql", {"ql": _ql(301), "trace": True})
+    qos = find_span(reply["result"]["trace"]["span_tree"], "qos")["tags"]
+    assert qos["pool_wait_ms"] == 0.0 and isinstance(qos["pool_wait_ms"], float)
+    assert qos["rpc_busy"] == 0 and qos["inflight"] == 1
+
+
+def test_qos_span_over_the_bus_server_carries_its_wait_and_the_gauge_reads_zero(estate):
+    """Over gRPC a request alone finds a worker free: a wait of some
+    microseconds, a number; nothing waits at the scrape."""
+    srv, _ = estate
+    reply = rpc.GrpcTransport().call(
+        srv.grpc.addr, "bydbql", {"ql": _ql(302), "trace": True}, timeout=WAIT_S
+    )
+    qos = find_span(reply["result"]["trace"]["span_tree"], "qos")["tags"]
+    assert 0.0 <= qos["pool_wait_ms"] < 1000.0 and qos["rpc_busy"] == 1
+    assert _gauge(srv, "rpc_pool_queued") == 0

@@ -46,9 +46,11 @@ def test_span_tree_shape_golden():
     with tr.span("merge"):
         pass
     tree = tr.finish()
+    # the root says whether its thread ran or waited, always; the rest
+    # only for a request that asked for its tree (tests/test_tracing.py)
     assert _shape(tree) == {
         "name": "root",
-        "tags": [],
+        "tags": ["minflt", "off_cpu_ms", "tid"],
         "children": [
             {"name": "plan", "tags": ["nodes"], "children": []},
             {

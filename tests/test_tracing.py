@@ -206,6 +206,157 @@ def test_no_hook_no_annotation():
     assert tracer.annotate("anything") is tracer._NULL_CTX
 
 
+# -- ran or waited: off_cpu_ms, minflt, tid (ISSUE 37) ---------------------------
+
+
+def _busy(seconds: float) -> None:
+    import time
+
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < seconds:
+        pass
+
+
+def test_a_sleeping_span_was_off_the_cpu_and_a_spinning_one_was_not():
+    import time
+
+    tr = Tracer("root", usage=True)  # the request asked for its tree
+    with tr.span("sleeps"):
+        time.sleep(0.05)
+    with tr.span("spins"):
+        _busy(0.05)
+    tree = tr.finish()
+    sleeps, spins = find_span(tree, "sleeps"), find_span(tree, "spins")
+    assert 40.0 <= sleeps["tags"]["off_cpu_ms"] <= sleeps["duration_ms"]
+    # it ran, but for what the machine's other threads took from it; the
+    # CPU clock moves a tick at a time, so the reading may fall below 0
+    assert -20.0 <= spins["tags"]["off_cpu_ms"] < 0.5 * spins["duration_ms"]
+    for span in iter_spans(tree):
+        assert span["tags"]["tid"] == threading.get_native_id()  # no call made for it
+        assert isinstance(span["tags"]["minflt"], int)
+    # the root holds both: it waited what its sleeping child waited (to
+    # the microseconds by which two clocks of one interval differ)
+    assert tree["tags"]["off_cpu_ms"] >= sleeps["tags"]["off_cpu_ms"] - 0.1
+
+
+def test_a_request_that_did_not_ask_pays_for_the_roots_reading_alone(monkeypatch):
+    """Each reading is a system call (~6 us under the sandboxed kernel
+    of the benchmark's machine): a tracer whose request did not ask for
+    its tree reads the root's thread only, which is what a slow query's
+    tree in the recorder has to go on."""
+    calls: list = []
+    real = tracer.resource.getrusage
+    monkeypatch.setattr(
+        tracer.resource, "getrusage", lambda who: calls.append(who) or real(who)
+    )
+    tr = Tracer("root")
+    with tr.span("a") as a:
+        a.child("a1").finish()
+    with tr.span("b"):
+        pass
+    tree = tr.finish()
+    assert len(calls) == 2
+    assert {"off_cpu_ms", "minflt", "tid"} <= set(tree["tags"])
+    assert all(s["tags"] == {} for s in iter_spans(tree) if s is not tree)
+    asked = Tracer("root", usage=True)
+    with asked.span("a") as a:
+        a.child("a1").finish()
+    assert all("off_cpu_ms" in s["tags"] for s in iter_spans(asked.finish()))
+    assert len(calls) == 2 + 2 * 3
+
+
+def test_the_pad_thunks_read_their_threads_clock_only_under_a_span_that_does():
+    m, req, srcs = _query("topn")
+    root = Span("execute", usage=False)
+    measure_exec.compute_partials(m, req, srcs, span=root)
+    decode = find_span(root.to_dict(), "decode")["tags"]
+    assert "pack_ms" in decode
+    assert "pack_off_cpu_ms" not in decode and "pack_minflt" not in decode
+
+
+def test_a_span_finished_on_another_thread_says_nothing_of_its_thread():
+    """No one thread's clock covers it: the three tags are left out,
+    the span is otherwise whole."""
+    sp = Span("handed-over").tag("rows", 7)
+    t = threading.Thread(target=sp.finish)
+    t.start()
+    t.join(10)
+    d = sp.to_dict()
+    assert d["tags"] == {"rows": 7} and d["duration_ms"] > 0
+    here = Span("kept").finish().to_dict()
+    assert {"off_cpu_ms", "minflt", "tid"} <= set(here["tags"])
+
+
+def test_noop_tracer_reads_no_clock_of_the_thread(monkeypatch):
+    calls: list = []
+    real = tracer.resource.getrusage
+
+    def counted(who):
+        calls.append(who)
+        return real(who)
+
+    monkeypatch.setattr(tracer.resource, "getrusage", counted)
+    t = tracer.NOOP_TRACER
+    with t.span("a") as sp:
+        sp.tag("k", 1).child("b").finish()
+    t.current().finish()
+    assert t.finish() == {} and calls == []
+    Span("real").finish()  # one at open, one at finish, of this thread
+    assert calls == [tracer.resource.RUSAGE_THREAD] * 2
+
+
+def test_minflt_counts_the_first_touch_of_fresh_pages():
+    import mmap
+
+    # 8 MB no one has touched: an anonymous map, so that the allocator
+    # cannot hand back pages an earlier test freed
+    fresh = mmap.mmap(-1, 8 << 20)
+    a = np.frombuffer(fresh, dtype=np.uint8)
+    with Span("fills") as sp:
+        a[:] = 1
+    assert a[-1] == 1 and sp.tags["minflt"] > 0
+    with Span("idles") as quiet:
+        pass
+    assert quiet.tags["minflt"] <= sp.tags["minflt"]
+
+
+def test_serialized_span_keeps_its_five_keys():
+    tr = Tracer("root")
+    with tr.span("a"):
+        pass
+    tree = tr.finish()
+    five = {"name", "start_ms", "duration_ms", "tags", "children"}
+    assert set(tree) == five | {"start_unix_ms", "trace_id"}
+    assert set(tree["children"][0]) == five  # the new readings are tags
+
+
+# -- the plan signature is a span (ISSUE 37) -------------------------------------
+
+
+@pytest.mark.parametrize("kind", ["topn", "percentile"])
+def test_execute_holds_gather_signature_reduce_release_merge_in_that_order(kind):
+    from banyandb_tpu.server import _served_class
+
+    tree = _run(kind)
+    assert [c["name"] for c in tree["children"]] == [
+        "gather", "signature", "reduce", "release", "merge",
+    ]
+    gather, sig, reduce_, release = tree["children"][:4]
+    # the gathered rows are handed back inside a span, not between two
+    assert release["children"] == [] and release["tags"].keys() <= {"off_cpu_ms", "minflt", "tid"}
+    assert sig["children"] == []  # its self time is signature_ms
+    assert sig["tags"]["epoch_ms"] >= 0.0 and sig["tags"]["preds_ms"] > 0.0
+    assert sig["tags"]["epoch_ms"] + sig["tags"]["preds_ms"] <= sig["duration_ms"] + 0.01
+    # opened where the gather closes, closed where the reduce opens
+    assert sig["start_ms"] >= gather["start_ms"] + gather["duration_ms"] - 0.01
+    assert sig["start_ms"] + sig["duration_ms"] <= reduce_["start_ms"] + 0.01
+    assert "dict_lock_wait_ms" in gather["tags"]  # tagged after its finish, as before
+    assert _served_class(tree) == "scan"
+    decode = find_span(tree, "decode")["tags"]
+    assert -20.0 <= decode["pack_off_cpu_ms"] <= decode["pack_ms"] + 0.01
+    assert isinstance(decode["pack_minflt"], int) and decode["pack_minflt"] >= 0
+
+
 # -- gather / decode are open while their work runs ----------------------------
 
 

@@ -688,18 +688,20 @@ def test_real_tree_shared_state_clean_with_pinned_suppressions():
     pkg = Path(banyandb_tpu.__file__).parent
     findings, stats = run_whole_program(pkg, plan_audit=False)
     assert findings == [], "\n".join(f.render() for f in findings)
-    # 7 wp-shared-state suppressions: bydbql._Parser (per-call instance),
+    # 8 wp-shared-state suppressions: bydbql._Parser (per-call instance),
     # StreamEngine.last_scan_stats (atomic diagnostic rebind),
     # Bloom.bits (function-local during part build),
     # obs.tracer.Span.t1 (a Span belongs to ONE query's tracer; many
-    # roots run queries but no two roots share a Span instance),
+    # roots run queries but no two roots share a Span instance) and,
+    # for the same reason, Span.tags where finish() sets off_cpu_ms /
+    # minflt / tid (ISSUE 37),
     # WorkerPool._jbytes/_journal (every write holds the per-worker
     # self._jlocks[widx] — a lock in a LIST, outside the analyzer's
     # attribute-lock model),
     # _WorkerServer.applied_seq (ORDERED_TOPICS routes every ordered
     # envelope to the single writer thread, so the field is
     # single-writer and read on that same thread by the flush handler)
-    assert stats["wp_suppressed"] == 7
+    assert stats["wp_suppressed"] == 8
     # root discovery is not vacuous: threads, subscribers, grpc methods
     assert stats["wp_roots"] >= 60
 
