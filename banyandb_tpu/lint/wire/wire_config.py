@@ -398,6 +398,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "compile_cache_enabled": frozenset(),
     "decode_ship_bytes": frozenset({"form"}),
     "dict_state_kept": frozenset(),
+    "dict_values_entered": frozenset({"kind"}),
     "dict_state_resets": frozenset(),
     "failover_attempts": frozenset(),
     "jit_compile_seconds": frozenset(),

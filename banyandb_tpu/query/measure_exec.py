@@ -2124,8 +2124,14 @@ def _finalize_partials_inner(
             # top set; resolve exactly those by group key so selection is
             # replay-identical across topologies without paying a Python
             # sort over all G groups (vectorized argsort does the bulk).
+            # Ties above the cut are ordered by group key too: the stable
+            # argsort leaves them in code order, which depends on the
+            # order the sources' dictionaries were met.
             kth_val = metric[order[k - 1]]
-            head = [int(i) for i in order if metric[i] != kth_val]
+            head = sorted(
+                (int(i) for i in order if metric[i] != kth_val),
+                key=lambda i: (metric[i] if asc else -metric[i], p.group_key(i)),
+            )
             tied = sorted(
                 (
                     int(i)

@@ -293,10 +293,24 @@ def estimate_scan(engine, db, m, req) -> ScanEstimate:
     # the geometric middle)
     tag_sum = {t: 0 for t in group_tags}
     tag_max = {t: 1 for t in group_tags}
+    # a tag that is the whole entity: series are placed on shards by the
+    # entity's hash, so shards hold disjoint values and the union is at
+    # least the SUM over shards of each shard's largest dictionary (a
+    # dictionary holds the values its rows use, storage/memtable.py)
+    entity = tuple(m.entity.tag_names)
+    entity_tag = entity[0] if len(entity) == 1 else None
+    shard_max: dict[int, int] = {}
     scan_rows_total = 0  # rows surviving the zone pass (gather size)
     zone_surv_total = 0  # ... further scaled by predicate selectivity
+
+    def count_dict(t: str, shard_idx: int, sz: int) -> None:
+        tag_sum[t] += sz
+        tag_max[t] = max(tag_max[t], sz)
+        if t == entity_tag:
+            shard_max[shard_idx] = max(shard_max.get(shard_idx, 1), sz)
+
     for seg in db.select_segments(begin, end):
-        for shard in seg.shards:
+        for shard_idx, shard in enumerate(seg.shards):
             for mem_cols in shard.hot_columns(m.name):
                 n = int(mem_cols.ts.size)
                 est.rows += n
@@ -305,9 +319,7 @@ def estimate_scan(engine, db, m, req) -> ScanEstimate:
                 for t in group_tags:
                     col = mem_cols.tags.get(t)
                     d = mem_cols.dicts.get(t) if col is not None else None
-                    sz = len(d) if d is not None else 1
-                    tag_sum[t] += sz
-                    tag_max[t] = max(tag_max[t], sz)
+                    count_dict(t, shard_idx, len(d) if d is not None else 1)
             for part in shard.parts:
                 if part.meta.get("measure") != m.name:
                     continue
@@ -327,9 +339,9 @@ def estimate_scan(engine, db, m, req) -> ScanEstimate:
                 scan_rows_total += zone_surv if zone_conds else cand
                 zone_surv_total += int(zone_surv * sel) if zone_conds else cand
                 for t in group_tags:
-                    sz = len(part.dict_for(t)) or 1
-                    tag_sum[t] += sz
-                    tag_max[t] = max(tag_max[t], sz)
+                    count_dict(t, shard_idx, len(part.dict_for(t)) or 1)
+    if shard_max:
+        tag_max[entity_tag] = sum(shard_max.values())
     est.scan_rows = min(scan_rows_total, est.rows)
     est.surviving_rows = min(zone_surv_total, est.rows)
     est.zone_markable_rows = est.rows - est.scan_rows
@@ -340,7 +352,8 @@ def estimate_scan(engine, db, m, req) -> ScanEstimate:
     groups = 1
     for t in group_tags:
         # geometric middle of [per-part max, cross-part sum]: the union
-        # is at least the largest single dictionary and at most the sum
+        # is at least the largest single dictionary (an entity tag's:
+        # the sum of the shards' largest) and at most the sum
         hi = max(tag_sum[t], 1)
         lo = tag_max[t]
         static *= hi

@@ -13,6 +13,7 @@ from typing import Mapping
 
 import numpy as np
 
+from banyandb_tpu.obs import metrics as obs_metrics
 from banyandb_tpu.storage.part import ColumnData
 
 # Monotonic memtable generation counter (itertools.count is GIL-atomic).
@@ -117,9 +118,14 @@ class MemTable:
         here via np.unique so each distinct value hits the dict once) or
         an already dictionary-encoded column (duck-typed: has .values +
         .codes, models.measure.DictColumn) whose dict remaps straight
-        into this table's dict — zero per-row Python.
+        into this table's dict — zero per-row Python.  Of such a column
+        only the values its codes name enter this table's dict, as the
+        row path enters only the values it sees: a message's dictionary
+        spans every shard, one shard's rows use its share of it, and a
+        part keeps what its memtable's dict holds.
         """
         n = len(ts_millis)
+        used = unused = 0
         with self._lock:
             self._ts.extend(ts_millis.tolist())
             self._series.extend(series_ids.tolist())
@@ -132,17 +138,17 @@ class MemTable:
                     self._tag_codes[t].extend([code] * n)
                     continue
                 if hasattr(vals, "codes"):  # dictionary-encoded column
-                    lut = np.fromiter(
-                        (d.setdefault(v, len(d)) for v in vals.values),
-                        dtype=np.int64,
-                        count=len(vals.values),
+                    codes, inv = np.unique(
+                        np.asarray(vals.codes, dtype=np.int64),
+                        return_inverse=True,
                     )
-                    self._tag_codes[t].extend(
-                        lut[np.asarray(vals.codes, dtype=np.int64)].tolist()
+                    uniq = [vals.values[c] for c in codes.tolist()]
+                    used += len(uniq)
+                    unused += len(vals.values) - len(uniq)
+                else:
+                    uniq, inv = np.unique(
+                        np.asarray(vals, dtype=object), return_inverse=True
                     )
-                    continue
-                arr = np.asarray(vals, dtype=object)
-                uniq, inv = np.unique(arr, return_inverse=True)
                 lut = np.fromiter(
                     (d.setdefault(v, len(d)) for v in uniq),
                     dtype=np.int64,
@@ -159,6 +165,12 @@ class MemTable:
                     )
             if self._payloads is not None:
                 self._payloads.extend(payloads or [b""] * n)
+        if used or unused:
+            meter = obs_metrics.global_meter()
+            meter.counter_add("dict_values_entered", used, {"kind": "used"})
+            meter.counter_add(
+                "dict_values_entered", unused, {"kind": "unused"}
+            )
 
     def drain(self) -> list[tuple[str, ColumnData, dict]]:
         """Flush protocol: [(part-name-suffix, columns, extra metadata)]."""

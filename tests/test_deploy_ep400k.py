@@ -15,8 +15,10 @@ the group-by is `sort`.  Here both bounds are monkeypatched DOWN (1,024 and
 of both.  It is loaded as the benchmark loads it: through the columnar
 write path, a day a message with the names as one `DictColumn`, flushed
 after each (the server's flusher runs every second), so every part holds
-one shard's rows of one day and, since `MemTable.append_bulk` enters the
-message's whole dictionary into every shard (ROADMAP S14), every name.
+one shard's rows of one day and the names those rows use, a quarter of
+the message's dictionary (`MemTable.append_bulk` enters only the values a
+shard's codes name; until ISSUE 38 every part held every name, ROADMAP
+S14).
 It is asked the cell's own BydbQL text
 (benchmarks/e2e/traffic/topn-7d.json) the way the server's `bydbql`
 handler asks it; the reference is a NumPy group-sum and top-k on the same
@@ -35,6 +37,7 @@ Tolerances, each with its reason:
 """
 
 import dataclasses
+import json
 
 import numpy as np
 import pytest
@@ -60,6 +63,7 @@ from banyandb_tpu.obs.tracer import iter_spans
 from banyandb_tpu.ops import groupby
 from banyandb_tpu.query import measure_exec
 from banyandb_tpu.server import result_to_json
+from banyandb_tpu.storage.memtable import MemTable
 
 SERIES, REGIONS, BUCKETS, SHARDS = 3000, 8, 8, 4
 BUCKET_MS = 86_400_000
@@ -83,11 +87,9 @@ def big_sides_of_the_bounds():
         yield
 
 
-@pytest.fixture(scope="module")
-def store(tmp_path_factory):
+def load(root):
     """-> (engine, hits [BUCKETS, SERIES] int64): a day a message, each
     flushed, as benchmarks/e2e/run.py loads configs/ep400k.json."""
-    root = tmp_path_factory.mktemp("ep400k")
     reg = SchemaRegistry(root / "schema")
     opts = ResourceOpts(shard_num=SHARDS, segment_interval=IntervalRule(15, "day"))
     reg.create_group(Group("g", Catalog.MEASURE, opts))
@@ -116,7 +118,36 @@ def store(tmp_path_factory):
         )
         assert written == SERIES
         eng.flush()
+    return eng, hits
+
+
+@pytest.fixture(scope="module")
+def store(tmp_path_factory):
+    eng, hits = load(tmp_path_factory.mktemp("ep400k"))
     yield eng, hits
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def whole_dict_store(tmp_path_factory):
+    """The same rows as `store`, every part holding its message's whole
+    dictionary in the message's order: what a part written before ISSUE 38
+    holds (`MemTable.append_bulk` entered every value of a `DictColumn`)."""
+    append_bulk = MemTable.append_bulk
+
+    def whole_dicts(self, ts, sids, versions, tags, fields, payloads=None):
+        with self._lock:
+            for t, col in tags.items():
+                if isinstance(col, DictColumn):
+                    d = self._dicts[t]
+                    for v in col.values:
+                        d.setdefault(v, len(d))
+        append_bulk(self, ts, sids, versions, tags, fields, payloads)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(MemTable, "append_bulk", whole_dicts)
+        eng, _ = load(tmp_path_factory.mktemp("ep400k-whole"))
+    yield eng
     eng.close()
 
 
@@ -194,8 +225,8 @@ def _counted(name: str) -> float:
 @pytest.mark.parametrize("region, b0, nb", DRAWS)
 def test_top10_is_the_references_query_after_query(store, region, b0, nb):
     """The kept state: every query finds the group space over the bound,
-    and all of it live (a part holds one shard's rows of one day and its
-    message's whole dictionary, so every source references every name):
+    and all of it live (a part holds one shard's rows of one day and the
+    names they use, so the sources of one day reference every name):
     the `DictState` is kept, every table comes from `dict_state.remaps`
     and no dictionary entry is walked.  The answer is the reference's
     every time."""
@@ -228,6 +259,37 @@ def test_every_group_and_count_is_the_references(store, region, b0, nb):
     assert len(want) == SERIES - SERIES // REGIONS
     compare(got, want, top=None)
     assert [v for _, v in got.values()] == [float(want[g][1]) for g in got]  # exact, in fact
+
+
+def part_dict_sizes(eng) -> dict:
+    """-> {shard: sizes of its parts' `svc` dictionaries}."""
+    return {
+        i: {len(part.dict_for("svc")) for part in shard.parts}
+        for seg in eng._tsdb("g").segments for i, shard in enumerate(seg.shards)
+    }
+
+
+@pytest.mark.parametrize("tail", ["TOP 10 BY hits", f"LIMIT {SERIES}"], ids=["top10", "listing"])
+@pytest.mark.parametrize("region, b0, nb", DRAWS)
+def test_the_reply_is_the_whole_dictionary_stores_byte_for_byte(
+    store, whole_dict_store, region, b0, nb, tail,
+):
+    """A part that holds only the names its rows use (a quarter of the
+    message's) changes the order in which a query's global codes are
+    given, not one byte of a reply: a listing and the members of a Top-N
+    whose values tie are ordered by the group key's bytes.  A part written
+    before ISSUE 38 stays readable."""
+    eng, _ = store
+    assert part_dict_sizes(whole_dict_store) == {i: {SERIES} for i in range(SHARDS)}
+    sizes = part_dict_sizes(eng)  # a shard's series, the same every day
+    assert all(len(s) == 1 for s in sizes.values())
+    assert sum(s.pop() for s in sizes.values()) == SERIES
+    ql = ql_of(region, b0, nb, tail, off=101 + region)
+    replies = []
+    for e in (eng, whole_dict_store):
+        _, req = bydbql.parse_with_catalog(ql)
+        replies.append(json.dumps(result_to_json(e.query(req))))
+    assert replies[0] == replies[1]
 
 
 def test_the_method_taken_is_sort(store):
@@ -266,7 +328,9 @@ def test_under_the_bound_the_second_query_builds_no_table(store, monkeypatch):
     eng._dict_state("g", "m").reset()
     over = [serve(eng, ql_of(region, b0, nb, tail, off=21 + i)) for i, tail in enumerate(tails)]
     tags = [spans["gather"] for _, spans in over]
-    assert [t["lut_entries"] for t in tags] == [nb * SHARDS * (SERIES + REGIONS), 0]
+    # a day's four sources: their shards' names, SERIES together, and the
+    # regions each
+    assert [t["lut_entries"] for t in tags] == [nb * (SERIES + SHARDS * REGIONS), 0]
     # the first found an empty state and left it over the bound
     assert [t["dict_over_bound"] for t in tags] == [False, True]
     assert [t["dict_live_share"] for t in tags] == [100.0, 100.0] and len(counts) == 2
@@ -310,7 +374,7 @@ def test_a_state_more_than_half_dead_is_reset_once(store):
     compare(got, want, top=10)
     gather = spans["gather"]
     assert gather["dict_reset"] is True and gather["dict_over_bound"] is True
-    assert gather["lut_entries"] == nb * SHARDS * (SERIES + REGIONS)
+    assert gather["lut_entries"] == nb * (SERIES + SHARDS * REGIONS)
     assert spans["reduce"]["groups"] == SERIES and st.token != token
     assert gather["dict_live_share"] == 100.0
     token = st.token

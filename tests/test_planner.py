@@ -210,6 +210,125 @@ def test_group_method_override_only_when_crossover_flips(tmp_path):
     assert static == "sort" and dynamic != "sort"
 
 
+def _layout_db(shards: int, parts: int, names: int, rows: int):
+    """Part metadata of one segment as `estimate_scan` reads it: `shards`
+    shards of `parts` parts, each with `rows` rows in one block inside
+    the range and a `svc` dictionary of `names` entries (8 regions)."""
+    from types import SimpleNamespace
+
+    def part():
+        sizes = {"svc": names, "region": 8}
+        return SimpleNamespace(
+            meta={"measure": "m", "tags": ["svc", "region"]},
+            blocks=[{"min_ts": T0, "max_ts": T0 + 1, "count": rows}],
+            dict_for=lambda t: range(sizes[t]),
+        )
+
+    seg = SimpleNamespace(shards=[
+        SimpleNamespace(parts=[part() for _ in range(parts)], hot_columns=lambda name: [])
+        for _ in range(shards)
+    ])
+    return SimpleNamespace(select_segments=lambda begin, end: [seg])
+
+
+# (cell, shards, parts a shard, names a part, the cell's G, the method it
+# runs, the pin plan_scan gives): each part holds the names its rows use
+# (ISSUE 38), then the same parts as the parent wrote them, each with its
+# message's whole dictionary
+_LAYOUTS = [
+    ("r1ep9k", 2, 1, 4_500, 9_000, "scatter", None),
+    ("r1ep9k-2h", 2, 2, 4_500, 9_000, "scatter", None),
+    ("ep9k-4", 2, 2, 4_500, 9_000, "scatter", None),
+    ("ep9k-8", 2, 4, 4_500, 9_000, "scatter", None),
+    ("ep400k", 4, 7, 100_000, 400_000, "sort", None),
+    ("topn100k", 4, 2, 25_000, 100_000, "sort", None),
+    ("svc1k", 2, 2, 500, 1_000, "pallas", None),
+    ("parent-r1ep9k", 2, 1, 9_000, 9_000, "scatter", None),
+    ("parent-ep9k-8", 2, 4, 9_000, 9_000, "scatter", "scatter"),
+    ("parent-ep400k", 4, 7, 400_008, 400_000, "sort", None),
+]
+
+
+@pytest.mark.parametrize(
+    "shards, parts, names, groups, method, pin",
+    [layout[1:] for layout in _LAYOUTS], ids=[layout[0] for layout in _LAYOUTS],
+)
+def test_plan_pins_no_other_method_at_the_cells_layouts(
+    monkeypatch, shards, parts, names, groups, method, pin,
+):
+    """On a TPU the planner pins a group-by method only where its estimate
+    of the groups lands on another side of a crossover than the static
+    product of the dictionaries' sizes.  `svc` is the measure's whole
+    entity, so shards hold disjoint names and the estimate's lower bound
+    is the sum of the shards' largest dictionaries: with dictionaries that
+    hold only what a part's rows use, no cell's layout is pinned and each
+    runs the method it ran.  The parent's `ep9k` queries over 8 parts were
+    pinned to `scatter` against a static product past the sort threshold;
+    they now run `scatter` under `auto`.  The per-part largest dictionary
+    alone, the bound before ISSUE 38, would pin `r1ep9k`'s 2 parts of
+    4,500 names to `pallas`, above its 8,192 limit at G = 9,000."""
+    import jax
+
+    from banyandb_tpu.ops import groupby
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    m = Measure(
+        group="g", name="m",
+        tags=(TagSpec("svc", TagType.STRING), TagSpec("region", TagType.STRING)),
+        fields=(FieldSpec("v", FieldType.INT),), entity=Entity(("svc",)),
+    )
+    db = _layout_db(shards, parts, names, rows=names * 60)
+    d = planner.plan_scan(None, db, m, _req(
+        group_by=GroupBy(("svc",)), agg=Aggregation("sum", "v"), top=Top(10, "v"),
+    ))
+    nrows = min(d.est.scan_rows, 1 << 20)
+    assert d.est.static_groups == shards * parts * names
+    assert d.group_method == pin
+    # what the executor runs at the cell's G: the pin, else `auto`
+    assert groupby.resolve_group_method(d.group_method or "auto", nrows, groups) == method
+    if pin is not None:  # a pin only against the static product's side
+        assert groupby.select_group_method(nrows, d.est.static_groups) != pin
+    if (shards, parts, names) == (2, 1, 4_500):
+        per_part_bound = int((names * d.est.static_groups) ** 0.5)
+        assert groupby.select_group_method(nrows, per_part_bound) == "pallas"
+
+
+def test_an_r1ep9k_store_plans_scatter_on_a_tpu(tmp_path, monkeypatch):
+    """The real write path at `r1ep9k`'s widths: a message of 9,000 names
+    over 2 shards leaves each part the ~4,500 its rows use, and a Top-N
+    over the two parts is left to `auto`, which is `scatter` at G = 9,000."""
+    import jax
+
+    from banyandb_tpu.models.measure import DictColumn
+    from banyandb_tpu.ops import groupby
+
+    eng = _engine(tmp_path, shard_num=2)
+    n = 9_000
+    svc = np.arange(n, dtype=np.int32)
+    eng.write_columns(
+        "g", "m", ts_millis=np.full(n, T0, np.int64),
+        tags={
+            "svc": DictColumn(["svc_%06d" % i for i in range(n)], svc),
+            "region": DictColumn(["r%d" % i for i in range(8)], svc % 8),
+        },
+        fields={"v": (svc % 999).astype(np.float64), "lat": np.ones(n)},
+        versions=np.ones(n, np.int64),
+    )
+    eng.flush()
+    db = eng._tsdb("g")
+    m = eng.registry.get_measure("g", "m")
+    sizes = [len(p.dict_for("svc")) for seg in db.segments for s in seg.shards for p in s.parts]
+    assert len(sizes) == 2 and sum(sizes) == n and max(sizes) < 8_192
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d = planner.plan_scan(eng, db, m, _req(
+        group_by=GroupBy(("svc",)), agg=Aggregation("sum", "v"), top=Top(10, "v"),
+    ))
+    assert d.est.groups == n and d.est.static_groups == n
+    assert d.group_method is None
+    assert groupby.resolve_group_method("auto", n, n) == "scatter"
+    eng.close()
+
+
 def test_planner_module_is_host_only():
     """The kernel-budget hygiene pin (docs/linting.md, the streamagg
     ingest exemption pattern): the planner is metadata-only — it must
