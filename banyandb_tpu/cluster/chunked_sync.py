@@ -17,6 +17,7 @@ completion chunk.
 from __future__ import annotations
 
 import json
+import threading
 import time
 import uuid
 import zlib
@@ -31,6 +32,8 @@ SERVICE = "banyandb.cluster.v1.ChunkedSyncService"
 METHOD = f"/{SERVICE}/SyncPart"
 CHUNK_SIZE = 1 << 20
 API_VERSION = "1.0"
+# how long a stream's iteration may outlive its status (sync_part_dirs)
+_WEDGE_GRACE_S = 2.0
 
 
 def _crc(data: bytes) -> str:
@@ -234,7 +237,7 @@ def parse_epoch_topic(topic: str) -> "tuple[str, int | None]":
 
 
 def sync_part_dirs(
-    channel: grpc.Channel,
+    dial: Callable[[], grpc.Channel],
     part_dirs: Iterable[str | Path],
     *,
     group: str,
@@ -247,11 +250,16 @@ def sync_part_dirs(
 ):
     """Ship sealed part dirs over one SyncPart stream; -> SyncResult.
 
+    dial() -> the channel the stream rides, of its own: this closes it
+    on return (GrpcTransport.channel dials one).
+
     placement_epoch: optional epoch fence — stamped as a ``@epoch=N``
     topic suffix so the receiver can reject sessions from a sender
     routing on a superseded placement map.
 
-    Raises TransportError on any non-OK chunk status or stream failure.
+    Raises TransportError on any non-OK chunk status or stream failure,
+    at the latest ``_WEDGE_GRACE_S`` after the call's status (which its
+    deadline, ``timeout``, gives at the latest).
     """
     from banyandb_tpu.cluster import faults
     from banyandb_tpu.cluster.rpc import TransportError
@@ -351,23 +359,38 @@ def sync_part_dirs(
             fin = injector.mutate_request(fin)
         yield fin
 
-    call = channel.stream_stream(
-        METHOD,
-        request_serializer=lambda m: m.SerializeToString(),
-        response_deserializer=rpcpb.SyncPartResponse.FromString,
-    )
-    final = None
-    try:
-        for resp in call(requests(), timeout=timeout):
-            if resp.status not in (1, 5):  # RECEIVED | COMPLETE
-                raise TransportError(
-                    f"sync chunk {resp.chunk_index} failed: "
-                    f"status={resp.status} {resp.error}"
-                )
-            if resp.status == 5:
-                final = resp.sync_result
-    except grpc.RpcError as e:
-        raise TransportError(f"sync stream failed: {e.code()}") from e
+    with dial() as channel:
+        call = channel.stream_stream(
+            METHOD,
+            request_serializer=lambda m: m.SerializeToString(),
+            response_deserializer=rpcpb.SyncPartResponse.FromString,
+        )
+        responses = call(requests(), timeout=timeout)
+        # A call that fails as its peer goes away (stopped with grace=0)
+        # can get its status while grpc (1.76) never completes its
+        # pending receive: the iterator then waits forever, deadline or
+        # not.  So the status starts a timer that closes the channel, which
+        # completes every batch still pending on it, if the iteration has
+        # not ended within the grace.
+        guard = threading.Timer(_WEDGE_GRACE_S, channel.close)
+        if not responses.add_callback(guard.start):
+            guard.start()  # the status is in already
+        final = None
+        try:
+            for resp in responses:
+                if resp.status not in (1, 5):  # RECEIVED | COMPLETE
+                    raise TransportError(
+                        f"sync chunk {resp.chunk_index} failed: "
+                        f"status={resp.status} {resp.error}"
+                    )
+                if resp.status == 5:
+                    final = resp.sync_result
+        except grpc.RpcError as e:
+            raise TransportError(f"sync stream failed: {e.code()}") from e
+        finally:
+            guard.cancel()
+            if guard.ident is not None:
+                guard.join()
     if final is None or not final.success:
         raise TransportError(
             f"sync incomplete: {final.parts_results[0].error if final and final.parts_results else 'no completion'}"

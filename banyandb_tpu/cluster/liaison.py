@@ -15,6 +15,7 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 import uuid
@@ -628,9 +629,9 @@ class Liaison:
                     errors.append(f"{node.name} down")
                     continue
                 try:
-                    chan = self.transport.channel(node.addr)
                     chunked_sync.sync_part_dirs(
-                        chan, [part_dir], group=group, shard_id=shard,
+                        functools.partial(self.transport.channel, node.addr),
+                        [part_dir], group=group, shard_id=shard,
                         # the epoch fence rides the stream topic: a
                         # straggling shipper's sealed part from before
                         # a cutover is rejected instead of installed on
@@ -653,12 +654,6 @@ class Liaison:
                         errors.append(f"{node.name}: {e}")
                         continue
                     self._mark_dead(node.name)
-                    # drop the stream's channel: a wedged one would
-                    # otherwise poison every retry after the node
-                    # returns (rpc.GrpcTransport.evict)
-                    evict = getattr(self.transport, "evict", None)
-                    if evict is not None:
-                        evict(node.addr)
                     errors.append(f"{node.name}: {e}")
             if errors or not delivered:
                 raise TransportError(

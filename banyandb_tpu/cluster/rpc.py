@@ -415,36 +415,37 @@ class GrpcTransport:
         self._ca_file = ca_file
         self._server_name_override = server_name_override
 
+    def channel(self, addr: str):
+        """-> a new channel to addr for one stream; the caller closes it
+        (chunked_sync.sync_part_dirs dials through this and does).  A
+        stream never rides the cached channel of call(): one opened on a
+        channel that has just taken its peer's GOAWAY can wait forever,
+        and the stream's wedge guard closes the channel it rides."""
+        import grpc
+
+        options = [
+            ("grpc.max_receive_message_length", 64 * 1024 * 1024),
+            ("grpc.max_send_message_length", 64 * 1024 * 1024),
+        ]
+        if not self._ca_file:
+            return grpc.insecure_channel(addr, options=options)
+        from pathlib import Path as _P
+
+        creds = grpc.ssl_channel_credentials(_P(self._ca_file).read_bytes())
+        if self._server_name_override:
+            options.append(
+                ("grpc.ssl_target_name_override", self._server_name_override)
+            )
+        return grpc.secure_channel(addr, creds, options=options)
+
     def _stub(self, addr: str):
         """-> (unary-unary stub, the channel it rides) for addr.  The
         channel is returned so a failing call can evict exactly the
         channel it used (see _evict)."""
-        import grpc
-
         with self._lock:
             ch = self._channels.get(addr)
             if ch is None:
-                options = [
-                    ("grpc.max_receive_message_length", 64 * 1024 * 1024),
-                    ("grpc.max_send_message_length", 64 * 1024 * 1024),
-                ]
-                if self._ca_file:
-                    from pathlib import Path as _P
-
-                    creds = grpc.ssl_channel_credentials(
-                        _P(self._ca_file).read_bytes()
-                    )
-                    if self._server_name_override:
-                        options.append(
-                            (
-                                "grpc.ssl_target_name_override",
-                                self._server_name_override,
-                            )
-                        )
-                    ch = grpc.secure_channel(addr, creds, options=options)
-                else:
-                    ch = grpc.insecure_channel(addr, options=options)
-                self._channels[addr] = ch
+                ch = self._channels[addr] = self.channel(addr)
             return (
                 ch.unary_unary(
                     _METHOD,
@@ -453,20 +454,6 @@ class GrpcTransport:
                 ),
                 ch,
             )
-
-    def channel(self, addr: str):
-        """Raw grpc channel for streaming services (chunked sync)."""
-        return self._stub(addr)[1]
-
-    def evict(self, addr: str) -> None:
-        """Public eviction for STREAMING users: a failed SyncPart stream
-        never passes through call(), so its wedged channel would survive
-        the UNAVAILABLE-eviction below and poison every retry against a
-        restarted peer (same gVisor-class wedge, see _evict).  Dropping
-        the cache entry makes the next dial fresh; the old channel is
-        released when its last user lets go."""
-        with self._lock:
-            self._channels.pop(addr, None)
 
     def _evict(self, addr: str, failed) -> None:
         """Drop the channel a call just failed on so the next call dials
@@ -478,9 +465,8 @@ class GrpcTransport:
         retry — while a fresh dial to the same address connects
         instantly.  Evicting on UNAVAILABLE bounds the damage to one
         failed call.  Identity-checked (a concurrent re-dial's healthy
-        replacement is never dropped) and NOT closed: a streaming user
-        (chunked sync holds channels via .channel()) may still ride it,
-        and close() would cancel its in-flight RPCs — the dropped
+        replacement is never dropped) and NOT closed: a concurrent call
+        may still ride it, and close() would cancel it — the dropped
         channel is released when its last user lets go."""
         with self._lock:
             if self._channels.get(addr) is failed:

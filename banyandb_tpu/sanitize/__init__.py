@@ -10,8 +10,8 @@ whole pytest run).  ``install()`` is idempotent and does three things:
    acquisition-order witness edges mapped to their static declaration
    identities (lockwatch.py);
 2. enables ``faulthandler`` so a wedged process dumps every thread's
-   stack on SIGABRT/SIGSEGV and on the per-test watchdog
-   (``arm_watchdog``/``disarm_watchdog``);
+   stack on SIGABRT/SIGSEGV (``arm_watchdog``/``disarm_watchdog``, the
+   per-test-phase watchdog, also fail a unit of work that hangs);
 3. exposes the leak-tracking primitives (leaks.py) the conftest
    thread-parity fixture and the stress tests build on.
 
@@ -51,16 +51,46 @@ def installed() -> bool:
     return _installed
 
 
-def arm_watchdog(timeout_s: float) -> None:
-    """Dump every thread's traceback if the process is still inside the
-    current unit of work after ``timeout_s`` (non-fatal; the dump goes to
-    stderr and work continues).  Re-arming replaces the previous timer."""
-    import faulthandler
+class WatchdogExpired(BaseException):
+    """Raised in the main thread when a unit of work outlives its
+    watchdog.  A BaseException, so an ``except Exception`` around the
+    wait that hung cannot swallow it."""
 
-    faulthandler.dump_traceback_later(timeout_s, exit=False)
+
+def arm_watchdog(timeout_s: float, what: str, dump_fd: int) -> None:
+    """Fail ``what`` if it is still running after ``timeout_s``: write
+    every thread's stack to ``dump_fd`` and raise WatchdogExpired in the
+    main thread.  A SIGALRM timer does it, so it interrupts sleep,
+    Lock.acquire, Thread.join and Event.wait; the main thread must call
+    this.  A wait no signal interrupts (a C call that never returns to
+    the interpreter) is ended at 1.25 x ``timeout_s`` by faulthandler,
+    which dumps the stacks and exits the process.  Re-arming replaces
+    both timers."""
+    import faulthandler
+    import os
+    import signal
+
+    def expired(signum, frame):
+        os.write(
+            dump_fd,
+            f"\n+++ bdsan watchdog: {what} still running after "
+            f"{timeout_s:g} s; every thread's stack: +++\n".encode(),
+        )
+        faulthandler.dump_traceback(dump_fd, all_threads=True)
+        raise WatchdogExpired(
+            f"{what} still running after {timeout_s:g} s "
+            "(BYDB_SANITIZE_WATCHDOG_S); the stacks are on stderr"
+        )
+
+    signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, timeout_s)
+    faulthandler.dump_traceback_later(timeout_s * 1.25, exit=True, file=dump_fd)
 
 
 def disarm_watchdog() -> None:
     import faulthandler
+    import signal
 
+    signal.setitimer(signal.ITIMER_REAL, 0)
+    signal.signal(signal.SIGALRM, signal.SIG_DFL)
     faulthandler.cancel_dump_traceback_later()

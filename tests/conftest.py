@@ -3,9 +3,15 @@ tests run without TPU hardware (matches the driver's dryrun harness).
 
 The whole run executes under the bdsan runtime sanitizers
 (BYDB_SANITIZE=1, docs/sanitizers.md): package locks are traced for
-lock-order witnesses, faulthandler arms a per-test dump-on-timeout
-watchdog, and every test must end with the thread set it started with
-(allowlisted process-wide daemons excepted) — the gleak analog."""
+lock-order witnesses, every test must end with the thread set it
+started with (allowlisted process-wide daemons excepted) — the gleak
+analog — and a watchdog fails a hung test.  Each phase of a test
+(setup, call, teardown: a module- or session-scoped fixture's too) has
+BYDB_SANITIZE_WATCHDOG_S (180 s) to end.  One that does not is failed
+in its own worker, which runs on: the log shows a line
+``+++ bdsan watchdog: <test id> (<phase>) still running after 180 s``,
+every thread's stack under it, and the test as failed with
+``WatchdogExpired``."""
 
 import os
 
@@ -47,10 +53,11 @@ if sanitize.enabled():
     # every lock they construct is traced with its declaration identity
     sanitize.install()
 
-# One test may legitimately outlive this only by hanging: the watchdog
-# dumps every thread's stack (non-fatal) so a wedged run leaves evidence
-# instead of a silent timeout kill.
+# One phase of a test (setup, call, teardown) may outlive this only by
+# hanging: the watchdog then fails it in its own worker, with every
+# thread's stack on the worker's real stderr, and the run goes on.
 _TEST_WATCHDOG_S = float(os.environ.get("BYDB_SANITIZE_WATCHDOG_S", "180"))
+_real_stderr = None  # a dup of fd 2 taken outside pytest's capture
 
 
 def pytest_configure(config):
@@ -58,11 +65,18 @@ def pytest_configure(config):
     cold) so the C paths are TESTED, never skipped: test_native.py's
     skipif evaluates after this.  A failed build degrades to the old
     skip behavior rather than failing collection."""
+    global _real_stderr
     config.addinivalue_line(
         "markers",
         "slow: long-running E2E; tier-1 runs -m 'not slow' (ROADMAP.md), "
         "fast smoke variants keep the coverage",
     )
+    capman = config.pluginmanager.getplugin("capturemanager")
+    if capman is None:  # -p no:capture: fd 2 is never redirected
+        _real_stderr = os.dup(2)
+    else:
+        with capman.global_and_fixture_disabled():
+            _real_stderr = os.dup(2)
     from banyandb_tpu.utils import native
 
     try:
@@ -71,23 +85,46 @@ def pytest_configure(config):
         print(f"# native build unavailable ({exc}); native tests will skip")
 
 
+def _watched(item, phase):
+    """Run one phase of a test under the bdsan watchdog."""
+    if not sanitize.enabled():
+        return (yield)
+    sanitize.arm_watchdog(_TEST_WATCHDOG_S, f"{item.nodeid} ({phase})", _real_stderr)
+    try:
+        return (yield)
+    finally:
+        sanitize.disarm_watchdog()
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    return (yield from _watched(item, "setup"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    return (yield from _watched(item, "call"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    return (yield from _watched(item, "teardown"))
+
+
 @pytest.fixture(autouse=True)
 def _bdsan_guard(request):
-    """Per-test sanitizer envelope: arm the faulthandler watchdog and
-    enforce thread-count parity (ROADMAP item 8).  Baseline is captured
-    at test start, so a long-lived fixture's threads (set up earlier at
-    higher scope) never count; anything the test itself started and
-    failed to stop fails the test after a grace window."""
+    """Per-test thread-count parity (ROADMAP item 8).  Baseline is
+    captured at test start, so a long-lived fixture's threads (set up
+    earlier at higher scope) never count; anything the test itself
+    started and failed to stop fails the test after a grace window."""
     if not sanitize.enabled():
         yield
         return
     from banyandb_tpu.sanitize import leaks
 
-    sanitize.arm_watchdog(_TEST_WATCHDOG_S)
     before = leaks.thread_snapshot()
     before_procs = leaks.process_snapshot()
     yield
-    sanitize.disarm_watchdog()
     leaked = leaks.leaked_threads(before, grace_s=5.0)
     if leaked:
         names = ", ".join(f"{t.name} (ident={t.ident})" for t in leaked)

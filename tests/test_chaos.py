@@ -161,7 +161,10 @@ def test_streamagg_failover_windows_gap_free(tmp_path, monkeypatch):
 def test_chaos_soak(tmp_path):
     import os
 
-    seconds = float(os.environ.get("BYDB_CHAOS_SECONDS", "90"))
+    # default 45: the call phase is the soak plus four boots, three
+    # restarts and the convergence wait, and must end inside
+    # BYDB_SANITIZE_WATCHDOG_S (180 s) on a loaded runner too
+    seconds = float(os.environ.get("BYDB_CHAOS_SECONDS", "45"))
     stats = chaos.run_soak(tmp_path / "soak", seconds=seconds)
     assert stats["kill_cycles"] >= 3
     assert stats["degraded_seen"] >= 1

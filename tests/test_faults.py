@@ -310,12 +310,10 @@ def sync_stack(tmp_path):
     )
     port = server.add_insecure_port("127.0.0.1:0")
     server.start()
-    chan = grpc.insecure_channel(f"127.0.0.1:{port}")
     part = tmp_path / "0000000000000001-0001"
     part.mkdir()
     (part / "primary.bin").write_bytes(b"\x07" * 4096)
-    yield chan, part, installs
-    chan.close()
+    yield lambda: grpc.insecure_channel(f"127.0.0.1:{port}"), part, installs
     server.stop(grace=0.2).wait()
     pool.shutdown(wait=True)
 
@@ -323,11 +321,11 @@ def sync_stack(tmp_path):
 def test_sync_boundary_plane_driven(sync_stack):
     from banyandb_tpu.cluster import chunked_sync
 
-    chan, part, installs = sync_stack
+    dial, part, installs = sync_stack
 
     def ship():
         return chunked_sync.sync_part_dirs(
-            chan, [part], group="g", shard_id=0
+            dial, [part], group="g", shard_id=0
         )
 
     faults.configure("sync=corrupt:every=1:count=1")

@@ -215,6 +215,79 @@ def test_thread_grace_window_tolerates_finishing_threads():
     t.join()
 
 
+# -- the per-phase watchdog ---------------------------------------------
+
+_HANGS = {
+    # a test blocked in its call
+    "call": """
+def test_blocked():
+    _ran()
+    threading.Event().wait()
+""",
+    # a test blocked in a module-scoped fixture's setup
+    "setup": """
+@pytest.fixture(scope="module")
+def stuck():
+    _ran()
+    threading.Event().wait()
+    yield
+
+
+def test_blocked(stuck):
+    pass
+""",
+}
+
+
+@pytest.mark.parametrize("phase", sorted(_HANGS))
+def test_watchdog_fails_a_hung_test_and_the_run_goes_on(tmp_path, phase):
+    """Under xdist, a test that hangs past BYDB_SANITIZE_WATCHDOG_S fails
+    once, in its own worker, with every thread's stack on the
+    controller's stderr, and the tests beside it still run and pass."""
+    import subprocess
+    import sys
+    import xml.etree.ElementTree as ET
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parent.parent
+    mark = tmp_path / "ran"
+    (tmp_path / "test_hang.py").write_text(
+        "import threading\n\nimport pytest\n\n\n"
+        f"def _ran():\n    with open({str(mark)!r}, 'a') as f:\n"
+        "        f.write('ran\\n')\n\n\n"
+        "def test_before():\n    pass\n\n"
+        + _HANGS[phase]
+        + "\n\ndef test_after():\n    pass\n"
+    )
+    xml = tmp_path / "junit.xml"
+    env = dict(os.environ, BYDB_SANITIZE="1", BYDB_SANITIZE_WATCHDOG_S="2",
+               PYTHONPATH=str(repo))
+    t0 = time.monotonic()
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", str(tmp_path / "test_hang.py"),
+         "-p", "tests.conftest", "-p", "xdist", "-n", "2", "--dist",
+         "loadfile", "-p", "no:cacheprovider", "-p", "no:randomly", "-q",
+         f"--junitxml={xml}", f"--rootdir={tmp_path}"],
+        cwd=repo, env=env, capture_output=True, text=True, timeout=120,
+    )
+    out = proc.stdout + proc.stderr
+    assert proc.returncode == 1, out
+    cases = {c.get("name"): c for c in ET.parse(xml).getroot().iter("testcase")}
+    assert sorted(cases) == ["test_after", "test_before", "test_blocked"], out
+    for name in ("test_before", "test_after"):
+        assert len(cases[name]) == 0, out  # passed: no failure/error child
+    (verdict,) = cases["test_blocked"]
+    assert verdict.tag == ("failure" if phase == "call" else "error"), out
+    assert "WatchdogExpired" in verdict.get("message", ""), out
+    assert float(cases["test_blocked"].get("time")) < 2 + 30
+    assert mark.read_text() == "ran\n"  # run once: xdist did not re-run it
+    blocked_fn = "test_blocked" if phase == "call" else "stuck"
+    assert f"+++ bdsan watchdog: test_hang.py::test_blocked ({phase})" in out
+    assert f"test_hang.py\", line" in out and f"in {blocked_fn}" in out, out
+    assert "node down" not in out, out
+    assert time.monotonic() - t0 < 100
+
+
 # -- the capstone stress ------------------------------------------------
 
 
@@ -451,8 +524,9 @@ def test_stress_smoke_one_shard(tmp_path):
 
 @pytest.mark.slow
 def test_stress_one_shard_sustained(tmp_path):
-    """Minutes-long stress (BYDB_STRESS_SECONDS overrides, default 180)."""
-    seconds = float(os.environ.get("BYDB_STRESS_SECONDS", "180"))
+    """Minutes-long stress (BYDB_STRESS_SECONDS overrides, default 120:
+    its call phase must end inside BYDB_SANITIZE_WATCHDOG_S, 180 s)."""
+    seconds = float(os.environ.get("BYDB_STRESS_SECONDS", "120"))
     _assert_stress_clean(
         _run_stress(tmp_path, seconds=seconds, writers=3, queriers=3)
     )

@@ -5,7 +5,9 @@ Every test drives the REAL wire: a grpc server hosting SyncPart and a
 client shipping real part dirs, with deterministic faults injected at
 the sender."""
 
+import functools
 import threading
+import time
 from concurrent import futures
 
 import pytest
@@ -33,34 +35,33 @@ def sync_stack(tmp_path):
     server.add_generic_rpc_handlers((chunked_sync.generic_handler(install_cb),))
     port = server.add_insecure_port("127.0.0.1:0")
     server.start()
-    chan = grpc.insecure_channel(f"127.0.0.1:{port}")
+    dial = functools.partial(grpc.insecure_channel, f"127.0.0.1:{port}")
 
     part = tmp_path / "0000000000000001-0001"
     part.mkdir()
     (part / "primary.bin").write_bytes(b"\x07" * 4096)
     (part / "timestamps.bin").write_bytes(b"\x01" * 512)
 
-    yield chan, part, installs
+    yield dial, part, installs
     chunked_sync.clear_failure_injector()
-    chan.close()
     server.stop(grace=0.2).wait()
     pool.shutdown(wait=True)
 
 
-def _ship(chan, part):
-    return chunked_sync.sync_part_dirs(chan, [part], group="g", shard_id=0)
+def _ship(dial, part):
+    return chunked_sync.sync_part_dirs(dial, [part], group="g", shard_id=0)
 
 
 def test_no_injector_baseline(sync_stack):
-    chan, part, installs = sync_stack
-    res = _ship(chan, part)
+    dial, part, installs = sync_stack
+    res = _ship(dial, part)
     assert res.success and res.parts_received == 1
     assert installs[0][0] == "g"
     assert installs[0][1][0]["primary.bin"] == b"\x07" * 4096
 
 
 def test_before_sync_short_circuit(sync_stack):
-    chan, part, installs = sync_stack
+    dial, part, installs = sync_stack
 
     class Inj(chunked_sync.SyncFailureInjector):
         def before_sync(self, part_dirs):
@@ -69,16 +70,16 @@ def test_before_sync_short_circuit(sync_stack):
 
     chunked_sync.register_failure_injector(Inj())
     with pytest.raises(TransportError, match="injected"):
-        _ship(chan, part)
+        _ship(dial, part)
     assert installs == []  # the stream never opened
 
     # clearing the injector restores the path (queue.go:250 analog)
     chunked_sync.clear_failure_injector()
-    assert _ship(chan, part).success
+    assert _ship(dial, part).success
 
 
 def test_corrupted_chunk_rejected_by_receiver_crc(sync_stack):
-    chan, part, installs = sync_stack
+    dial, part, installs = sync_stack
 
     class Inj(chunked_sync.SyncFailureInjector):
         def mutate_request(self, req):
@@ -89,12 +90,12 @@ def test_corrupted_chunk_rejected_by_receiver_crc(sync_stack):
 
     chunked_sync.register_failure_injector(Inj())
     with pytest.raises(TransportError, match="status=2"):  # CRC mismatch
-        _ship(chan, part)
+        _ship(dial, part)
     assert installs == []  # no partial install
 
 
 def test_out_of_order_chunk_rejected(sync_stack):
-    chan, part, installs = sync_stack
+    dial, part, installs = sync_stack
 
     class Inj(chunked_sync.SyncFailureInjector):
         def mutate_request(self, req):
@@ -104,12 +105,12 @@ def test_out_of_order_chunk_rejected(sync_stack):
 
     chunked_sync.register_failure_injector(Inj())
     with pytest.raises(TransportError, match="status=3"):  # OUT_OF_ORDER
-        _ship(chan, part)
+        _ship(dial, part)
     assert installs == []
 
 
 def test_stream_killed_mid_flight(sync_stack):
-    chan, part, installs = sync_stack
+    dial, part, installs = sync_stack
 
     class Boom(RuntimeError):
         pass
@@ -122,14 +123,104 @@ def test_stream_killed_mid_flight(sync_stack):
 
     chunked_sync.register_failure_injector(Inj())
     with pytest.raises((TransportError, Boom)):
-        _ship(chan, part)
+        _ship(dial, part)
     assert installs == []  # receiver never installed a half sync
 
     # recovery: the same sealed part ships cleanly on retry (the spool
     # contract — a failed ship leaves the part intact for the next tick)
     chunked_sync.clear_failure_injector()
-    assert _ship(chan, part).success
+    assert _ship(dial, part).success
     assert len(installs) == 1
+
+
+class _WedgedCall(grpc.RpcError):
+    """A SyncPart call that has its status but whose pending receive
+    never completes until its channel closes: what grpc (1.76) did to a
+    stream whose peer stopped with grace=0."""
+
+    def __init__(self, closed):
+        self._closed = closed
+
+    def add_callback(self, callback):
+        return False  # the status is in already
+
+    def code(self):
+        return grpc.StatusCode.CANCELLED
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        self._closed.wait()
+        raise self
+
+
+class _WedgedChannel:
+    """A channel whose SyncPart stream wedges; dial() is the channel."""
+
+    def __init__(self):
+        self.closed = threading.Event()
+
+    def __call__(self):
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+
+    def close(self):
+        self.closed.set()
+
+    def stream_stream(self, method, **_):
+        return lambda requests, timeout: _WedgedCall(self.closed)
+
+
+def test_a_wedged_sync_stream_ends_within_its_grace(tmp_path):
+    """A stream whose status is in but whose iteration never ends gets
+    its channel closed _WEDGE_GRACE_S later and raises TransportError:
+    it never holds the liaison's shipper (in the suite,
+    test_chaos.py::test_streamagg_failover_windows_gap_free hung the
+    driver's tier-1 run on PR 38's tree in this wait)."""
+    part = tmp_path / "p-0001"
+    part.mkdir()
+    (part / "primary.bin").write_bytes(b"z" * 128)
+    chan = _WedgedChannel()
+    t0 = time.monotonic()
+    with pytest.raises(TransportError, match="CANCELLED"):
+        chunked_sync.sync_part_dirs(chan, [part], group="g", shard_id=0)
+    assert chan.closed.is_set()
+    assert time.monotonic() - t0 < chunked_sync._WEDGE_GRACE_S + 3.0
+
+
+def test_sync_to_a_peer_stopped_with_grace_0_ends(tmp_path):
+    """A SyncPart stream on a connected channel whose peer has just
+    stopped with grace=0 raises TransportError inside its timeout and
+    the wedge grace, as the liaison's shipper meets a node killed
+    between its dial and its stream."""
+    from banyandb_tpu.cluster.bus import LocalBus
+    from banyandb_tpu.cluster.rpc import GrpcBusServer, GrpcTransport
+
+    part = tmp_path / "p-0001"
+    part.mkdir()
+    (part / "primary.bin").write_bytes(b"z" * 128)
+    transport = GrpcTransport()
+    for _ in range(10):
+        srv = GrpcBusServer(LocalBus(), sync_install=lambda meta, parts: None)
+        srv.start()
+        assert _ship(functools.partial(transport.channel, srv.addr), part).success
+        chan = transport.channel(srv.addr)
+        grpc.channel_ready_future(chan).result(timeout=10)
+        srv.stop(grace=0)
+        t0 = time.monotonic()
+        with pytest.raises(TransportError):
+            chunked_sync.sync_part_dirs(
+                lambda chan=chan: chan, [part], group="g", shard_id=0,
+                timeout=2.0,
+            )
+        # timeout 2 s + the wedge grace + slack
+        assert time.monotonic() - t0 < 8.0
 
 
 def test_install_failure_reported_in_band(tmp_path):
@@ -144,15 +235,14 @@ def test_install_failure_reported_in_band(tmp_path):
     server.add_generic_rpc_handlers((chunked_sync.generic_handler(install_cb),))
     port = server.add_insecure_port("127.0.0.1:0")
     server.start()
-    chan = grpc.insecure_channel(f"127.0.0.1:{port}")
+    dial = functools.partial(grpc.insecure_channel, f"127.0.0.1:{port}")
     part = tmp_path / "p-0001"
     part.mkdir()
     (part / "primary.bin").write_bytes(b"z" * 128)
     try:
         with pytest.raises(TransportError, match="disk full"):
-            chunked_sync.sync_part_dirs(chan, [part], group="g", shard_id=0)
+            chunked_sync.sync_part_dirs(dial, [part], group="g", shard_id=0)
     finally:
-        chan.close()
         server.stop(grace=0.2).wait()
         pool.shutdown(wait=True)
 

@@ -121,7 +121,7 @@ def test_wqueue_batches_and_ships_100k(cluster):
     assert total_parts >= 2  # at least one sealed part per shard
 
 
-def test_chunked_sync_crc_and_order_rejection(cluster, tmp_path):
+def test_chunked_sync_crc_and_order_rejection(cluster, tmp_path, request):
     """Corrupted chunks are rejected with the proto's status codes."""
     from banyandb_tpu.api import pb
 
@@ -129,6 +129,7 @@ def test_chunked_sync_crc_and_order_rejection(cluster, tmp_path):
     rpcpb = pb.cluster_rpc_pb2
     addr = liaison.selector.nodes[0].addr
     chan = liaison.transport.channel(addr)
+    request.addfinalizer(chan.close)
     call = chan.stream_stream(
         chunked_sync.METHOD,
         request_serializer=lambda m: m.SerializeToString(),
@@ -421,10 +422,12 @@ def test_sync_redelivery_is_idempotent(cluster):
     dn, ni, shard_idx, part_dir = target
     before = part_count(dn)
 
-    chan = liaison.transport.channel(ni.addr)
+    def dial():
+        return liaison.transport.channel(ni.addr)
+
     for _ in range(2):  # re-deliver twice; both must be skipped
         chunked_sync.sync_part_dirs(
-            chan, [part_dir], group="wq", shard_id=shard_idx
+            dial, [part_dir], group="wq", shard_id=shard_idx
         )
     assert part_count(dn) == before
 
@@ -435,5 +438,5 @@ def test_sync_redelivery_is_idempotent(cluster):
         json.loads((dn.root / ".sync-installed.json").read_text())
     )
     assert dn._installed  # persisted record was non-empty
-    chunked_sync.sync_part_dirs(chan, [part_dir], group="wq", shard_id=shard_idx)
+    chunked_sync.sync_part_dirs(dial, [part_dir], group="wq", shard_id=shard_idx)
     assert part_count(dn) == before
