@@ -83,16 +83,19 @@ BUDGETS: dict[str, KernelBudget] = {
     # the builtin measure plan matrix (query/fused_exec): ONE dispatch +
     # ONE batched get per part-batch regardless of chunk count — the
     # executor's raison d'être, ratcheted so staging can never creep
-    # back; puts = stacked chunk columns + traced predicate arrays.
-    "fused/flat-count":        _b(1, 1, 5, 4, 20, 4, 0),
-    "fused/group-eq-lut":      _b(1, 1, 8, 4, 23, 5, 0),
-    "fused/percentile-hist":   _b(1, 1, 6, 4, 25, 5, 0),
-    "fused/or-expr":           _b(1, 1, 7, 4, 20, 4, 0),
-    "fused/topn-dashboard":    _b(1, 1, 7, 4, 23, 5, 0),
+    # back; puts = stacked chunk columns + traced predicate arrays.  Of
+    # the per-row key columns a batch holds what its program reads
+    # (fused_exec.key_columns): valid, and ts + row where the plan tracks
+    # scan order (group-eq-lut; the multi-chunk rows run a flat plan).
+    "fused/flat-count":        _b(1, 1, 2, 4, 20, 4, 0),
+    "fused/group-eq-lut":      _b(1, 1, 7, 4, 23, 5, 0),
+    "fused/percentile-hist":   _b(1, 1, 3, 4, 25, 5, 0),
+    "fused/or-expr":           _b(1, 1, 4, 4, 20, 4, 0),
+    "fused/topn-dashboard":    _b(1, 1, 4, 4, 23, 5, 0),
     # the staging tripwire: a 2-chunk part-batch, still 1 dispatch/get
     # (dispatch columns only: the bucket is synthesized per run, so it
     # has no standing jaxpr/lowering entry)
-    "fused/multi-chunk":       _b(1, 1, 5),
+    "fused/multi-chunk":       _b(1, 1, 2),
     # device-side decode twins (BYDB_DEVICE_DECODE=1, ROADMAP item 3):
     # the compressed ship form — narrow local codes + [S, L] remap LUTs
     # + src-ordinals + narrow int fields — STILL costs exactly one
@@ -100,14 +103,14 @@ BUDGETS: dict[str, KernelBudget] = {
     # puts grow by the LUT/ordinal ships, bytes_class is pinned so the
     # in-program decode can never double the traffic class, and
     # widest=4 proves the i8->i32 widen never leaks 64-bit
-    "fused+decode/flat-count":      _b(1, 1, 5, 4, 20, 5, 0),
-    "fused+decode/group-eq-lut":    _b(1, 1, 11, 4, 23, 5, 0),
-    "fused+decode/percentile-hist": _b(1, 1, 8, 4, 25, 5, 0),
-    "fused+decode/or-expr":         _b(1, 1, 9, 4, 20, 4, 0),
-    "fused+decode/topn-dashboard":  _b(1, 1, 10, 4, 23, 5, 0),
+    "fused+decode/flat-count":      _b(1, 1, 2, 4, 20, 5, 0),
+    "fused+decode/group-eq-lut":    _b(1, 1, 10, 4, 23, 5, 0),
+    "fused+decode/percentile-hist": _b(1, 1, 5, 4, 25, 5, 0),
+    "fused+decode/or-expr":         _b(1, 1, 6, 4, 20, 4, 0),
+    "fused+decode/topn-dashboard":  _b(1, 1, 7, 4, 23, 5, 0),
     # compressed multi-chunk tripwire: staging AND decode-stage
     # de-fusion both show up here first
-    "fused+decode/multi-chunk":     _b(1, 1, 5),
+    "fused+decode/multi-chunk":     _b(1, 1, 2),
     # fused chunked-scan mesh step: the whole distributed scan as one
     # collective program, SAME psum(count/sums)+pmin+pmax set
     "fused/dist-step":         _b(widest=4, bytes_class=16, fusion_class=5, collectives=4),

@@ -406,6 +406,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "fault_injected": frozenset({"kind", "site"}),
     "fused_chunks": frozenset({"kind"}),
     "fused_dispatches_outstanding": frozenset(),
+    "fused_key_columns": frozenset({"column", "mode"}),
     "gather_rows": frozenset({"dedup"}),
     "group_reduce_rows": frozenset({"method"}),
     "kernel_dispatch_budget": frozenset({"signature"}),

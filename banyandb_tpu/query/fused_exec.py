@@ -99,6 +99,16 @@ def chunk_count_bucket(n_chunks: int) -> int:
     return b
 
 
+def key_columns(spec: PlanSpec) -> tuple[str, ...]:
+    """The per-row columns besides the tag and field columns that the
+    plan's program reads, so the only ones a chunk batch pads and ships:
+    ``valid`` always (the mask, and the branch past a padding chunk);
+    the epoch-relative ``ts`` and the global ``row`` only where the
+    program tracks scan order (``bydb.rep``).  No program reads the
+    series id: an ungrouped plan's key is zeros of ``valid``'s shape."""
+    return ("ts", "valid", "row") if spec.want_rep else ("valid",)
+
+
 _KERNEL_CACHE: dict[FusedSpec, object] = {}
 
 
@@ -177,7 +187,7 @@ def estimate_bytes(spec: PlanSpec, num_chunks: int) -> int:
         per_chunk_out += g * _num_hist_buckets()
     if spec.want_rep:
         per_chunk_out += 2 * g
-    cols = 4 + len(spec.tags_code) + nf  # ts/series/valid/row + tags + fields
+    cols = len(key_columns(spec)) + len(spec.tags_code) + nf
     per_row = 4 * cols
     if enc_mod.device_decode_enabled():
         # narrow inputs (<=2 B/row per tag/field) + src_ord (2 B/row)
@@ -239,8 +249,9 @@ def _stacked_chunks(
 ) -> dict:
     """Pad the gathered columns into ``[C, nrows]`` device arrays.
 
-    THE padded chunk layout (per-row dtypes, zero padding, the
-    epoch-relative int32 ts, the global row index), in either ship
+    THE padded chunk layout (per-row dtypes, zero padding, and of the
+    epoch-relative int32 ts, the valid mask and the global row index
+    what ``key_columns`` says the program reads), in either ship
     form: compressed snapshots (``BYDB_DEVICE_DECODE``) stack the narrow
     local tag codes, the per-row source ordinals and exact-int fields,
     plus the per-batch [S, L] remap LUTs the in-program decode stage
@@ -252,8 +263,9 @@ def _stacked_chunks(
     (given when the query's spans read their threads' clocks) one
     (seconds off the CPU, minor page faults) pair a pad thunk: whether
     the worker ran or waited (``obs/tracer.thread_usage``); ``ship_stats``
-    one (shipped, dense) byte pair for the whole part-batch (decode-span
-    attribution).
+    one (shipped, dense, packed) byte triple for the whole part-batch
+    (decode-span attribution): shipped counts the tag and field columns,
+    packed every array padded and shipped.
     """
     from banyandb_tpu.storage.chunk_stream import prefetched
 
@@ -272,13 +284,15 @@ def _stacked_chunks(
             out[k, : e - s] = True
         return out
 
-    paths: list[tuple] = [("ts",), ("series",), ("valid",), ("row",)]
-    thunks = [
-        lambda: pad2(lambda s, e: cols["ts"][s:e] - epoch, np.int32),
-        lambda: pad2(lambda s, e: cols["series"][s:e] % (2**31), np.int32),
-        valid2,
-        lambda: pad2(lambda s, e: np.arange(s, e, dtype=np.int32), np.int32),
-    ]
+    key_thunks = {
+        "ts": lambda: pad2(lambda s, e: cols["ts"][s:e] - epoch, np.int32),
+        "valid": valid2,
+        "row": lambda: pad2(
+            lambda s, e: np.arange(s, e, dtype=np.int32), np.int32
+        ),
+    }
+    paths: list[tuple] = [(k,) for k in key_columns(spec)]
+    thunks = [key_thunks[k] for k in key_columns(spec)]
     counted: set = set()
     if compressed:
         from banyandb_tpu.storage import encoded as enc_mod
@@ -362,7 +376,7 @@ def _stacked_chunks(
         "fields": {},
         "fields_enc": {},
     }
-    shipped = 0
+    shipped = packed = 0
     for path, arr in zip(
         paths,
         prefetched([timed(fn) for fn in thunks], name="bydb-fused-pad"),
@@ -371,6 +385,7 @@ def _stacked_chunks(
         dev = jnp.asarray(arr)
         if h2d_s is not None:
             h2d_s.append(time.perf_counter() - t0)
+        packed += dev.nbytes
         if path in counted:
             shipped += dev.nbytes
         if len(path) == 1:
@@ -385,7 +400,7 @@ def _stacked_chunks(
             del out[key]
     if ship_stats is not None:
         dense = (len(spec.tags_code) + len(spec.fields)) * C * nb * 4
-        ship_stats.append((shipped, dense))
+        ship_stats.append((shipped, dense, packed))
     return out
 
 
@@ -441,7 +456,9 @@ def run_fused(
     if dev_cache is not None:
         # stacked inputs depend only on (gathered data, the batch's row
         # span, bucket, columns): keep them device-resident so repeat
-        # queries skip pad+ship too
+        # queries skip pad+ship too.  The key columns ride the key: a
+        # Top-N's batch holds no ts / row, and a listing over the same
+        # gather must not be served it
         ck = (
             "fused_chunks",
             gather_key,
@@ -451,6 +468,7 @@ def run_fused(
             spec.nrows,
             spec.tags_code,
             spec.fields,
+            key_columns(spec),
         )
         dev_chunks = dev_cache.get_or_load(ck, _build)
     else:

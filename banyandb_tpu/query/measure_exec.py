@@ -208,7 +208,7 @@ def _kernel_body(spec: PlanSpec):
             if key_cols:
                 key, _ = ops.mixed_radix_key(key_cols, spec.radices)
             else:
-                key = jnp.zeros_like(chunk["series"])
+                key = jnp.zeros(valid.shape, jnp.int32)
 
         res = ops.group_reduce(
             key,
@@ -880,12 +880,16 @@ def compute_partials(
     # epoch = global min ts keeps chunk-relative int32 offsets
     # nonnegative for the scan-order key; spans >= 2^31 ms (~24.8 days)
     # would wrap the int32 cast, so rep tracking degrades to canonical
-    # ordering there instead of silently corrupting
+    # ordering there instead of silently corrupting.  Only a plan that
+    # tracks scan order ships ts (fused_exec.key_columns): no other
+    # reads the epoch, and none pays its two passes over ts
     t_phase0 = _time.perf_counter()
-    epoch = int(chunks_np["ts"].min()) if n else 0
-    if n and int(chunks_np["ts"].max()) - epoch >= 2**31:
-        want_rep = rep_desc = False
-        rep_tags = ()
+    epoch = 0
+    if want_rep and n:
+        epoch = int(chunks_np["ts"].min())
+        if int(chunks_np["ts"].max()) - epoch >= 2**31:
+            want_rep = rep_desc = False
+            rep_tags = ()
     epoch_ms = (_time.perf_counter() - t_phase0) * 1000
 
     # All gd reads happen under the DictState lock (concurrent queries
@@ -1198,9 +1202,10 @@ def _reduce_partials(
     # whether the pad thunks ran or waited, one (off-CPU seconds, minor
     # faults) pair a thunk: read when this query's spans read their clocks
     pack_use: list | None = [] if span is not None and span.usage else None
-    # (shipped, dense) bytes per batch: the decode span's compression
-    # evidence (dense = what the decoded i32/f32 ship form would have
-    # moved for the same columns)
+    # (shipped, dense, packed) bytes per batch: the decode span's
+    # compression evidence (dense = what the decoded i32/f32 ship form
+    # would have moved for the same tag and field columns), and every
+    # array the batch padded and shipped (packed: its key columns too)
     ship_stats: list = []
 
     chunk_spans = []
@@ -1287,6 +1292,17 @@ def _reduce_partials(
             "plans_scan_order",
             labels={"mode": "tracked" if want_rep else "skipped"},
         )
+        # the per-row key columns the batches padded and shipped, and
+        # those they left out because the program reads none of them
+        shipped_keys = fused_exec.key_columns(spec)
+        for column in ("ts", "series", "row"):
+            meter.counter_add(
+                "fused_key_columns",
+                labels={
+                    "column": column,
+                    "mode": "shipped" if column in shipped_keys else "skipped",
+                },
+            )
     # -- decode stage attribution (ROADMAP item 3) ------------------------
     # host half = narrow pack + pad (pack_s) + H2D ship (h2d_s): column
     # j+1 pads while column j ships under BYDB_PIPELINE; the device half
@@ -1296,8 +1312,9 @@ def _reduce_partials(
     pack_ms = sum(pack_s) * 1000
     h2d_ms = sum(h2d_s) * 1000
     decode_ms = pack_ms + h2d_ms
-    shipped_bytes = sum(s for s, _ in ship_stats)
-    dense_bytes = sum(d for _, d in ship_stats)
+    shipped_bytes = sum(s for s, _, _ in ship_stats)
+    dense_bytes = sum(d for _, d, _ in ship_stats)
+    packed_bytes = sum(p for _, _, p in ship_stats)
     decode_mode = "device" if "src_ord" in chunks_np else "host"
     _H_DECODE.observe(decode_ms)
     if ship_stats:
@@ -1315,7 +1332,7 @@ def _reduce_partials(
             "h2d_ms", round(h2d_ms, 3)
         ).tag("shipped_bytes", shipped_bytes).tag(
             "dense_bytes", dense_bytes
-        ).tag(
+        ).tag("packed_bytes", packed_bytes).tag(
             "ratio",
             round(dense_bytes / shipped_bytes, 2) if shipped_bytes else 1.0,
         )

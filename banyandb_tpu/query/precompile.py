@@ -201,6 +201,20 @@ def mask_warm_args(mspec) -> tuple:
     return (_zeros_like_structs(cols), _zeros_like_structs(vals))
 
 
+def _key_column_structs(spec, shape) -> dict:
+    """The per-row key columns the plan's program reads
+    (fused_exec.key_columns, the set _stacked_chunks pads and ships)."""
+    import jax
+    import jax.numpy as jnp
+
+    from banyandb_tpu.query.fused_exec import key_columns
+
+    dtypes = {"ts": jnp.int32, "valid": jnp.bool_, "row": jnp.int32}
+    return {
+        k: jax.ShapeDtypeStruct(shape, dtypes[k]) for k in key_columns(spec)
+    }
+
+
 def fused_chunk_struct(fspec) -> dict:
     """ShapeDtypeStruct pytree matching fused_exec._stacked_chunks in the
     dense ship form."""
@@ -211,10 +225,7 @@ def fused_chunk_struct(fspec) -> dict:
     spec = fspec.plan
     shape = (fspec.num_chunks, spec.nrows)
     return {
-        "ts": S(shape, jnp.int32),
-        "series": S(shape, jnp.int32),
-        "valid": S(shape, jnp.bool_),
-        "row": S(shape, jnp.int32),
+        **_key_column_structs(spec, shape),
         "tags_code": {t: S(shape, jnp.int32) for t in spec.tags_code},
         "fields": {f: S(shape, jnp.float32) for f in spec.fields},
     }
@@ -270,10 +281,7 @@ def fused_decode_chunk_struct(fspec) -> dict:
     lut_len = lambda t: _decode_lut_len(spec, t)  # noqa: E731
 
     out = {
-        "ts": S((c, n), jnp.int32),
-        "series": S((c, n), jnp.int32),
-        "valid": S((c, n), jnp.bool_),
-        "row": S((c, n), jnp.int32),
+        **_key_column_structs(spec, (c, n)),
         "tags_code": {},
         "fields": {
             f: S((c, n), jnp.float32)

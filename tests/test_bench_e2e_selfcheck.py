@@ -218,6 +218,16 @@ SPAN_TAG_FILES = {
          ("reused", {"decode": {"pack_ms": 27.5, "pack_minflt": 0}}, 0.0),
          ("no-tag", {"decode": {"pack_ms": 27.5}}, None)],
     ),
+    "packed_mb_per_query": (
+        {"kind": "span_tag", "span": "decode", "tag": "packed_bytes", "scale": 1e-06},
+        # a Top-N over [8, 1M]: the tag and field columns + the valid mask;
+        # a listing over [1, 1M] ships ts and row besides
+        [("topn", {"decode": {"shipped_bytes": 58982400, "packed_bytes": 58982400 + (8 << 20)}},
+          67.371008),
+         ("listing", {"decode": {"shipped_bytes": 4194304, "packed_bytes": 4194304 + (9 << 20)}},
+          13.631488),
+         ("no-tag", {"decode": {"shipped_bytes": 58982400}}, None)],
+    ),
 }
 # the count of requests the server works on at once is better higher; a
 # wait, and everything of ISSUE 33's, lower

@@ -800,18 +800,27 @@ def test_cli_dump_reports_zone_presence(tmp_path, capsys):
 # -- precompile warm covers the compressed ship form -------------------------
 
 
-def test_warm_structs_match_production_compressed_chunks(monkeypatch):
-    """The cold-start contract under the default flag: the canonical
-    compressed warm structs (precompile.fused_decode_chunk_struct) must
-    have EXACTLY the pytree structure,
-    shapes and dtypes the pad/ship stage produces for canonical-width
-    data — else warming compiles a trace production never hits."""
+@pytest.mark.parametrize("want_rep", [True, False], ids=["rep", "no-rep"])
+@pytest.mark.parametrize("compressed", [True, False], ids=["compressed", "dense"])
+def test_warm_structs_match_production_compressed_chunks(
+    compressed, want_rep, monkeypatch
+):
+    """The cold-start contract: the canonical warm structs
+    (precompile.fused_decode_chunk_struct under the default flag,
+    fused_chunk_struct in the dense form) must have EXACTLY the pytree
+    structure, shapes and dtypes the pad/ship stage produces for
+    canonical-width data, for a plan that tracks scan order (its batch
+    holds ts and row) and one that does not — else warming compiles a
+    trace production never hits."""
+    import dataclasses
+
     import jax
 
     from banyandb_tpu.query import fused_exec, precompile
     from banyandb_tpu.query.measure_exec import GlobalDicts, _gather_rows
 
     name, spec = precompile.builtin_plans()[1]  # measure/group-eq-lut
+    spec = dataclasses.replace(spec, want_rep=want_rep)
     n = spec.nrows
     r = np.random.default_rng(31)
     src = ColumnData(
@@ -830,7 +839,8 @@ def test_warm_structs_match_production_compressed_chunks(monkeypatch):
     )
     gd = GlobalDicts(["region", "svc"])
     cols = _gather_rows(
-        [src], ["region", "svc"], ["v"], gd, T0, T0 + n, device_decode=True
+        [src], ["region", "svc"], ["v"], gd, T0, T0 + n,
+        device_decode=compressed,
     )
 
     def spec_of(tree):
@@ -840,11 +850,17 @@ def test_warm_structs_match_production_compressed_chunks(monkeypatch):
 
     fspec = fused_exec.FusedSpec(plan=spec, num_chunks=1)
     stacked = fused_exec._stacked_chunks(cols, [(0, n)], spec, 1, T0)
+    struct = (
+        precompile.fused_decode_chunk_struct
+        if compressed
+        else precompile.fused_chunk_struct
+    )
     fwant = jax.tree_util.tree_map(
-        lambda s: (tuple(s.shape), str(s.dtype)),
-        precompile.fused_decode_chunk_struct(fspec),
+        lambda s: (tuple(s.shape), str(s.dtype)), struct(fspec)
     )
     assert spec_of(stacked) == fwant
+    assert ("ts" in stacked) == ("row" in stacked) == want_rep
+    assert "series" not in stacked
 
 
 def test_warm_dispatches_both_ship_forms(monkeypatch):

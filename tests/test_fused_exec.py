@@ -750,7 +750,8 @@ def test_plan_batches_follow_the_budget(monkeypatch):
     # footprint estimate grows with the chunk bucket
     per_chunk = fused_exec.estimate_bytes(spec, 1)
     assert fused_exec.estimate_bytes(spec, 8) == 8 * per_chunk
-    assert 16 << 20 < per_chunk < 32 << 20
+    # the mask and one field: no key column but `valid` (key_columns)
+    assert 8 << 20 < per_chunk < 16 << 20
 
     assert fused_exec.plan_batches(spec, []) == (1, [])
     # under the budget: one batch, today's bucket rule and hint
@@ -760,7 +761,7 @@ def test_plan_batches_follow_the_budget(monkeypatch):
         [spans[:3]],
     )
     # over it: the largest power of two that fits, a short last batch
-    monkeypatch.setenv("BYDB_FUSED_MAX_MB", "64")
+    monkeypatch.setenv("BYDB_FUSED_MAX_MB", "32")
     assert fused_exec.plan_batches(spec, spans) == (
         2,
         [spans[0:2], spans[2:4], spans[4:5]],
@@ -810,6 +811,139 @@ def test_batches_do_not_share_a_device_cache_entry(monkeypatch):
     assert _partial_bytes(p_bat) == _partial_bytes(p_one)
     assert t_again["dispatches"] == 3 and t_again["device_cache"] == "hit"
     assert _partial_bytes(p_again) == _partial_bytes(p_one)
+
+
+# -- a batch holds the key columns its program reads ---------------------------
+
+
+# plan -> (scenario, the request's changes, the key columns its batch holds)
+KEY_PLANS = {
+    # TOP 10 that projects no tag: no scan order, so neither ts nor row
+    "topn": ("topn-dashboard", {}, {"valid"}),
+    # grouped, no TOP: groups emit in first-appearance order
+    "listing": ("topn-dashboard", {"top": None, "agg": Aggregation("mean", "value")},
+                {"ts", "valid", "row"}),
+    # no GROUP BY: the key is zeros of the mask's shape
+    "ungrouped": ("or-expr", {}, {"valid"}),
+}
+NON_KEY = {"tags_code", "tags_enc", "tags_lut", "src_ord", "fields", "fields_enc"}
+
+
+def _capture_batches(monkeypatch) -> list:
+    """Record every stacked batch -> the list of them."""
+    real_stacked = fused_exec._stacked_chunks
+    seen = []
+
+    def stacked(*args, **kwargs):
+        out = real_stacked(*args, **kwargs)
+        seen.append(out)
+        return out
+
+    monkeypatch.setattr(fused_exec, "_stacked_chunks", stacked)
+    return seen
+
+
+@pytest.mark.parametrize("decode", ["0", "1"], ids=["dense", "compressed"])
+@pytest.mark.parametrize("plan", sorted(KEY_PLANS))
+def test_batch_holds_the_key_columns_its_program_reads(plan, decode, monkeypatch):
+    """A batch pads and ships `valid`, and `ts` and `row` only for a plan
+    that tracks scan order, beside its tag and field columns; never the
+    series id.  The answer is byte-identical to the one over a batch
+    that ships all three, and is the NumPy reduction's."""
+    monkeypatch.setenv("BYDB_DEVICE_DECODE", decode)
+    monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 16384)
+    scenario, changes, want = KEY_PLANS[plan]
+    m, req, srcs = next((m, r, s) for n, m, r, s in _scenarios() if n == scenario)
+    req = dataclasses.replace(req, **changes)
+    seen = _capture_batches(monkeypatch)
+    p, tags = _run(m, req, srcs, ONE_BATCH, monkeypatch)
+    assert tags["scan_order_tracked"] == int("ts" in want)
+    assert [set(b) - NON_KEY for b in seen] == [want]
+    assert {"tags_code", "fields"} <= set(seen[0])
+    monkeypatch.setattr(fused_exec, "key_columns", lambda spec: ("ts", "valid", "row"))
+    p_all, _ = _run(m, req, srcs, ONE_BATCH, monkeypatch)
+    assert [set(b) - NON_KEY for b in seen[1:]] == [{"ts", "valid", "row"}]
+    assert _partial_bytes(p) == _partial_bytes(p_all)
+    assert _result_json(m, req, p) == _result_json(m, req, p_all)
+    _assert_matches_numpy(m, req, srcs, p)
+
+
+@pytest.mark.parametrize("plan", sorted(KEY_PLANS))
+def test_decode_span_and_counter_count_the_key_columns(plan, monkeypatch):
+    """The `decode` span's `packed_bytes` is every array padded and
+    shipped: `shipped_bytes` (tag and field columns) and the key columns,
+    1 B a slot for `valid` and 4 each for `ts` and `row`;
+    `fused_key_columns{column, mode}` counts each key column once a plan."""
+    import jax
+
+    from banyandb_tpu.obs import metrics as obs_metrics
+    from banyandb_tpu.obs.tracer import Tracer, iter_spans
+
+    def counted():
+        snap = obs_metrics.global_meter().snapshot()["counters"]
+        return {
+            (c, mode): snap.get(
+                ("fused_key_columns", (("column", c), ("mode", mode))), 0.0
+            )
+            for c in ("ts", "series", "row")
+            for mode in ("shipped", "skipped")
+        }
+
+    monkeypatch.setenv("BYDB_DEVICE_DECODE", "0")
+    monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 16384)
+    scenario, changes, want = KEY_PLANS[plan]
+    m, req, srcs = next((m, r, s) for n, m, r, s in _scenarios() if n == scenario)
+    req = dataclasses.replace(req, **changes)
+    seen = _capture_batches(monkeypatch)
+    before = counted()
+    tr = Tracer("t")
+    with tr.span("q") as sp:
+        compute_partials(m, req, srcs, span=sp)
+    (dec,) = [s["tags"] for s in iter_spans(tr.finish()) if s["name"] == "decode"]
+    (batch,) = seen
+    assert dec["packed_bytes"] == sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(batch)
+    )
+    slots = batch["valid"].size
+    assert dec["packed_bytes"] - dec["shipped_bytes"] == slots * (
+        1 + (8 if "ts" in want else 0)
+    )
+    delta = {k: v - before[k] for k, v in counted().items()}
+    assert delta == {
+        (c, mode): float((mode == "shipped") == (c in want))
+        for c in ("ts", "series", "row")
+        for mode in ("shipped", "skipped")
+    }
+
+
+def test_topn_then_listing_over_one_cached_gather(monkeypatch):
+    """The device cache keys a batch by the key columns it holds: a
+    no-rep Top-N's batch, which has no `ts` or `row`, is not served to a
+    listing over the same gather; the listing builds its own, and a
+    second listing is served that one."""
+    from banyandb_tpu.storage import cache
+
+    monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 16384)
+    _, m, top, srcs = next(s for s in _scenarios() if s[0] == "topn-dashboard")
+    listing = dataclasses.replace(top, top=None, agg=Aggregation("mean", "value"))
+    srcs = [dataclasses.replace(srcs[0], cache_key=("part", "keys"))]
+    seen = _capture_batches(monkeypatch)
+    cache.reset_global_cache()
+    dicts = measure_exec.DictState()
+    try:
+        _, t_top = _run(m, top, srcs, ONE_BATCH, monkeypatch, dict_state=dicts)
+        p_list, t_list = _run(m, listing, srcs, ONE_BATCH, monkeypatch, dict_state=dicts)
+        cache.global_cache().clear()  # the partials: reduce again
+        p_again, t_again = _run(m, listing, srcs, ONE_BATCH, monkeypatch, dict_state=dicts)
+    finally:
+        cache.reset_global_cache()
+    assert (t_top["device_cache"], t_list["device_cache"]) == ("built", "built")
+    assert t_again["device_cache"] == "hit"
+    assert [set(b) - NON_KEY for b in seen] == [{"valid"}, {"ts", "valid", "row"}]
+    assert t_list["scan_order_tracked"] == 1 and p_list.rep_key is not None
+    fresh, _ = _run(m, listing, srcs, ONE_BATCH, monkeypatch)
+    assert _partial_bytes(p_list) == _partial_bytes(fresh) == _partial_bytes(p_again)
+    assert _result_json(m, listing, p_list) == _result_json(m, listing, fresh)
 
 
 def test_chunk_count_bucket_powers_of_two():

@@ -163,6 +163,10 @@ def _force_tracking(monkeypatch) -> list:
     def tracked(measure, chunks_np, conds, expr, pred_vals, spec, *args, **kw):
         built.append(spec)
         spec = dataclasses.replace(spec, want_rep=True)
+        # the epoch a tracking plan's ts is shipped relative to: computed
+        # only where the request's own plan tracks
+        args = list(args)
+        args[7] = int(chunks_np["ts"].min())
         return real(measure, chunks_np, conds, expr, pred_vals, spec, *args, **kw)
 
     monkeypatch.setattr(measure_exec, "_reduce_partials", tracked)
@@ -192,28 +196,40 @@ def _counted(mode: str) -> float:
 # -- (1) a TOP n answer does not read the key ---------------------------------
 
 
+@pytest.mark.parametrize("decode", ["0", "1"], ids=["dense", "compressed"])
 @pytest.mark.parametrize("sort", ["desc", "asc"])
 @pytest.mark.parametrize("method", METHODS)
-def test_topn_answer_is_the_tracked_plans_byte_for_byte(method, sort, monkeypatch):
+def test_topn_answer_is_the_tracked_plans_byte_for_byte(method, sort, decode, monkeypatch):
     """Groups, order and values of a TOP 10 (ties at the cut on both
     sides) are those of the same query answered with tracking forced on
-    in the spec, for each group-by method; the plan that does not track
-    fetches 8 B a group a chunk, the tracked one 16."""
+    in the spec, for each group-by method and in both ship forms; the
+    plan that does not track fetches 8 B a group a chunk, the tracked
+    one 16, and its batches ship neither ts nor row."""
+    monkeypatch.setenv("BYDB_DEVICE_DECODE", decode)
     req = _request(top=Top(10, "hits", sort))
     srcs = [_source(np.arange(N))]
     skipped = _counted("skipped")
-    p, tags, answer = _answer(req, srcs, monkeypatch, method)
+    p, spans, res = _ask(req, srcs, monkeypatch, method)
+    tags, answer = spans["reduce"], json.dumps(result_to_json(res), sort_keys=True)
     assert _counted("skipped") - skipped == 1
     assert tags["group_method"] == method and (tags["chunks"], tags["chunks_skipped"]) == (3, 1)
     assert tags["scan_order_tracked"] == 0 and p.rep_key is None
     assert tags["partials_bytes"] == 4 * SVCS * 8
+    # beside the tag and field columns, the 4-bucket's valid mask alone
+    slots = 4 * SCAN_CHUNK
+    assert spans["decode"]["packed_bytes"] - spans["decode"]["shipped_bytes"] == slots
     built = _force_tracking(monkeypatch)
     tracked = _counted("tracked")
-    p_on, tags_on, answer_on = _answer(req, srcs, monkeypatch, method)
+    p_on, spans_on, res_on = _ask(req, srcs, monkeypatch, method)
+    tags_on = spans_on["reduce"]
+    answer_on = json.dumps(result_to_json(res_on), sort_keys=True)
     assert [s.want_rep for s in built] == [False]
     assert _counted("tracked") - tracked == 1
     assert tags_on["scan_order_tracked"] == 1 and p_on.rep_key is not None
     assert tags_on["partials_bytes"] == 4 * SVCS * 16
+    # and its ts and row, 4 B a slot each
+    dec_on = spans_on["decode"]
+    assert dec_on["packed_bytes"] - dec_on["shipped_bytes"] == 9 * slots
     assert answer == answer_on
     for a, b in ((p.count, p_on.count), (p.codes, p_on.codes), (p.sums["hits"], p_on.sums["hits"])):
         assert a.tobytes() == b.tobytes()
@@ -266,12 +282,14 @@ def test_topn_that_projects_a_tag_keeps_its_representative_row(order, monkeypatc
     assert plain.groups == res.groups and not plain.rep_tags
 
 
+@pytest.mark.parametrize("decode", ["0", "1"], ids=["dense", "compressed"])
 @pytest.mark.parametrize("order", ["", "desc"])
 @pytest.mark.parametrize("method", METHODS)
-def test_listing_pages_in_first_appearance_order(method, order, monkeypatch):
+def test_listing_pages_in_first_appearance_order(method, order, decode, monkeypatch):
     """A listing tracks: LIMIT / OFFSET page through the groups in the
     order their first row appears in the scan, asc and ORDER BY time
-    DESC."""
+    DESC, in both ship forms."""
+    monkeypatch.setenv("BYDB_DEVICE_DECODE", decode)
     first = _first_seen(desc=order == "desc")
     ts = ROWS["ts"]
     by_first = sorted(first, key=lambda s: -ts[first[s]] if order == "desc" else ts[first[s]])
