@@ -97,6 +97,12 @@ def stored_entries(registry=None, limit: int = 16):
                     precompile.pred_struct(spec.plan),
                     S((), jnp.float32),
                     S((), jnp.float32),
+                    # a program that inverts: no histogram yet, Q quantiles
+                    *(
+                        (None, S((spec.quantiles,), jnp.float32))
+                        if spec.quantiles
+                        else ()
+                    ),
                 )
                 anchor = fused_exec._build_kernel
             elif kind == "stream_mask":

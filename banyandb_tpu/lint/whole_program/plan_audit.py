@@ -248,8 +248,6 @@ def default_entries() -> list[KernelAudit]:
             if spec.want_minmax:  # min/max arrays exist only when asked
                 out[f"['mins']['{f}']"] = ("float32", g)
                 out[f"['maxs']['{f}']"] = ("float32", g)
-        if spec.hist_field:
-            out["['hist']"] = ("float32", (spec.num_groups, 512))
         if spec.want_rep:
             out["['rep_ts']"] = ("int32", g)
             out["['rep_row']"] = ("int32", g)
@@ -277,11 +275,19 @@ def default_entries() -> list[KernelAudit]:
 
     fpath = _rel_path(inspect.getsourcefile(fused_exec))
     fline = inspect.getsourcelines(fused_exec._build_kernel)[1]
-    for name, fspec in precompile.builtin_fused():
-        fexpect = {
+    def fused_expect(fspec) -> dict[str, tuple[str, tuple]]:
+        """Per-chunk partials stacked [C, ...]; a percentile plan's
+        histogram once, the flat int32 sum the scan carried."""
+        out = {
             key: (dtype, (fspec.num_chunks,) + shape)
             for key, (dtype, shape) in base_expect(fspec.plan).items()
         }
+        if fspec.plan.hist_field:
+            out["['hist']"] = ("int32", (fspec.plan.num_groups * 512,))
+        return out
+
+    for name, fspec in precompile.builtin_fused():
+        fexpect = fused_expect(fspec)
         entries.append(
             KernelAudit(
                 name=name,
@@ -305,10 +311,7 @@ def default_entries() -> list[KernelAudit]:
     # contract identical and introduce no 64-bit dtypes, and the
     # lowering audit pins the bytes-accessed class the compression buys
     for name, fspec in precompile.builtin_fused_decode():
-        fexpect = {
-            key: (dtype, (fspec.num_chunks,) + shape)
-            for key, (dtype, shape) in base_expect(fspec.plan).items()
-        }
+        fexpect = fused_expect(fspec)
         entries.append(
             KernelAudit(
                 name=name,

@@ -415,6 +415,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "measure_write_points": frozenset(),
     "placement_epoch": frozenset(),
     "planner_decisions": frozenset({"path"}),
+    "percentile_hist_bytes": frozenset({"where"}),
     "plans_scan_order": frozenset({"mode"}),
     "qos_enabled": frozenset(),
     "qos_inflight_bytes": frozenset({"tenant"}),

@@ -30,7 +30,9 @@ from banyandb_tpu.ops.groupby import (
 )
 from banyandb_tpu.ops.topk import topk_groups
 from banyandb_tpu.ops.percentile import (
+    HistogramRanks,
     group_histogram,
     group_percentile_histogram,
+    invert_histogram,
 )
 from banyandb_tpu.ops.dedup import latest_by_version

@@ -15,11 +15,18 @@ plan (``measure_exec._reduce_partials`` always comes here).
   the host then folds them into the f64 accumulators in scan order.
   A chunk with no valid row (the bucket's padding) is branched past on
   the device: no decode, no body.
+- a percentile plan's histogram is not a per-chunk partial: the scan
+  carries ONE exact int32 histogram (``[G * 512]``, flat: the layout
+  the device's scatter writes) across its chunks and
+  batches, and it leaves the device once a query at most — where the
+  caller combines partials — or never: where the partial is finalized
+  alone the last batch's program ends with the CDF inversion
+  (``ops.invert_histogram``) and returns ``[G, Q, 3]`` integers.
 - a scan whose stacked footprint passes the device budget
   (``BYDB_FUSED_MAX_MB``) runs the SAME program over consecutive chunk
-  batches, one after another (``plan_batches``): same per-chunk graph,
-  same absorb order => byte-identical partials and results whatever the
-  batching.
+  batches, one after another (``plan_batches``), the histogram handed
+  from one to the next on the device: same per-chunk graph, same absorb
+  order => byte-identical partials and results whatever the batching.
 - group-by strategy (hash/scatter vs segment-sort, per arXiv
   2411.13245) resolves through ``ops.groupby.select_group_method`` from
   the signature's (nrows, num_groups).
@@ -85,6 +92,9 @@ class FusedSpec:
 
     plan: PlanSpec
     num_chunks: int
+    # > 0: a percentile plan's program ends with the inversion for this
+    # many quantiles and returns the ranks, not the histogram
+    quantiles: int = 0
 
 
 def chunk_count_bucket(n_chunks: int) -> int:
@@ -129,35 +139,55 @@ def _build_kernel(fspec: FusedSpec):
     sees a canonical chunk.  Elementwise integer decode, so the two
     ship forms stay byte-identical and a padding chunk is not widened
     either."""
-    from banyandb_tpu.ops import decode as ops_decode
+    from banyandb_tpu import ops
 
     body = _kernel_body(fspec.plan)
+    plan = fspec.plan
 
     # the name is the device trace's module line: jit_bydb_fused_plan
-    def bydb_fused_plan(chunks: dict, pred_vals: dict, hist_lo, hist_span):
+    def bydb_fused_plan(
+        chunks: dict, pred_vals: dict, hist_lo, hist_span, hist=None,
+        quantiles=None,
+    ):
         per_row = {k: v for k, v in chunks.items() if k != "tags_lut"}
         per_batch = {k: v for k, v in chunks.items() if k == "tags_lut"}
 
-        def real_chunk(chunk):
-            chunk = ops_decode.decode_chunk({**chunk, **per_batch})
-            return body(chunk, pred_vals, hist_lo, hist_span)
+        def real_chunk(chunk, hist):
+            chunk = ops.decode_chunk({**chunk, **per_batch})
+            return body(chunk, pred_vals, hist_lo, hist_span, hist)
 
-        def padding_chunk(chunk):
-            return jax.tree.map(
+        def padding_chunk(chunk, hist):
+            out = jax.tree.map(
                 lambda s: jnp.zeros(s.shape, s.dtype),
-                jax.eval_shape(real_chunk, chunk),
+                jax.eval_shape(real_chunk, chunk, hist),
             )
+            if hist is not None:
+                out["hist"] = hist  # a padding chunk adds nothing
+            return out
 
-        def step(carry, chunk):
-            return carry, jax.lax.cond(
-                jnp.any(chunk["valid"]), real_chunk, padding_chunk, chunk
+        def step(hist, chunk):
+            out = jax.lax.cond(
+                jnp.any(chunk["valid"]), real_chunk, padding_chunk, chunk, hist
             )
+            return out.pop("hist", None), out
 
+        if plan.hist_field and hist is None:  # the scan's first batch
+            hist = jnp.zeros(plan.num_groups * _num_hist_buckets(), jnp.int32)
         with jax.named_scope("bydb.fused_scan"):
-            _, stacked = jax.lax.scan(step, None, per_row)
+            hist, stacked = jax.lax.scan(step, hist, per_row)
+        if hist is not None:
+            stacked["hist"] = hist  # flat [G * 512]
+            if fspec.quantiles:
+                with jax.named_scope("bydb.invert"):
+                    r = ops.invert_histogram(
+                        hist.reshape(plan.num_groups, -1), quantiles
+                    )
+                    stacked["ranks"] = jnp.stack([r.hit, r.before, r.at], axis=-1)
         return stacked
 
-    return jax.jit(bydb_fused_plan)
+    # the carried histogram is updated in place: each batch's program
+    # takes over the buffer the previous one returned
+    return jax.jit(bydb_fused_plan, donate_argnames=("hist",))
 
 
 def _num_hist_buckets() -> int:
@@ -168,7 +198,8 @@ def _num_hist_buckets() -> int:
 
 def estimate_bytes(spec: PlanSpec, num_chunks: int) -> int:
     """Device footprint of one fused part-batch: stacked input columns
-    plus the stacked per-chunk partials pytree.
+    plus the stacked per-chunk partials pytree, plus once (not per
+    chunk) a percentile plan's carried int32 histogram.
 
     Under ``BYDB_DEVICE_DECODE`` the ceiling accounts the compressed
     inputs (narrow tag/field buffers, the i16 src-ordinal column)
@@ -183,16 +214,15 @@ def estimate_bytes(spec: PlanSpec, num_chunks: int) -> int:
     g = spec.num_groups
     nf = len(spec.fields)
     per_chunk_out = g * (1 + nf + (2 * nf if spec.want_minmax else 0))
-    if spec.hist_field:
-        per_chunk_out += g * _num_hist_buckets()
     if spec.want_rep:
         per_chunk_out += 2 * g
+    hist = g * _num_hist_buckets() if spec.hist_field else 0
     cols = len(key_columns(spec)) + len(spec.tags_code) + nf
     per_row = 4 * cols
     if enc_mod.device_decode_enabled():
         # narrow inputs (<=2 B/row per tag/field) + src_ord (2 B/row)
         per_row += 2 + 2 * (len(spec.tags_code) + nf)
-    return num_chunks * (per_row * spec.nrows + 4 * per_chunk_out)
+    return num_chunks * (per_row * spec.nrows + 4 * per_chunk_out) + 4 * hist
 
 
 def _resolve_bucket(n_chunks: int, min_bucket: int | None) -> int:
@@ -422,20 +452,37 @@ def run_fused(
     ship_stats: list | None = None,
     decode_span=None,
     pack_use: list | None = None,
-) -> tuple[list[dict], str]:
+    hist=None,
+    quantiles=None,
+    fetch_hist: bool = False,
+) -> tuple[list[dict], dict, object, str]:
     """Execute one chunk batch (``plan_batches``) through the fused
     program of its ``num_chunks`` bucket.
 
     -> (per-chunk host partials in scan order for the f64 absorb loop,
-    input-cache outcome tag).  Exactly one kernel dispatch and one
-    batched device_get regardless of chunk count; their host-clock
-    times, what the dispatch compiled and how many dispatches of other
-    queries were outstanding when it was issued add to ``leg`` (one leg
-    per reduction, summed over its batches).  ``decode_span`` (open, or
+    the batch's whole-scan results on the host, a percentile plan's
+    histogram on the device, input-cache outcome tag).  Exactly one
+    kernel dispatch and one batched device_get regardless of chunk
+    count; their host-clock times, the bytes the get brought back, what
+    the dispatch compiled and how many dispatches of other queries were
+    outstanding when it was issued add to ``leg`` (one leg per
+    reduction, summed over its batches).  ``decode_span`` (open, or
     None) is finished when the stacked inputs are on the device: it
     covers the pad + ship loop and nothing of this dispatch.
+
+    A percentile plan's histogram: ``hist`` is the device histogram the
+    previous batch returned (None for the first; it is donated), and it
+    stays on the device unless ``fetch_hist`` (the whole-scan results
+    then hold it as ``hist``).  ``quantiles`` (a device f32 [Q], the
+    last batch only): the program also inverts it, and the whole-scan
+    results hold the int32 ``ranks`` [G, Q, 3] (hit bucket, count below
+    it, count in it).
     """
-    fspec = FusedSpec(plan=spec, num_chunks=num_chunks)
+    fspec = FusedSpec(
+        plan=spec,
+        num_chunks=num_chunks,
+        quantiles=0 if quantiles is None else int(quantiles.shape[0]),
+    )
     kernel = _KERNEL_CACHE.get(fspec)
     if kernel is None:
         kernel = _KERNEL_CACHE[fspec] = _build_kernel(fspec)
@@ -486,23 +533,33 @@ def run_fused(
     try:
         with leg.paid:  # what this dispatch traces or compiles
             t0 = time.perf_counter()
-            out = kernel(dev_chunks, pred_vals, hist_lo, hist_span)
+            out = kernel(
+                dev_chunks, pred_vals, hist_lo, hist_span, hist, quantiles
+            )
             leg.dispatch_s += time.perf_counter() - t0
+        hist = out.pop("hist", None)
+        whole = {"ranks": out.pop("ranks")} if "ranks" in out else {}
+        if fetch_hist and hist is not None:
+            whole["hist"] = hist
         t0 = time.perf_counter()
         # bdlint: disable=host-sync -- THE result boundary of the fused
         # plan: the whole batch's stacked partials move in one batched
         # transfer (1 get per dispatch, ratcheted by kernel_budgets)
-        moved = jax.device_get(out)
+        moved, whole = jax.device_get((out, whole))
         leg.get_s += time.perf_counter() - t0
     finally:
         with _OUTSTANDING_LOCK:
             _OUTSTANDING -= 1
-    leg.get_bytes += sum(a.nbytes for a in jax.tree_util.tree_leaves(moved))
+    leg.get_bytes += sum(
+        a.nbytes for a in jax.tree_util.tree_leaves((moved, whole))
+    )
+    if "hist" in whole:
+        leg.hist_bytes += whole["hist"].nbytes
     chunks_out = [
         jax.tree_util.tree_map(lambda a, k=k: a[k], moved)
         for k in range(len(chunk_spans))
     ]
-    return chunks_out, ("built" if built else "hit")
+    return chunks_out, whole, hist, ("built" if built else "hit")
 
 
 # ---------------------------------------------------------------------------

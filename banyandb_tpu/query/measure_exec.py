@@ -128,20 +128,23 @@ class DeviceLeg:
     (``get_s``) — a dispatch that blocks (a trace, a compile, a
     synchronous transfer) and a device that works read differently —
     the bytes those gets brought back (``get_bytes``: the stacked
-    per-chunk partials, a padding chunk's zeros included), the most
+    per-chunk partials, a padding chunk's zeros included; ``hist_bytes``
+    of them a percentile plan's histogram), the most
     fused dispatches of other queries that were issued and not yet
     fetched when one of these was issued (``dispatches_ahead``: the
     device runs them first, and ``get_s`` holds them), plus what the
     dispatches traced or compiled on this thread."""
 
     __slots__ = (
-        "dispatch_s", "get_s", "get_bytes", "dispatches_ahead", "paid",
+        "dispatch_s", "get_s", "get_bytes", "hist_bytes", "dispatches_ahead",
+        "paid",
     )
 
     def __init__(self):
         self.dispatch_s = 0.0
         self.get_s = 0.0
         self.get_bytes = 0
+        self.hist_bytes = 0
         self.dispatches_ahead = 0
         # entered around the dispatches (`with leg.paid:`)
         self.paid = compile_cache.watch()
@@ -170,9 +173,12 @@ def _kernel_body(spec: PlanSpec):
     jitted program (each step first widens its compressed chunk with
     ops.decode.decode_chunk, and skips a chunk that holds no valid
     row): one trace graph per chunk however the scan is batched, which
-    is what keeps partials byte-identical across batchings."""
+    is what keeps partials byte-identical across batchings.  A
+    percentile plan's ``hist`` is the scan's int32 histogram, flat
+    [G * 512]: the chunk's rows are added into it and ``out["hist"]`` is
+    the sum."""
 
-    def kernel(chunk: dict, pred_vals: dict, hist_lo, hist_span):
+    def kernel(chunk: dict, pred_vals: dict, hist_lo, hist_span, hist=None):
         valid = chunk["valid"]
 
         def pred_mask(i: int):
@@ -225,6 +231,8 @@ def _kernel_body(spec: PlanSpec):
             "maxs": res.maxs,
         }
         if spec.hist_field:
+            # added into the scan's one int32 histogram (`hist`), which
+            # the fused program carries across chunks and batches
             out["hist"] = ops.group_histogram(
                 key,
                 mask,
@@ -233,6 +241,7 @@ def _kernel_body(spec: PlanSpec):
                 hist_lo,
                 hist_span,
                 _NUM_HIST_BUCKETS,
+                counts=hist,
             )
         if spec.want_rep:
             # scan-order tracking, 32-bit friendly (device x64 stays
@@ -555,7 +564,7 @@ class Partials:
     __slots__ = (
         "group_tags", "count", "sums", "mins", "maxs", "hist", "hist_lo",
         "hist_span", "field_stats", "_groups", "codes", "group_values",
-        "rep_key", "rep_desc", "rep_vals",
+        "rep_key", "rep_desc", "rep_vals", "ranks", "ranks_q",
     )
 
     def __init__(
@@ -575,6 +584,11 @@ class Partials:
         rep_key: Optional[np.ndarray] = None,  # int64 [K] scan-order key
         rep_desc: bool = False,
         rep_vals: Optional[dict] = None,  # tag -> list[bytes] [K] rep row
+        # int32 [K, Q, 3] in place of `hist` where the device inverted it
+        # for the quantiles `ranks_q`: (hit bucket, count below it, count
+        # in it) per group and quantile; such a partial is final alone
+        ranks: Optional[np.ndarray] = None,
+        ranks_q: tuple = (),
     ):
         if groups is None and codes is None:
             raise TypeError("Partials needs groups or codes+group_values")
@@ -593,6 +607,8 @@ class Partials:
         self.rep_key = rep_key
         self.rep_desc = rep_desc
         self.rep_vals = rep_vals
+        self.ranks = ranks
+        self.ranks_q = ranks_q
 
     @property
     def groups(self) -> list[tuple[bytes, ...]]:
@@ -642,6 +658,9 @@ class Partials:
                 parts.append(d[k].tobytes())
         if self.hist is not None:
             parts.append(self.hist.tobytes())
+        if self.ranks is not None:
+            parts.append(self.ranks.tobytes())
+            parts.append(repr(self.ranks_q).encode())
         if self.rep_key is not None:
             parts.append(self.rep_key.tobytes())
         if self.rep_vals is not None:
@@ -661,7 +680,7 @@ def execute_aggregate(
     """Run a group-by/aggregate/top-N/percentile query over decoded sources."""
     partial = compute_partials(
         measure, request, sources, dict_state=dict_state, analyzers=analyzers,
-        span=span, plan_hints=plan_hints,
+        span=span, plan_hints=plan_hints, final=True,
     )
     return finalize_partials(
         measure, request, [partial], dict_state=dict_state, span=span
@@ -677,6 +696,7 @@ def compute_partials(
     analyzers: Optional[dict] = None,
     span=None,
     plan_hints=None,
+    final: bool = False,
 ) -> Partials:
     """The 'map' phase: device scan+reduce over local sources.
 
@@ -700,6 +720,15 @@ def compute_partials(
     minimum fused chunk-count bucket (signature stability).
     ``actual_rows`` is written back for the planner
     span's est-vs-actual tag.
+
+    `final`: the caller finalizes this partial alone
+    (``finalize_partials([partial])``, the standalone path).  A
+    percentile plan then inverts its histogram on the device for the
+    request's quantiles: the partial holds their ranks (``Partials.ranks``)
+    and the [G, 512] histogram never leaves the device.  A caller that
+    combines partials (a data node's reply, streamagg's rescans, the
+    two-pass range) leaves it False and gets the histogram, fetched once
+    a query.
     """
     import time as _time
     conds, expr = _lower_criteria(request.criteria)
@@ -955,6 +984,9 @@ def compute_partials(
 
     want_percentile = bool(agg and agg.function == "percentile")
     hist_field = agg.field_name if want_percentile else ""
+    # the quantiles the device inverts the histogram for, where the
+    # partial is final alone; () keeps the histogram
+    ranks_q = tuple(agg.quantiles or (0.5,)) if want_percentile and final else ()
     # min/max always computed when percentile (field_stats feed the
     # distributed two-pass range agreement).
     want_minmax = not agg or agg.function in ("min", "max") or want_percentile
@@ -1031,6 +1063,9 @@ def compute_partials(
             rep_tags,
             round(hist_lo, 9),
             round(hist_span, 9),
+            # a partial that holds ranks answers these quantiles alone
+            # and no caller that combines
+            ranks_q,
             h.hexdigest(),
         )
 
@@ -1047,7 +1082,7 @@ def compute_partials(
             measure, chunks_np, conds, expr, pred_vals, spec,
             group_values, rep_tags, gd, dict_state,
             hist_lo, hist_span, want_percentile, epoch, gather_key, agg,
-            span=rspan, plan_hints=plan_hints,
+            span=rspan, plan_hints=plan_hints, ranks_q=ranks_q,
         )
 
     try:
@@ -1096,6 +1131,7 @@ def _reduce_partials(
     agg,
     span=None,
     plan_hints=None,
+    ranks_q: tuple = (),
 ):
     """The reduction tail of compute_partials (cacheable unit).
 
@@ -1106,7 +1142,11 @@ def _reduce_partials(
     it the f64 fold of the chunks' partials), all summed over the
     scan's chunk batches.  Its
     `decode` child is open while chunks are padded and shipped
-    (pack_ms + h2d_ms = its host_ms)."""
+    (pack_ms + h2d_ms = its host_ms).  A percentile plan's tags say
+    where its histogram was: hist_groups (G), hist_device_bytes (the
+    int32 [G, 512] the program carried) and hist_fetched_bytes (of it,
+    what the gets brought back: all of it once where partials combine,
+    none where ``ranks_q`` names the quantiles it is inverted for)."""
     import contextlib
     import time as _time
 
@@ -1149,7 +1189,6 @@ def _reduce_partials(
     sums = {f: np.zeros(G, dtype=np.float64) for f in spec.fields}
     mins = {f: np.full(G, np.inf, dtype=np.float64) for f in spec.fields}
     maxs = {f: np.full(G, -np.inf, dtype=np.float64) for f in spec.fields}
-    hist = np.zeros((G, _NUM_HIST_BUCKETS), dtype=np.float64) if want_percentile else None
     rep_ts_acc = rep_row_acc = None
     if want_rep:
         sentinel = -(2**62) if rep_desc else 2**62
@@ -1170,15 +1209,13 @@ def _reduce_partials(
     def _absorb(out: dict) -> None:
         """Fold ONE chunk's partials (already on host) into the f64
         accumulators — the host half of the precision contract."""
-        nonlocal count, hist, rep_ts_acc, rep_row_acc
+        nonlocal count, rep_ts_acc, rep_row_acc
         count += out["count"].astype(np.float64)
         for f in spec.fields:
             sums[f] += out["sums"][f].astype(np.float64)
             if want_minmax:
                 mins[f] = np.minimum(mins[f], out["mins"][f])
                 maxs[f] = np.maximum(maxs[f], out["maxs"][f])
-        if hist is not None:
-            hist += out["hist"].astype(np.float64)
         if rep_ts_acc is not None:
             rts = out["rep_ts"].astype(np.int64) + epoch
             rrow = out["rep_row"].astype(np.int64)
@@ -1240,8 +1277,16 @@ def _reduce_partials(
     )
     cache_tags = []
     absorb_s = 0.0  # the host fold of the chunks' partials, all batches
+    # a percentile plan's histogram stays on the device from batch to
+    # batch; the last batch brings it back, or inverts it for ranks_q
+    hist_dev = None
+    whole: dict = {}
+    quantiles_dev = (
+        jnp.asarray(np.asarray(ranks_q, np.float32)) if ranks_q else None
+    )
     for i, batch in enumerate(batches):
-        moved_chunks, cache_tag = fused_exec.run_fused(
+        last = i == len(batches) - 1
+        moved_chunks, whole, hist_dev, cache_tag = fused_exec.run_fused(
             chunks_np,
             batch,
             spec,
@@ -1256,8 +1301,11 @@ def _reduce_partials(
             pack_s=pack_s,
             h2d_s=h2d_s,
             ship_stats=ship_stats,
-            decode_span=dspan if i == len(batches) - 1 else None,
+            decode_span=dspan if last else None,
             pack_use=pack_use,
+            hist=hist_dev,
+            quantiles=quantiles_dev if last else None,
+            fetch_hist=last and not ranks_q,
         )
         cache_tags.append(cache_tag)
         t_absorb0 = _time.perf_counter()
@@ -1342,6 +1390,48 @@ def _reduce_partials(
             dspan.tag(
                 "pack_off_cpu_ms", round(sum(w for w, _ in pack_use) * 1000, 3)
             ).tag("pack_minflt", sum(f for _, f in pack_use))
+    # --- dense [G] arrays -> nonempty-group records (codes stay dense
+    # int32 rows; value tuples materialize lazily, Partials.groups) -------
+    if group_tags:
+        nz = np.nonzero(count > 0)[0]
+        codes = (
+            np.stack(np.unravel_index(nz, radices), axis=1).astype(np.int32)
+            if len(nz)
+            else np.zeros((0, len(group_tags)), np.int32)
+        )
+    else:
+        nz = np.asarray([0])
+        codes = np.zeros((1, 0), np.int32)
+    hist = ranks = None
+    if want_percentile and ranks_q:
+        ranks = (
+            whole["ranks"][nz]
+            if "ranks" in whole
+            else np.zeros((len(nz), len(ranks_q), 3), np.int32)
+        )
+        _settle_ranks(ranks, count[nz], ranks_q, nz, hist_dev, leg)
+    elif want_percentile:
+        hist = (
+            whole["hist"].reshape(G, _NUM_HIST_BUCKETS)[nz].astype(np.float64)
+            if "hist" in whole
+            else np.zeros((len(nz), _NUM_HIST_BUCKETS), np.float64)
+        )
+    hist_dev = None
+    if want_percentile and chunk_spans:
+        device_bytes = G * _NUM_HIST_BUCKETS * 4
+        meter = obs_metrics.global_meter()
+        meter.counter_add(
+            "percentile_hist_bytes", float(leg.hist_bytes),
+            labels={"where": "fetched"},
+        )
+        meter.counter_add(
+            "percentile_hist_bytes", float(device_bytes - leg.hist_bytes),
+            labels={"where": "kept"},
+        )
+        if span is not None:
+            span.tag("hist_groups", G).tag(
+                "hist_device_bytes", device_bytes
+            ).tag("hist_fetched_bytes", leg.hist_bytes)
     if span is not None:
         total_ms = (_time.perf_counter() - t_reduce0) * 1000
         leg.tag(span)
@@ -1363,18 +1453,6 @@ def _reduce_partials(
                 "device_cache", "built" if "built" in cache_tags else "hit"
             )
 
-    # --- dense [G] arrays -> nonempty-group records (codes stay dense
-    # int32 rows; value tuples materialize lazily, Partials.groups) -------
-    if group_tags:
-        nz = np.nonzero(count > 0)[0]
-        codes = (
-            np.stack(np.unravel_index(nz, radices), axis=1).astype(np.int32)
-            if len(nz)
-            else np.zeros((0, len(group_tags)), np.int32)
-        )
-    else:
-        nz = np.asarray([0])
-        codes = np.zeros((1, 0), np.int32)
     rep_key = None
     if rep_ts_acc is not None:
         # [K, 2] (absolute ts, row) scan-order key, compared
@@ -1412,14 +1490,42 @@ def _reduce_partials(
         sums={f: sums[f][nz] for f in spec.fields},
         mins={f: mins[f][nz] for f in spec.fields},
         maxs={f: maxs[f][nz] for f in spec.fields},
-        hist=hist[nz] if hist is not None else None,
+        hist=hist,
         hist_lo=hist_lo,
         hist_span=hist_span,
         field_stats=field_stats,
         rep_key=rep_key,
         rep_desc=rep_desc,
         rep_vals=rep_vals,
+        ranks=ranks,
+        ranks_q=ranks_q if ranks is not None else (),
     )
+
+
+def _settle_ranks(ranks, count, qs, rows, hist_dev, leg) -> None:
+    """Hold the device's ranks [K, Q, 3] to the host's own: the host
+    takes each rank ceil(q*N) in f64 from the group's count, as
+    ``_invert_histogram`` does, and the device took it in f32, which can
+    land one rank off where q*N lies within f32's rounding of a whole
+    number.  A triple is the host's iff its bucket holds the host's rank
+    (count below < rank <= count below + count in it); the groups whose
+    triple does not are fetched as histogram rows (``rows`` index the
+    device histogram ``hist_dev``, flat [G * 512]) and inverted here, in
+    place."""
+    total = count[:, None]
+    target = _quantile_targets(total, qs)
+    before, at = ranks[..., 1], ranks[..., 2]
+    off = ((target <= before) | (target > before + at)) & (total > 0)
+    redo = np.nonzero(off.any(axis=1))[0]
+    if not redo.size:
+        return
+    at = jnp.asarray(rows[redo].astype(np.int32))
+    # bdlint: disable=host-sync -- the histogram rows of the few groups
+    # whose f32 rank missed the f64 one; no other query fetches any
+    counts = jax.device_get(hist_dev.reshape(-1, _NUM_HIST_BUCKETS)[at])
+    leg.hist_bytes += counts.nbytes
+    _, _, hit, below, inside = _histogram_ranks(counts.astype(np.float64), qs)
+    ranks[redo] = np.stack([hit, below, inside], axis=-1).astype(np.int32)
 
 
 def _host_float_partials(
@@ -1927,6 +2033,8 @@ def combine_partials(partials: list[Partials]) -> Partials:
     Histograms only combine when every contributing partial used the same
     (hist_lo, hist_span) — the distributed two-pass guarantees this.
     """
+    if any(p.ranks is not None for p in partials):
+        raise ValueError("inverted percentile partials do not combine")
     base = partials[0]
     want_hist = base.hist is not None
     want_rep = all(p.rep_key is not None for p in partials)
@@ -2192,14 +2300,84 @@ def _finalize_partials_inner(
     if agg:
         if agg.function == "percentile":
             qs = list(agg.quantiles or (0.5,))
-            result.values[f"percentile({agg.field_name})"] = _invert_histogram(
-                p.hist, group_ids, qs, p.hist_lo, p.hist_span
-            )
+            # the host's part of the inversion: the whole of it over a
+            # combined histogram, the f64 estimate over the device's ranks
+            ispan = mspan.child("invert") if mspan is not None else None
+            if p.ranks is not None:
+                if tuple(qs) != tuple(p.ranks_q):
+                    raise ValueError("ranks inverted for other quantiles")
+                values = _invert_ranks(
+                    p.ranks, count, group_ids, qs, p.hist_lo, p.hist_span
+                )
+            else:
+                values = _invert_histogram(
+                    p.hist, group_ids, qs, p.hist_lo, p.hist_span
+                )
+            if ispan is not None:
+                ispan.tag("groups", len(group_ids)).finish()
+            result.values[f"percentile({agg.field_name})"] = values
         else:
             v = agg_values(agg.function, agg.field_name)[group_ids]
             result.values[f"{agg.function}({agg.field_name})"] = v.tolist()
     result.values["count"] = count[group_ids].tolist()
     return result
+
+
+def _quantile_targets(total: np.ndarray, qs) -> np.ndarray:
+    """[K, 1] group counts -> [K, Q] f64 ranks of the quantiles:
+    ceil(q*N) clamped to [1, N] so q=0 lands on the min-value bucket."""
+    q = np.asarray(qs, dtype=np.float64)[None, :]  # [1, Q]
+    return np.clip(np.ceil(q * total), 1.0, np.maximum(total, 1.0))
+
+
+def _histogram_ranks(counts: np.ndarray, qs):
+    """f64 [K, B] histograms -> (total [K, 1], target, hit bucket, count
+    below it, count in it; [K, Q] each): the host half of
+    ops.invert_histogram, in f64."""
+    cdf = np.cumsum(counts, axis=1)  # [G, B]
+    total = cdf[:, -1:]  # [G, 1]
+    target = _quantile_targets(total, qs)
+    hit = np.argmax(cdf[:, None, :] >= target[:, :, None], axis=2)  # [G, Q]
+    cdf_at = np.take_along_axis(cdf, hit, axis=1)
+    cnt_at = np.take_along_axis(counts, hit, axis=1)
+    return total, target, hit, cdf_at - cnt_at, cnt_at
+
+
+def _estimate(total, target, hit, prev, cnt_at, lo: float, span: float) -> list:
+    """The quantile estimate, linear inside the hit bucket, as a list
+    [K][Q]; an empty group reads `lo`."""
+    width = span / _NUM_HIST_BUCKETS
+    frac = np.where(cnt_at > 0, (target - prev) / np.maximum(cnt_at, 1.0), 0.0)
+    est = lo + (hit + np.clip(frac, 0.0, 1.0)) * width
+    est = np.where(total > 0, est, lo)
+    return est.tolist()
+
+
+def _invert_ranks(
+    ranks: np.ndarray,
+    count: np.ndarray,
+    group_ids: np.ndarray,
+    qs: list[float],
+    lo: float,
+    span: float,
+) -> list[list[float]]:
+    """The estimate from the device's ranks (``Partials.ranks``): the
+    same f64 arithmetic as ``_invert_histogram``, on the same integers,
+    so the same bytes."""
+    ids = np.asarray(group_ids, dtype=np.int64)
+    if ids.size == 0:
+        return []
+    total = count[ids][:, None]
+    r = ranks[ids]
+    return _estimate(
+        total,
+        _quantile_targets(total, qs),
+        r[..., 0].astype(np.int64),
+        r[..., 1].astype(np.float64),
+        r[..., 2].astype(np.float64),
+        lo,
+        span,
+    )
 
 
 def _invert_histogram(
@@ -2213,7 +2391,6 @@ def _invert_histogram(
     same interpolation the device kernel uses
     (ops/percentile.py group_percentile_histogram), on [G, B] arrays
     instead of a per-group per-quantile Python loop."""
-    width = span / _NUM_HIST_BUCKETS
     ids = np.asarray(group_ids, dtype=np.int64)
     if ids.size == 0:
         return []
@@ -2222,15 +2399,4 @@ def _invert_histogram(
     valid = ids < len(hist)
     counts = np.zeros((ids.size, hist.shape[1]), dtype=np.float64)
     counts[valid] = hist[ids[valid]]
-    cdf = np.cumsum(counts, axis=1)  # [G, B]
-    total = cdf[:, -1:]  # [G, 1]
-    q = np.asarray(qs, dtype=np.float64)[None, :]  # [1, Q]
-    target = np.clip(np.ceil(q * total), 1.0, np.maximum(total, 1.0))
-    hit = np.argmax(cdf[:, None, :] >= target[:, :, None], axis=2)  # [G, Q]
-    cdf_at = np.take_along_axis(cdf, hit, axis=1)
-    cnt_at = np.take_along_axis(counts, hit, axis=1)
-    prev = cdf_at - cnt_at
-    frac = np.where(cnt_at > 0, (target - prev) / np.maximum(cnt_at, 1.0), 0.0)
-    est = lo + (hit + np.clip(frac, 0.0, 1.0)) * width
-    est = np.where(total > 0, est, lo)
-    return est.tolist()
+    return _estimate(*_histogram_ranks(counts, qs), lo, span)

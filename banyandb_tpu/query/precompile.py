@@ -319,6 +319,16 @@ def builtin_fused_decode():
     )
 
 
+def _whole_scan_warm_args(fspec) -> tuple:
+    """The histogram and quantile args of a program that inverts: the
+    first batch's (no histogram yet) and Q quantiles."""
+    import jax.numpy as jnp
+
+    if not fspec.quantiles:
+        return ()
+    return (None, jnp.zeros(fspec.quantiles, jnp.float32))
+
+
 def fused_warm_args(fspec) -> tuple:
     """Zero-filled production-shaped args for one fused plan program."""
     import jax.numpy as jnp
@@ -328,6 +338,7 @@ def fused_warm_args(fspec) -> tuple:
         _zeros_like_structs(pred_struct(fspec.plan)),
         jnp.float32(0.0),
         jnp.float32(1.0),
+        *_whole_scan_warm_args(fspec),
     )
 
 
@@ -341,6 +352,7 @@ def fused_decode_warm_args(fspec) -> tuple:
         _zeros_like_structs(pred_struct(fspec.plan)),
         jnp.float32(0.0),
         jnp.float32(1.0),
+        *_whole_scan_warm_args(fspec),
     )
 
 
@@ -366,7 +378,11 @@ def spec_from_json(d: dict):
         from banyandb_tpu.query.fused_exec import FusedSpec
 
         _, plan = spec_from_json({**d["plan"], "kind": "measure"})
-        return kind, FusedSpec(plan=plan, num_chunks=int(d["num_chunks"]))
+        return kind, FusedSpec(
+            plan=plan,
+            num_chunks=int(d["num_chunks"]),
+            quantiles=int(d.get("quantiles", 0)),
+        )
     if kind == "measure":
         from banyandb_tpu.query.measure_exec import PlanSpec, _PredSpec
 

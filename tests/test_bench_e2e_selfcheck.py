@@ -78,7 +78,7 @@ ADOPTED = _adopt()
 def test_the_fast_files_were_adopted():
     assert {
         "test_selfcheck.py", "test_control.py", "test_control_ep9k.py", "test_control_ep400k.py",
-        "test_control_r1ep9k.py",
+        "test_control_r1ep9k.py", "test_control_ep400k_pctl.py",
     } <= set(ADOPTED)
     assert not SLOW & set(ADOPTED)
     assert any(k.startswith("test_selfcheck__test_selfcheck") for k in globals())
@@ -327,8 +327,8 @@ def test_ep400k_is_a_cell_at_issue_33s_size():
     `BYDB_MAX_PERSISTENT_GROUPS`' default, one day a message."""
     bench = _bench_json("BENCHMARK.json")
     (entry,) = [c for c in bench["configs"] if c["name"] == "ep400k"]
-    (cell,) = [w for w in bench["workloads"] if w["config"] == "ep400k"]
-    assert (cell["name"], cell["traffic"], cell["chips"]) == ("ep400k.topn-7d", "topn-7d", 1)
+    (cell,) = [w for w in bench["workloads"] if w["name"] == "ep400k.topn-7d"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("ep400k", "topn-7d", 1)
     assert all(w["chips"] == 1 for w in bench["workloads"])
     cfg = _bench_json(entry["file"])
     assert cfg["guarantees"] == _bench_json("benchmarks", "e2e", "configs", "ep9k.json")["guarantees"]
@@ -385,3 +385,119 @@ def test_r1ep9k_is_a_cell_at_issue_35s_size():
     assert [w["traffic"] for w in bench["workloads"][:4]] == list(older)
     for name in older:
         assert _bench_json("benchmarks", "e2e", "traffic", name + ".json")["clients"] == 1
+
+
+# the two metrics of the percentile histogram's layers, read in the two
+# cells that run a percentile plan: name -> (the reader the file must
+# hold, [(case, tree, what it reads)])
+PERCENTILE_CELLS = ["svc1k.pctl-6h", "ep400k.pctl-7d"]
+
+
+def _merge_tree(invert_ms: float | None) -> dict:
+    """A percentile answer's tree; without `invert` as the parent of the
+    span builds it, where the inversion ran inside `merge`."""
+    merge = {"name": "merge", "duration_ms": 9.0, "tags": {"groups": 1000}, "children": []}
+    if invert_ms is not None:
+        merge["children"].append(
+            {"name": "invert", "duration_ms": invert_ms, "tags": {"groups": 1000},
+             "children": []}
+        )
+    return {"name": "standalone:measure", "duration_ms": 60.0, "children": [
+        {"name": "execute", "duration_ms": 50.0, "children": [merge]},
+    ]}
+
+
+PERCENTILE_FILES = {
+    "invert_ms": (
+        {"kind": "span_self_ms", "span": "invert"},
+        [("inverted", _merge_tree(0.812), 0.812), ("parent", _merge_tree(None), None)],
+    ),
+    "hist_fetched_mb_per_query": (
+        {"kind": "span_tag", "span": "reduce", "tag": "hist_fetched_bytes", "scale": 1e-06},
+        # where partials combine the int32 [G, 512] crosses once; the
+        # standalone path inverts on the device and fetches none of it
+        [("combined", _reduce_tree({"hist_fetched_bytes": 400000 * 512 * 4}), 819.2),
+         ("inverted", _reduce_tree({"hist_fetched_bytes": 0, "hist_groups": 400000}), 0.0),
+         ("parent", _reduce_tree({"partials_bytes": 2486400000}), None)],
+    ),
+}
+
+
+@pytest.mark.parametrize(
+    "name, tree, want",
+    [
+        pytest.param(name, tree, want, id=f"{name}-{case}")
+        for name, (_, cases) in PERCENTILE_FILES.items()
+        for case, tree, want in cases
+    ],
+)
+def test_a_percentile_metric_file_reads_what_the_program_records(name, tree, want):
+    """Each file is data for a reader that is there, names the two cells
+    that run a percentile plan (`cells` and BENCHMARK.json's `workloads`
+    agree), and on the parent's tree, which has no such span or tag,
+    returns nothing and does not raise."""
+    readers = _load(os.path.join(CHECKOUT, "benchmarks", "e2e", "readers.py"), "bench_e2e_readers")
+    metric = _bench_json("benchmarks", "e2e", "metrics", name + ".json")
+    assert metric["reader"] == PERCENTILE_FILES[name][0]
+    assert metric["cells"] == PERCENTILE_CELLS and metric["better"] == "lower"
+    (entry,) = [m for m in _bench_json("BENCHMARK.json")["per_layer"] if m["name"] == name]
+    keys = ("name", "unit", "better", "source", "layer", "moves")
+    assert entry == dict({k: metric[k] for k in keys}, workloads=PERCENTILE_CELLS)
+    assert entry["moves"] == "query_p50_ms"
+    rec = {"queries": [{"served": "scan", "tree": tree} for _ in range(3)]}
+    got = readers.read(metric, rec)
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def test_ep400k_pctl_7d_is_the_percentile_half_of_the_node():
+    """The cell: `ep400k`'s node under its percentile source, asked p50
+    and p99 of every endpoint of a drawn zone over 7 days, one client, one
+    chip, the three end-to-end metrics the benchmark has; the five cells
+    before it are there as they were."""
+    bench = _bench_json("BENCHMARK.json")
+    (cell,) = [w for w in bench["workloads"] if w["traffic"] == "pctl-7d"]
+    assert (cell["name"], cell["config"], cell["chips"]) == ("ep400k.pctl-7d", "ep400k-pctl", 1)
+    assert bench["workloads"][-1] == cell and len(cell["why"]) <= 200
+    assert [w["traffic"] for w in bench["workloads"][:5]] == [
+        "topn-24h", "pctl-6h", "topn-6h", "topn-7d", "topn-15m-c50",
+    ]
+    mix = _bench_json("benchmarks", "e2e", "traffic", "pctl-7d.json")
+    panel = mix["panels"]["pctl"]
+    assert (mix["clients"], mix["warm_spread"], mix["cycle"]) == (1, 1, ["pctl"])
+    assert panel["quantiles"] == [0.5, 0.99] and panel["group_by"] == "svc"
+    assert panel["limit"] == 400000 == _bench_json(
+        "benchmarks", "e2e", "configs", "ep400k-pctl.json"
+    )["data"]["series"]
+    topn = _bench_json("benchmarks", "e2e", "traffic", "topn-7d.json")["panels"]["topn"]
+    assert (panel["range_ms"], panel["lo"]) == (topn["range_ms"], topn["lo"])
+    assert all("workloads" not in m for m in bench["end_to_end"])
+
+
+def test_ep400k_pctl_is_ep400k_s_node_under_the_percentile_source():
+    """`ep400k-pctl` is the last configuration, with a file of its own: the
+    node `ep400k` holds (schema, data and cuts the same, so the same seed
+    loads the same points), under BASELINE.json's percentile fan-out line
+    (configs[4]) instead of the TopN/percentile one, with the guarantees a
+    percentile answer is held to and no TopN one."""
+    bench = _bench_json("BENCHMARK.json")
+    entry, old = bench["configs"][-1], [c for c in bench["configs"] if c["name"] == "ep400k"][0]
+    assert [c["name"] for c in bench["configs"]] == [
+        "topn100k", "svc1k", "ep9k", "ep400k", "r1ep9k", "ep400k-pctl",
+    ]
+    assert entry["file"] == "benchmarks/e2e/configs/ep400k-pctl.json" != old["file"]
+    assert entry["reduced"] == old["reduced"] and entry["source"] != old["source"]
+    assert "BASELINE.json configs[4]" in entry["source"] and "percentile" in entry["source"]
+    assert len(entry["why"]) <= 200 and len(entry["source"]) <= 200
+    cfg = _bench_json(*entry["file"].split("/"))
+    node = _bench_json(*old["file"].split("/"))
+    assert (cfg["name"], cfg["source"]) == (entry["name"], entry["source"])
+    assert (cfg["schema"], cfg["data"], cfg["reduced"]) == (
+        node["schema"], node["data"], node["reduced"]
+    )
+    assert [g for g in node["guarantees"] if g not in cfg["guarantees"]] == [
+        g for g in node["guarantees"] if g.startswith("TopN")
+    ]
+    assert any("percentile" in g for g in cfg["guarantees"])
+    assert [w["name"] for w in bench["workloads"] if w["config"] == "ep400k-pctl"] == [
+        "ep400k.pctl-7d"
+    ]

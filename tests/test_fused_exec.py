@@ -428,6 +428,34 @@ def test_multichunk_batches_parity_all_builtin_signatures(name, monkeypatch):
     assert _result_json(m, req, p_one) == _result_json(m, req, p_bat)
 
 
+@pytest.mark.parametrize("scan_chunk", [None, 2048], ids=["one-chunk", "multi-chunk"])
+@pytest.mark.parametrize("max_mb", SIDES)
+def test_percentile_ranks_parity(scan_chunk, max_mb, monkeypatch):
+    """A percentile partial finalized alone holds the device's ranks of
+    its quantiles, not the histogram: byte-identical (ranks included)
+    whatever the batching, no histogram byte fetched, and the result
+    JSON is the one the combinable partial's histogram gives."""
+    if scan_chunk is not None:
+        monkeypatch.setattr(measure_exec, "SCAN_CHUNK", scan_chunk)
+    m, req, srcs = next(
+        (m, r, s) for n, m, r, s in _scenarios() if n == "percentile-hist"
+    )
+    p_one, _ = _run(m, req, srcs, ONE_BATCH, monkeypatch, final=True)
+    p_fin, t_fin = _run(m, req, srcs, max_mb, monkeypatch, final=True)
+    p_hist, t_hist = _run(m, req, srcs, max_mb, monkeypatch)
+    assert p_fin.hist is None and p_fin.ranks.dtype == np.int32
+    assert p_fin.ranks.shape == (len(p_fin.count), 2, 3)
+    assert p_fin.ranks_q == req.agg.quantiles
+    assert p_hist.ranks is None and p_hist.hist.shape == (len(p_hist.count), 512)
+    assert _partial_bytes(p_fin) == _partial_bytes(p_one)
+    assert _partial_bytes(p_fin) != _partial_bytes(p_hist)
+    assert t_fin["hist_fetched_bytes"] == 0
+    assert t_hist["hist_fetched_bytes"] == t_hist["hist_device_bytes"] == 16 * 512 * 4
+    assert t_fin["dispatches"] == t_hist["dispatches"]
+    assert _result_json(m, req, p_fin) == _result_json(m, req, p_hist)
+    _assert_matches_numpy(m, req, srcs, p_fin)
+
+
 def _three_chunk_scan():
     rng = np.random.default_rng(3)
     n = 3 * 2048
@@ -492,9 +520,9 @@ def test_scan_body_runs_once_per_real_chunk(name, monkeypatch):
     def counting_body(spec):
         body = real_body(spec)
 
-        def counted(chunk, pred_vals, hist_lo, hist_span):
+        def counted(chunk, pred_vals, hist_lo, hist_span, hist=None):
             jax.debug.callback(lambda: calls.append(1))
-            return body(chunk, pred_vals, hist_lo, hist_span)
+            return body(chunk, pred_vals, hist_lo, hist_span, hist)
 
         return counted
 

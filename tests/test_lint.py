@@ -497,6 +497,8 @@ def test_tree_is_bdlint_clean():
     # socket, both closed by their owners' teardown paths) + the
     # exhaustive read-failover walk (cluster/liaison._scatter): every
     # round dials a DIFFERENT replica, so inter-round backoff would
-    # only burn the query's deadline budget
-    assert stats["suppressed"] == 11
+    # only burn the query's deadline budget + the percentile ranks'
+    # settling (measure_exec._settle_ranks): the histogram rows of the
+    # rare groups whose f32 device rank missed the host's f64 one
+    assert stats["suppressed"] == 12
     assert stats["files"] > 90
