@@ -399,6 +399,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "decode_ship_bytes": frozenset({"form"}),
     "dict_state_kept": frozenset(),
     "dict_values_entered": frozenset({"kind"}),
+    "reply_columns": frozenset({"path"}),
     "dict_state_resets": frozenset(),
     "failover_attempts": frozenset(),
     "jit_compile_seconds": frozenset(),

@@ -12,6 +12,7 @@ health, snapshot.
 from __future__ import annotations
 
 import base64
+import itertools
 import os
 import time
 from pathlib import Path
@@ -24,6 +25,7 @@ from banyandb_tpu.cluster import serde
 from banyandb_tpu.cluster.bus import LocalBus, Topic
 from banyandb_tpu.admin.accesslog import AccessLog
 from banyandb_tpu.admin.metrics import SelfMeasureSink
+from banyandb_tpu.obs.metrics import global_meter
 from banyandb_tpu.obs.tracer import attach_tree
 from banyandb_tpu.admin.protector import MemoryProtector
 from banyandb_tpu.cluster.rpc import GrpcBusServer, tag_qos
@@ -107,16 +109,50 @@ def _jsonable(v):
     return v
 
 
+# value types json.dumps encodes as they are.  Exact types: bool is named
+# apart from int, and a subclass (np.float64, an IntEnum) takes the walk
+_JSON_NATIVE = frozenset({str, int, float, bool, type(None)})
+_ROW_TYPES = frozenset({list, tuple})
+
+
+def _native_column(col: list, rows: bool):
+    """`col` (a fresh list) as json.dumps may take it, or None when some
+    value needs `_jsonable`'s walk (bytes, a dict, a subclass).
+
+    Decided by a type scan at C speed, one level into list / tuple rows:
+    a reply of 50,000 groups is a few scans, not a Python call a value.
+    `rows`: every element must be a row (the group keys); otherwise a
+    column of native scalars passes too.  Tuples become lists; list rows
+    are shared with the QueryResult, which nothing touches once encoded.
+    """
+    types = set(map(type, col))
+    if not rows and types <= _JSON_NATIVE:
+        return col
+    if types <= _ROW_TYPES and (
+        set(map(type, itertools.chain.from_iterable(col))) <= _JSON_NATIVE
+    ):
+        return col if types <= {list} else list(map(list, col))
+    return None
+
+
 def result_to_json(res: QueryResult) -> dict:
+    native = []  # one bool a column: went out without the walk
+
+    def column(vs, rows=False):
+        col = list(vs)
+        out = _native_column(col, rows)
+        native.append(out is not None)
+        if out is not None:
+            return out
+        return [_jsonable(list(g)) for g in col] if rows else _jsonable(col)
+
     out = {
-        "groups": [_jsonable(list(g)) for g in res.groups],
-        "values": {k: _jsonable(list(vs)) for k, vs in res.values.items()},
+        "groups": column(res.groups, rows=True),
+        "values": {k: column(vs) for k, vs in res.values.items()},
         "data_points": [_jsonable(dp) for dp in res.data_points],
     }
     if res.rep_tags:
-        out["rep_tags"] = {
-            t: _jsonable(list(vs)) for t, vs in res.rep_tags.items()
-        }
+        out["rep_tags"] = {t: column(vs) for t, vs in res.rep_tags.items()}
     if res.trace is not None:
         out["trace"] = res.trace
     if getattr(res, "degraded", False):
@@ -124,6 +160,14 @@ def result_to_json(res: QueryResult) -> dict:
         # must be able to tell "empty" from "missing replicas"
         out["degraded"] = True
         out["unavailable_nodes"] = sorted(res.unavailable_nodes)
+    meter = global_meter()
+    n_native = sum(native)
+    if n_native:
+        meter.counter_add("reply_columns", n_native, {"path": "native"})
+    if n_native < len(native):
+        meter.counter_add(
+            "reply_columns", len(native) - n_native, {"path": "walked"}
+        )
     return out
 
 
@@ -141,7 +185,6 @@ class StandaloneServer:
         workers: int | None = None,
     ):
         from banyandb_tpu.obs import SlowQueryRecorder
-        from banyandb_tpu.obs.metrics import global_meter
         from banyandb_tpu.utils.envflag import env_float, env_int
 
         # worker count first, before anything opens: -1 = auto, resolved
