@@ -34,6 +34,16 @@ def decode_tag_value(raw: bytes, tag_type: TagType):
     return raw
 
 
+def decode_tag_column(raws, tag_type: TagType) -> list:
+    """`decode_tag_value` over a column of raw values, in one pass: the
+    same rules, without a Python call and a type test a value."""
+    if tag_type == TagType.INT:
+        return [int.from_bytes(v, "little", signed=True) if v else 0 for v in raws]
+    if tag_type == TagType.STRING:
+        return [v.decode(errors="replace") for v in raws]
+    return list(raws)
+
+
 _RANGE_OPS = {"lt", "le", "gt", "ge"}
 
 _WORD_RE = __import__("re").compile(r"[0-9A-Za-z]+")

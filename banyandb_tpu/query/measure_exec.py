@@ -23,6 +23,7 @@ row count (tests/test_precision.py).
 
 from __future__ import annotations
 
+import operator
 import os
 import threading
 import time
@@ -544,6 +545,14 @@ def _lower_criteria(c: Optional[Criteria]) -> tuple[list[Condition], tuple]:
     return conds, (() if pure_and(expr) else expr)
 
 
+def _take(seq, ids: list):
+    """[seq[i] for i in ids] as one C-level gather (`itemgetter` hands a
+    single index back bare, so fewer than two take the comprehension)."""
+    if len(ids) > 1:
+        return operator.itemgetter(*ids)(seq)
+    return [seq[i] for i in ids]
+
+
 class Partials:
     """Per-node partial aggregates keyed by decoded tag-value tuples.
 
@@ -616,16 +625,8 @@ class Partials:
             k = self.codes.shape[0]
             if not self.group_tags:
                 self._groups = [()] * k
-            elif k == 0:
-                self._groups = []
             else:
-                cols = [
-                    np.asarray(self.group_values[t], dtype=object)[
-                        self.codes[:, i]
-                    ]
-                    for i, t in enumerate(self.group_tags)
-                ]
-                self._groups = list(zip(*cols))
+                self._groups = list(zip(*self.group_columns(np.arange(k, dtype=np.int64))))
         return self._groups
 
     @groups.setter
@@ -640,6 +641,19 @@ class Partials:
             self.group_values[t][int(self.codes[i, j])]
             for j, t in enumerate(self.group_tags)
         )
+
+    def group_columns(self, ids: np.ndarray) -> list:
+        """The value tuples of groups `ids` as columns, one sequence a
+        group tag: `group_key` over many groups, one C-level gather a
+        column instead of a Python step a group."""
+        if self._groups is not None:
+            if not len(ids):
+                return [[] for _ in self.group_tags]
+            return list(zip(*_take(self._groups, ids.tolist())))
+        return [
+            _take(self.group_values[t], col)
+            for t, col in zip(self.group_tags, self.codes[ids].T.tolist())
+        ]
 
     def content_bytes(self) -> bytes:
         """Canonical byte serialization of every numeric/representative
@@ -2273,29 +2287,34 @@ def _finalize_partials_inner(
         group_ids = group_ids[off:]
     group_ids = group_ids[: request.limit] if request.limit else group_ids
 
-    # Decode group tuples (bytes) to client values via the schema types.
+    # Decode group tuples (bytes) to client values via the schema types,
+    # a column at a time: one gather and one comprehension a group tag.
     from banyandb_tpu.query import filter as qfilter
 
-    for g in group_ids:
-        raw = p.group_key(int(g))
-        result.groups.append(
-            tuple(
-                qfilter.decode_tag_value(v, measure.tag(t).type)
-                for t, v in zip(group_tags, raw)
-            )
-        )
+    ids = group_ids.tolist()
+    if not group_tags:
+        result.groups = [()] * len(ids)
+    elif ids:
+        cols = [
+            qfilter.decode_tag_column(raw, measure.tag(t).type)
+            for t, raw in zip(group_tags, p.group_columns(group_ids))
+        ]
+        result.groups = list(zip(*cols))
+    if mspan is not None:
+        mspan.tag("decoded_groups", len(result.groups))
     if p.rep_vals:
         # representative (first-scanned row) values for projected-but-
-        # not-grouped tags, aligned with result.groups
+        # not-grouped tags, aligned with result.groups; None where a group
+        # has none
         for t, vals in p.rep_vals.items():
-            result.rep_tags[t] = [
-                (
-                    qfilter.decode_tag_value(vals[int(g)], measure.tag(t).type)
-                    if vals[int(g)] is not None
-                    else None
-                )
-                for g in group_ids
-            ]
+            raw = _take(vals, ids)
+            present = [v for v in raw if v is not None]
+            decoded = iter(
+                qfilter.decode_tag_column(present, measure.tag(t).type)
+                if present
+                else ()
+            )
+            result.rep_tags[t] = [None if v is None else next(decoded) for v in raw]
 
     if agg:
         if agg.function == "percentile":

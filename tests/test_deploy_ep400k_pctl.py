@@ -214,7 +214,8 @@ def test_every_percentile_is_the_references(store, region, b0, nb):
     """Every group of the zone, its count exact and both quantiles within
     one bucket; the histogram stays on the device: one dispatch of the
     4-bucket, the ranks of the two quantiles fetched and no histogram
-    byte, and the host's part of the inversion under span `invert`."""
+    byte, and the host's part of the inversion under span `invert`; span
+    `merge` decoded every group of the reply."""
     eng, value = store
     kept = _counted("percentile_hist_bytes", 'where="kept"')
     text, spans = serve(eng, ql_of(region, b0, nb, off=1 + region))
@@ -233,6 +234,7 @@ def test_every_percentile_is_the_references(store, region, b0, nb):
     assert tags["hist_fetched_bytes"] == 0
     assert tags["partials_bytes"] == bucket * SERIES * STATS_BYTES + SERIES * RANKS_BYTES
     assert spans["invert"]["groups"] == GROUPS
+    assert spans["merge"]["decoded_groups"] == len(json.loads(text)["groups"]) == GROUPS
     assert _counted("percentile_hist_bytes", 'where="kept"') - kept == HIST_BYTES
 
 
