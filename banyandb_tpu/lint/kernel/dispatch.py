@@ -410,16 +410,14 @@ def _multichunk_scenario():
     return run
 
 
-def _stream_scenario(narrow: bool = False):
-    """The stream retrieval-mask scenario; ``narrow=True`` feeds the
-    source at stored i8 code width (the device-decode read path,
-    models/stream narrow_codes) so the widen-on-device ship form is
-    budget-audited alongside the dense one."""
+def _stream_scenario():
+    """The stream retrieval-mask scenario, its source at the stored i8
+    code width models/stream reads (``narrow_codes``): the columns ship
+    narrow and widen on device."""
     from banyandb_tpu.api.model import Condition
     from banyandb_tpu.query import precompile, stream_exec
 
     builtin = dict(precompile.builtin_masks())["stream/mask-eq-in"]
-    code_dtype = np.int8 if narrow else np.int32
 
     def run():
         n = 32768
@@ -430,11 +428,11 @@ def _stream_scenario(narrow: bool = False):
             {
                 "svc": (
                     [b"a", b"b"],
-                    rng.integers(0, 2, n).astype(code_dtype),
+                    rng.integers(0, 2, n).astype(np.int8),
                 ),
                 "region": (
                     [b"r0", b"r1", b"r2", b"r3"],
-                    rng.integers(0, 4, n).astype(code_dtype),
+                    rng.integers(0, 4, n).astype(np.int8),
                 ),
             },
             {},
@@ -446,8 +444,7 @@ def _stream_scenario(narrow: bool = False):
         mask = stream_exec.device_tag_mask(src, conds)
         assert mask is not None and mask.shape == (n,)
 
-    name = "stream+decode/mask-eq-in" if narrow else "stream/mask-eq-in"
-    return (name, builtin, run)
+    return ("stream/mask-eq-in", builtin, run)
 
 
 def _ql_scenarios():
@@ -509,74 +506,32 @@ def _anchor(kind: str) -> tuple[str, int]:
     return _rel_path(inspect.getsourcefile(mod)), inspect.getsourcelines(fn)[1]
 
 
-@contextlib.contextmanager
-def _env(overrides: Optional[dict]):
-    """Scoped os.environ overrides ({} / None = ambient values)."""
-    import os
-
-    overrides = overrides or {}
-    saved = {name: os.environ.get(name) for name in overrides}
-    os.environ.update(overrides)
-    try:
-        yield
-    finally:
-        for name, old in saved.items():
-            if old is None:
-                os.environ.pop(name, None)
-            else:
-                os.environ[name] = old
-
-
 def audit_dispatch() -> dict[str, DispatchTrace]:
     """Run every scenario under the stub device -> measured traces.
 
-    Each measure scenario runs TWICE: in the dense ship form (the
-    ``fused/*`` rows, pinned to the precompile registry's builtin
-    FusedSpecs at dispatches=1/gets=1) and with ``BYDB_DEVICE_DECODE=1``
-    (the ``fused+decode/*`` rows: the compressed ship form must STILL
-    cost exactly one dispatch and one batched get — the decode stage
-    fuses into the plan program or the whole point is lost), plus the
-    multi-chunk staging tripwire.  The ``fused/*`` rows pin
-    ``BYDB_DEVICE_DECODE=0`` explicitly so their put counts stay the
-    dense-ship baseline regardless of the ambient default."""
-    fused_env = {"BYDB_DEVICE_DECODE": "0"}
-    decode_env = {"BYDB_DEVICE_DECODE": "1"}
-    measure = _measure_scenarios()
+    Each measure scenario (the ``fused/*`` rows, pinned to the
+    precompile registry's builtin FusedSpecs) must cost exactly one
+    dispatch and one batched get with its batch shipped compressed —
+    the decode stage fuses into the plan program or the whole point is
+    lost — plus the multi-chunk staging tripwire."""
     scenarios = [
-        (name, "measure", builtin, run, fused_env)
-        for name, builtin, run in measure
-    ]
-    # same builtin FusedSpec: the ship form changes the chunk pytree,
-    # never the plan signature
-    scenarios += [
-        (name.replace("fused/", "fused+decode/"), "measure", builtin, run, decode_env)
-        for name, builtin, run in measure
+        (name, "measure", builtin, run)
+        for name, builtin, run in _measure_scenarios()
     ]
     scenarios.append(
-        ("fused/multi-chunk", "measure", None, _multichunk_scenario(), fused_env)
-    )
-    scenarios.append(
-        (
-            "fused+decode/multi-chunk",
-            "measure",
-            None,
-            _multichunk_scenario(),
-            decode_env,
-        )
+        ("fused/multi-chunk", "measure", None, _multichunk_scenario())
     )
     s_name, s_builtin, s_run = _stream_scenario()
-    scenarios.append((s_name, "stream_mask", s_builtin, s_run, {"BYDB_DEVICE_DECODE": "0"}))
-    d_name, d_builtin, d_run = _stream_scenario(narrow=True)
-    scenarios.append((d_name, "stream_mask", d_builtin, d_run, {"BYDB_DEVICE_DECODE": "1"}))
+    scenarios.append((s_name, "stream_mask", s_builtin, s_run))
     scenarios += [
-        (name, "ql", builtin, run, None)
+        (name, "ql", builtin, run)
         for name, builtin, run in _ql_scenarios()
     ]
 
     out: dict[str, DispatchTrace] = {}
-    for name, kind, builtin, run, env in scenarios:
+    for name, kind, builtin, run in scenarios:
         path, line = _anchor(kind)
-        with stub_device() as counters, _env(env):
+        with stub_device() as counters:
             error = ""
             try:
                 run()

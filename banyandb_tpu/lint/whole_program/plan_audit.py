@@ -305,30 +305,6 @@ def default_entries() -> list[KernelAudit]:
             )
         )
 
-    # the device-decode twins (ROADMAP item 3): SAME fused program, the
-    # COMPRESSED chunk ship form (narrow codes + remap LUTs + narrow int
-    # fields) — the in-program decode stage must keep the output
-    # contract identical and introduce no 64-bit dtypes, and the
-    # lowering audit pins the bytes-accessed class the compression buys
-    for name, fspec in precompile.builtin_fused_decode():
-        fexpect = fused_expect(fspec)
-        entries.append(
-            KernelAudit(
-                name=name,
-                path=str(fpath),
-                line=fline,
-                fn=fused_exec._build_kernel(fspec),
-                args=(
-                    precompile.fused_decode_chunk_struct(fspec),
-                    precompile.pred_struct(fspec.plan),
-                    S((), f32),
-                    S((), f32),
-                ),
-                expect=fexpect,
-                cache_key=fspec,
-            )
-        )
-
     # 6. the shared ops reductions every plan lowers onto, at a
     # representative grouped shape (method dispatch goes through "auto")
     opath = _rel_path(inspect.getsourcefile(ops.groupby))

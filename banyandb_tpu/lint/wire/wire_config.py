@@ -396,7 +396,7 @@ OBS_CONTRACT: dict[str, frozenset | None] = {
     "autoreg_signatures": frozenset({"source"}),
     "blocks_skipped": frozenset({"reason"}),
     "compile_cache_enabled": frozenset(),
-    "decode_ship_bytes": frozenset({"form"}),
+    "decode_ship_bytes": frozenset(),
     "dict_state_kept": frozenset(),
     "dict_values_entered": frozenset({"kind"}),
     "reply_columns": frozenset({"path"}),

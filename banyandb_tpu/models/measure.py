@@ -1085,7 +1085,6 @@ class MeasureEngine:
         tag_names = _tag_col_names(m)  # incl. '@f:' raw-field columns
         field_names = [f.name for f in _numeric_fields(m)]
         entity_conds = _entity_eq_conditions(m, req)
-        narrow = enc_mod.device_decode_enabled()
         # Zone-map skipping (ROADMAP item 3 / arXiv 2104.12815):
         # conjunctive eq/in tag predicates prune at BLOCK granularity
         # against the per-block code zone maps written at flush/merge —
@@ -1175,7 +1174,7 @@ class MeasureEngine:
                     blocks,
                     tags=[t for t in tag_names if t in part.meta["tags"]],
                     fields=[f for f in field_names if f in part.meta["fields"]],
-                    narrow_codes=narrow,
+                    narrow_codes=True,
                     outcome=outcome,
                 )
                 return filt(src, src.cache_key), outcome[0]

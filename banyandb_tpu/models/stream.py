@@ -26,7 +26,6 @@ from banyandb_tpu.obs import metrics as obs_metrics
 from banyandb_tpu.obs.tracer import NOOP_TRACER, Tracer
 from banyandb_tpu.query import filter as qfilter
 from banyandb_tpu.query import measure_exec
-from banyandb_tpu.storage import encoded as _encoded
 from banyandb_tpu.storage.memtable import PayloadMemtable
 from banyandb_tpu.storage.part import ColumnData
 from banyandb_tpu.storage.tsdb import TSDB
@@ -306,7 +305,7 @@ class StreamEngine:
                                     if t in p.meta["tags"]
                                 ],
                                 want_payload=True,
-                                narrow_codes=_encoded.device_decode_enabled(),
+                                narrow_codes=True,
                             )
                         )
         for src in prefetched(read_ops, name="bydb-stream-prefetch"):

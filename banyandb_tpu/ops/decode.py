@@ -126,8 +126,8 @@ def decode_chunk(chunk: dict) -> dict:
     - ``src_ord``   per-row source ordinal (shared by all tag columns)
     - ``fields_enc``  narrow exact-int field columns
 
-    Chunks without those keys (``BYDB_DEVICE_DECODE=0``) pass through
-    unchanged, which is what makes the A/B flag a pure ship-form flip.
+    A chunk with nothing to decode (no tag column, every field shipped
+    as f32) passes through unchanged.
     """
     if "tags_enc" not in chunk and "fields_enc" not in chunk:
         return chunk

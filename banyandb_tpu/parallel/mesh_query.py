@@ -140,7 +140,10 @@ class MeshExecutor:
                 req.time_range.end_millis,
             )
             if cols["ts"].shape[0]:
-                per_node_cols.append(cols)
+                # the mesh step reads global codes: composed on the host
+                per_node_cols.append(
+                    measure_exec._materialize_tag_codes(cols, tags_code)
+                )
 
         radices = tuple(gd.size(t) for t in group_tags)
         num_groups = 1

@@ -132,13 +132,11 @@ def _build_kernel(fspec: FusedSpec):
     on the device, not a select) and a padding chunk's partials are
     zeros the host never reads (``run_fused`` absorbs real chunks only).
 
-    Compressed part-batches (``BYDB_DEVICE_DECODE``) decode inside the
-    step's taken branch: ops.decode.decode_chunk widens/remaps ONE
-    chunk's per-row leaves (the per-batch ``[S, L]`` remap LUTs are
-    closed over: loop invariants, not scanned leaves), then the body
-    sees a canonical chunk.  Elementwise integer decode, so the two
-    ship forms stay byte-identical and a padding chunk is not widened
-    either."""
+    A part-batch ships compressed and decodes inside the step's taken
+    branch: ops.decode.decode_chunk widens/remaps ONE chunk's per-row
+    leaves (the per-batch ``[S, L]`` remap LUTs are closed over: loop
+    invariants, not scanned leaves), then the body sees a canonical
+    chunk; a padding chunk is not widened."""
     from banyandb_tpu import ops
 
     body = _kernel_body(fspec.plan)
@@ -201,16 +199,13 @@ def estimate_bytes(spec: PlanSpec, num_chunks: int) -> int:
     plus the stacked per-chunk partials pytree, plus once (not per
     chunk) a percentile plan's carried int32 histogram.
 
-    Under ``BYDB_DEVICE_DECODE`` the ceiling accounts the compressed
-    inputs (narrow tag/field buffers, the i16 src-ordinal column)
-    ALONGSIDE a decoded i32/f32 copy of every chunk.  Conservative
-    since the decode moved into the scan step (one chunk's decoded copy
-    is live at a time) and left so: no scan is near the budget, and
-    moving the estimate moves the split points.  (The [S, L] remap
-    LUTs are a rounding error next to the per-row columns and ride the
-    same margin.)"""
-    from banyandb_tpu.storage import encoded as enc_mod
-
+    The ceiling accounts the compressed inputs (narrow tag/field
+    buffers, the i16 src-ordinal column) ALONGSIDE a decoded i32/f32
+    copy of every chunk.  Conservative since the decode moved into the
+    scan step (one chunk's decoded copy is live at a time) and left so:
+    no scan is near the budget, and moving the estimate moves the split
+    points.  (The [S, L] remap LUTs are a rounding error next to the
+    per-row columns and ride the same margin.)"""
     g = spec.num_groups
     nf = len(spec.fields)
     per_chunk_out = g * (1 + nf + (2 * nf if spec.want_minmax else 0))
@@ -218,10 +213,9 @@ def estimate_bytes(spec: PlanSpec, num_chunks: int) -> int:
         per_chunk_out += 2 * g
     hist = g * _num_hist_buckets() if spec.hist_field else 0
     cols = len(key_columns(spec)) + len(spec.tags_code) + nf
-    per_row = 4 * cols
-    if enc_mod.device_decode_enabled():
-        # narrow inputs (<=2 B/row per tag/field) + src_ord (2 B/row)
-        per_row += 2 + 2 * (len(spec.tags_code) + nf)
+    # decoded copy (4 B/row a column) + narrow inputs (<=2 B/row per
+    # tag/field) + src_ord (2 B/row)
+    per_row = 4 * cols + 2 + 2 * (len(spec.tags_code) + nf)
     return num_chunks * (per_row * spec.nrows + 4 * per_chunk_out) + 4 * hist
 
 
@@ -281,26 +275,26 @@ def _stacked_chunks(
 
     THE padded chunk layout (per-row dtypes, zero padding, and of the
     epoch-relative int32 ts, the valid mask and the global row index
-    what ``key_columns`` says the program reads), in either ship
-    form: compressed snapshots (``BYDB_DEVICE_DECODE``) stack the narrow
-    local tag codes, the per-row source ordinals and exact-int fields,
-    plus the per-batch [S, L] remap LUTs the in-program decode stage
-    consumes; dense ones the i32 global codes and f32 fields.  Per-column
-    pad work rides the chunk_stream prefetch worker (BYDB_PIPELINE
-    honored) so padding column j+1 overlaps shipping column j.
+    what ``key_columns`` says the program reads), compressed: the narrow
+    local tag codes, the per-row source ordinals, each field at the
+    exact narrow int width ``fields_narrow`` names or as f32 where it
+    names none, plus the per-batch [S, L] remap LUTs the in-program
+    decode stage consumes.  Per-column pad work rides the chunk_stream
+    prefetch worker (BYDB_PIPELINE honored) so padding column j+1
+    overlaps shipping column j.
     ``pack_s`` collects the pad thunks' seconds (worker thread),
     ``h2d_s`` the ``jnp.asarray`` ships' (this thread); ``pack_use``
     (given when the query's spans read their threads' clocks) one
     (seconds off the CPU, minor page faults) pair a pad thunk: whether
     the worker ran or waited (``obs/tracer.thread_usage``); ``ship_stats``
-    one (shipped, dense, packed) byte triple for the whole part-batch
+    one (shipped, packed) byte pair for the whole part-batch
     (decode-span attribution): shipped counts the tag and field columns,
     packed every array padded and shipped.
     """
+    from banyandb_tpu.storage import encoded as enc_mod
     from banyandb_tpu.storage.chunk_stream import prefetched
 
     C, nb = num_chunks, spec.nrows
-    compressed = "src_ord" in cols
 
     def pad2(get, dtype):
         out = np.zeros((C, nb), dtype=dtype)
@@ -323,63 +317,32 @@ def _stacked_chunks(
     }
     paths: list[tuple] = [(k,) for k in key_columns(spec)]
     thunks = [key_thunks[k] for k in key_columns(spec)]
-    counted: set = set()
-    if compressed:
-        from banyandb_tpu.storage import encoded as enc_mod
-
-        if spec.tags_code:
-            for t in spec.tags_code:
-                paths.append(("tags_enc", t))
-                counted.add(("tags_enc", t))
-                thunks.append(
-                    lambda t=t: pad2(
-                        lambda s, e: cols["tags_enc"][t][s:e],
-                        cols["tags_enc"][t].dtype,
-                    )
-                )
-                paths.append(("tags_lut", t))
-                counted.add(("tags_lut", t))
-                thunks.append(
-                    lambda t=t: enc_mod.pack_luts(cols["tags_lut"][t])
-                )
-            paths.append(("src_ord",))
-            counted.add(("src_ord",))
-            thunks.append(
-                lambda: pad2(
-                    lambda s, e: cols["src_ord"][s:e], enc_mod.SRC_ORD_DTYPE
-                )
-            )
-        for f in spec.fields:
-            ndt = cols["fields_narrow"].get(f)
-            if ndt is not None:
-                paths.append(("fields_enc", f))
-                counted.add(("fields_enc", f))
-                thunks.append(
-                    lambda f=f, ndt=ndt: pad2(
-                        lambda s, e: cols["fields"][f][s:e], ndt
-                    )
-                )
-            else:
-                paths.append(("fields", f))
-                counted.add(("fields", f))
-                thunks.append(
-                    lambda f=f: pad2(
-                        lambda s, e: cols["fields"][f][s:e], np.float32
-                    )
-                )
-    else:
+    n_keys = len(paths)  # the rest are the tag and field columns
+    if spec.tags_code:
         for t in spec.tags_code:
-            paths.append(("tags_code", t))
-            counted.add(("tags_code", t))
+            paths.append(("tags_enc", t))
             thunks.append(
-                lambda t=t: pad2(lambda s, e: cols["tags_code"][t][s:e], np.int32)
+                lambda t=t: pad2(
+                    lambda s, e: cols["tags_enc"][t][s:e],
+                    cols["tags_enc"][t].dtype,
+                )
             )
-        for f in spec.fields:
-            paths.append(("fields", f))
-            counted.add(("fields", f))
-            thunks.append(
-                lambda f=f: pad2(lambda s, e: cols["fields"][f][s:e], np.float32)
+            paths.append(("tags_lut", t))
+            thunks.append(lambda t=t: enc_mod.pack_luts(cols["tags_lut"][t]))
+        paths.append(("src_ord",))
+        thunks.append(
+            lambda: pad2(
+                lambda s, e: cols["src_ord"][s:e], enc_mod.SRC_ORD_DTYPE
             )
+        )
+    for f in spec.fields:
+        ndt = cols["fields_narrow"].get(f)
+        paths.append(("fields" if ndt is None else "fields_enc", f))
+        thunks.append(
+            lambda f=f, dt=np.float32 if ndt is None else ndt: pad2(
+                lambda s, e: cols["fields"][f][s:e], dt
+            )
+        )
 
     def timed(fn):
         def pad_thunk():  # host-side work on the prefetch worker
@@ -407,30 +370,30 @@ def _stacked_chunks(
         "fields_enc": {},
     }
     shipped = packed = 0
-    for path, arr in zip(
-        paths,
-        prefetched([timed(fn) for fn in thunks], name="bydb-fused-pad"),
+    for i, (path, arr) in enumerate(
+        zip(
+            paths,
+            prefetched([timed(fn) for fn in thunks], name="bydb-fused-pad"),
+        )
     ):
         t0 = time.perf_counter()
         dev = jnp.asarray(arr)
         if h2d_s is not None:
             h2d_s.append(time.perf_counter() - t0)
         packed += dev.nbytes
-        if path in counted:
+        if i >= n_keys:
             shipped += dev.nbytes
         if len(path) == 1:
             out[path[0]] = dev
         else:
             out[path[0]][path[1]] = dev
-    # canonical keys (tags_code/fields) stay present even when empty —
-    # the pre-decode chunk structure the precompile warm args share;
-    # the compressed-only keys appear only when used
+    # tags_code/fields stay present even when empty, the encoded keys
+    # only when used: the structure precompile's warm args share
     for key in ("tags_enc", "tags_lut", "fields_enc"):
         if not out[key]:
             del out[key]
     if ship_stats is not None:
-        dense = (len(spec.tags_code) + len(spec.fields)) * C * nb * 4
-        ship_stats.append((shipped, dense, packed))
+        ship_stats.append((shipped, packed))
     return out
 
 

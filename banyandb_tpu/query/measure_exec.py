@@ -658,8 +658,8 @@ class Partials:
     def content_bytes(self) -> bytes:
         """Canonical byte serialization of every numeric/representative
         component — THE byte-parity oracle the A/B contracts
-        (``BYDB_DEVICE_DECODE``, ``BYDB_PIPELINE``, one dispatch against
-        chunk batches) are asserted against (tests/test_fused_exec.py,
+        (``BYDB_PIPELINE``, one dispatch against chunk batches, zone
+        skipping) are asserted against (tests/test_fused_exec.py,
         tests/test_decode.py, scripts/decode_smoke.py all compare this
         one serialization, so a new Partials field added here is
         parity-pinned everywhere at once)."""
@@ -838,13 +838,6 @@ def compute_partials(
     elif dict_over_bound:
         obs_metrics.global_meter().counter_add("dict_state_kept")
 
-    # the compressed-ship flag is read ONCE per query and pinned into the
-    # gather cache key: the two ship forms produce differently-shaped
-    # gathered snapshots, and a live flag flip must never serve one
-    # mode's cache entry to the other
-    from banyandb_tpu.storage import encoded as enc_mod
-
-    device_decode = enc_mod.device_decode_enabled()
     gather_key = None
     if dict_state is not None and sources and all(
         s.cache_key is not None for s in sources
@@ -857,7 +850,6 @@ def compute_partials(
             request.time_range.end_millis,
             tuple(sorted(tags_code)),
             tuple(sorted(fields)),
-            device_decode,
         )
 
     # span tags of the gather that runs (none on a serving-cache hit),
@@ -874,7 +866,6 @@ def compute_partials(
             request.time_range.begin_millis,
             request.time_range.end_millis,
             dict_state=dict_state,
-            device_decode=device_decode,
             tags_out=gather_tags,
             luts_out=gather_luts,
         )
@@ -1253,10 +1244,8 @@ def _reduce_partials(
     # whether the pad thunks ran or waited, one (off-CPU seconds, minor
     # faults) pair a thunk: read when this query's spans read their clocks
     pack_use: list | None = [] if span is not None and span.usage else None
-    # (shipped, dense, packed) bytes per batch: the decode span's
-    # compression evidence (dense = what the decoded i32/f32 ship form
-    # would have moved for the same tag and field columns), and every
-    # array the batch padded and shipped (packed: its key columns too)
+    # (shipped, packed) bytes per batch: the tag and field columns, and
+    # every array the batch padded and shipped (its key columns too)
     ship_stats: list = []
 
     chunk_spans = []
@@ -1369,35 +1358,23 @@ def _reduce_partials(
     # host half = narrow pack + pad (pack_s) + H2D ship (h2d_s): column
     # j+1 pads while column j ships under BYDB_PIPELINE; the device half
     # (widen/remap/f32 convert) is fused into the plan dispatch and is
-    # deliberately part of device_execute.  Byte counters attribute the
-    # compression ratio independently of the platform.
+    # deliberately part of device_execute.
     pack_ms = sum(pack_s) * 1000
     h2d_ms = sum(h2d_s) * 1000
     decode_ms = pack_ms + h2d_ms
-    shipped_bytes = sum(s for s, _, _ in ship_stats)
-    dense_bytes = sum(d for _, d, _ in ship_stats)
-    packed_bytes = sum(p for _, _, p in ship_stats)
-    decode_mode = "device" if "src_ord" in chunks_np else "host"
+    shipped_bytes = sum(s for s, _ in ship_stats)
+    packed_bytes = sum(p for _, p in ship_stats)
     _H_DECODE.observe(decode_ms)
     if ship_stats:
-        meter = obs_metrics.global_meter()
-        meter.counter_add(
-            "decode_ship_bytes", float(shipped_bytes), labels={"form": "shipped"}
-        )
-        meter.counter_add(
-            "decode_ship_bytes", float(dense_bytes), labels={"form": "dense"}
+        obs_metrics.global_meter().counter_add(
+            "decode_ship_bytes", float(shipped_bytes)
         )
     if dspan is not None:
-        dspan.tag("mode", decode_mode).tag(
-            "host_ms", round(decode_ms, 3)
-        ).tag("pack_ms", round(pack_ms, 3)).tag(
-            "h2d_ms", round(h2d_ms, 3)
-        ).tag("shipped_bytes", shipped_bytes).tag(
-            "dense_bytes", dense_bytes
-        ).tag("packed_bytes", packed_bytes).tag(
-            "ratio",
-            round(dense_bytes / shipped_bytes, 2) if shipped_bytes else 1.0,
-        )
+        dspan.tag("host_ms", round(decode_ms, 3)).tag(
+            "pack_ms", round(pack_ms, 3)
+        ).tag("h2d_ms", round(h2d_ms, 3)).tag(
+            "shipped_bytes", shipped_bytes
+        ).tag("packed_bytes", packed_bytes)
         if pack_use is not None:
             # of pack_ms, what the pad thread did not run (it waited for
             # the interpreter), and its minor page faults in the thunks
@@ -1755,7 +1732,6 @@ def _gather_rows(
     begin_millis: int,
     end_millis: int,
     dict_state: Optional[DictState] = None,
-    device_decode: bool = False,
     tags_out: Optional[dict] = None,
     luts_out: Optional[dict] = None,
 ) -> dict:
@@ -1784,15 +1760,15 @@ def _gather_rows(
     ``(dict_state.remaps key or None, table)`` of every source's remap
     table, the input of ``DictState.note_live``.
 
-    ``device_decode`` (ROADMAP item 3, ``BYDB_DEVICE_DECODE``): the
-    gathered snapshot keeps tag columns in the COMPRESSED ship form —
-    per-row narrow LOCAL codes (``tags_enc``), the per-source
-    local->global LUTs (``tags_lut``) and a per-row source ordinal
-    (``src_ord``) — instead of materializing the remapped i32 columns;
-    the widen + remap run on device inside the plan kernel
-    (ops.decode.decode_chunk).  Fields stay host-f64 (the exact host
-    paths need them) but carry a ``fields_narrow`` dtype decision so the
-    pad/ship stage can ship exact-int columns at i8/i16."""
+    The snapshot keeps tag columns compressed: per-row narrow LOCAL
+    codes (``tags_enc``), the per-source local->global LUTs
+    (``tags_lut``) and a per-row source ordinal (``src_ord``); the
+    widen + remap run on device inside the plan kernel
+    (ops.decode.decode_chunk), and host paths that need global codes
+    compose them with ``_host_tag_codes``.  Fields stay host-f64 (the
+    exact host paths need them) but carry a ``fields_narrow`` dtype
+    decision so the pad/ship stage can ship exact-int columns at
+    i8/i16."""
     import time as _time
 
     from banyandb_tpu.storage import encoded as enc_mod
@@ -1823,8 +1799,7 @@ def _gather_rows(
             series_l.append(src.series[rng])
             if n_src in dedups:
                 ver_l[n_src] = src.version[rng]
-            if device_decode:
-                ord_l.append(np.full(nsel, n_src, dtype=enc_mod.SRC_ORD_DTYPE))
+            ord_l.append(np.full(nsel, n_src, dtype=enc_mod.SRC_ORD_DTYPE))
             for t in tags_code:
                 col = src.tags.get(t)
                 if col is None:
@@ -1836,15 +1811,11 @@ def _gather_rows(
                             absent = gd.absent_code(t)
                     else:
                         absent = gd.absent_code(t)
+                    # a one-entry LUT row and local code 0 everywhere:
+                    # the remap lands the global absent code
                     absent_lut = np.asarray([absent], dtype=np.int32)
-                    if device_decode:
-                        # compressed form: a one-entry LUT row and local
-                        # code 0 everywhere — the device remap lands the
-                        # same global absent code the dense path bakes in
-                        tc_l[t].append(np.zeros(nsel, dtype=np.int8))
-                        lut_l[t].append(absent_lut)
-                    else:
-                        tc_l[t].append(np.full(nsel, absent, dtype=np.int32))
+                    tc_l[t].append(np.zeros(nsel, dtype=np.int8))
+                    lut_l[t].append(absent_lut)
                     if t in luts_out:
                         luts_out[t].append((None, absent_lut))
                 else:
@@ -1854,21 +1825,13 @@ def _gather_rows(
                     lut_entries += walked
                     if t in luts_out:
                         luts_out[t].append((rk, lut))
-                    codes = col[rng]
-                    if device_decode:
-                        if lut.size:
-                            w = enc_mod.code_dtype(lut.size)
-                            tc_l[t].append(codes.astype(w, copy=False))
-                            lut_l[t].append(lut)
-                        else:
-                            tc_l[t].append(np.zeros(nsel, dtype=np.int8))
-                            lut_l[t].append(np.zeros(1, dtype=np.int32))
+                    if lut.size:
+                        w = enc_mod.code_dtype(lut.size)
+                        tc_l[t].append(col[rng].astype(w, copy=False))
+                        lut_l[t].append(lut)
                     else:
-                        tc_l[t].append(
-                            lut[codes]
-                            if lut.size
-                            else np.zeros(nsel, np.int32)
-                        )
+                        tc_l[t].append(np.zeros(nsel, dtype=np.int8))
+                        lut_l[t].append(np.zeros(1, dtype=np.int32))
             for f in fields:
                 col = src.fields.get(f)
                 if col is None:
@@ -1887,21 +1850,15 @@ def _gather_rows(
     if not ts_l:
         if tags_out is not None:
             tags_out["select_ms"] = round(select_s * 1000, 3)
-        empty = dict(
+        return dict(
             ts=np.zeros(0, np.int64),
             series=np.zeros(0, np.int64),
             fields={f: np.zeros(0, np.float64) for f in fields},
+            tags_enc={t: np.zeros(0, np.int8) for t in tags_code},
+            tags_lut={t: () for t in tags_code},
+            src_ord=np.zeros(0, enc_mod.SRC_ORD_DTYPE),
+            fields_narrow={f: np.dtype(np.int8) for f in fields},
         )
-        if device_decode:
-            empty["tags_enc"] = {t: np.zeros(0, np.int8) for t in tags_code}
-            empty["tags_lut"] = {t: () for t in tags_code}
-            empty["src_ord"] = np.zeros(0, enc_mod.SRC_ORD_DTYPE)
-            empty["fields_narrow"] = {f: np.dtype(np.int8) for f in fields}
-        else:
-            empty["tags_code"] = {
-                t: np.zeros(0, np.int32) for t in tags_code
-            }
-        return empty
 
     # Same order of allocations as ever: each column is concatenated and
     # taken in one expression, so its temporary is freed before the next
@@ -1970,20 +1927,17 @@ def _gather_rows(
         ts=take(ts),
         series=take(series),
         fields={f: take(cat(f_l[f])) for f in fields},
+        # mixed per-source widths promote to the widest (np.concatenate's
+        # int promotion), values untouched
+        tags_enc={t: take(cat(tc_l[t])) for t in tags_code},
+        tags_lut={t: tuple(lut_l[t]) for t in tags_code},
+        src_ord=take(cat(ord_l)),
     )
-    if device_decode:
-        # narrow gather: mixed per-source widths promote to the widest
-        # (np.concatenate's int promotion), values untouched
-        out["tags_enc"] = {t: take(cat(tc_l[t])) for t in tags_code}
-        out["tags_lut"] = {t: tuple(lut_l[t]) for t in tags_code}
-        out["src_ord"] = take(cat(ord_l))
-        t0 = _time.perf_counter()
-        out["fields_narrow"] = {
-            f: enc_mod.narrow_int_dtype(out["fields"][f]) for f in fields
-        }
-        take_s += _time.perf_counter() - t0
-    else:
-        out["tags_code"] = {t: take(cat(tc_l[t])) for t in tags_code}
+    t0 = _time.perf_counter()
+    out["fields_narrow"] = {
+        f: enc_mod.narrow_int_dtype(out["fields"][f]) for f in fields
+    }
+    take_s += _time.perf_counter() - t0
     meter = obs_metrics.global_meter()
     meter.counter_add("gather_rows", n - n_dedup, labels={"dedup": "skipped"})
     meter.counter_add("gather_rows", n_dedup, labels={"dedup": "sorted"})
@@ -2004,14 +1958,10 @@ def _gather_rows(
 def _host_tag_codes(
     cols: dict, tag: str, rows: Optional[np.ndarray] = None
 ) -> np.ndarray:
-    """Global i32 codes for `tag` from a gathered snapshot, either ship
-    form.  The compressed form (device_decode) materializes host-side
-    only where the host genuinely needs values — the exact-f64 float
-    path and per-group representative rows — via the same
-    local->global LUT composition the device kernel applies."""
-    if "tags_code" in cols:
-        col = cols["tags_code"][tag]
-        return col if rows is None else col[rows]
+    """Global i32 codes for `tag` from a gathered snapshot (of its
+    `rows`, or all), for the host paths that need values — the exact-f64
+    float path, per-group representative rows and the mesh pack — by
+    the same local->global LUT composition the device kernel applies."""
     codes = cols["tags_enc"][tag]
     src_ord = cols["src_ord"]
     if rows is not None:
@@ -2027,9 +1977,8 @@ def _host_tag_codes(
 
 
 def _materialize_tag_codes(cols: dict, tags: Sequence[str]) -> dict:
-    """Snapshot with dense i32 ``tags_code`` present (host-path input)."""
-    if "tags_code" in cols:
-        return cols
+    """Snapshot with global i32 ``tags_code`` of `tags` added (host-path
+    input)."""
     out = dict(cols)
     out["tags_code"] = {t: _host_tag_codes(cols, t) for t in tags}
     return out

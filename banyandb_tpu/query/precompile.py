@@ -215,22 +215,6 @@ def _key_column_structs(spec, shape) -> dict:
     }
 
 
-def fused_chunk_struct(fspec) -> dict:
-    """ShapeDtypeStruct pytree matching fused_exec._stacked_chunks in the
-    dense ship form."""
-    import jax
-    import jax.numpy as jnp
-
-    S = jax.ShapeDtypeStruct
-    spec = fspec.plan
-    shape = (fspec.num_chunks, spec.nrows)
-    return {
-        **_key_column_structs(spec, shape),
-        "tags_code": {t: S(shape, jnp.int32) for t in spec.tags_code},
-        "fields": {f: S(shape, jnp.float32) for f in spec.fields},
-    }
-
-
 def _decode_lut_len(spec, t: str) -> int:
     for tag, radix in zip(spec.group_tags, spec.radices):
         if tag == t:
@@ -256,22 +240,22 @@ def _decode_code_dtype(spec, t: str):
     return jnp.dtype(_np.int8)
 
 
-def fused_decode_chunk_struct(fspec) -> dict:
-    """ShapeDtypeStruct pytree for the COMPRESSED ship form of a fused
-    part-batch (``BYDB_DEVICE_DECODE``, fused_exec._stacked_chunks'
-    compressed branch), at a canonical single-source shape:
+def fused_chunk_struct(fspec, float_fields: bool = False) -> dict:
+    """ShapeDtypeStruct pytree of a fused part-batch
+    (fused_exec._stacked_chunks) at a canonical single-source shape:
 
     - tag columns as narrow local codes (width from the group radix, i8
       otherwise) plus a ``[1, L]`` remap LUT with L
       the power-of-two bucket of the tag's radix (group tags) or 16;
     - one i16 source-ordinal column;
     - fields as i16 exact-int columns, except the histogram field
-      (percentile inputs are real-valued) which stays dense f32.
+      (percentile inputs are real-valued) and, with ``float_fields``,
+      every field (values non-integral or wider than i16): f32.
 
     Production widths vary with the data (i8 dictionaries, multi-source
     LUT stacks — jit re-specializes per pytree); this canonical shape is
-    what the ``fused+decode/*`` budget rows lower and jaxpr-audit, the
-    same way nrows is a representative row bucket."""
+    what the ``fused/*`` budget rows lower and jaxpr-audit, the same
+    way nrows is a representative row bucket."""
     import jax
     import jax.numpy as jnp
 
@@ -279,15 +263,12 @@ def fused_decode_chunk_struct(fspec) -> dict:
     spec = fspec.plan
     c, n = fspec.num_chunks, spec.nrows
     lut_len = lambda t: _decode_lut_len(spec, t)  # noqa: E731
+    floats = [f for f in spec.fields if float_fields or f == spec.hist_field]
 
     out = {
         **_key_column_structs(spec, (c, n)),
         "tags_code": {},
-        "fields": {
-            f: S((c, n), jnp.float32)
-            for f in spec.fields
-            if f == spec.hist_field
-        },
+        "fields": {f: S((c, n), jnp.float32) for f in floats},
     }
     if spec.tags_code:
         out["tags_enc"] = {
@@ -298,25 +279,10 @@ def fused_decode_chunk_struct(fspec) -> dict:
             t: S((1, lut_len(t)), jnp.int32) for t in spec.tags_code
         }
         out["src_ord"] = S((c, n), jnp.int16)
-    enc = {
-        f: S((c, n), jnp.int16)
-        for f in spec.fields
-        if f != spec.hist_field
-    }
+    enc = {f: S((c, n), jnp.int16) for f in spec.fields if f not in floats}
     if enc:
         out["fields_enc"] = enc
     return out
-
-
-def builtin_fused_decode():
-    """(name, FusedSpec) pairs for the ``fused+decode/*`` audit rows —
-    the SAME FusedSpecs as builtin_fused() (the ship form is not part of
-    the plan signature), paired by the kernel audit with the compressed
-    chunk structs from fused_decode_chunk_struct."""
-    return tuple(
-        (name.replace("fused/", "fused+decode/"), fspec)
-        for name, fspec in builtin_fused()
-    )
 
 
 def _whole_scan_warm_args(fspec) -> tuple:
@@ -329,26 +295,13 @@ def _whole_scan_warm_args(fspec) -> tuple:
     return (None, jnp.zeros(fspec.quantiles, jnp.float32))
 
 
-def fused_warm_args(fspec) -> tuple:
-    """Zero-filled production-shaped args for one fused plan program."""
+def fused_warm_args(fspec, float_fields: bool = False) -> tuple:
+    """Zero-filled args of one fused plan program at the canonical
+    widths (``fused_chunk_struct``)."""
     import jax.numpy as jnp
 
     return (
-        _zeros_like_structs(fused_chunk_struct(fspec)),
-        _zeros_like_structs(pred_struct(fspec.plan)),
-        jnp.float32(0.0),
-        jnp.float32(1.0),
-        *_whole_scan_warm_args(fspec),
-    )
-
-
-def fused_decode_warm_args(fspec) -> tuple:
-    """Warm args for the COMPRESSED fused ship form (the production
-    default under ``BYDB_DEVICE_DECODE=1``) at the canonical widths."""
-    import jax.numpy as jnp
-
-    return (
-        _zeros_like_structs(fused_decode_chunk_struct(fspec)),
+        _zeros_like_structs(fused_chunk_struct(fspec, float_fields)),
         _zeros_like_structs(pred_struct(fspec.plan)),
         jnp.float32(0.0),
         jnp.float32(1.0),
@@ -577,21 +530,19 @@ class PrecompileRegistry:
         import jax
 
         from banyandb_tpu.query import fused_exec, stream_exec
-        from banyandb_tpu.storage import encoded as enc_mod
 
-        # fused kernels trace per chunk-pytree STRUCTURE, and the
-        # compressed ship form (BYDB_DEVICE_DECODE, default on) is a
-        # different structure from the dense one — warm the form(s)
-        # production queries will actually resolve, at the canonical
-        # decode widths
+        # fused kernels trace per chunk-pytree STRUCTURE: warm the one a
+        # batch of canonical-width data resolves and, for a plan with no
+        # tag column, the batch whose fields ship as f32 (nothing of it
+        # is encoded then)
         if kind == "fused":
             cache, build = (
                 fused_exec._KERNEL_CACHE,
                 fused_exec._build_kernel,
             )
             args_list = [fused_warm_args(spec)]
-            if enc_mod.device_decode_enabled():
-                args_list.append(fused_decode_warm_args(spec))
+            if not spec.plan.tags_code:
+                args_list.append(fused_warm_args(spec, float_fields=True))
         elif kind == "stream_mask":
             cache, build = (
                 stream_exec._KERNEL_CACHE,
@@ -603,7 +554,7 @@ class PrecompileRegistry:
         kernel = cache.get(spec)
         if kernel is None:
             kernel = cache[spec] = build(spec)
-        # one dispatch per ship form on zero args of the production
+        # one dispatch per structure on zero args of the production
         # shapes: populates the jit executable cache AND (through
         # utils/compile_cache) the persistent XLA cache; values are
         # irrelevant to the cache key

@@ -18,9 +18,6 @@ narrow-code mode) and the query executors (the pad/ship stage feeding
   f64 -> f32 cast because int -> f32 conversion of values within the
   narrow range is exact from either source width.
 
-``BYDB_DEVICE_DECODE`` (default on) is an A/B flag: flipping it live
-must be byte-identical on partials bytes and result JSON (tests/test_fused_exec.py +
-tests/test_decode.py pin this across every builtin plan signature).
 ``BYDB_ZONE_SKIP`` (default on) gates the zone-map block skipping half
 of the same ROADMAP item (storage/part.select_blocks).
 """
@@ -33,12 +30,6 @@ from banyandb_tpu.utils.envflag import env_flag
 
 # source-ordinal column dtype: a part-batch never exceeds i16 sources
 SRC_ORD_DTYPE = np.int16
-
-
-def device_decode_enabled() -> bool:
-    """The device-decode A/B flag; default on, read per call so tests
-    and operators can flip it live."""
-    return env_flag("BYDB_DEVICE_DECODE", default=True)
 
 
 def zone_skip_enabled() -> bool:

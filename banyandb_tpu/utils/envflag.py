@@ -66,7 +66,6 @@ FLAGS: dict[str, str] = {
     "BYDB_AUTOREG_MIN_HITS": "int: query-shape hits before autoreg",
     "BYDB_CONFIG": "str: server config file path (CLI --config wins)",
     "BYDB_DEVICE_CACHE_BYTES": "int: device-resident block cache budget",
-    "BYDB_DEVICE_DECODE": "bool: decode encoded blocks on-device",
     "BYDB_FAULTS": "str: fault-injection schedule spec (cluster/faults)",
     "BYDB_FUSED_MAX_MB": "int: device-memory budget of one fused dispatch",
     "BYDB_MAX_PERSISTENT_GROUPS": (

@@ -44,10 +44,8 @@ echo "== cold-path smoke =="
 env JAX_PLATFORMS=cpu python scripts/cold_smoke.py || fail=1
 
 echo "== device-decode smoke =="
-# compressed-ship A/B byte parity on a real multi-block part (in one
-# dispatch and in the over-budget route's chunk batches), zone-map
-# block skipping with identical results, decode span + shipped-bytes
-# counters, fused+decode budget agreement
+# zone-map block skipping with identical results on a real multi-block
+# part, decode span + shipped-bytes counter, fused budget agreement
 # (docs/performance.md "Device-side decode & zone maps")
 env JAX_PLATFORMS=cpu python scripts/decode_smoke.py || fail=1
 

@@ -515,7 +515,6 @@ def test_registry_and_plan_audit_agree():
     audit_names = {e.name for e in default_entries()}
     builtin_names = (
         {n for n, _ in precompile.builtin_fused()}
-        | {n for n, _ in precompile.builtin_fused_decode()}
         | {n for n, _ in precompile.builtin_masks()}
     )
     missing = builtin_names - audit_names

@@ -8,10 +8,11 @@ Covers:
 - narrow width decisions: encode/decode_dict_codes_narrow at the
   i8/i16/i32 downcast boundaries, storage/encoded.narrow_int_dtype
   edges (non-integral, NaN, +-2^7/2^15 boundaries);
-- ``BYDB_DEVICE_DECODE`` A/B byte-parity (partials bytes + result JSON)
-  over multi-source gathers with mixed dictionary widths, absent tag
-  columns (schema evolution) and part-backed sources, in one dispatch
-  and in chunk batches;
+- the compressed ship held to a plain NumPy reduction over multi-source
+  gathers with mixed dictionary widths, absent tag columns (schema
+  evolution), representative tags and the exact-f64 float path, in one
+  dispatch and in chunk batches; a narrow part read byte-identical to
+  the widened one;
 - zone maps: written at flush AND merge, select_blocks skipping with
   identical results, the ``blocks_skipped_total{reason=zone}`` counter,
   whole-part exclusion, OR criteria disabling pruning;
@@ -51,6 +52,7 @@ from banyandb_tpu.storage import encoded
 from banyandb_tpu.storage.part import ColumnData, Part, PartWriter
 from banyandb_tpu.utils import compress as zst
 from banyandb_tpu.utils import encoding as enc
+from tests._golden_infra import numpy_exec
 
 T0 = 1_700_000_000_000
 
@@ -221,7 +223,7 @@ def test_narrow_int_dtype_edges():
     assert nd(np.array([128.0])) == np.int16
     assert nd(np.array([-32768.0, 32767.0])) == np.int16
     assert nd(np.array([32768.0])) is None  # i32 ship wins nothing
-    assert nd(np.array([1.5])) is None  # non-integral -> dense f32
+    assert nd(np.array([1.5])) is None  # non-integral -> ships f32
     assert nd(np.array([1.0, np.nan])) is None
     assert nd(np.array([np.inf])) is None
 
@@ -274,7 +276,7 @@ def test_pack_luts_shapes():
     assert out3.shape == (4, 1)
 
 
-# -- gather-level A/B parity -------------------------------------------------
+# -- the compressed ship against NumPy ----------------------------------------
 
 
 def _measure(fields=(("v", FieldType.INT),)):
@@ -319,12 +321,31 @@ def _result_json(m, req, p) -> str:
     )
 
 
+def _tag_values(src, tag) -> np.ndarray:
+    """Each row's value of `tag` (the empty value where the source
+    predates the tag), from the source's own dictionary."""
+    if tag not in src.tags:
+        return np.full(src.ts.shape[0], b"", dtype=object)
+    return np.asarray(src.dicts[tag], dtype=object)[src.tags[tag]]
+
+
+def _numpy_by_group(srcs, tag, field, begin, end) -> dict:
+    """{(value,): the field's values in row order} over the rows of
+    `srcs` in [begin, end): the plain NumPy reduction (no two sources
+    share a key here, so nothing dedups)."""
+    sel = [(s.ts >= begin) & (s.ts < end) for s in srcs]
+    keys = np.concatenate([_tag_values(s, tag)[m] for s, m in zip(srcs, sel)])
+    vals = np.concatenate([s.fields[field][m] for s, m in zip(srcs, sel)])
+    return numpy_exec(np.ones(len(vals), dtype=bool), [keys], vals)
+
+
 @pytest.mark.parametrize(
     "batches", [False, True], ids=["one-batch", "chunk-batches"]
 )
 def test_decode_parity_multi_source_mixed_widths(batches, monkeypatch):
     """3 sources with i8/i16/i32-wide dictionaries, real remap, absent
-    column in one source: compressed ship == dense ship byte-for-byte."""
+    column in one source: the compressed ship's groups, counts and sums
+    are NumPy's over the same rows."""
     m = _measure()
     srcs = [
         _src(3000, 5, 1),
@@ -345,12 +366,13 @@ def test_decode_parity_multi_source_mixed_widths(batches, monkeypatch):
         from banyandb_tpu.query import measure_exec
 
         monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "0")
-    p_dense = compute_partials(m, req, srcs)
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
-    p_dec = compute_partials(m, req, srcs)
-    assert _partial_bytes(p_dense) == _partial_bytes(p_dec)
-    assert _result_json(m, req, p_dense) == _result_json(m, req, p_dec)
+    p = compute_partials(m, req, srcs)
+    want = _numpy_by_group(srcs, "svc", "v", T0, T0 + 10_000)
+    assert sorted(p.groups) == sorted(want)
+    for i, g in enumerate(p.groups):
+        assert p.count[i] == len(want[g])
+        # int values, f32 partials: exact to 2^24 a chunk
+        np.testing.assert_allclose(p.sums["v"][i], want[g].sum(), rtol=1e-6)
 
 
 def test_decode_parity_rep_tags_and_float_path(monkeypatch):
@@ -387,33 +409,36 @@ def test_decode_parity_rep_tags_and_float_path(monkeypatch):
         tag_projection=("svc", "az"),
         agg=Aggregation("mean", "lat"),
     )
-    outs = []
-    for flag in ("0", "1"):
-        monkeypatch.setenv("BYDB_DEVICE_DECODE", flag)
-        p = compute_partials(m, req, [src])
-        outs.append((_partial_bytes(p), _result_json(m, req, p)))
-    assert outs[0] == outs[1]
-    assert p.rep_vals and "az" in p.rep_vals  # rep decode ran
+    p = compute_partials(m, req, [src])
+    want = _numpy_by_group([src], "svc", "lat", T0, T0 + n)
+    assert sorted(p.groups) == sorted(want)
+    svc, az = _tag_values(src, "svc"), _tag_values(src, "az")
+    for i, g in enumerate(p.groups):
+        assert p.count[i] == len(want[g])
+        # f64 host partials: equal but for the order of summation
+        np.testing.assert_allclose(p.sums["lat"][i], want[g].sum(), rtol=1e-12)
+        # the representative: the group's first row in the scan (ts asc)
+        assert p.rep_vals["az"][i] == az[np.flatnonzero(svc == g[0])[0]]
 
 
 def test_host_tag_codes_matches_dense(monkeypatch):
+    """The global codes composed on the host are each source's own
+    `lut[codes]`, the lut mapping its dictionary into the global one."""
     from banyandb_tpu.query.measure_exec import GlobalDicts, _gather_rows
 
     srcs = [_src(1000, 5, 1), _src(1000, 300, 2, toff=2000)]
-    outs = {}
-    for decode in (False, True):
-        gd = GlobalDicts(["svc"])
-        outs[decode] = _gather_rows(
-            srcs, ["svc"], ["v"], gd, T0, T0 + 5000, device_decode=decode
-        )
-    dense = outs[False]["tags_code"]["svc"]
-    assert np.array_equal(_host_tag_codes(outs[True], "svc"), dense)
+    gd = GlobalDicts(["svc"])
+    cols = _gather_rows(srcs, ["svc"], ["v"], gd, T0, T0 + 5000)
+    glob = {v: c for c, v in enumerate(gd.values("svc"))}
+    dense = np.concatenate([
+        np.asarray([glob[v] for v in s.dicts["svc"]], np.int32)[s.tags["svc"]]
+        for s in srcs
+    ])
+    assert np.array_equal(_host_tag_codes(cols, "svc"), dense)
     rows = np.array([0, 5, 999, 1500])
-    assert np.array_equal(
-        _host_tag_codes(outs[True], "svc", rows), dense[rows]
-    )
-    # narrow form really is narrow
-    assert outs[True]["tags_enc"]["svc"].dtype.itemsize < 4
+    assert np.array_equal(_host_tag_codes(cols, "svc", rows), dense[rows])
+    # the snapshot really is narrow
+    assert cols["tags_enc"]["svc"].dtype.itemsize < 4
 
 
 def test_part_backed_narrow_read_parity(tmp_path, monkeypatch):
@@ -447,9 +472,7 @@ def test_part_backed_narrow_read_parity(tmp_path, monkeypatch):
         group_by=GroupBy(("svc",)),
         agg=Aggregation("sum", "v"),
     )
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
     p_n = compute_partials(m, req, [narrow])
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "0")
     p_w = compute_partials(m, req, [wide])
     assert _partial_bytes(p_n) == _partial_bytes(p_w)
 
@@ -772,7 +795,6 @@ def test_pre_upgrade_part_loads_scans_never_skips(tmp_path, monkeypatch):
         criteria=Condition("svc", "eq", "rare"),
         agg=Aggregation("count", "v"),
     )
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
     src = part.read(sel, tags=["svc"], fields=["v"], narrow_codes=True)
     p = compute_partials(m, req, [src])
     assert p.count.sum() == 40.0
@@ -797,21 +819,21 @@ def test_cli_dump_reports_zone_presence(tmp_path, capsys):
     assert cli.main(["dump", "stream", str(tmp_path / "part-1")]) == 2
 
 
-# -- precompile warm covers the compressed ship form -------------------------
+# -- precompile warm covers the shipped batch --------------------------------
 
 
 @pytest.mark.parametrize("want_rep", [True, False], ids=["rep", "no-rep"])
-@pytest.mark.parametrize("compressed", [True, False], ids=["compressed", "dense"])
+@pytest.mark.parametrize("field", ["int", "float"])
 def test_warm_structs_match_production_compressed_chunks(
-    compressed, want_rep, monkeypatch
+    field, want_rep, monkeypatch
 ):
-    """The cold-start contract: the canonical warm structs
-    (precompile.fused_decode_chunk_struct under the default flag,
-    fused_chunk_struct in the dense form) must have EXACTLY the pytree
-    structure, shapes and dtypes the pad/ship stage produces for
-    canonical-width data, for a plan that tracks scan order (its batch
-    holds ts and row) and one that does not — else warming compiles a
-    trace production never hits."""
+    """The cold-start contract: the canonical warm struct
+    (precompile.fused_chunk_struct; with ``float_fields`` for a field
+    that ships as f32) must have EXACTLY the pytree structure, shapes
+    and dtypes the pad/ship stage produces for canonical-width data,
+    with an exact-int field and with a non-integral one, for a plan
+    that tracks scan order (its batch holds ts and row) and one that
+    does not — else warming compiles a trace production never hits."""
     import dataclasses
 
     import jax
@@ -831,17 +853,18 @@ def test_warm_structs_match_production_compressed_chunks(
             "svc": r.integers(0, 8, n).astype(np.int32),
             "region": r.integers(0, 4, n).astype(np.int32),
         },
-        fields={"v": r.integers(0, 30_000, n).astype(np.float64)},  # i16
+        fields={
+            "v": r.integers(0, 30_000, n).astype(np.float64)  # i16
+            if field == "int"
+            else r.random(n) * 30_000
+        },
         dicts={
             "svc": [b"s%d" % i for i in range(8)],
             "region": [b"r%d" % i for i in range(4)],
         },
     )
     gd = GlobalDicts(["region", "svc"])
-    cols = _gather_rows(
-        [src], ["region", "svc"], ["v"], gd, T0, T0 + n,
-        device_decode=compressed,
-    )
+    cols = _gather_rows([src], ["region", "svc"], ["v"], gd, T0, T0 + n)
 
     def spec_of(tree):
         return jax.tree_util.tree_map(
@@ -850,45 +873,51 @@ def test_warm_structs_match_production_compressed_chunks(
 
     fspec = fused_exec.FusedSpec(plan=spec, num_chunks=1)
     stacked = fused_exec._stacked_chunks(cols, [(0, n)], spec, 1, T0)
-    struct = (
-        precompile.fused_decode_chunk_struct
-        if compressed
-        else precompile.fused_chunk_struct
-    )
+    struct = precompile.fused_chunk_struct(fspec, field == "float")
     fwant = jax.tree_util.tree_map(
-        lambda s: (tuple(s.shape), str(s.dtype)), struct(fspec)
+        lambda s: (tuple(s.shape), str(s.dtype)), struct
     )
     assert spec_of(stacked) == fwant
+    assert ("fields_enc" in stacked) == (field == "int")
     assert ("ts" in stacked) == ("row" in stacked) == want_rep
     assert "series" not in stacked
 
 
 def test_warm_dispatches_both_ship_forms(monkeypatch):
-    """warm() under BYDB_DEVICE_DECODE=1 compiles the dense AND the
-    compressed form of each builtin program (jit re-specializes per
-    pytree structure, so both need a boot-time trace); a ``measure``
-    evidence row of the same plan compiles nothing of its own."""
+    """warm() compiles each builtin program once, for the batch its
+    canonical-width data ships, and a plan with no tag column once more,
+    for its batch of f32 fields (jit re-specializes per pytree
+    structure); a ``measure`` evidence row of the same plan compiles
+    nothing of its own."""
     from banyandb_tpu.query import fused_exec, precompile
 
     monkeypatch.setenv("BYDB_PRECOMPILE", "1")
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
     monkeypatch.setattr(fused_exec, "_KERNEL_CACHE", {})
     r = precompile.PrecompileRegistry()
-    spec = precompile.builtin_plans()[0][1]
-    fspec = precompile.builtin_fused()[0][1]
-    assert r.warm(sigs=[("measure", spec), ("fused", fspec)]) == 1
+    (_, flat), (_, grouped) = precompile.builtin_plans()[:2]
+    (_, f_flat), (_, f_grouped) = precompile.builtin_fused()[:2]
+    assert not flat.tags_code and grouped.tags_code
+    sigs = [("measure", flat), ("fused", f_flat), ("fused", f_grouped)]
+    assert r.warm(sigs=sigs) == 2
     assert r.errors == 0
-    assert list(fused_exec._KERNEL_CACHE) == [fspec]
-    # one compiled entry per ship form
-    assert fused_exec._KERNEL_CACHE[fspec]._cache_size() == 2
+    assert list(fused_exec._KERNEL_CACHE) == [f_flat, f_grouped]
+    assert fused_exec._KERNEL_CACHE[f_grouped]._cache_size() == 1
+    assert fused_exec._KERNEL_CACHE[f_flat]._cache_size() == 2
 
 
 # -- decode span + counters --------------------------------------------------
 
 
 def test_decode_span_and_ship_counters(monkeypatch):
+    """The `decode` span's `shipped_bytes` is what the batch's tag and
+    field columns hold on the device, `decode_ship_bytes` adds it up,
+    and the compressed batch is smaller than i32 codes and f32 values
+    of the same slots."""
+    import jax
+
     from banyandb_tpu.obs.metrics import global_meter
-    from banyandb_tpu.obs.tracer import Tracer
+    from banyandb_tpu.obs.tracer import Tracer, iter_spans
+    from banyandb_tpu.query import fused_exec
 
     m = _measure()
     src = _src(5000, 5, 21)
@@ -899,30 +928,33 @@ def test_decode_span_and_ship_counters(monkeypatch):
         group_by=GroupBy(("svc",)),
         agg=Aggregation("sum", "v"),
     )
+    real_stacked = fused_exec._stacked_chunks
+    seen = []
 
-    def decode_span(tree):
-        if tree.get("name") == "decode":
-            return tree
-        for c in tree.get("children", ()):
-            hit = decode_span(c)
-            if hit is not None:
-                return hit
-        return None
+    def stacked(*args, **kwargs):
+        seen.append(real_stacked(*args, **kwargs))
+        return seen[-1]
 
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
+    monkeypatch.setattr(fused_exec, "_stacked_chunks", stacked)
+
+    def shipped_total() -> float:
+        return global_meter().snapshot()["counters"].get(
+            ("decode_ship_bytes", ()), 0.0
+        )
+
+    before = shipped_total()
     tr = Tracer("t")
     with tr.span("q") as sp:
         compute_partials(m, req, [src], span=sp)
-    tags = decode_span(tr.finish())["tags"]
-    assert tags["mode"] == "device"
-    assert 0 < tags["shipped_bytes"] < tags["dense_bytes"]
-    counters = global_meter().snapshot()["counters"]
-    assert counters.get(("decode_ship_bytes", (("form", "shipped"),), ), 0) > 0
-
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "0")
-    tr = Tracer("t")
-    with tr.span("q") as sp:
-        compute_partials(m, req, [src], span=sp)
-    tags = decode_span(tr.finish())["tags"]
-    assert tags["mode"] == "host"
-    assert tags["shipped_bytes"] == tags["dense_bytes"] > 0
+    (tags,) = [
+        s["tags"] for s in iter_spans(tr.finish()) if s["name"] == "decode"
+    ]
+    for removed in ("mode", "dense_bytes", "ratio"):
+        assert removed not in tags
+    (batch,) = seen
+    columns = {k: v for k, v in batch.items() if k not in ("ts", "valid", "row")}
+    assert tags["shipped_bytes"] == sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(columns)
+    )
+    assert 0 < tags["shipped_bytes"] < 2 * 4 * batch["valid"].size
+    assert shipped_total() - before == tags["shipped_bytes"]

@@ -288,6 +288,15 @@ def _tag_values(src, tag):
     return np.asarray(vals, dtype=object)[src.tags[tag]]
 
 
+def _criteria_tags(c) -> set:
+    """The tags a criteria tree names."""
+    if c is None:
+        return set()
+    if isinstance(c, LogicalExpression):
+        return _criteria_tags(c.left) | _criteria_tags(c.right)
+    return {c.name}
+
+
 def _numpy_mask(c, src) -> np.ndarray:
     if c is None:
         return np.ones(len(src.ts), dtype=bool)
@@ -366,34 +375,36 @@ def test_parity_all_builtin_signatures(name, monkeypatch):
 @pytest.mark.parametrize("name", [s[0] for s in _scenarios()])
 @pytest.mark.parametrize("max_mb", SIDES)
 def test_device_decode_parity_all_builtin_signatures(name, max_mb, monkeypatch):
-    """``BYDB_DEVICE_DECODE=1`` (compressed ship + in-kernel decode,
-    ISSUE 9) is byte-identical to ``=0`` on partials bytes AND result
-    JSON for every builtin plan signature, on both routes, and equal to
-    the NumPy reduction."""
+    """Every builtin plan signature, on both routes, ships its batch
+    compressed (narrow local codes + remap LUTs + source ordinals, an
+    exact-int field narrow, the percentile's real-valued one as f32),
+    decodes inside its one program, and answers the NumPy reduction."""
     m, req, srcs = next(
         (m, r, s) for n, m, r, s in _scenarios() if n == name
     )
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "0")
-    p_dense, _ = _run(m, req, srcs, max_mb, monkeypatch)
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
-    p_dec, t_dec = _run(m, req, srcs, max_mb, monkeypatch)
-    assert t_dec["dispatches"] == 1  # decode fused into the one program
-    assert _partial_bytes(p_dense) == _partial_bytes(p_dec)
-    assert _result_json(m, req, p_dense) == _result_json(m, req, p_dec)
-    _assert_matches_numpy(m, req, srcs, p_dec)
+    seen = _capture_batches(monkeypatch)
+    p, tags = _run(m, req, srcs, max_mb, monkeypatch)
+    assert tags["dispatches"] == 1  # decode fused into the one program
+    (batch,) = seen
+    want = _criteria_tags(req.criteria) | set(
+        req.group_by.tag_names if req.group_by else ()
+    )
+    assert set(batch.get("tags_enc", {})) == set(batch.get("tags_lut", {})) == want
+    assert ("src_ord" in batch) == bool(want) and not batch["tags_code"]
+    (field,) = p.sums
+    assert (field in batch["fields"]) == (name == "percentile-hist")
+    assert (field in batch.get("fields_enc", {})) == (name != "percentile-hist")
+    _assert_matches_numpy(m, req, srcs, p)
 
 
 def test_device_decode_multichunk_parity(monkeypatch):
     """Compressed ship over a multi-chunk part-batch: still one fused
-    dispatch, byte-identical to the dense multi-chunk run."""
+    dispatch, and the NumPy reduction's answer."""
     monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
     name, m, req, srcs = _scenarios()[1]
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "0")
-    p_dense, _ = _run(m, req, srcs, ONE_BATCH, monkeypatch)
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "1")
-    p_dec, t_dec = _run(m, req, srcs, ONE_BATCH, monkeypatch)
-    assert t_dec["chunks"] == 4 and t_dec["dispatches"] == 1
-    assert _partial_bytes(p_dense) == _partial_bytes(p_dec)
+    p, tags = _run(m, req, srcs, ONE_BATCH, monkeypatch)
+    assert tags["chunks"] == 4 and tags["dispatches"] == 1
+    _assert_matches_numpy(m, req, srcs, p)
 
 
 def test_multichunk_parity_one_dispatch(monkeypatch):
@@ -561,6 +572,26 @@ def test_reduce_span_and_counter_count_skipped_chunks(name, monkeypatch):
     assert (tags["chunks"], tags["chunks_skipped"]) == (chunks, 0)
 
 
+# how a scenario's field values ship: exact small ints as a narrow
+# `fields_enc` column, non-integral values as f32 `fields`
+FIELD_FORMS = ["int", "float"]
+
+
+def _field_form(srcs, form):
+    """The one-source scenario with field values of `form`: its values
+    floored ("int", under 2^7), or floored plus a half ("float")."""
+    (src,) = srcs
+    shift = 0.0 if form == "int" else 0.5
+    fields = {f: np.floor(v) + shift for f, v in src.fields.items()}
+    return [dataclasses.replace(src, fields=fields)]
+
+
+def _assert_field_form(batches, form):
+    """Every batch shipped its field in `form`'s column."""
+    key = "fields_enc" if form == "int" else "fields"
+    assert batches and all(b.get(key) for b in batches)
+
+
 def _spoil_padding(monkeypatch):
     """Have ``_stacked_chunks`` fill every per-row column of the padding
     chunks with garbage (``valid`` stays False there) -> the number of
@@ -601,16 +632,15 @@ def _spoil_padding(monkeypatch):
     return spoiled
 
 
-@pytest.mark.parametrize("decode", ["0", "1"], ids=["dense", "compressed"])
+@pytest.mark.parametrize("field", FIELD_FORMS)
 @pytest.mark.parametrize(
     "plan", ["matmul", "scatter", "sort", "pallas", "percentile"]
 )
-def test_garbage_in_padding_chunks_changes_nothing(plan, decode, monkeypatch):
+def test_garbage_in_padding_chunks_changes_nothing(plan, field, monkeypatch):
     """4 real chunks in the 8-bucket whose 4 padding chunks hold garbage
     in every column: partials byte-identical to the same scan run as
     exact one-chunk batches, for each group-by method and a percentile
-    plan, in both ship forms."""
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", decode)
+    plan, with the field shipped as a narrow int and as f32."""
     if plan == "percentile":
         monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 16384)
         _, m, req, srcs = _scenarios()[2]
@@ -619,6 +649,8 @@ def test_garbage_in_padding_chunks_changes_nothing(plan, decode, monkeypatch):
         monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 2048)
         _, m, req, srcs = _scenarios()[1]
         hints = PlanDecision(chunk_bucket=8, group_method=plan)
+    srcs = _field_form(srcs, field)
+    seen = _capture_batches(monkeypatch)
     p_exact, t_exact = _run(
         m, req, srcs, CHUNK_BATCHES, monkeypatch, plan_hints=hints
     )
@@ -631,6 +663,7 @@ def test_garbage_in_padding_chunks_changes_nothing(plan, decode, monkeypatch):
         assert t_pad["group_method"] == t_exact["group_method"] == plan
     assert _partial_bytes(p_pad) == _partial_bytes(p_exact)
     assert _result_json(m, req, p_pad) == _result_json(m, req, p_exact)
+    _assert_field_form(seen, field)
 
 
 # -- group-by strategy selection ---------------------------------------------
@@ -871,18 +904,19 @@ def _capture_batches(monkeypatch) -> list:
     return seen
 
 
-@pytest.mark.parametrize("decode", ["0", "1"], ids=["dense", "compressed"])
+@pytest.mark.parametrize("field", FIELD_FORMS)
 @pytest.mark.parametrize("plan", sorted(KEY_PLANS))
-def test_batch_holds_the_key_columns_its_program_reads(plan, decode, monkeypatch):
+def test_batch_holds_the_key_columns_its_program_reads(plan, field, monkeypatch):
     """A batch pads and ships `valid`, and `ts` and `row` only for a plan
-    that tracks scan order, beside its tag and field columns; never the
-    series id.  The answer is byte-identical to the one over a batch
-    that ships all three, and is the NumPy reduction's."""
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", decode)
+    that tracks scan order, beside its tag and field columns (the field
+    as a narrow int and as f32); never the series id.  The answer is
+    byte-identical to the one over a batch that ships all three, and is
+    the NumPy reduction's."""
     monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 16384)
     scenario, changes, want = KEY_PLANS[plan]
     m, req, srcs = next((m, r, s) for n, m, r, s in _scenarios() if n == scenario)
     req = dataclasses.replace(req, **changes)
+    srcs = _field_form(srcs, field)
     seen = _capture_batches(monkeypatch)
     p, tags = _run(m, req, srcs, ONE_BATCH, monkeypatch)
     assert tags["scan_order_tracked"] == int("ts" in want)
@@ -893,6 +927,7 @@ def test_batch_holds_the_key_columns_its_program_reads(plan, decode, monkeypatch
     assert [set(b) - NON_KEY for b in seen[1:]] == [{"ts", "valid", "row"}]
     assert _partial_bytes(p) == _partial_bytes(p_all)
     assert _result_json(m, req, p) == _result_json(m, req, p_all)
+    _assert_field_form(seen, field)
     _assert_matches_numpy(m, req, srcs, p)
 
 
@@ -917,7 +952,6 @@ def test_decode_span_and_counter_count_the_key_columns(plan, monkeypatch):
             for mode in ("shipped", "skipped")
         }
 
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", "0")
     monkeypatch.setattr(measure_exec, "SCAN_CHUNK", 16384)
     scenario, changes, want = KEY_PLANS[plan]
     m, req, srcs = next((m, r, s) for n, m, r, s in _scenarios() if n == scenario)

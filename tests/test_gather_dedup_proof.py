@@ -4,8 +4,9 @@
 sources proven disjoint and unique (storage/part.py KeySpan) and runs it
 per connected component elsewhere.  Every case here holds the result to
 the always-dedup reference — `hostops.dedup_max_version` over the whole
-concat — byte for byte, in both ship forms, and pins which rows went
-through the sort (`proven_unique_share`, the `gather_rows` counter)."""
+concat — byte for byte, over an i8-wide and an i32-wide dictionary (the
+local code width the snapshot keeps), and pins which rows went through
+the sort (`proven_unique_share`, the `gather_rows` counter)."""
 
 import dataclasses
 import json
@@ -31,7 +32,12 @@ from banyandb_tpu.api.schema import (
 )
 from banyandb_tpu.obs.metrics import global_meter
 from banyandb_tpu.query import measure_exec
-from banyandb_tpu.query.measure_exec import GlobalDicts, _gather_rows
+from banyandb_tpu.query.measure_exec import (
+    GlobalDicts,
+    _gather_rows,
+    _host_tag_codes,
+)
+from banyandb_tpu.storage import encoded
 from banyandb_tpu.storage.part import (
     ColumnData,
     KeyInterval,
@@ -43,7 +49,12 @@ from banyandb_tpu.utils import hostops
 
 T0 = 1_700_000_000_000
 HOUR = 3_600_000
-SVCS = [b"svc-%d" % i for i in range(300)]  # > 127: codes need i16
+# the tag dictionary of every source: its length decides the width of
+# the local codes (encoded.code_dtype); series, and so codes, stay < 40
+DICTS = {
+    "i8": [b"svc-%d" % i for i in range(100)],
+    "i32": [b"svc-%d" % i for i in range(40_000)],
+}
 
 
 def _rows(series, hours, version=1):
@@ -55,7 +66,7 @@ def _rows(series, hours, version=1):
         series=s,
         ts=T0 + h * HOUR,
         version=np.full(s.shape, version, dtype=np.int64),
-        svc=(s % len(SVCS)).astype(np.int32),
+        svc=s.astype(np.int32),
         v=(s * 1000 + h * 10 + version).astype(np.float64),
     )
 
@@ -64,31 +75,33 @@ def _cat(*rowsets):
     return {k: np.concatenate([r[k] for r in rowsets]) for k in rowsets[0]}
 
 
-def _write(scope_dir, name, rows) -> Part:
+def _write(scope_dir, name, rows, svcs) -> Part:
     PartWriter.write(
         scope_dir / name,
         ts=rows["ts"],
         series=rows["series"],
         version=rows["version"],
         tag_codes={"svc": rows["svc"]},
-        tag_dicts={"svc": SVCS},
+        tag_dicts={"svc": svcs},
         fields={"v": rows["v"]},
         extra_meta={"measure": "m"},
     )
     return Part(scope_dir / name)
 
 
-def _read(part: Part, narrow: bool) -> ColumnData:
+def _read(part: Part) -> ColumnData:
+    """The part's rows as models/measure.py reads them: codes at their
+    stored width."""
     return part.read(
         range(len(part.blocks)),
         tags=["svc"],
         fields=["v"],
         cached=False,
-        narrow_codes=narrow,
+        narrow_codes=True,
     )
 
 
-def _hot(scope_dir, rows, narrow) -> ColumnData:
+def _hot(scope_dir, rows, svcs) -> ColumnData:
     """A memtable / flushing-snapshot source as models/measure.py hands
     it over: rows in arrival order, conservative span, nothing proven."""
     return ColumnData(
@@ -97,7 +110,7 @@ def _hot(scope_dir, rows, narrow) -> ColumnData:
         version=rows["version"],
         tags={"svc": rows["svc"]},
         fields={"v": rows["v"]},
-        dicts={"svc": SVCS},
+        dicts={"svc": svcs},
         key_span=KeySpan.unproven(str(scope_dir), rows["series"], rows["ts"]),
     )
 
@@ -106,111 +119,109 @@ def _hot(scope_dir, rows, narrow) -> ColumnData:
 # each builder -> (sources, ordinals of the sources that must be sorted)
 
 
-def _disjoint_2x2(tmp, narrow):
+def _disjoint_2x2(tmp, svcs):
     srcs = []
     for seg, hours in (("seg-a", (0, 12)), ("seg-b", (24, 36))):
         for shard in (0, 1):
             d = tmp / seg / f"shard-{shard}"
             series = range(shard, 40, 2)
             for k, h0 in enumerate(hours):
-                part = _write(d, f"part-{k}", _rows(series, range(h0, h0 + 12)))
+                part = _write(d, f"part-{k}", _rows(series, range(h0, h0 + 12)), svcs)
                 assert part.meta["unique_keys"] is True
-                srcs.append(_read(part, narrow))
+                srcs.append(_read(part))
     return srcs, []
 
 
-def _double_write_in_one_part(tmp, narrow):
+def _double_write_in_one_part(tmp, svcs):
     d = tmp / "seg-a" / "shard-0"
     dup = _cat(_rows(range(20), range(0, 6)), _rows([3, 7], [2, 4], version=2))
-    flushed = _write(d, "part-0", dup)
+    flushed = _write(d, "part-0", dup, svcs)
     assert flushed.meta["unique_keys"] is False
     srcs = [
-        _read(flushed, narrow),
-        _read(_write(d, "part-1", _rows(range(20), range(6, 12))), narrow),
-        _read(_write(d, "part-2", _rows(range(20), range(12, 18))), narrow),
+        _read(flushed),
+        _read(_write(d, "part-1", _rows(range(20), range(6, 12)), svcs)),
+        _read(_write(d, "part-2", _rows(range(20), range(12, 18)), svcs)),
     ]
     return srcs, [0]
 
 
-def _overlapping_rewrite(tmp, narrow):
+def _overlapping_rewrite(tmp, svcs):
     d = tmp / "seg-a" / "shard-0"
     srcs = [
-        _read(_write(d, "part-0", _rows(range(20), range(0, 8))), narrow),
+        _read(_write(d, "part-0", _rows(range(20), range(0, 8)), svcs)),
         # rewrites (5, hour 7) and (9, hour 6) at version 2
         _read(
             _write(d, "part-1", _cat(
                 _rows(range(20), range(8, 12)),
                 _rows([5], [7], version=2),
                 _rows([9], [6], version=2),
-            )),
-            narrow,
+            ), svcs),
         ),
-        _read(_write(d, "part-2", _rows(range(20), range(12, 20))), narrow),
+        _read(_write(d, "part-2", _rows(range(20), range(12, 20)), svcs)),
     ]
     return srcs, [0, 1]
 
 
-def _interleaved_ranges(tmp, narrow, scopes):
+def _interleaved_ranges(tmp, svcs, scopes):
     """Even series in one part, odd in another, the same hours: the key
     ranges interleave (no key is shared)."""
     a, b = (tmp / s for s in scopes)
     return [
-        _read(_write(a, "part-0", _rows(range(0, 20, 2), range(0, 6))), narrow),
-        _read(_write(b, "part-1", _rows(range(1, 19, 2), range(0, 6))), narrow),
+        _read(_write(a, "part-0", _rows(range(0, 20, 2), range(0, 6)), svcs)),
+        _read(_write(b, "part-1", _rows(range(1, 19, 2), range(0, 6)), svcs)),
     ]
 
 
-def _same_ranges_other_scope(tmp, narrow):
+def _same_ranges_other_scope(tmp, svcs):
     srcs = _interleaved_ranges(
-        tmp, narrow, ("seg-a/shard-0", "seg-a/shard-1")
+        tmp, svcs, ("seg-a/shard-0", "seg-a/shard-1")
     )
     assert srcs[0].key_span.interval.intersects(srcs[1].key_span.interval)
     return srcs, []
 
 
-def _same_ranges_one_scope(tmp, narrow):
+def _same_ranges_one_scope(tmp, svcs):
     return _interleaved_ranges(
-        tmp, narrow, ("seg-a/shard-0", "seg-a/shard-0")
+        tmp, svcs, ("seg-a/shard-0", "seg-a/shard-0")
     ), [0, 1]
 
 
-def _hot_double_expose(tmp, narrow):
+def _hot_double_expose(tmp, svcs):
     """A query racing flush's second commit: the drained rows are in the
     flushing snapshot AND in the new part; the live memtable holds a
     rewrite of an older part's key."""
     d = tmp / "seg-a" / "shard-0"
     drained = _rows(range(10), range(12, 16))
     srcs = [
-        _hot(d, _cat(_rows(range(10), range(16, 18)), _rows([4], [3], 2)), narrow),
-        _hot(d, drained, narrow),
-        _read(_write(d, "part-0", _rows(range(10), range(0, 6))), narrow),
-        _read(_write(d, "part-1", _rows(range(10), range(6, 12))), narrow),
-        _read(_write(d, "part-2", drained), narrow),
+        _hot(d, _cat(_rows(range(10), range(16, 18)), _rows([4], [3], 2)), svcs),
+        _hot(d, drained, svcs),
+        _read(_write(d, "part-0", _rows(range(10), range(0, 6)), svcs)),
+        _read(_write(d, "part-1", _rows(range(10), range(6, 12)), svcs)),
+        _read(_write(d, "part-2", drained, svcs)),
         _read(
-            _write(tmp / "seg-a" / "shard-1", "part-0", _rows(range(10, 20), range(0, 18))),
-            narrow,
+            _write(tmp / "seg-a" / "shard-1", "part-0", _rows(range(10, 20), range(0, 18)), svcs),
         ),
     ]
     # the memtable's rect [h3, h17] meets every source of its shard
     return srcs, [0, 1, 2, 3, 4]
 
 
-def _one_source_without_span(tmp, narrow):
-    srcs, _ = _disjoint_2x2(tmp, narrow)
+def _one_source_without_span(tmp, svcs):
+    srcs, _ = _disjoint_2x2(tmp, svcs)
     srcs[3] = dataclasses.replace(srcs[3], key_span=None)
     return srcs, list(range(len(srcs)))
 
 
-def _metadata_without_unique_keys(tmp, narrow):
+def _metadata_without_unique_keys(tmp, svcs):
     d = tmp / "seg-a" / "shard-0"
-    old = _write(d, "part-0", _rows(range(20), range(0, 6)))
+    old = _write(d, "part-0", _rows(range(20), range(0, 6)), svcs)
     meta = json.loads((old.dir / "metadata.json").read_text())
     del meta["unique_keys"]  # as a part written before the key existed
     (old.dir / "metadata.json").write_text(json.dumps(meta))
     old = Part(old.dir)
     srcs = [
-        _read(old, narrow),
-        _read(_write(d, "part-1", _rows(range(20), range(6, 12))), narrow),
+        _read(old),
+        _read(_write(d, "part-1", _rows(range(20), range(6, 12)), svcs)),
     ]
     assert srcs[0].key_span.unique is False
     return srcs, [0]
@@ -231,11 +242,12 @@ CASES = {
 # -- the check -------------------------------------------------------------------
 
 
-def _gather(srcs, begin, end, device_decode, tags_out=None):
+def _gather(srcs, begin, end, tags_out=None):
+    """-> (the gathered snapshot, its global dictionaries)."""
+    gd = GlobalDicts(["svc"])
     return _gather_rows(
-        srcs, ["svc"], ["v"], GlobalDicts(["svc"]), begin, end,
-        device_decode=device_decode, tags_out=tags_out,
-    )
+        srcs, ["svc"], ["v"], gd, begin, end, tags_out=tags_out
+    ), gd
 
 
 def _flat(cols: dict) -> dict:
@@ -266,7 +278,7 @@ def _counter(kind: str) -> float:
     )
 
 
-def _assert_as_always_dedup(srcs, sorted_ordinals, device_decode, monkeypatch,
+def _assert_as_always_dedup(srcs, sorted_ordinals, monkeypatch,
                             begin=T0 + HOUR, end=T0 + 30 * HOUR):
     in_range = [
         int(((s.ts >= begin) & (s.ts < end)).sum()) for s in srcs
@@ -278,7 +290,7 @@ def _assert_as_always_dedup(srcs, sorted_ordinals, device_decode, monkeypatch,
     # the reference first, through the same function with nothing proven
     bare = [dataclasses.replace(s, key_span=None) for s in srcs]
     ref_tags: dict = {}
-    ref = _gather(bare, begin, end, device_decode, ref_tags)
+    ref, _ = _gather(bare, begin, end, ref_tags)
     assert ref_tags["proven_unique_share"] == 0.0
 
     calls = []
@@ -291,7 +303,7 @@ def _assert_as_always_dedup(srcs, sorted_ordinals, device_decode, monkeypatch,
     monkeypatch.setattr(hostops, "dedup_max_version", counting)
     skipped0, sorted0 = _counter("skipped"), _counter("sorted")
     tags: dict = {}
-    got = _gather(srcs, begin, end, device_decode, tags)
+    got, gd = _gather(srcs, begin, end, tags)
     monkeypatch.setattr(hostops, "dedup_max_version", real)
 
     assert _flat(got) == _flat(ref)
@@ -301,10 +313,20 @@ def _assert_as_always_dedup(srcs, sorted_ordinals, device_decode, monkeypatch,
     series = np.concatenate([s.series[m] for s, m in zip(srcs, sel)])
     version = np.concatenate([s.version[m] for s, m in zip(srcs, sel)])
     v = np.concatenate([s.fields["v"][m] for s, m in zip(srcs, sel)])
+    svc = np.concatenate(
+        [np.asarray(s.dicts["svc"], dtype=object)[s.tags["svc"][m]]
+         for s, m in zip(srcs, sel)]
+    )
     keep = real(series, ts, version)
     assert np.array_equal(got["ts"], ts[keep])
     assert np.array_equal(got["series"], series[keep])
     assert np.array_equal(got["fields"]["v"], v[keep])
+    # the tag: local codes at the dictionary's width, their values those
+    # the rows were written with
+    width = encoded.code_dtype(len(srcs[0].dicts["svc"]))
+    assert got["tags_enc"]["svc"].dtype == width
+    values = np.asarray(gd.values("svc"), dtype=object)
+    assert np.array_equal(values[_host_tag_codes(got, "svc")], svc[keep])
     # the newest version of every key, once
     keys = set(zip(got["series"].tolist(), got["ts"].tolist()))
     assert len(keys) == got["ts"].shape[0] == len(set(zip(series.tolist(), ts.tolist())))
@@ -325,15 +347,15 @@ def _assert_as_always_dedup(srcs, sorted_ordinals, device_decode, monkeypatch,
     return got, tags
 
 
-@pytest.mark.parametrize("device_decode", [False, True], ids=["dense", "device-decode"])
+@pytest.mark.parametrize("width", sorted(DICTS))
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_gather_equals_always_dedup(tmp_path, monkeypatch, case, device_decode):
-    srcs, sorted_ordinals = CASES[case](tmp_path, device_decode)
-    _assert_as_always_dedup(srcs, sorted_ordinals, device_decode, monkeypatch)
+def test_gather_equals_always_dedup(tmp_path, monkeypatch, case, width):
+    srcs, sorted_ordinals = CASES[case](tmp_path, DICTS[width])
+    _assert_as_always_dedup(srcs, sorted_ordinals, monkeypatch)
 
 
-@pytest.mark.parametrize("device_decode", [False, True], ids=["dense", "device-decode"])
-def test_no_row_dropped_means_no_take_and_no_version(tmp_path, monkeypatch, device_decode):
+@pytest.mark.parametrize("width", sorted(DICTS))
+def test_no_row_dropped_means_no_take_and_no_version(tmp_path, monkeypatch, width):
     """Proven sources: the dedup never runs, `version` is never read and
     the concatenated columns are handed on as they are."""
 
@@ -341,14 +363,14 @@ def test_no_row_dropped_means_no_take_and_no_version(tmp_path, monkeypatch, devi
         def __getitem__(self, _):
             raise AssertionError("version read though nothing dedups")
 
-    srcs, _ = _disjoint_2x2(tmp_path, device_decode)
+    srcs, _ = _disjoint_2x2(tmp_path, DICTS[width])
     srcs = [dataclasses.replace(s, version=NoRead()) for s in srcs]
     monkeypatch.setattr(
         hostops, "dedup_max_version",
         lambda *a: pytest.fail("dedup ran over proven sources"),
     )
     tags: dict = {}
-    got = _gather(srcs, T0, T0 + 48 * HOUR, device_decode, tags)
+    got, _ = _gather(srcs, T0, T0 + 48 * HOUR, tags)
     assert tags["proven_unique_share"] == 100.0
     assert got["ts"].shape[0] == sum(s.ts.shape[0] for s in srcs)
     for col in (got["ts"], got["series"], got["fields"]["v"]):
@@ -358,21 +380,21 @@ def test_no_row_dropped_means_no_take_and_no_version(tmp_path, monkeypatch, devi
 def test_time_filter_decides_which_sources_connect(tmp_path, monkeypatch):
     """Components are built over the sources that have rows in range: a
     part wholly outside it does not chain its neighbours together."""
-    d = tmp_path / "seg-a" / "shard-0"
+    d, svcs = tmp_path / "seg-a" / "shard-0", DICTS["i8"]
     # rewrites hour 2 and holds hour 30: its rect spans both neighbours
     bridge = _cat(_rows(range(10), [2], version=2), _rows(range(10), [30]))
     srcs = [
-        _read(_write(d, "part-0", _rows(range(10), range(0, 5))), False),
-        _read(_write(d, "part-1", bridge), False),
-        _read(_write(d, "part-2", _rows(range(10), range(6, 12))), False),
+        _read(_write(d, "part-0", _rows(range(10), range(0, 5)), svcs)),
+        _read(_write(d, "part-1", bridge, svcs)),
+        _read(_write(d, "part-2", _rows(range(10), range(6, 12)), svcs)),
     ]
     # whole range: the bridge part's rect meets both neighbours
     _assert_as_always_dedup(
-        srcs, [0, 1, 2], False, monkeypatch, begin=T0, end=T0 + 40 * HOUR
+        srcs, [0, 1, 2], monkeypatch, begin=T0, end=T0 + 40 * HOUR
     )
     # hours 6..11: the bridge has no row there, so part-2 stands alone
     _assert_as_always_dedup(
-        srcs, [], False, monkeypatch, begin=T0 + 6 * HOUR, end=T0 + 12 * HOUR
+        srcs, [], monkeypatch, begin=T0 + 6 * HOUR, end=T0 + 12 * HOUR
     )
 
 

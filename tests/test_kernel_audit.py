@@ -298,7 +298,7 @@ def test_budget_table_row_count_pinned():
     """The reviewed budget-table shape: one row per audited signature.
     Adding a kernel forces a row (the table is total); dropping one
     forces deleting the row AND this pin."""
-    assert len(kernel_budgets.BUDGETS) == 20
+    assert len(kernel_budgets.BUDGETS) == 13
     assert set(kernel_budgets.BUDGETS) == {
         "fused/flat-count",
         "fused/group-eq-lut",
@@ -307,14 +307,7 @@ def test_budget_table_row_count_pinned():
         "fused/topn-dashboard",
         "fused/multi-chunk",
         "fused/dist-step",
-        "fused+decode/flat-count",
-        "fused+decode/group-eq-lut",
-        "fused+decode/percentile-hist",
-        "fused+decode/or-expr",
-        "fused+decode/topn-dashboard",
-        "fused+decode/multi-chunk",
         "stream/mask-eq-in",
-        "stream+decode/mask-eq-in",
         "ops/group_reduce",
         "ops/group_histogram",
         "parallel/dist-step",

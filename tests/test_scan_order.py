@@ -52,6 +52,9 @@ T0 = 1_700_000_000_000
 SVCS, ZONES = 64, 5
 SCAN_CHUNK = 2048  # 3 real chunks in the 4-bucket, as `topn100k.topn-24h` runs
 METHODS = ["scatter", "pallas", "sort"]
+# what each row's `hits` is: an exact int ships as a narrow `fields_enc`
+# column, a half as f32 `fields`; sums keep their ties either way
+HITS = [pytest.param(1.0, id="int"), pytest.param(0.5, id="float")]
 
 MEASURE = Measure(
     group="g",
@@ -107,13 +110,13 @@ REGION_NAMES = [b"r%d" % i for i in range(4)]
 ZONE_NAMES = [b"z%d" % i for i in range(ZONES)]
 
 
-def _source(idx: np.ndarray, cache_key=None) -> ColumnData:
+def _source(idx: np.ndarray, cache_key=None, hits=1.0) -> ColumnData:
     return ColumnData(
         ts=ROWS["ts"][idx],
         series=ROWS["svc"][idx].astype(np.int64),
         version=np.ones(len(idx), dtype=np.int64),
         tags={t: ROWS[t][idx] for t in ("svc", "region", "zone")},
-        fields={"hits": ROWS["hits"][idx]},
+        fields={"hits": ROWS["hits"][idx] * hits},
         dicts={"svc": SVC_NAMES, "region": REGION_NAMES, "zone": ZONE_NAMES},
         cache_key=cache_key,
     )
@@ -196,18 +199,18 @@ def _counted(mode: str) -> float:
 # -- (1) a TOP n answer does not read the key ---------------------------------
 
 
-@pytest.mark.parametrize("decode", ["0", "1"], ids=["dense", "compressed"])
+@pytest.mark.parametrize("hits", HITS)
 @pytest.mark.parametrize("sort", ["desc", "asc"])
 @pytest.mark.parametrize("method", METHODS)
-def test_topn_answer_is_the_tracked_plans_byte_for_byte(method, sort, decode, monkeypatch):
+def test_topn_answer_is_the_tracked_plans_byte_for_byte(method, sort, hits, monkeypatch):
     """Groups, order and values of a TOP 10 (ties at the cut on both
     sides) are those of the same query answered with tracking forced on
-    in the spec, for each group-by method and in both ship forms; the
-    plan that does not track fetches 8 B a group a chunk, the tracked
-    one 16, and its batches ship neither ts nor row."""
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", decode)
+    in the spec, for each group-by method and with the field shipped as
+    a narrow int and as f32; the plan that does not track fetches 8 B a
+    group a chunk, the tracked one 16, and its batches ship neither ts
+    nor row."""
     req = _request(top=Top(10, "hits", sort))
-    srcs = [_source(np.arange(N))]
+    srcs = [_source(np.arange(N), hits=hits)]
     skipped = _counted("skipped")
     p, spans, res = _ask(req, srcs, monkeypatch, method)
     tags, answer = spans["reduce"], json.dumps(result_to_json(res), sort_keys=True)
@@ -243,7 +246,7 @@ def test_topn_answer_is_the_tracked_plans_byte_for_byte(method, sort, decode, mo
     want = head + tied[: 10 - len(head)]
     assert 0 < len(head) < 10 < len(head) + len(tied)
     assert got["groups"] == [[SVC_NAMES[s].decode()] for s in want]
-    assert got["values"]["sum(hits)"] == [float(sums[s]) for s in want]
+    assert got["values"]["sum(hits)"] == [float(sums[s] * hits) for s in want]
 
 
 def test_order_by_time_desc_is_the_same_topn_program(monkeypatch):
@@ -282,20 +285,19 @@ def test_topn_that_projects_a_tag_keeps_its_representative_row(order, monkeypatc
     assert plain.groups == res.groups and not plain.rep_tags
 
 
-@pytest.mark.parametrize("decode", ["0", "1"], ids=["dense", "compressed"])
+@pytest.mark.parametrize("hits", HITS)
 @pytest.mark.parametrize("order", ["", "desc"])
 @pytest.mark.parametrize("method", METHODS)
-def test_listing_pages_in_first_appearance_order(method, order, decode, monkeypatch):
+def test_listing_pages_in_first_appearance_order(method, order, hits, monkeypatch):
     """A listing tracks: LIMIT / OFFSET page through the groups in the
     order their first row appears in the scan, asc and ORDER BY time
-    DESC, in both ship forms."""
-    monkeypatch.setenv("BYDB_DEVICE_DECODE", decode)
+    DESC, with the field shipped as a narrow int and as f32."""
     first = _first_seen(desc=order == "desc")
     ts = ROWS["ts"]
     by_first = sorted(first, key=lambda s: -ts[first[s]] if order == "desc" else ts[first[s]])
     want = [[SVC_NAMES[s].decode()] for s in by_first]
     assert len(want) == SVCS and want != sorted(want)
-    srcs = [_source(np.arange(N))]
+    srcs = [_source(np.arange(N), hits=hits)]
     pages = []
     for offset, limit in ((0, 10), (10, 30), (40, SVCS)):
         req = _request(limit=limit, offset=offset, order_by_ts=order)

@@ -77,18 +77,16 @@ def test_mesh_steps_lower_for_tpu_with_pallas_inside_shard_map(tpu_routing):
 
 
 def test_builtin_plans_lower_for_tpu(tpu_routing):
-    """The TopN dashboard plan (G=1024 -> pallas) in both forms the
-    server dispatches: the dense and the compressed ship."""
+    """The TopN dashboard plan (G=1024 -> pallas) in both structures
+    its batch ships: the field as a narrow int, and as f32."""
     from banyandb_tpu.query import fused_exec
 
     fspec = dict(precompile.builtin_fused())["fused/topn-dashboard"]
     preds = precompile.pred_struct(fspec.plan)
     # a fresh build: never the executor's process-global kernel cache
     kernel = fused_exec._build_kernel(fspec)
-    for chunk in (
-        precompile.fused_chunk_struct(fspec),
-        precompile.fused_decode_chunk_struct(fspec),
-    ):
+    for float_fields in (False, True):
+        chunk = precompile.fused_chunk_struct(fspec, float_fields)
         assert "tpu_custom_call" in _lower_tpu(kernel, chunk, preds, *_SCALARS)
 
 
@@ -114,10 +112,8 @@ def test_ep9k_plan_lowers_for_tpu_as_xla_scatter(tpu_routing):
     assert groupby.select_group_method(spec.nrows, spec.num_groups) == "scatter"
     fspec = fused_exec.FusedSpec(plan=spec, num_chunks=4)
     kernel = fused_exec._build_kernel(fspec)  # a fresh build, not the cache
-    for chunk in (
-        precompile.fused_decode_chunk_struct(fspec),
-        precompile.fused_chunk_struct(fspec),
-    ):
+    for float_fields in (False, True):
+        chunk = precompile.fused_chunk_struct(fspec, float_fields)
         text = _lower_tpu(kernel, chunk, precompile.pred_struct(spec), *_SCALARS)
         assert "tpu_custom_call" not in text
         assert "stablehlo.scatter" in text and "stablehlo.sort" not in text
